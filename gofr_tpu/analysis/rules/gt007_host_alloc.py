@@ -5,8 +5,8 @@ The zero-copy data plane (ISSUE 9) exists because ``np.asarray`` +
 decode loops were the measured gap between the served path and the
 hardware. The staging pool (``gofr_tpu/tpu/staging.py``) kills those
 copies; this rule keeps them dead — a fresh host allocation on a
-dispatch path is exactly the regression the bench's relay block would
-take rounds to re-attribute.
+dispatch path is exactly the regression a benchmark would take rounds
+to re-attribute.
 
 Detection (v2, whole-program): take every function reachable from a
 *dispatch root* — a function whose name is

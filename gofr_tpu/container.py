@@ -250,7 +250,8 @@ class Container:
             (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1, 3))
         # zero-copy data plane (ISSUE 9): every host→device transfer —
         # staged dispatch uploads, coalesced tick inputs, adopted KV —
-        # lands here, so the bench's relay gap is attributable per path
+        # lands here, so the gap between the served path and the device
+        # is attributable per path
         metrics.new_updown_counter(
             "app_tpu_h2d_bytes_total",
             "host→device bytes shipped, per path "

@@ -55,8 +55,8 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     # pallas flash-attention prefill (ops/pallas): O(S) memory, causal-block
-    # skipping — required beyond ~8K context on one core; falls back to the
-    # dense einsum when shapes don't meet TPU tiling constraints
+    # skipping — required beyond ~8K context on one core; sequence lengths
+    # Mosaic cannot tile take the dense einsum (_prefill_attend)
     use_flash: bool = False
     # pallas decode attention (ops/pallas/decode_attention): numerics
     # verified, but MEASURED ~5x SLOWER at 7B geometry — a
@@ -141,6 +141,74 @@ def init(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     }
 
 
+def init_int8(cfg: LlamaConfig, mesh=None) -> Dict[str, Any]:
+    """Random params with int8 matmul weights (the ``quantize_params``
+    layout), seeded, for serving runs where only the layout matters.
+
+    Every leaf is made by its own ``jit`` directly in its final dtype —
+    and, with ``mesh``, directly into its ``llama_param_specs`` sharding
+    (``out_shardings``) — so the 7B geometry never exists in float32 and
+    no leaf is ever whole on one device. ``init`` + ``quantize_params``
+    builds ``w_gate`` alone as 5.8 GB of float32 first, which a 16 GB
+    chip does not survive. Scales are sized so dequantized weights look
+    ~N(0, 1/fan_in)."""
+    d, f, l_count = cfg.dim, cfg.ffn_dim, cfg.n_layers
+    qd = cfg.n_heads * cfg.head_dim
+    kvd = cfg.n_kv_heads * cfg.head_dim
+
+    def qrand(seed, *shape):
+        def make():
+            q = jax.random.randint(jax.random.PRNGKey(seed), shape, -127,
+                                   128, jnp.int8)
+            scale = jnp.full(shape[:-2] + (1, shape[-1]),
+                             1.0 / (127.0 * math.sqrt(shape[-2])),
+                             jnp.float32)
+            return {"q": q, "s": scale}
+        return make
+
+    def brand(seed, *shape):
+        def make():
+            return (jax.random.normal(jax.random.PRNGKey(seed), shape,
+                                      jnp.float32)
+                    / math.sqrt(shape[-2])).astype(cfg.dtype)
+        return make
+
+    def ones(*shape):
+        return lambda: jnp.ones(shape, cfg.dtype)
+
+    makers = {
+        "tok_emb": brand(0, cfg.vocab_size, d),
+        "layers": {
+            "attn_norm": ones(l_count, d),
+            "wq": qrand(1, l_count, d, qd),
+            "wk": qrand(2, l_count, d, kvd),
+            "wv": qrand(3, l_count, d, kvd),
+            "wo": qrand(4, l_count, qd, d),
+            "ffn_norm": ones(l_count, d),
+            "w_gate": qrand(5, l_count, d, f),
+            "w_up": qrand(6, l_count, d, f),
+            "w_down": qrand(7, l_count, f, d),
+        },
+        "out_norm": ones(d),
+        "lm_head": qrand(8, d, cfg.vocab_size),
+    }
+
+    def born(make, sharding=None):
+        # graftcheck: ignore[GT003] — one jit per leaf, run once at
+        # start-up: the point is that each leaf is created on device in
+        # its final dtype and sharding, not that the callable is reused
+        return jax.jit(make, out_shardings=sharding)()
+
+    if mesh is None:
+        return jax.tree.map(born, makers)
+    from gofr_tpu.ops.quant import quantized_specs
+    from gofr_tpu.parallel.sharding import (llama_param_specs,
+                                            named_shardings, prune_specs)
+    abstract = jax.tree.map(jax.eval_shape, makers)
+    return jax.tree.map(born, makers, named_shardings(mesh, prune_specs(
+        quantized_specs(llama_param_specs(), abstract), mesh)))
+
+
 def init_cache(cfg: LlamaConfig, batch: int,
                max_len: Optional[int] = None) -> Dict[str, jnp.ndarray]:
     """Static-shape per-layer KV cache resident in HBM. With
@@ -154,6 +222,37 @@ def init_cache(cfg: LlamaConfig, batch: int,
                 "ks": jnp.ones(shape[:-1], jnp.float32),
                 "vs": jnp.ones(shape[:-1], jnp.float32)}
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _prefill_attend(cfg: LlamaConfig, seq_len: int):
+    """The causal self-attention a ``seq_len``-token prompt forward runs:
+    the Pallas flash kernel when ``cfg.use_flash`` asks for it and Mosaic
+    can tile the shape, else the dense einsum. The engine reports the
+    same choice per prompt bucket at start (``attention_paths``)."""
+    if not cfg.use_flash:
+        return prefill_attention
+    from gofr_tpu.ops.pallas import flash_attention, flash_tileable
+    if flash_tileable(seq_len, cfg.head_dim):
+        return flash_attention
+    # dense materializes a (B, H, S, S) f32 score tensor: at long S that
+    # is an opaque device OOM (16 GB at B=1, H=8, S=32K), so the miss is
+    # never silent
+    import warnings
+    warnings.warn(
+        f"use_flash: S={seq_len}, head_dim={cfg.head_dim} does not tile "
+        f"(head_dim % 128, S >= 128, whole 512-blocks) — DENSE attention "
+        f"with a {cfg.n_heads * seq_len * seq_len * 4 / 2**30:.2f} GB "
+        f"score tensor per sequence", stacklevel=3)
+    return prefill_attention
+
+
+def _flash_decode(cfg: LlamaConfig, t_max: int) -> bool:
+    """Does a decode step over a ``t_max``-row cache view run the Pallas
+    flash-decode kernel (``cfg.use_flash_decode`` and a tileable view)?"""
+    if not cfg.use_flash_decode or cfg.kv_int8:
+        return False
+    from gofr_tpu.ops.pallas import decode_shapes_tileable
+    return decode_shapes_tileable(t_max, 128, cfg.head_dim, cfg.n_heads)
 
 
 def _qkv(layer, x, cfg, cos, sin, positions):
@@ -200,11 +299,8 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig, tokens: jnp.ndarray,
         def attend(q, k, v):
             return ring_attention(q, k, v, mesh, axis_name=sp_axis,
                                   batch_axis=dp_axis, head_axis=tp_axis)
-    elif cfg.use_flash:
-        from gofr_tpu.ops.pallas import flash_attention
-        attend = flash_attention
     else:
-        attend = prefill_attention
+        attend = _prefill_attend(cfg, s)
 
     def body(x, layer):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
@@ -253,12 +349,10 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jnp.ndarray,
     positions = jnp.broadcast_to(
         prefix_len + jnp.arange(s, dtype=jnp.int32), (b, s))
     x = params["tok_emb"][tokens]
-    if cfg.use_flash and prefix is None:
-        # the flash kernel is strictly causal — the prefix path needs the
-        # rectangular prefix block, so it uses the dense mask form
-        from gofr_tpu.ops.pallas import flash_attention as attend
-    else:
-        attend = prefill_attention
+    # the flash kernel is strictly causal — the prefix path needs the
+    # rectangular prefix block, so it uses the dense mask form
+    attend = (_prefill_attend(cfg, s) if prefix is None
+              else prefill_attention)
 
     xs: Dict[str, Any] = {"layer": params["layers"], "cache": cache}
     if prefix is not None:
@@ -359,7 +453,7 @@ def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
             views = [v[:, :window] for v in views]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
-        if cfg.use_flash_decode and not int8:
+        if _flash_decode(cfg, views[0].shape[1]):
             from gofr_tpu.ops.pallas import flash_decode_attention
             attn = flash_decode_attention(q, views[0], views[1], k[:, 0],
                                           v[:, 0], cache_len)
@@ -431,9 +525,10 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     prefetch — so ``page_table`` may carry the slot's *full* table (no
     ladder rung slicing) and int8 dequant happens in-kernel from the
     scale planes. Takes priority over ``cfg.use_flash_decode`` and,
-    unlike it, supports int8. Token-identical to the gather path (that
-    formulation remains the correctness oracle and the fallback on
-    unsupported shapes / off-TPU).
+    unlike it, supports int8. Token-identical to the gather path, which
+    remains the correctness oracle. Whether the geometry suits the
+    kernel is the caller's call (ops.pallas.ragged_tileable); nothing in
+    here falls back.
     """
     b = token.shape[0]
     cos, sin = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
@@ -465,7 +560,7 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
                 cache_len,
                 k_scale_pages=planes[2] if int8 else None,
                 v_scale_pages=planes[3] if int8 else None)
-        elif cfg.use_flash_decode and not int8:
+        elif _flash_decode(cfg, page_table.shape[1] * page):
             from gofr_tpu.ops.pallas import flash_decode_attention
             views = [gather_kv_pages(p, page_table) for p in planes]
             attn = flash_decode_attention(q, views[0], views[1], k[:, 0],

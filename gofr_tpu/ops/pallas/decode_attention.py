@@ -21,8 +21,9 @@ einsum stays the production path (`use_flash_decode=False`); a win here
 needs a kernel spanning the whole decode step (weights + attention in
 one grid), for which this is the numerics-tested starting point.
 
-Falls back to the dense implementation when shapes miss TPU tiling
-(head_dim % 128, T % block, heads % 8) or off-TPU.
+The entry point is the kernel only; models/llama selects it from
+``select.decode_shapes_tileable`` (head_dim % 128, T % block, heads % 8)
+and otherwise runs the dense implementation.
 """
 
 from __future__ import annotations
@@ -33,6 +34,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from gofr_tpu.ops.pallas.select import lower_for_target
 
 _NEG_INF = -1e30
 
@@ -102,7 +105,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref,
         o_ref[0, 0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
-def _pallas_decode(q, k_cache, v_cache, k_new, v_new, cache_len,
+def _pallas_decode(q, k_cache, v_cache, k_new, v_new, cache_len, *,
                    block_k: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -168,22 +171,17 @@ def _pallas_decode(q, k_cache, v_cache, k_new, v_new, cache_len,
 def flash_decode_attention(q, k_cache, v_cache, k_new, v_new, cache_len,
                            block_k: int = 128,
                            interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Drop-in for ops.attention.decode_attention_cached with automatic
-    dense fallback. q (B,1,Hq,D); caches (B,Tmax,Hkv,D); k_new/v_new
-    (B,Hkv,D); cache_len (B,) valid entries excluding the current token.
-    Returns (B,1,Hq,D)."""
-    from gofr_tpu.ops.pallas.fallback import (decode_shapes_tileable,
-                                              resolve_interpret)
-
-    t_max, head_dim = k_cache.shape[1], q.shape[3]
-    q_heads = q.shape[2]
-    # call-time backend check (shared with the ragged kernel): tests that
-    # swap platforms between calls must not see a stale decision
-    interpret = resolve_interpret(interpret)
+    """Kernel counterpart of ops.attention.decode_attention_cached.
+    q (B,1,Hq,D); caches (B,Tmax,Hkv,D); k_new/v_new (B,Hkv,D);
+    cache_len (B,) valid entries excluding the current token. Tmax must
+    split into whole ``block_k`` blocks; ``interpret=None`` follows the
+    lowering target (ops/pallas/select). Returns (B,1,Hq,D)."""
+    t_max = k_cache.shape[1]
     block_k = min(block_k, t_max)
-    if not decode_shapes_tileable(t_max, block_k, head_dim, q_heads):
-        from gofr_tpu.ops.attention import decode_attention_cached
-        return decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
-                                       cache_len)
-    return _pallas_decode(q, k_cache, v_cache, k_new, v_new, cache_len,
-                          block_k, interpret)
+    if t_max % block_k:
+        raise ValueError(
+            f"flash_decode_attention: Tmax={t_max} does not split into "
+            f"{block_k}-row blocks")
+    return lower_for_target(
+        functools.partial(_pallas_decode, block_k=block_k), interpret,
+        q, k_cache, v_cache, k_new, v_new, cache_len)

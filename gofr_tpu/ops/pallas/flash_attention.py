@@ -14,10 +14,10 @@ GQA is expressed in the K/V BlockSpec index maps: the flattened (batch·Hq)
 grid axis maps onto (batch·Hkv), so grouped heads read the same K/V tile
 without materialising a repeat.
 
-``flash_attention`` falls back to the dense einsum implementation when
-shapes don't meet TPU tiling constraints (head_dim % 128, seq % block) or
-off-TPU — same numerics either way (tests assert equality against
-ops.attention).
+``flash_attention`` is the kernel and nothing else: callers that want
+the dense einsum for shapes Mosaic cannot tile choose it themselves from
+``select.flash_tileable`` (models/llama does, and says so). Same numerics
+either way (tests assert equality against ops.attention).
 """
 
 from __future__ import annotations
@@ -28,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from gofr_tpu.ops.pallas.select import lower_for_target
 
 _NEG_INF = -1e30
 
@@ -80,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                     ).astype(o_ref.dtype)
 
 
-def _pallas_flash(q, k, v, causal: bool, block_q: int, block_k: int,
+def _pallas_flash(q, k, v, *, causal: bool, block_q: int, block_k: int,
                   interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -132,38 +134,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, block_q: int = 512,
                     block_k: int = 512,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Flash attention with automatic dense fallback.
+    """Flash attention. q (B,S,Hq,D), k/v (B,S,Hkv,D) → (B,S,Hq,D).
 
-    q (B,S,Hq,D), k/v (B,S,Hkv,D) → (B,S,Hq,D). Uses the Pallas kernel
-    when S divides the block sizes and D meets lane tiling; otherwise the
-    dense GQA einsum from gofr_tpu.ops.attention (identical numerics).
+    S must split into whole blocks (blocks clamp to S, so any S up to the
+    block size does). ``interpret=None`` follows the lowering target
+    (ops/pallas/select); compiled for TPU the geometry must also satisfy
+    ``select.flash_tileable`` or Mosaic rejects it.
     """
-    seq_len, head_dim = q.shape[1], q.shape[3]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    seq_len = q.shape[1]
     block_q = min(block_q, seq_len)
     block_k = min(block_k, seq_len)
-    tileable = (seq_len % block_q == 0 and seq_len % block_k == 0
-                and head_dim % 128 == 0 and seq_len >= 128)
-    if not tileable:
-        # the dense path materializes a (B, H, S, S) score tensor: falling
-        # back *silently* turns a shape mistake into an opaque device OOM
-        # (r5: 16 GB at B=1,H=8,S=32K). Warn whenever that tensor alone
-        # would exceed ~2 GB — it scales with batch and heads, not S
-        # only. Scores/softmax accumulate in fp32 regardless of input
-        # dtype (ops/attention.py), so size at 4 bytes per element.
-        score_bytes = q.shape[0] * q.shape[2] * seq_len * seq_len * 4
-        if score_bytes > 2 * 1024**3:
-            import warnings
-
-            warnings.warn(
-                f"flash_attention falling back to DENSE attention with a "
-                f"{score_bytes / 2**30:.1f} GB score tensor "
-                f"(B={q.shape[0]}, H={q.shape[2]}, S={seq_len}; "
-                f"untileable: head_dim {head_dim} must be a multiple of "
-                f"128 and S divisible by the block sizes) — this may "
-                f"exceed HBM", stacklevel=2)
-        from gofr_tpu.ops.attention import attention, causal_mask
-        mask = causal_mask(seq_len)[None, None, None] if causal else None
-        return attention(q, k, v, mask)
-    return _pallas_flash(q, k, v, causal, block_q, block_k, interpret)
+    if seq_len % block_q or seq_len % block_k:
+        raise ValueError(
+            f"flash_attention: S={seq_len} does not split into "
+            f"{block_q}/{block_k}-row blocks")
+    return lower_for_target(
+        functools.partial(_pallas_flash, causal=causal, block_q=block_q,
+                          block_k=block_k),
+        interpret, q, k, v)

@@ -50,9 +50,9 @@ is an opaque boundary to XLA's weight-prefetch pipeline. The economics
 here differ — this kernel *replaces* a per-layer HBM gather
 materialization instead of competing with a fused einsum — but the same
 rule applies: judge it on the full decode tick (bench.py
-``llama_ragged_attn``), never the standalone op. Off-TPU or on
-tiling-miss shapes it falls back to the gather formulation, which stays
-the correctness oracle.
+``llama_ragged_attn``), never the standalone op. The gather formulation
+stays the correctness oracle; choosing it over this kernel is the
+caller's decision (ops/pallas/select), never taken in here.
 """
 
 from __future__ import annotations
@@ -64,21 +64,35 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from gofr_tpu.ops.pallas.fallback import (ragged_shapes_supported,
-                                          resolve_interpret)
+from gofr_tpu.ops.pallas.select import lower_for_target
 
 _NEG_INF = -1e30
 
-__all__ = ["ragged_paged_decode_attention", "ragged_paged_verify_attention",
-           "ragged_supported"]
+__all__ = ["ragged_paged_decode_attention", "ragged_paged_verify_attention"]
 
 
-def ragged_supported(head_dim: int, q_heads: int, kv_heads: int, page: int,
-                     interpret: Optional[bool] = None) -> bool:
-    """Would these shapes run the fused kernel (vs the gather fallback)?
-    The engine's ``ragged_attn="auto"`` resolves through this."""
-    return ragged_shapes_supported(head_dim, q_heads, kv_heads, page,
-                                   resolve_interpret(interpret))
+def _round_to(x, dtype):
+    """``ops/attention._snap`` for the kernel body: round f32 ``x`` to
+    ``dtype``'s mantissa, nearest-even, without leaving f32.
+
+    Pallas TPU has no lowering for ``lax.reduce_precision`` and an astype
+    round trip is a convert pair XLA may fold in interpret mode, so the
+    rounding is spelled out on the bit pattern — the one form that means
+    the same thing to Mosaic, to the interpreter and to the oracle. Only
+    the mantissa narrows, which is all reduce_precision does when the
+    exponent keeps f32's 8 bits (bfloat16); f32 passes through."""
+    info = jnp.finfo(dtype)
+    if info.bits >= 32:
+        return x
+    if info.nexp != 8:
+        raise ValueError(
+            f"ragged paged attention reproduces bfloat16 and float32 "
+            f"rounding only, got cache dtype {jnp.dtype(dtype).name}")
+    drop = 23 - info.nmant
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    odd = lax.shift_right_logical(bits, drop) & 1
+    bits = (bits + ((1 << (drop - 1)) - 1) + odd) & ~((1 << drop) - 1)
+    return lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
@@ -96,7 +110,14 @@ def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
     the new tokens' contribution and writes the output. ``rest`` is
     (ks, vs, out, acc, m, l) on int8 pools — the scale-plane blocks ride
     the same index maps as their pages — and (out, acc, m, l) on bf16
-    pools, so bf16 never fetches a dead operand."""
+    pools, so bf16 never fetches a dead operand.
+
+    Everything is per kv-head with the head on a LEADING axis (q, the new
+    K/V, the output and the scratch all arrive head-major from the
+    wrapper), and every mask is built at the shape it is applied at:
+    Mosaic tiles the last two dims, so a head picked off a leading axis
+    is a plain tile load, while reshaping heads out of the sublane dim or
+    broadcasting/tiling an i1 vector is a layout change it refuses."""
     from jax.experimental import pallas as pl
 
     if int8:
@@ -109,148 +130,130 @@ def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
     pj = lax.rem(pi, num_pi)                   # page index within a phase
     length = len_ref[b]                        # valid tokens, excl. new
     cdt = o_ref.dtype                          # the oracle's cache dtype
-
-    rp_bits = None
-    if jnp.finfo(cdt).bits < 32:
-        rp_bits = (jnp.finfo(cdt).nexp, jnp.finfo(cdt).nmant)
+    rows = g_len * group                       # query rows per kv-head
 
     def _round(x):
-        # the gather oracle snaps to the cache dtype's precision at every
-        # materialization point (ops/attention._snap): mimic it with the
-        # same reduce_precision — an astype round-trip could be folded
-        # away by the compiler, silently moving the rounding points
-        # (identity at f32)
-        if rp_bits is None:
-            return x
-        return lax.reduce_precision(x, *rp_bits)
+        return _round_to(x, cdt)
 
     @pl.when(pi == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    def q_rows():
-        # (G, Hq, D) -> per-kv-head (G*group, D) row stacks; query g of
-        # kv-head h owns rows [g*group, (g+1)*group). UNSCALED: the
-        # oracle applies sm_scale after the (rounded) score einsum.
-        q = q_ref[0].astype(jnp.float32).reshape(g_len, kv_heads, group, -1)
-        return [q[:, h].reshape(g_len * group, -1) for h in range(kv_heads)]
+    def scale_row(s_ref, h):
+        # (page, Hkv) scale block -> head h's scales as a (1, page) lane
+        # row. The column sits on sublanes; an exact select-and-sum over
+        # the diagonal moves it to lanes without a transpose.
+        col = s_ref[0][:, h:h + 1]                          # (page, 1)
+        eye = (lax.broadcasted_iota(jnp.int32, (page, page), 0)
+               == lax.broadcasted_iota(jnp.int32, (page, page), 1))
+        return jnp.where(eye, col, 0.0).sum(axis=0, keepdims=True)
 
-    def block_scores():
-        # per-kv-head dots unrolled in Python: Mosaic does not lower a
-        # batched dot_general with unequal non-contracting dims. Rounding
-        # order matches the oracle exactly: dot -> cache-dtype round ->
-        # * sm_scale -> (* k_scale on int8) -> length mask.
-        qh = q_rows()
-        k_blk = k_ref[0].astype(jnp.float32)       # (page, Hkv, D)
-        parts = []
-        for h in range(kv_heads):
-            s_h = _round(jnp.dot(qh[h], k_blk[:, h, :].T,
-                                 preferred_element_type=jnp.float32))
-            s_h = s_h * sm_scale                   # (G*grp, page)
-            if int8:
-                # fused dequant, oracle formulation: the int8 scores are
-                # exact through the rounded dot, and the per-vector scale
-                # folds into f32 AFTER — never a converted cache copy
-                s_h = s_h * ks_ref[0][:, h][None, :]
-            parts.append(s_h)
-        scores = jnp.concatenate(parts, axis=0)    # (rows, page)
-        pos = pj * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        return jnp.where(pos < length, scores, _NEG_INF)
+    def block_scores(h):
+        # rounding order matches the oracle exactly: dot -> cache-dtype
+        # round -> * sm_scale -> (* k_scale on int8) -> length mask.
+        # q stays UNSCALED: the oracle applies sm_scale after the
+        # (rounded) score einsum.
+        q_h = q_ref[0, h].astype(jnp.float32)               # (rows, D)
+        k_h = k_ref[0][:, h, :].astype(jnp.float32)         # (page, D)
+        s_h = _round(jnp.dot(q_h, k_h.T,
+                             preferred_element_type=jnp.float32)) * sm_scale
+        if int8:
+            # fused dequant, oracle formulation: the int8 scores are
+            # exact through the rounded dot, and the per-vector scale
+            # folds into f32 AFTER — never a converted cache copy
+            s_h = s_h * scale_row(ks_ref, h)
+        pos = pj * page + lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+        return jnp.where(pos < length, s_h, _NEG_INF)
 
-    def new_scores():
+    def new_scores(h):
         # the G new tokens (positions length..length+G-1, causal among
         # themselves: key u attends to query s iff u <= s); their K
-        # arrives unquantized even on int8 pools (oracle contract)
-        qh = q_rows()
-        k_new = kn_ref[0].astype(jnp.float32)      # (G, Hkv, D)
-        s_new = jnp.concatenate(
-            [_round(jnp.dot(qh[h], k_new[:, h, :].T,
-                            preferred_element_type=jnp.float32)) * sm_scale
-             for h in range(kv_heads)], axis=0)    # (rows, G)
-        q_pos = lax.broadcasted_iota(
-            jnp.int32, (g_len * group, g_len), 0) // group
-        u_pos = lax.broadcasted_iota(
-            jnp.int32, (g_len * group, g_len), 1)
-        causal = u_pos <= q_pos
-        return jnp.where(jnp.tile(causal, (kv_heads, 1)), s_new, _NEG_INF)
+        # arrives unquantized even on int8 pools (oracle contract).
+        # Row r of a head is query r // group, and u <= r // group is
+        # u * group <= r.
+        q_h = q_ref[0, h].astype(jnp.float32)
+        k_new = kn_ref[0, h].astype(jnp.float32)            # (G, D)
+        s_new = _round(jnp.dot(q_h, k_new.T,
+                               preferred_element_type=jnp.float32)) * sm_scale
+        r_pos = lax.broadcasted_iota(jnp.int32, (rows, g_len), 0)
+        u_pos = lax.broadcasted_iota(jnp.int32, (rows, g_len), 1)
+        return jnp.where(u_pos * group <= r_pos, s_new, _NEG_INF)
+
+    def fold_stats(h, scores):
+        m_prev, l_prev = m_ref[h], l_ref[h]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        m_ref[h] = m_new
+        l_ref[h] = (l_prev * jnp.exp(m_prev - m_new)
+                    + jnp.exp(scores - m_new).sum(axis=-1, keepdims=True))
 
     # -- phase 0: softmax statistics over the live pages ------------------
     @pl.when(jnp.logical_and(pi < num_pi, pj * page < length))
     def _stats_step():
-        scores = block_scores()
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = (l_prev * corr
-                    + jnp.exp(scores - m_new).sum(axis=-1, keepdims=True))
+        for h in range(kv_heads):
+            fold_stats(h, block_scores(h))
 
     @pl.when(pi == num_pi - 1)
     def _stats_finish():
         # fold the new tokens' scores: m/l are FINAL after this step (the
         # causal diagonal guarantees l >= 1, so phase 1 never divides by
         # zero)
-        s_new = new_scores()
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_fin = jnp.maximum(m_prev, s_new.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_fin)
-        m_ref[:] = m_fin
-        l_ref[:] = (l_prev * corr
-                    + jnp.exp(s_new - m_fin).sum(axis=-1, keepdims=True))
+        for h in range(kv_heads):
+            fold_stats(h, new_scores(h))
 
     # -- phase 1: oracle-identical probabilities, P·V accumulation --------
     @pl.when(jnp.logical_and(pi >= num_pi, pj * page < length))
     def _value_step():
-        p = jnp.exp(block_scores() - m_ref[:]) / l_ref[:]  # (rows, page)
-        if not int8:
-            p = _round(p)                      # probs.astype(q.dtype)
-        v_blk = v_ref[0].astype(jnp.float32)
-        p3 = p.reshape(kv_heads, g_len * group, page)
-        parts = []
         for h in range(kv_heads):
-            ph = p3[h]
+            p = jnp.exp(block_scores(h) - m_ref[h]) / l_ref[h]
             if int8:
                 # oracle int8 V path: normalized probs stay f32 and the
                 # per-vector scale folds in pre-einsum (precision over
                 # bandwidth — see decode_attention_cached)
-                ph = ph * vs_ref[0][:, h][None, :]
-            parts.append(jnp.dot(ph, v_blk[:, h, :],
-                                 preferred_element_type=jnp.float32))
-        acc_ref[:] += jnp.concatenate(parts, axis=0)       # (rows, D)
+                p = p * scale_row(vs_ref, h)
+            else:
+                p = _round(p)                  # probs.astype(q.dtype)
+            v_h = v_ref[0][:, h, :].astype(jnp.float32)     # (page, D)
+            acc_ref[h] += jnp.dot(p, v_h,
+                                  preferred_element_type=jnp.float32)
 
     @pl.when(pi == 2 * num_pi - 1)
     def _finish():
-        p_new = _round(jnp.exp(new_scores() - m_ref[:]) / l_ref[:])
-        v_new = vn_ref[0].astype(jnp.float32)      # (G, Hkv, D)
-        p3 = p_new.reshape(kv_heads, g_len * group, g_len)
-        pv = jnp.concatenate(
-            [jnp.dot(p3[h], v_new[:, h, :],
-                     preferred_element_type=jnp.float32)
-             for h in range(kv_heads)], axis=0)            # (rows, D)
-        # the oracle snaps the cache and new-token einsum outputs, adds
-        # them in f32 and snaps the sum (ops/attention._snap schedule)
-        out = _round(_round(acc_ref[:]) + _round(pv))      # (rows, D)
-        head_dim = out.shape[-1]
-        o_ref[0] = out.reshape(kv_heads, g_len, group, head_dim) \
-            .swapaxes(0, 1).reshape(g_len, kv_heads * group, head_dim) \
-            .astype(o_ref.dtype)
+        for h in range(kv_heads):
+            p_new = _round(jnp.exp(new_scores(h) - m_ref[h]) / l_ref[h])
+            v_new = vn_ref[0, h].astype(jnp.float32)        # (G, D)
+            pv = jnp.dot(p_new, v_new, preferred_element_type=jnp.float32)
+            # the oracle snaps the cache and new-token einsum outputs,
+            # adds them in f32 and snaps the sum (ops/attention._snap
+            # schedule)
+            o_ref[0, h] = _round(_round(acc_ref[h]) + _round(pv)) \
+                .astype(o_ref.dtype)
 
 
 def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
-                   cache_len, k_scale_pages, v_scale_pages,
-                   interpret: bool):
+                   cache_len, *scale_pages, interpret: bool):
+    """``scale_pages`` is ``(k_scale_pages, v_scale_pages)`` on int8
+    pools and empty on bf16 pools."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, g_len, q_heads, head_dim = q.shape
     num_pages, page, kv_heads, _ = k_pages.shape
     group = q_heads // kv_heads
+    rows = g_len * group
     num_pi = page_table.shape[1]
-    int8 = k_scale_pages is not None
+    int8 = bool(scale_pages)
     table = page_table.astype(jnp.int32)
     lens = cache_len.astype(jnp.int32)
+    # head-major operands (see _ragged_kernel): q-head kv*group + j of
+    # query g becomes row g*group + j of kv-head kv. q and the new K/V
+    # are a few KB per slot, so the transposes cost nothing next to the
+    # pool walk; the pool itself is read in place.
+    q_hm = q.reshape(batch, g_len, kv_heads, group, head_dim) \
+        .transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads, rows, head_dim)
+    kn_hm = k_new.transpose(0, 2, 1, 3)            # (B, Hkv, G, D)
+    vn_hm = v_new.transpose(0, 2, 1, 3)
 
     def _row(b, pj, table_ref, len_ref):
         # scalar-prefetch table walk: fetch this slot's ACTUAL pool row.
@@ -286,39 +289,47 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         _ragged_kernel, page=page, num_pi=num_pi, kv_heads=kv_heads,
         group=group, g_len=g_len, int8=int8, sm_scale=head_dim ** -0.5)
     in_specs = [
-        pl.BlockSpec((1, g_len, q_heads, head_dim), q_index),
+        pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
         pl.BlockSpec((1, page, kv_heads, head_dim), k_index),
         pl.BlockSpec((1, page, kv_heads, head_dim), v_index),
-        pl.BlockSpec((1, g_len, kv_heads, head_dim), q_index),
-        pl.BlockSpec((1, g_len, kv_heads, head_dim), q_index),
+        pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
+        pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
     ]
-    operands = [q, k_pages, v_pages, k_new, v_new]
+    operands = [q_hm, k_pages, v_pages, kn_hm, vn_hm]
     if int8:
         in_specs += [pl.BlockSpec((1, page, kv_heads), ks_index),
                      pl.BlockSpec((1, page, kv_heads), vs_index)]
-        operands += [k_scale_pages, v_scale_pages]
+        operands += list(scale_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(batch, 2 * num_pi),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g_len, q_heads, head_dim), q_index),
+        out_specs=pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
         scratch_shapes=[
-            pltpu.VMEM((g_len * q_heads, head_dim), jnp.float32),
-            pltpu.VMEM((g_len * q_heads, 1), jnp.float32),
-            pltpu.VMEM((g_len * q_heads, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, head_dim), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
         ],
     )
     compiler_params = None
     if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_hm.shape, q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
     )(table, lens, *operands)
+    return out.reshape(batch, kv_heads, g_len, group, head_dim) \
+        .transpose(0, 2, 1, 3, 4).reshape(q.shape)
+
+
+def _scales(k_scale_pages, v_scale_pages):
+    if (k_scale_pages is None) != (v_scale_pages is None):
+        raise ValueError("int8 pools pass both scale planes, bf16 neither")
+    return () if k_scale_pages is None else (k_scale_pages, v_scale_pages)
 
 
 def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
@@ -326,25 +337,17 @@ def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
                                   v_scale_pages=None,
                                   interpret: Optional[bool] = None
                                   ) -> jnp.ndarray:
-    """Drop-in for ops.attention.paged_decode_attention with automatic
-    gather fallback. q (B,1,Hq,D); k_pages/v_pages (num_pages,page,Hkv,D);
-    page_table (B,P) int32 with ``num_pages`` the unallocated sentinel;
-    k_new/v_new (B,Hkv,D); cache_len (B,) valid tokens excluding the
-    current one; int8 pools pass the (num_pages,page,Hkv) scale planes.
+    """Kernel counterpart of ops.attention.paged_decode_attention.
+    q (B,1,Hq,D); k_pages/v_pages (num_pages,page,Hkv,D); page_table
+    (B,P) int32 with ``num_pages`` the unallocated sentinel; k_new/v_new
+    (B,Hkv,D); cache_len (B,) valid tokens excluding the current one;
+    int8 pools pass the (num_pages,page,Hkv) scale planes.
+    ``interpret=None`` follows the lowering target (ops/pallas/select).
     Returns (B,1,Hq,D)."""
-    interpret = resolve_interpret(interpret)
-    _, _, q_heads, head_dim = q.shape
-    page, kv_heads = k_pages.shape[1], k_pages.shape[2]
-    if not ragged_shapes_supported(head_dim, q_heads, kv_heads, page,
-                                   interpret):
-        from gofr_tpu.ops.attention import paged_decode_attention
-        return paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      k_new, v_new, cache_len,
-                                      k_scale_pages=k_scale_pages,
-                                      v_scale_pages=v_scale_pages)
-    return _pallas_ragged(q, k_pages, v_pages, page_table,
-                          k_new[:, None], v_new[:, None], cache_len,
-                          k_scale_pages, v_scale_pages, interpret)
+    return lower_for_target(
+        _pallas_ragged, interpret, q, k_pages, v_pages, page_table,
+        k_new[:, None], v_new[:, None], cache_len,
+        *_scales(k_scale_pages, v_scale_pages))
 
 
 def ragged_paged_verify_attention(q, k_pages, v_pages, page_table, k_new,
@@ -352,22 +355,11 @@ def ragged_paged_verify_attention(q, k_pages, v_pages, page_table, k_new,
                                   v_scale_pages=None,
                                   interpret: Optional[bool] = None
                                   ) -> jnp.ndarray:
-    """γ+1-token variant backing speculative verify: drop-in for
-    ops.attention.paged_verify_attention. q (B,G,Hq,D); k_new/v_new
+    """γ+1-token variant backing speculative verify: kernel counterpart
+    of ops.attention.paged_verify_attention. q (B,G,Hq,D); k_new/v_new
     (B,G,Hkv,D) — query g sits at position ``cache_len + g``, attends
     the paged cache (< cache_len) plus the new tokens causally
-    (u <= g). Falls back to the gather formulation exactly like the
-    decode variant. Returns (B,G,Hq,D)."""
-    interpret = resolve_interpret(interpret)
-    _, _, q_heads, head_dim = q.shape
-    page, kv_heads = k_pages.shape[1], k_pages.shape[2]
-    if not ragged_shapes_supported(head_dim, q_heads, kv_heads, page,
-                                   interpret):
-        from gofr_tpu.ops.attention import paged_verify_attention
-        return paged_verify_attention(q, k_pages, v_pages, page_table,
-                                      k_new, v_new, cache_len,
-                                      k_scale_pages=k_scale_pages,
-                                      v_scale_pages=v_scale_pages)
-    return _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
-                          cache_len, k_scale_pages, v_scale_pages,
-                          interpret)
+    (u <= g). Returns (B,G,Hq,D)."""
+    return lower_for_target(
+        _pallas_ragged, interpret, q, k_pages, v_pages, page_table,
+        k_new, v_new, cache_len, *_scales(k_scale_pages, v_scale_pages))

@@ -1,0 +1,72 @@
+"""Which Pallas kernel runs, decided where it can be seen.
+
+A kernel entry point in this package IS the kernel: it never swaps in
+the pure-jnp formulation from ops/attention on its own. That formulation
+stays the correctness oracle, and choosing it over a kernel is the
+caller's decision, taken from the predicates below and reported (the
+engine logs its attention path once at start). Two rules:
+
+- **Tiling predicates answer for Mosaic.** ``*_tileable`` says whether
+  the compiled TPU kernel accepts a geometry; callers that select
+  automatically use it on every platform, so a CPU run and a TPU run of
+  the same config take the same path. A caller that forces a kernel
+  onto a geometry Mosaic rejects gets the compiler's own error.
+- **Interpret mode follows the lowering target, not the process.**
+  ``interpret=None`` lowers the compiled kernel when the computation is
+  lowered for TPU and the interpreter everywhere else, chosen per
+  lowering by ``lax.platform_dependent``. A TPU executable built from a
+  CPU process (AOT against a topology) therefore holds the real kernel,
+  never an inlined interpreter, and nothing on a TPU ever interprets.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, Optional
+
+__all__ = ["flash_tileable", "decode_shapes_tileable", "ragged_tileable",
+           "lower_for_target"]
+
+
+def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
+                   block_k: int = 512) -> bool:
+    """Flash-attention (prefill) tiling predicate: whole blocks, a
+    128-lane head_dim and at least one 128-row tile of sequence."""
+    block_q, block_k = min(block_q, seq_len), min(block_k, seq_len)
+    return (seq_len % block_q == 0 and seq_len % block_k == 0
+            and head_dim % 128 == 0 and seq_len >= 128)
+
+
+def decode_shapes_tileable(t_max: int, block_k: int, head_dim: int,
+                           q_heads: int) -> bool:
+    """Dense flash-decode tiling predicate (ops/pallas/decode_attention):
+    the KV window must split into whole lane-aligned blocks and heads
+    must fill a sublane."""
+    block_k = min(block_k, t_max)
+    return (t_max % block_k == 0 and head_dim % 128 == 0
+            and t_max >= 128 and q_heads % 8 == 0)
+
+
+def ragged_tileable(head_dim: int, q_heads: int, kv_heads: int,
+                    page: int) -> bool:
+    """Ragged-paged-attention tiling predicate: a 128-lane head_dim, a
+    sublane-filling q-head count, and a page deep enough to tile the KV
+    block (AOT-compiled for v5e at MHA 32:32 and GQA 32:8 by
+    tests/test_pallas_aot.py)."""
+    return (q_heads % kv_heads == 0 and head_dim % 128 == 0
+            and q_heads % 8 == 0 and page % 16 == 0)
+
+
+def lower_for_target(kernel: Callable, interpret: Optional[bool],
+                     *operands):
+    """Run ``kernel(*operands, interpret=...)``. An explicit
+    ``interpret`` passes through; ``None`` resolves per lowering target
+    (module docstring)."""
+    if interpret is not None:
+        return kernel(*operands, interpret=bool(interpret))
+    from jax import lax
+
+    return lax.platform_dependent(
+        *operands,
+        tpu=functools.partial(kernel, interpret=False),
+        default=functools.partial(kernel, interpret=True))

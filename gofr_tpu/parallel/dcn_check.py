@@ -32,7 +32,8 @@ _CHILD_ENV_FLAG = "GOFR_DCN_CHECK_CHILD"
 
 def _child() -> None:
     """One process of the 2-process job. Must configure platform/devices
-    before any JAX backend use."""
+    before any JAX backend use. Forcing the CPU here is also what keeps a
+    child from wanting the chip its parent may hold."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")
@@ -47,10 +48,7 @@ def _child() -> None:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # type: ignore[attr-defined]
-    except ImportError:                              # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = multihost.hybrid_mesh(
         {"dp": jax.local_device_count()},
@@ -102,7 +100,6 @@ def run_two_process_check(local_devices: int = 4,
     children = []
     for process_id in range(2):
         env = dict(os.environ)
-        env.pop("JAX_PLATFORMS", None)
         env[_CHILD_ENV_FLAG] = "1"
         env["JAX_COORDINATOR"] = f"127.0.0.1:{port}"
         env["JAX_NUM_PROCESSES"] = "2"

@@ -48,15 +48,9 @@ def make_mesh(axes: Optional[Dict[str, int]] = None,
     # Auto axis types = classic GSPMD: annotate with with_sharding_constraint
     # / NamedSharding and let the partitioner propagate, no mesh context
     # manager needed (jax 0.9 defaults to Explicit, which requires one).
-    # Older jax (< 0.5) predates AxisType entirely — there Auto is the only
-    # behavior, so the plain call is equivalent.
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(names)
-    except AttributeError:
-        return jax.make_mesh(tuple(sizes), names, devices=devices[:total])
     return jax.make_mesh(
         tuple(sizes), names, devices=devices[:total],
-        axis_types=axis_types)
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def parse_mesh_spec(spec: Optional[str]) -> Optional[Dict[str, int]]:

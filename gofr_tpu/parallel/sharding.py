@@ -29,6 +29,14 @@ def shard_pytree(tree: Any, mesh: Mesh, specs: Any) -> Any:
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs)
 
 
+def named_shardings(mesh: Mesh, specs: Any) -> Any:
+    """NamedShardings mirroring a PartitionSpec pytree — the
+    ``out_shardings`` of a jit that creates a tree already sharded, so
+    no leaf is ever whole on one device."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
 def replicated_specs(tree: Any) -> Any:
     return jax.tree.map(lambda _: P(), tree)
 

@@ -23,7 +23,6 @@ wrap driver libs with config/logging/metrics/health (e.g.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -83,6 +82,9 @@ class Executor:
                  recorder: Any = None, staging: bool = True,
                  staging_depth: int = 2, donate_inputs: str = "auto"):
         import jax
+
+        from gofr_tpu.tpu.compile_cache import configure_compile_cache
+        configure_compile_cache()
         self._jax = jax
         self.logger = logger
         self.metrics = metrics
@@ -215,7 +217,8 @@ class Executor:
         and enqueue the XLA execute without syncing. Returns an opaque
         handle for ``fetch``. Double-buffering falls out: dispatching batch
         N+1 while batch N computes rides the transfer stream under the
-        running execute, so the device never idles waiting on PCIe/relay."""
+        running execute, so the device never idles waiting on the host
+        link."""
         model = self._models.get(name)
         if model is None:
             raise KeyError(f"tpu model {name!r} not registered "
@@ -266,8 +269,8 @@ class Executor:
 
         Step-phase anatomy replaces ``host_prep`` with a three-way split:
         ``serialize`` (non-ndarray leaves → arrays), ``stage`` (rows into
-        the slab), ``upload`` (device_put) — the bench's relay gap is
-        attributable per phase instead of one opaque host number.
+        the slab), ``upload`` (device_put) — the host side of a dispatch
+        is attributable per phase instead of one opaque number.
         """
         # graftcheck: ignore[GT007,GT001] — serialize phase: converting a
         # non-ndarray request leaf is the single permitted host copy.
@@ -471,10 +474,7 @@ class Executor:
         compiled = model.compiled.get(bucket) if model is not None else None
         if compiled is not None:
             try:
-                analysis = compiled.cost_analysis()
-                if isinstance(analysis, (list, tuple)):
-                    analysis = analysis[0] if analysis else {}
-                value = float(analysis.get("flops", 0.0))
+                value = float(compiled.cost_analysis().get("flops", 0.0))
                 flops = value if value > 0 else None
             except Exception:
                 flops = None
@@ -552,7 +552,7 @@ class Executor:
                     compiled = self._compile(model, padded, bucket, cause)
         # serving labels on the device timeline: an on-demand XProf
         # capture shows which model/bucket each execute belongs to
-        with self._trace_annotation(f"{model.name}/b{bucket}"):
+        with self._jax.profiler.TraceAnnotation(f"{model.name}/b{bucket}"):
             return compiled(model.params, self._constrain(padded))
 
     def _compile(self, model: _Model, padded: Any, bucket: int,
@@ -587,15 +587,6 @@ class Executor:
                 "fingerprint=%s)", model.name, bucket, duration, cause,
                 event.fingerprint)
         return compiled
-
-    def _trace_annotation(self, label: str):
-        """``jax.profiler.TraceAnnotation`` context for the given label, or
-        a no-op where the profiler API is unavailable — annotation must
-        never be the thing that breaks an execute."""
-        try:
-            return self._jax.profiler.TraceAnnotation(label)
-        except Exception:
-            return contextlib.nullcontext()
 
     # -- compile/shape-plane snapshot (/debug/xlaz) --------------------------
     def xlaz(self, recent: int = 64, max_rungs: int = 4) -> Dict[str, Any]:

@@ -112,26 +112,29 @@ class PagePool:
         cfg = self.cfg
         shape = (cfg.n_layers, self.num_pages, self.page, cfg.n_kv_heads,
                  cfg.head_dim)
-        if cfg.kv_int8:
-            leaves = {"k": jnp.zeros(shape, jnp.int8),
-                      "v": jnp.zeros(shape, jnp.int8),
-                      "ks": jnp.ones(shape[:-1], jnp.float32),
-                      "vs": jnp.ones(shape[:-1], jnp.float32)}
-        else:
-            leaves = {"k": jnp.zeros(shape, cfg.dtype),
-                      "v": jnp.zeros(shape, cfg.dtype)}
-        if self.mesh is not None:
-            # any slot gathers any page, so rows cannot shard over dp;
-            # kv-heads shard over tp exactly like the dense cache
-            from gofr_tpu.parallel.sharding import (
-                llama_prefix_pool_specs, prune_specs, shard_pytree)
-            leaves = shard_pytree(
-                leaves, self.mesh,
-                prune_specs(llama_prefix_pool_specs(kv_int8=cfg.kv_int8),
-                            self.mesh))
-        else:
-            leaves = self._jax.device_put(leaves)
-        self.leaves = leaves
+
+        def fresh():
+            if cfg.kv_int8:
+                return {"k": jnp.zeros(shape, jnp.int8),
+                        "v": jnp.zeros(shape, jnp.int8),
+                        "ks": jnp.ones(shape[:-1], jnp.float32),
+                        "vs": jnp.ones(shape[:-1], jnp.float32)}
+            return {"k": jnp.zeros(shape, cfg.dtype),
+                    "v": jnp.zeros(shape, cfg.dtype)}
+
+        if self.mesh is None:
+            self.leaves = fresh()
+            return
+        # any slot gathers any page, so rows cannot shard over dp;
+        # kv-heads shard over tp exactly like the dense cache. The leaves
+        # are BORN sharded (jit with out_shardings): a pool sized to what
+        # the mesh has left never fits whole on one device first.
+        from gofr_tpu.parallel.sharding import (
+            llama_prefix_pool_specs, named_shardings, prune_specs)
+        specs = prune_specs(llama_prefix_pool_specs(kv_int8=cfg.kv_int8),
+                            self.mesh)
+        self.leaves = self._jax.jit(
+            fresh, out_shardings=named_shardings(self.mesh, specs))()
 
     def reset(self) -> None:
         """Fresh device buffers, empty ownership. Called at engine

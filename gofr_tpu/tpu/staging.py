@@ -21,7 +21,7 @@ arxiv 1804.01138, micro-batch amortization per arxiv 1812.11731):
   identical with coalescing on or off.
 
 Both record ``app_tpu_h2d_bytes_total`` / ``app_tpu_h2d_seconds`` so the
-bench's relay block is attributable per phase.
+host link's share of a dispatch is attributable per phase.
 """
 
 from __future__ import annotations
@@ -196,8 +196,9 @@ class TransferCoalescer:
     """One H2D transfer for many small arrays.
 
     Decode ticks and admissions upload half a dozen tiny arrays each —
-    lengths, slots, temps, top-k/p, seeds — and every one pays the full
-    per-transfer relay floor. The coalescer packs them (all 4-byte
+    lengths, slots, temps, top-k/p, seeds — and every one pays the
+    fixed per-transfer cost of the host link. The coalescer packs them
+    (all 4-byte
     dtypes) into a single ``uint8`` blob on the host, ships it with one
     ``device_put``, and splits it back on device with a jitted
     ``bitcast_convert_type`` keyed by the static (name, shape, dtype)

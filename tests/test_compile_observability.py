@@ -243,7 +243,7 @@ class TestStepPhases:
         executor.register("m", fn, params, buckets=(4,))
         executor.predict("m", np.ones((3, 2), np.float32))
         # staged dispatch (the default) splits host_prep into
-        # serialize/stage/upload so the relay gap is attributable per phase
+        # serialize/stage/upload so the host side is attributable per phase
         staged_phases = ("serialize", "stage", "upload", "enqueue",
                          "device_wait")
         for phase in staged_phases:

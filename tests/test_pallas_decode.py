@@ -35,7 +35,12 @@ def test_kernel_matches_dense(q_heads, kv_heads, fills):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_untileable_shapes_fall_back():
+def test_untileable_shapes_run_the_kernel_and_llama_picks_dense():
+    """The entry point is the kernel on every geometry (interpreted
+    here); the model selects it only where Mosaic can tile."""
+    from gofr_tpu.models import llama
+    from gofr_tpu.models.llama import _flash_decode
+
     batch, t_max, heads, head_dim = 2, 32, 4, 16   # tiny preset geometry
     keys = jax.random.split(jax.random.PRNGKey(1), 5)
     q = _rand(keys[0], batch, 1, heads, head_dim)
@@ -44,9 +49,13 @@ def test_untileable_shapes_fall_back():
     k_new = _rand(keys[3], batch, heads, head_dim)
     v_new = _rand(keys[4], batch, heads, head_dim)
     cache_len = jnp.asarray([0, 17], jnp.int32)
-    out = flash_decode_attention(q, k_cache, v_cache, k_new, v_new,
-                                 cache_len)
-    ref = decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
-                                  cache_len)
+    args = (q, k_cache, v_cache, k_new, v_new, cache_len)
+    assert "pallas_call" in str(jax.make_jaxpr(flash_decode_attention)(*args))
+    out = flash_decode_attention(*args)
+    ref = decode_attention_cached(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-6, rtol=1e-6)
+                               atol=2e-5, rtol=2e-5)
+    assert not _flash_decode(
+        llama.config("tiny", use_flash_decode=True), t_max)
+    assert _flash_decode(llama.config("7b", use_flash_decode=True), 512)
+    assert not _flash_decode(llama.config("7b"), 512)

@@ -16,8 +16,9 @@ The load-bearing contracts, in order:
 3. LADDER RETIREMENT — with ragged active the engine compiles ONE
    decode executable per (steps, sampled) family: no per-width entries
    in the ledger, gather_widths collapses to the full table width.
-4. FALLBACK — unsupported geometry falls back to the gather
-   formulation at call time and stays bit-identical by construction.
+4. NO HIDDEN FALLBACK — the entry points are the kernel on every
+   geometry; choosing the gather formulation instead is the engine's
+   decision (``ragged_tileable`` + platform), taken once and reported.
 
 All tests run the kernel in Pallas interpret mode on CPU (tier-1).
 """
@@ -36,7 +37,7 @@ from gofr_tpu.ops.attention import (check_sentinel_masked,
                                     paged_verify_attention)
 from gofr_tpu.ops.pallas import (ragged_paged_decode_attention,
                                  ragged_paged_verify_attention,
-                                 ragged_supported)
+                                 ragged_tileable)
 from gofr_tpu.tpu.generate import GenerationEngine, Sampling
 from gofr_tpu.tpu.page_pool import PagePool
 
@@ -179,24 +180,32 @@ def test_pad_table_tiles_with_sentinel():
     assert PagePool.pad_table(padded, 4, SENTINEL) is padded
 
 
-# -- fallback ----------------------------------------------------------------
+# -- selection ---------------------------------------------------------------
 
-def test_fallback_on_misaligned_head_dim():
-    """head_dim=12 misses the interpret-mode tiling (not a multiple of
-    8): the ragged entry point must fall back to the gather formulation
-    and stay bit-identical by construction."""
-    assert not ragged_supported(12, HQ, HKV, PAGE, interpret=True)
+def test_no_fallback_on_untileable_head_dim():
+    """head_dim=12 tiles nowhere, and the entry point still runs the
+    KERNEL (interpreted here) rather than quietly handing the call to
+    the gather formulation — which it matches bit for bit anyway."""
+    assert not ragged_tileable(12, HQ, HKV, PAGE)
     args, _, _ = _scenario([5, 33], head_dim=12)
     oracle = paged_decode_attention(*args)
+    jaxpr = str(jax.make_jaxpr(ragged_paged_decode_attention)(*args))
+    assert "pallas_call" in jaxpr
     out = ragged_paged_decode_attention(*args)
     assert bool((out == oracle).all())
 
 
-def test_ragged_supported_predicate():
-    assert ragged_supported(16, 4, 2, 16, interpret=True)
-    assert ragged_supported(128, 8, 2, 16, interpret=False)
-    assert not ragged_supported(64, 8, 2, 16, interpret=False)   # hd % 128
-    assert not ragged_supported(16, 5, 2, 16, interpret=True)    # hq % hkv
+def test_ragged_tileable_predicate():
+    """The predicate answers for Mosaic on every platform, so a CPU run
+    and a TPU run of one config select the same path."""
+    assert ragged_tileable(128, 32, 32, 32)                  # 7B MHA
+    assert ragged_tileable(128, 32, 8, 32)                   # GQA 32:8
+    assert ragged_tileable(128, 8, 2, 16)
+    assert not ragged_tileable(64, 8, 2, 16)                 # hd % 128
+    assert not ragged_tileable(128, 4, 2, 16)                # hq % 8
+    assert not ragged_tileable(128, 8, 2, 8)                 # page % 16
+    assert not ragged_tileable(128, 8, 3, 16)                # hq % hkv
+    assert not ragged_tileable(D, HQ, HKV, PAGE)             # test shapes
 
 
 # -- engine integration ------------------------------------------------------
@@ -318,3 +327,6 @@ def test_ragged_attn_knob_validation(setup):
     engine, _ = _make_engine(cfg, params, paged_kv=True, kv_page=4,
                              ragged_attn="auto")
     assert engine.attn_path == "gather"
+    # ... and says why, instead of choosing silently
+    assert "cpu" in engine.attn_reason
+    assert engine.attention_paths()["decode"] == "gather"
