@@ -141,6 +141,12 @@ class Container:
         metrics.new_histogram("app_http_response",
                               "inbound HTTP response time (s)",
                               (0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1, 3, 10, 30))
+        metrics.new_histogram(
+            "app_http_stream_self_seconds",
+            "the server's own time in one streamed reply (s): head "
+            "serialisation, framing and socket writes; time awaiting the "
+            "producer is not in it",
+            (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1))
         metrics.new_histogram("app_http_service_response",
                               "outbound HTTP call time (s)",
                               (0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1, 3, 10, 30))
@@ -175,6 +181,12 @@ class Container:
             "app_tpu_ttft",
             "time to first generated token (s): admission wait + prefill "
             "(the first token is sampled inside the prefill executable)",
+            (0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0))
+        metrics.new_histogram(
+            "app_tpu_request_phase_seconds",
+            "app_tpu_ttft in its two parts, per request (s): "
+            "phase=queue (submit -> slot claimed) and phase=first_token "
+            "(slot claimed -> first token published)",
             (0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0))
         # SLO & saturation catalog (ISSUE 2): goodput vs raw throughput,
         # deadline outcome counts, device utilization, health transitions

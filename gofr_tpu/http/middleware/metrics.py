@@ -7,6 +7,8 @@ re-raising (previously failures bypassed the histogram entirely, so error
 storms were invisible in latency dashboards), and ``app_http_inflight``
 counts requests between arrival and response — the saturation signal a
 rate-of-completions histogram cannot give while requests are stuck.
+A streamed reply also records ``app_http_stream_self_seconds``: the
+server's own share of it (``StreamBody.self_s``).
 """
 
 from __future__ import annotations
@@ -57,6 +59,10 @@ def metrics_middleware(manager: Manager) -> Middleware:
                         "app_http_response", time.perf_counter() - start,
                         path=route, method=request.method,
                         status=str(status if ok else 500))
+                    # the server's share of that time: framing and writes
+                    manager.record_histogram(
+                        "app_http_stream_self_seconds", body.self_s,
+                        path=route)
                     settle()
 
                 body.on_complete(observe)

@@ -80,13 +80,17 @@ class StreamBody:
     registered via ``on_complete`` fire when the protocol finishes (or
     aborts) the stream, carrying ``(ok, messages)``. The logging/metrics
     middlewares use this to record true stream duration and a 500 status
-    on mid-stream producer failure instead of a near-zero 200."""
+    on mid-stream producer failure instead of a near-zero 200. By then
+    ``self_s`` holds the server's own seconds in the stream (head
+    serialisation, framing, socket writes), without the time it awaited
+    the producer."""
 
-    __slots__ = ("chunks", "sse", "_observers", "_completed")
+    __slots__ = ("chunks", "sse", "self_s", "_observers", "_completed")
 
     def __init__(self, chunks, sse: bool = False):
         self.chunks = chunks
         self.sse = sse
+        self.self_s = 0.0
         self._observers = []
         self._completed = False
 

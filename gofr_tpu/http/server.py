@@ -12,6 +12,7 @@ the hot serve loop free of framework overhead — important for the
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Awaitable, Callable, Dict, Optional, Tuple
 
 from gofr_tpu.aio import spawn_logged
@@ -224,6 +225,9 @@ class _HTTPProtocol(asyncio.Protocol):
                     pass
             body.complete(False, 0)
             return False
+        # body.self_s: the server's own segments (the head; per item,
+        # "item arrived" to "after transport.write"), never the awaits
+        began = time.perf_counter()
         head, _ = self._serialize_head(
             status, headers,
             extra=("Transfer-Encoding: chunked\r\n",
@@ -231,11 +235,13 @@ class _HTTPProtocol(asyncio.Protocol):
                    else "Connection: close\r\n"),
             skip=("content-length", "connection", "transfer-encoding"))
         self.transport.write((head + "\r\n").encode("latin-1"))
+        body.self_s = time.perf_counter() - began
         count = 0
         ok = False            # stream fully delivered (terminator written)
         client_gone = False   # client disconnected: not a server failure
         try:
             async for item in body.chunks:
+                began = time.perf_counter()
                 if self.closed or self.transport.is_closing():
                     client_gone = True
                     break          # stop producing
@@ -247,6 +253,7 @@ class _HTTPProtocol(asyncio.Protocol):
                     continue
                 count += 1
                 self.transport.write(b"%x\r\n%s\r\n" % (len(item), item))
+                body.self_s += time.perf_counter() - began
             if not client_gone and not self.closed \
                     and not self.transport.is_closing():
                 self.transport.write(b"0\r\n\r\n")

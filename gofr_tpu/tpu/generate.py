@@ -277,10 +277,11 @@ class _Fetch:
     engine-step span (dispatch → publish), finished when the fetch
     lands. ``dispatched_at`` anchors device-time attribution: dispatch →
     publish wall time is charged to the participating requests' {model,
-    slo class} (ISSUE 10). ``anatomy`` is the sampled decode-tick phase
-    breakdown (ISSUE 16): None on unsampled ticks; on every Nth tick the
-    loop stashes host-side phase timings here and ``_publish`` completes
-    them with the device wait before handing the dict to telemetry.
+    slo class} (ISSUE 10). ``anatomy`` carries a sampled tick's share of
+    the loop clock to ``_publish`` (None on unsampled ticks): the pass
+    that dispatched it stores its admit and dispatch laps here, and
+    ``_publish`` adds the device wait and its own lap before handing the
+    dict to telemetry.
     ``family`` names the compiled-executable family the dispatch hit
     (ISSUE 17) so the same elapsed window also lands in the
     per-executable roofline ledger."""
@@ -297,6 +298,86 @@ class _Fetch:
         self.dispatched_at = time.monotonic()
         self.anatomy = anatomy
         self.family = family
+
+
+class _LoopClock:
+    """The engine loop's one clock: who holds the serving thread.
+
+    Every pass of ``_loop_body`` is cut into contiguous phases. ``admit``
+    (admission staging, uploads, prefill / insert dispatch), ``dispatch``
+    (the decode or speculative tick up to its ``_Fetch``) and ``publish``
+    (every ``_publish`` of the pass) *hold* the event loop's thread;
+    ``wait`` (awaiting the oldest fetch, the 1 ms sleep of a pass that
+    dispatched nothing, a first-time compile running off the loop) and
+    ``park`` (no work) yield it to the other coroutines: HTTP parsing,
+    handlers, SSE framing, socket writes.
+
+    ``enter`` stamps a boundary with ``time.monotonic()`` and
+    ``time.thread_time()`` (CPU of the calling thread, which must be the
+    event loop's), charges the lap since the last stamp to the phase it
+    closes, and opens ``jax.profiler.TraceAnnotation("tpu.engine.<phase>")``
+    (a TraceMe no-op without a live capture) so the same spans lie on the
+    device trace's clock. The phases are contiguous, so the wall totals
+    sum to the time since the loop started; CPU that accrues in ``wait``
+    and ``park`` is everything else on the thread (``yield_cpu_s``), and
+    wall less CPU of a holding phase is time the engine kept the thread
+    while doing nothing (blocked in the runtime, or waiting for the GIL).
+    Readers: ``stats()["loop"]``, the profiler's trace, and the sampled
+    tick ring of ``/debug/timez`` (a view of the same stamps)."""
+
+    PHASES = ("admit", "dispatch", "publish", "wait", "park")
+    HOLDING = PHASES[:3]
+    __slots__ = ("wall", "cpu", "passes", "phase", "at", "_cpu_at", "_span",
+                 "_annotation")
+
+    def __init__(self, annotation):
+        self.wall = dict.fromkeys(self.PHASES, 0.0)
+        self.cpu = dict.fromkeys(self.PHASES, 0.0)
+        self.passes = 0
+        self.phase: Optional[str] = None    # None: the loop is not running
+        self.at = 0.0                       # monotonic stamp of the last boundary
+        self._cpu_at = 0.0
+        self._span = None
+        self._annotation = annotation
+
+    def enter(self, phase: Optional[str]) -> Tuple[float, float]:
+        """Close the open phase at a fresh stamp and open ``phase``
+        (``None`` stops the clock). Returns the closed lap as (wall, cpu)
+        seconds; entering the open phase again splits a lap."""
+        now, cpu = time.monotonic(), time.thread_time()
+        was, lap = self.phase, (0.0, 0.0)
+        if was is not None:
+            lap = (now - self.at, cpu - self._cpu_at)
+            self.wall[was] += lap[0]
+            self.cpu[was] += lap[1]
+        self.at, self._cpu_at = now, cpu
+        if phase != was:
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+            # a TraceMe starts when it is made; held across awaits, so not
+            # a ``with`` block: the next boundary closes it
+            self._span = (None if phase is None
+                          else self._annotation("tpu.engine." + phase))
+            self.phase = phase
+        return lap
+
+    def stats(self) -> Dict[str, Any]:
+        """Cumulative seconds, all monotone. The open phase's wall time up
+        to now is folded in, so ``wall_s`` is the time the loop has run;
+        its CPU lands at the next boundary."""
+        wall = dict(self.wall)
+        if self.phase is not None:
+            wall[self.phase] += max(0.0, time.monotonic() - self.at)
+        out: Dict[str, Any] = {"passes": self.passes,
+                               "wall_s": sum(wall.values())}
+        for phase in self.PHASES:
+            out[phase + "_s"] = wall[phase]
+        for phase in self.HOLDING:
+            out[phase + "_cpu_s"] = self.cpu[phase]
+        out["held_s"] = sum(wall[p] for p in self.HOLDING)
+        out["held_cpu_s"] = sum(self.cpu[p] for p in self.HOLDING)
+        out["yield_cpu_s"] = self.cpu["wait"] + self.cpu["park"]
+        return out
 
 
 class GenerationEngine:
@@ -643,10 +724,14 @@ class GenerationEngine:
         self._adopt_dedup_hits = 0
         self._brownout = 0
         self._quarantined: Dict[str, int] = {}
+        # the loop clock (always on): admit / dispatch / publish / wait /
+        # park, wall and thread CPU, read by stats()["loop"], the
+        # profiler's trace and the sampled tick ring
+        self._clock = _LoopClock(jax.profiler.TraceAnnotation)
         # continuous telemetry plane (ISSUE 16): when a TimeSeriesStore is
-        # attached, every Nth decode tick carries a phase-anatomy dict.
-        # Unsampled ticks pay one attribute load plus a modulo — nothing
-        # else changes on the hot path when telemetry is off (None).
+        # attached, every Nth decode tick hands it that tick's share of
+        # the loop clock. Unsampled ticks pay one attribute load plus a
+        # modulo — nothing else changes when telemetry is off (None).
         self.telemetry = None
         self._tick_seq = 0
         self._tick_every = 64
@@ -1784,6 +1869,7 @@ class GenerationEngine:
             except asyncio.CancelledError:
                 pass
             self._task = None
+            self._clock.enter(None)      # the loop is gone: stop its clock
 
     def _validate(self, prompt_ids, max_new_tokens: int) -> Tuple[List[int],
                                                                   int]:
@@ -2184,7 +2270,8 @@ class GenerationEngine:
         slot = self._slots[slot_idx]
         slot.future = future
         slot.submitted_at = (submitted_at if submitted_at is not None
-                             else time.monotonic())
+                             else record.admitted_at)
+        self._observe_phase("queue", record.admitted_at - slot.submitted_at)
         slot.deadline = current_deadline()
         slot.remaining = max_new_tokens
         slot.eos_id = eos_id
@@ -2484,8 +2571,9 @@ class GenerationEngine:
     def attach_telemetry(self, store, every: int = 64) -> None:
         """Wire the continuous telemetry plane (ISSUE 16): ``store`` gets
         a phase-anatomy dict for every ``every``-th decode tick via
-        ``note_tick``. Called by the app when telemetry is enabled; never
-        called → zero-cost (``self.telemetry`` stays None)."""
+        ``note_tick``, filled from the loop clock's stamps (``_LoopClock``).
+        Called by the app when telemetry is enabled; never called →
+        ``self.telemetry`` stays None and no dict is built."""
         self.telemetry = store
         self._tick_every = max(1, int(every))
 
@@ -2885,7 +2973,9 @@ class GenerationEngine:
                "device_seconds": {
                    f"{model}/{cls}": round(seconds, 6)
                    for (model, cls), seconds
-                   in sorted(self._device_seconds.items())}}
+                   in sorted(self._device_seconds.items())},
+               # who holds the serving thread, by phase (_LoopClock)
+               "loop": self._clock.stats()}
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
             out["prefix_cache"]["page_ladder"] = list(self._p_ladder)
@@ -3223,6 +3313,7 @@ class GenerationEngine:
                     self.logger.error("generation engine tick failed: %r",
                                       exc)
                 self._fail_outstanding(exc)
+                self._clock.enter("wait")    # the drain below yields
                 # drain in-flight fetches BEFORE rebuilding device state:
                 # their worker threads may still be reading the old buffers,
                 # and an unawaited task would log "exception was never
@@ -3329,14 +3420,9 @@ class GenerationEngine:
                     self._free.append(slot_idx)
 
     async def _loop_body(self, loop) -> None:
-        q = self._publishq
-        # sampled decode-tick anatomy (ISSUE 16): decide up front whether
-        # the NEXT dispatched tick is the Nth — only then do the phase
-        # clocks run. Unsampled passes cost one attr load plus a modulo.
-        ts = self.telemetry
-        sampled = (ts is not None
-                   and (self._tick_seq + 1) % self._tick_every == 0)
-        t_admit = time.monotonic() if sampled else 0.0
+        q, clock = self._publishq, self._clock
+        clock.passes += 1
+        clock.enter("admit")
         # 1. batched admission of everything pending (up to free slots);
         #    each prefill's first-token fetch starts concurrently
         for first_dev, claimed, step_span, family in \
@@ -3348,31 +3434,23 @@ class GenerationEngine:
 
         # 2. dispatch the next decode tick(s) up to the pipeline depth;
         #    its token fetch starts immediately in its own worker thread
-        dispatched = False
+        admit_lap = clock.enter("dispatch")
+        tick_entry = None
         if (self.active_slots > 0
                 and self._ticks_inflight < self.max_inflight_ticks):
-            t_dispatch = time.monotonic() if sampled else 0.0
             tick = await self._dispatch_tick(loop)
             if tick is not None:
                 kind, fetch, payload, step_span, family = tick
                 self._ticks_inflight += 1
-                anatomy = None
-                if ts is not None:
-                    self._tick_seq += 1
-                    if sampled:
-                        done = time.monotonic()
-                        anatomy = {
-                            "admission_s": t_dispatch - t_admit,
-                            "host_dispatch_s": done - t_dispatch,
-                        }
-                q.append(_Fetch(loop.run_in_executor(None, fetch),
-                                kind, payload, span=step_span,
-                                anatomy=anatomy, family=family))
-                dispatched = True
+                tick_entry = _Fetch(loop.run_in_executor(None, fetch),
+                                    kind, payload, span=step_span,
+                                    family=family)
+                q.append(tick_entry)
 
         if not q:
             if (self.active_slots == 0 and self._pending.empty()
                     and not self._overflow):
+                clock.enter("park")
                 self._wake.clear()
                 await self._wake.wait()
             else:
@@ -3384,13 +3462,27 @@ class GenerationEngine:
                 # loop would monopolize the event loop and starve the
                 # very coroutines (exporter quiesce poll, stream
                 # consumers) that unblock it.
+                clock.enter("wait")
                 await asyncio.sleep(0.001)
             return
 
         # 3. publish in dispatch order (per-slot token order). Block on the
         #    oldest fetch only when the pipeline can't go deeper; then
         #    drain whatever else already completed.
-        if not dispatched or self._ticks_inflight >= self.max_inflight_ticks:
+        block = (tick_entry is None
+                 or self._ticks_inflight >= self.max_inflight_ticks)
+        dispatch_lap = clock.enter("wait" if block else "publish")
+        if tick_entry is not None and self.telemetry is not None:
+            # every Nth tick carries this pass's laps to _publish, which
+            # completes them for the sampled tick ring (/debug/timez)
+            self._tick_seq += 1
+            if self._tick_seq % self._tick_every == 0:
+                tick_entry.anatomy = {
+                    "admission_s": admit_lap[0],
+                    "admission_cpu_s": admit_lap[1],
+                    "host_dispatch_s": dispatch_lap[0],
+                    "host_dispatch_cpu_s": dispatch_lap[1]}
+        if block:
             entry = q.popleft()
             self._publish(entry, await entry.task)
         while q and q[0].task.done():
@@ -3426,21 +3518,10 @@ class GenerationEngine:
             ledger=self.exec_ledger)
 
     def _publish(self, entry: _Fetch, host) -> None:
+        clock = self._clock
+        clock.enter("publish")       # ends the wait, or the last publish
+        landed = clock.at
         self._attribute_device_time(entry)
-        # sampled tick anatomy (ISSUE 16): the dispatch phases were
-        # clocked in _loop_body; the device wait (dispatch → fetch landed)
-        # completes the breakdown before it enters the flight-recorder
-        # ring. Unsampled entries carry anatomy=None — one pointer test.
-        if entry.anatomy is not None and self.telemetry is not None:
-            anatomy = entry.anatomy
-            anatomy["device_wait_s"] = time.monotonic() - entry.dispatched_at
-            anatomy["kind"] = entry.kind
-            anatomy["batch"] = len(entry.payload[0]
-                                   if entry.kind == "spec"
-                                   else entry.payload)
-            anatomy["step"] = self._steps
-            anatomy["at"] = time.time()
-            self.telemetry.note_tick(anatomy)
         if entry.kind == "prefill":
             for slot_idx, gen, row in entry.payload:
                 self._push_tokens(slot_idx, gen, [int(host[row])])
@@ -3484,6 +3565,18 @@ class GenerationEngine:
                                   [int(t) for t in host[:, slot_idx]])
         if entry.span is not None:   # step span covers dispatch → publish
             entry.span.finish()
+        if entry.anatomy is not None and self.telemetry is not None:
+            # a sampled tick (see _loop_body): the device wait (dispatch →
+            # fetch taken up) and this publish, off the same loop clock
+            publish_lap = clock.enter("publish")
+            entry.anatomy.update(
+                device_wait_s=landed - entry.dispatched_at,
+                publish_s=publish_lap[0], publish_cpu_s=publish_lap[1],
+                kind=entry.kind,
+                batch=len(entry.payload[0] if entry.kind == "spec"
+                          else entry.payload),
+                step=self._steps, at=time.time())
+            self.telemetry.note_tick(entry.anatomy)
 
     def _note_spec(self, proposed: int, accepted: int) -> None:
         """Acceptance accounting plus the adaptive-γ controller: every
@@ -3738,6 +3831,8 @@ class GenerationEngine:
                     flight.qspan.set_attribute("slot", slot_idx)
                     flight.qspan.finish()
                 flight.record.admitted()
+                self._observe_phase(
+                    "queue", flight.record.admitted_at - submitted_at)
                 flight.record.cached_prefix_len = plen
                 slot.record = flight.record
                 slot.req_span = flight.link_span
@@ -4013,7 +4108,7 @@ class GenerationEngine:
                             draft_dispatch()
                         return first
 
-                    first_dev = await loop.run_in_executor(None, cold)
+                    first_dev = await self._off_loop(loop, cold)
                 self._prefills += 1
                 self._prefill_bucket_tokens += nb * bucket
                 family = (f"suffix_prefill[nb={nb},p={p_rung},b={bucket}]"
@@ -4024,6 +4119,16 @@ class GenerationEngine:
                 self._prefix.release(leases)
         self._set_queue_gauges()
         return fetches
+
+    async def _off_loop(self, loop, compile_and_dispatch):
+        """A first-time compile runs in a worker thread; the engine yields
+        the event loop meanwhile, so the stretch is the clock's ``wait``."""
+        phase = self._clock.phase
+        self._clock.enter("wait")
+        try:
+            return await loop.run_in_executor(None, compile_and_dispatch)
+        finally:
+            self._clock.enter(phase)
 
     def _profile_step(self, name: str):
         """``StepTraceAnnotation`` for the on-demand profiler (ISSUE 10):
@@ -4260,7 +4365,7 @@ class GenerationEngine:
             with self._profile_step("tpu.engine.step"):
                 tokens_dev = dispatch()
         else:
-            tokens_dev = await loop.run_in_executor(None, dispatch)
+            tokens_dev = await self._off_loop(loop, dispatch)
         self._steps += 1
         if self.metrics is not None:
             exemplar = next(
@@ -4366,7 +4471,7 @@ class GenerationEngine:
         if warm:
             pair = dispatch()
         else:
-            pair = await loop.run_in_executor(None, dispatch)
+            pair = await self._off_loop(loop, dispatch)
         self._steps += 1
         if self.metrics is not None:
             self.metrics.record_histogram(
@@ -4515,6 +4620,20 @@ class GenerationEngine:
         if self._prefix is not None:
             self._prefix.reset()
 
+    def _observe_phase(self, phase: str, seconds: float) -> None:
+        """``app_tpu_request_phase_seconds{model, phase}``: a request's
+        ``queue`` phase (submit → slot claimed) and ``first_token`` phase
+        (slot claimed → first token published: the ticks in flight ahead
+        of its prefill, the prefill, the fetch), on the stamps
+        ``app_tpu_ttft`` uses, so per request queue + first_token = ttft.
+        A request cancelled, expired or refused before it got a slot has
+        neither, like ``app_tpu_ttft``; one that got a slot and ended
+        before its first token has ``queue`` alone."""
+        if self.metrics is not None:
+            self.metrics.record_histogram(
+                "app_tpu_request_phase_seconds", seconds,
+                model=self.model_name, phase=phase)
+
     def _push_tokens(self, slot_idx: int, gen: int,
                      tokens: List[int]) -> None:
         """Append generated tokens to a slot, handling eos/budget; stale
@@ -4530,9 +4649,12 @@ class GenerationEngine:
             # operator-facing TTFT — admission wait + prefill dispatch +
             # fetch (the first token is sampled in the prefill executable,
             # so no decode tick is included)
+            now = time.monotonic()
+            ttft = now - slot.submitted_at
             if slot.record is not None:
                 slot.record.first_token()
-            ttft = time.monotonic() - slot.submitted_at
+                self._observe_phase("first_token",
+                                    now - slot.record.admitted_at)
             if self.metrics is not None:
                 self.metrics.record_histogram(
                     "app_tpu_ttft", ttft,

@@ -587,6 +587,11 @@ def test_engine_records_sampled_tick_anatomy(setup):
     entry = out["recent"][-1]
     assert entry["kind"] in ("tick", "spec")
     assert entry["batch"] >= 1
-    for phase in ("admission_s", "host_dispatch_s", "device_wait_s"):
+    # the ring is a view of the loop clock (ISSUE 25): the old keys, the
+    # publish phase, and the CPU twins of the phases that hold the thread
+    for phase in ("admission_s", "host_dispatch_s", "device_wait_s",
+                  "publish_s", "admission_cpu_s", "host_dispatch_cpu_s",
+                  "publish_cpu_s"):
         assert entry[phase] >= 0.0
+    assert out["phases"]["publish_s"]["mean_s"] > 0.0
     assert out["phases"]["device_wait_s"]["mean_s"] > 0.0
