@@ -244,6 +244,16 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
         ref = jax.jit(functools.partial(reference, **kwargs))(*args)
         results[name] = _agree(name, out, ref)
 
+    def one_plane(kernel):
+        # the ragged kernels read the stacked (L, ...) pool and take the
+        # layer; a case holding one plane is layer 0 of plane[None]
+        def call(q, k_pages, v_pages, *rest, interpret, **scales):
+            return kernel(q, k_pages[None], v_pages[None], *rest, 0,
+                          interpret=interpret,
+                          **{name: plane[None]
+                             for name, plane in scales.items()})
+        return call
+
     for q_heads, kv_heads in spec["heads"]:
         tag = f"{q_heads}:{kv_heads}"
         seq = spec["flash_seq"]
@@ -255,11 +265,12 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
             args, scales = _paged_case(rng, slots, pages_per_slot, page,
                                        kv_heads, q_heads, head_dim, 1, int8)
             compare(f"ragged_decode {tag} {'int8' if int8 else 'bf16'}",
-                    ragged_paged_decode_attention,
+                    one_plane(ragged_paged_decode_attention),
                     paged_decode_attention, args, scales)
         args, scales = _paged_case(rng, slots, pages_per_slot, page,
                                    kv_heads, q_heads, head_dim, 5, False)
-        compare(f"ragged_verify {tag} g=5", ragged_paged_verify_attention,
+        compare(f"ragged_verify {tag} g=5",
+                one_plane(ragged_paged_verify_attention),
                 paged_verify_attention, args)
         # dense flash-decode reads a per-slot cache, not the pool
         t_max = spec["dense_len"]

@@ -488,6 +488,15 @@ def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     return logits, new_cache, cache_len + 1
 
 
+def _gather_layer_pages(pools, idx, page_table):
+    """Layer ``idx``'s dense-cache-shaped (B, P*page, ...) views of the
+    carried pool leaves. XLA fuses the layer slice into the gather, so
+    no plane is materialized on this path."""
+    return [gather_kv_pages(
+        lax.dynamic_index_in_dim(c, idx, 0, keepdims=False), page_table)
+        for c in pools]
+
+
 def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
                       token: jnp.ndarray, pool: Dict[str, jnp.ndarray],
                       page_table: jnp.ndarray, cache_len: jnp.ndarray,
@@ -524,11 +533,17 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     materialized — the kernel walks the slot's actual pages via scalar
     prefetch — so ``page_table`` may carry the slot's *full* table (no
     ladder rung slicing) and int8 dequant happens in-kernel from the
-    scale planes. Takes priority over ``cfg.use_flash_decode`` and,
-    unlike it, supports int8. Token-identical to the gather path, which
-    remains the correctness oracle. Whether the geometry suits the
-    kernel is the caller's call (ops.pallas.ragged_tileable); nothing in
-    here falls back.
+    scale planes. The kernel takes the carried leaves WHOLE plus the
+    scan's layer index and picks the layer in its index maps: a
+    ``dynamic_index_in_dim`` here cannot fuse into a pallas_call the way
+    it fuses into the gather, so XLA would copy one layer's plane of
+    the entire pool per leaf, per layer, per step (8 ms of a 35 ms step
+    on a v5e, PERF.md PR 26). It reads the pool as it was before this
+    layer's append; the new row arrives beside it. Takes priority over
+    ``cfg.use_flash_decode`` and, unlike it, supports int8.
+    Token-identical to the gather path, which remains the correctness
+    oracle. Whether the geometry suits the kernel is the caller's call
+    (ops.pallas.ragged_tileable); nothing in here falls back.
     """
     b = token.shape[0]
     cos, sin = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
@@ -549,24 +564,20 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
         x = carry[0]
         pools = carry[1:]
         layer, idx = layer_and_idx
-        planes = [lax.dynamic_index_in_dim(c, idx, 0, keepdims=False)
-                  for c in pools]                        # (N, page, ...)
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
         if ragged:
             from gofr_tpu.ops.pallas import ragged_paged_decode_attention
             attn = ragged_paged_decode_attention(
-                q, planes[0], planes[1], page_table, k[:, 0], v[:, 0],
-                cache_len,
-                k_scale_pages=planes[2] if int8 else None,
-                v_scale_pages=planes[3] if int8 else None)
+                q, pools[0], pools[1], page_table, k[:, 0], v[:, 0],
+                cache_len, idx, *pools[2:])
         elif _flash_decode(cfg, page_table.shape[1] * page):
             from gofr_tpu.ops.pallas import flash_decode_attention
-            views = [gather_kv_pages(p, page_table) for p in planes]
+            views = _gather_layer_pages(pools, idx, page_table)
             attn = flash_decode_attention(q, views[0], views[1], k[:, 0],
                                           v[:, 0], cache_len)
         else:
-            views = [gather_kv_pages(p, page_table) for p in planes]
+            views = _gather_layer_pages(pools, idx, page_table)
             k_scale = views[2] if int8 else None
             v_scale = views[3] if int8 else None
             attn = decode_attention_cached(q, views[0], views[1], k[:, 0],
@@ -681,8 +692,8 @@ def verify_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     guarantees an active row's allocated pages cover
     ``cache_len + G`` before dispatching a γ=G verify rung.
     ``ragged=True`` runs the fused Pallas kernel's γ+1-query variant
-    over the pool pages directly (no gathered view), same semantics as
-    on :func:`decode_step_paged`.
+    over the stacked pool leaves in place (no gathered view, no
+    per-layer slice), same semantics as on :func:`decode_step_paged`.
     """
     b, g_len = tokens.shape
     cos, sin = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
@@ -704,18 +715,15 @@ def verify_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
         x = carry[0]
         pools = carry[1:]
         layer, idx = layer_and_idx
-        planes = [lax.dynamic_index_in_dim(c, idx, 0, keepdims=False)
-                  for c in pools]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
         if ragged:
             from gofr_tpu.ops.pallas import ragged_paged_verify_attention
             attn = ragged_paged_verify_attention(
-                q, planes[0], planes[1], page_table, k, v, cache_len,
-                k_scale_pages=planes[2] if int8 else None,
-                v_scale_pages=planes[3] if int8 else None)
+                q, pools[0], pools[1], page_table, k, v, cache_len, idx,
+                *pools[2:])
         else:
-            views = [gather_kv_pages(p, page_table) for p in planes]
+            views = _gather_layer_pages(pools, idx, page_table)
             k_scale = views[2] if int8 else None
             v_scale = views[3] if int8 else None
             attn = verify_attention(q, views[0], views[1], k, v, cache_len,

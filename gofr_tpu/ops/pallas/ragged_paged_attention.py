@@ -13,6 +13,16 @@ could walk in place. This kernel walks them in place:
   **scalar prefetch**, so each program's K/V BlockSpec index map reads
   its slot's *actual* pool row directly from the table — no materialized
   gather, no static width ladder, one executable for every fill level.
+- The pool arrives STACKED, ``(L, num_pages, page, Hkv, D)``, exactly as
+  the layer scan carries it, and the layer index is a third
+  scalar-prefetch operand that the same index maps read. A pallas_call
+  is opaque to XLA: a ``dynamic_index_in_dim`` taken outside it cannot
+  fuse into the kernel as it fuses into a gather, so XLA materializes
+  one layer's plane of the WHOLE pool (live or free) per operand, per
+  layer, per step — 8 ms of a 35 ms Mistral-7B decode step on a v5e
+  (PERF.md, PR 26). Picking the layer in the BlockSpec is what makes
+  "in place" true on the chip; tests/test_pallas_aot.py holds the
+  compiled engine tick to it.
 - TWO-PHASE page walk for token identity: the page-block axis runs the
   table twice. Phase 0 streams K only and finishes the softmax
   statistics (max and normalizer in VMEM scratch); phase 1 re-derives
@@ -95,9 +105,9 @@ def _round_to(x, dtype):
     return lax.bitcast_convert_type(bits, jnp.float32)
 
 
-def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
-                   *rest, page: int, num_pi: int, kv_heads: int, group: int,
-                   g_len: int, int8: bool, sm_scale: float):
+def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, kn_ref,
+                   vn_ref, *rest, page: int, num_pi: int, kv_heads: int,
+                   group: int, g_len: int, int8: bool, sm_scale: float):
     """One (slot, walk-step) program on the doubled page-block axis.
 
     Steps ``[0, num_pi)`` are phase 0 (K only): accumulate the softmax
@@ -110,7 +120,9 @@ def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
     the new tokens' contribution and writes the output. ``rest`` is
     (ks, vs, out, acc, m, l) on int8 pools — the scale-plane blocks ride
     the same index maps as their pages — and (out, acc, m, l) on bf16
-    pools, so bf16 never fetches a dead operand.
+    pools, so bf16 never fetches a dead operand. ``layer_ref`` is read by
+    the index maps alone: the layer dimension is squeezed out of every
+    pool block, so the body sees one layer's ``(1, page, Hkv, D)`` page.
 
     Everything is per kv-head with the head on a LEADING axis (q, the new
     K/V, the output and the scratch all arrive head-major from the
@@ -232,14 +244,25 @@ def _ragged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
 
 
 def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
-                   cache_len, *scale_pages, interpret: bool):
-    """``scale_pages`` is ``(k_scale_pages, v_scale_pages)`` on int8
-    pools and empty on bf16 pools."""
+                   cache_len, layer, *scale_pages, interpret: bool):
+    """``k_pages`` / ``v_pages`` are the STACKED pool leaves
+    ``(L, num_pages, page, Hkv, D)`` and ``layer`` (int32, shape (1,),
+    traced) says which layer to read; ``scale_pages`` is
+    ``(k_scale_pages, v_scale_pages)``, stacked the same way, on int8
+    pools and empty on bf16 pools.
+
+    The scale leaves are the one thing sliced out here: their minor
+    dimension is Hkv (8 of 128 lanes), so XLA carries them in a layout
+    of its own, and handing a custom call the whole leaf makes it
+    re-lay the WHOLE leaf out, padded sixteenfold, every layer (AOT at
+    Mistral-7B sizes: two 452 MB copies a layer). One layer's scale
+    plane is 1/128 of its K plane, so slicing it costs what it always
+    did."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, g_len, q_heads, head_dim = q.shape
-    num_pages, page, kv_heads, _ = k_pages.shape
+    _, num_pages, page, kv_heads, _ = k_pages.shape
     group = q_heads // kv_heads
     rows = g_len * group
     num_pi = page_table.shape[1]
@@ -249,7 +272,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     # head-major operands (see _ragged_kernel): q-head kv*group + j of
     # query g becomes row g*group + j of kv-head kv. q and the new K/V
     # are a few KB per slot, so the transposes cost nothing next to the
-    # pool walk; the pool itself is read in place.
+    # pool walk; the pool itself is read in place, the layer picked by
+    # the index maps (module docstring).
     q_hm = q.reshape(batch, g_len, kv_heads, group, head_dim) \
         .transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads, rows, head_dim)
     kn_hm = k_new.transpose(0, 2, 1, 3)            # (B, Hkv, G, D)
@@ -266,23 +290,24 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         pid = table_ref[b, jnp.minimum(pj, last)]
         return jnp.minimum(pid, num_pages - 1)
 
-    def k_index(b, pi, table_ref, len_ref):
+    def k_index(b, pi, table_ref, len_ref, layer_ref):
         # K streams in BOTH phases (scores are re-derived in phase 1)
-        return (_row(b, lax.rem(pi, num_pi), table_ref, len_ref), 0, 0, 0)
+        return (layer_ref[0],
+                _row(b, lax.rem(pi, num_pi), table_ref, len_ref), 0, 0, 0)
 
-    def v_index(b, pi, table_ref, len_ref):
+    def v_index(b, pi, table_ref, len_ref, layer_ref):
         # V is only read in phase 1; during phase 0 the map parks on the
         # row phase 1 fetches first, so no dead V block is ever streamed
         pj = jnp.where(pi >= num_pi, lax.rem(pi, num_pi), 0)
-        return (_row(b, pj, table_ref, len_ref), 0, 0, 0)
+        return (layer_ref[0], _row(b, pj, table_ref, len_ref), 0, 0, 0)
 
-    def ks_index(b, pi, table_ref, len_ref):
-        return k_index(b, pi, table_ref, len_ref)[:3]
+    def ks_index(*args):
+        return k_index(*args)[1:4]
 
-    def vs_index(b, pi, table_ref, len_ref):
-        return v_index(b, pi, table_ref, len_ref)[:3]
+    def vs_index(*args):
+        return v_index(*args)[1:4]
 
-    def q_index(b, pi, table_ref, len_ref):
+    def q_index(b, pi, table_ref, len_ref, layer_ref):
         return (b, 0, 0, 0)
 
     kernel = functools.partial(
@@ -290,8 +315,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         group=group, g_len=g_len, int8=int8, sm_scale=head_dim ** -0.5)
     in_specs = [
         pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
-        pl.BlockSpec((1, page, kv_heads, head_dim), k_index),
-        pl.BlockSpec((1, page, kv_heads, head_dim), v_index),
+        pl.BlockSpec((None, 1, page, kv_heads, head_dim), k_index),
+        pl.BlockSpec((None, 1, page, kv_heads, head_dim), v_index),
         pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
         pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
     ]
@@ -299,9 +324,10 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     if int8:
         in_specs += [pl.BlockSpec((1, page, kv_heads), ks_index),
                      pl.BlockSpec((1, page, kv_heads), vs_index)]
-        operands += list(scale_pages)
+        operands += [lax.dynamic_index_in_dim(s, layer[0], 0, keepdims=False)
+                     for s in scale_pages]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(batch, 2 * num_pi),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
@@ -321,7 +347,7 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         out_shape=jax.ShapeDtypeStruct(q_hm.shape, q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(table, lens, *operands)
+    )(table, lens, layer, *operands)
     return out.reshape(batch, kv_heads, g_len, group, head_dim) \
         .transpose(0, 2, 1, 3, 4).reshape(q.shape)
 
@@ -332,34 +358,43 @@ def _scales(k_scale_pages, v_scale_pages):
     return () if k_scale_pages is None else (k_scale_pages, v_scale_pages)
 
 
+def _layer(layer):
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
-                                  v_new, cache_len, k_scale_pages=None,
-                                  v_scale_pages=None,
+                                  v_new, cache_len, layer,
+                                  k_scale_pages=None, v_scale_pages=None,
                                   interpret: Optional[bool] = None
                                   ) -> jnp.ndarray:
-    """Kernel counterpart of ops.attention.paged_decode_attention.
-    q (B,1,Hq,D); k_pages/v_pages (num_pages,page,Hkv,D); page_table
-    (B,P) int32 with ``num_pages`` the unallocated sentinel; k_new/v_new
-    (B,Hkv,D); cache_len (B,) valid tokens excluding the current one;
-    int8 pools pass the (num_pages,page,Hkv) scale planes.
-    ``interpret=None`` follows the lowering target (ops/pallas/select).
-    Returns (B,1,Hq,D)."""
+    """Kernel counterpart of ops.attention.paged_decode_attention, over
+    the pool as the engine holds it. q (B,1,Hq,D); k_pages/v_pages the
+    stacked pool leaves (L,num_pages,page,Hkv,D) and ``layer`` the int32
+    scalar (traced: the layer scan's index) naming the plane to read —
+    a caller holding a single plane passes ``plane[None]`` and 0;
+    page_table (B,P) int32 with ``num_pages`` the unallocated sentinel;
+    k_new/v_new (B,Hkv,D); cache_len (B,) valid tokens excluding the
+    current one; int8 pools pass the (L,num_pages,page,Hkv) scale
+    planes. ``interpret=None`` follows the lowering target
+    (ops/pallas/select). Returns (B,1,Hq,D)."""
     return lower_for_target(
         _pallas_ragged, interpret, q, k_pages, v_pages, page_table,
-        k_new[:, None], v_new[:, None], cache_len,
+        k_new[:, None], v_new[:, None], cache_len, _layer(layer),
         *_scales(k_scale_pages, v_scale_pages))
 
 
 def ragged_paged_verify_attention(q, k_pages, v_pages, page_table, k_new,
-                                  v_new, cache_len, k_scale_pages=None,
-                                  v_scale_pages=None,
+                                  v_new, cache_len, layer,
+                                  k_scale_pages=None, v_scale_pages=None,
                                   interpret: Optional[bool] = None
                                   ) -> jnp.ndarray:
     """γ+1-token variant backing speculative verify: kernel counterpart
-    of ops.attention.paged_verify_attention. q (B,G,Hq,D); k_new/v_new
+    of ops.attention.paged_verify_attention, pool and ``layer`` as on
+    :func:`ragged_paged_decode_attention`. q (B,G,Hq,D); k_new/v_new
     (B,G,Hkv,D) — query g sits at position ``cache_len + g``, attends
     the paged cache (< cache_len) plus the new tokens causally
     (u <= g). Returns (B,G,Hq,D)."""
     return lower_for_target(
         _pallas_ragged, interpret, q, k_pages, v_pages, page_table,
-        k_new, v_new, cache_len, *_scales(k_scale_pages, v_scale_pages))
+        k_new, v_new, cache_len, _layer(layer),
+        *_scales(k_scale_pages, v_scale_pages))

@@ -94,17 +94,18 @@ def main() -> None:
     table = np.full((1, 4), num_pages, np.int32)
     table[0, :2] = [0, 1]
     cache_len = jnp.asarray([13], jnp.int32)
-    args = (q, k_pages, v_pages, jnp.asarray(table), k_new, v_new,
-            cache_len)
-    clean = ragged_paged_decode_attention(*args)
+    # the kernel reads the stacked (L, ...) pool: one plane is layer 0
+    clean = ragged_paged_decode_attention(
+        q, k_pages[None], v_pages[None], jnp.asarray(table), k_new, v_new,
+        cache_len, 0)
     poisoned_k = np.asarray(k_pages, np.float32)
     poisoned_k[2:] = np.nan
     poisoned_v = np.asarray(v_pages, np.float32)
     poisoned_v[2:] = np.nan
     out = ragged_paged_decode_attention(
-        q, jnp.asarray(poisoned_k).astype(cfg.dtype),
-        jnp.asarray(poisoned_v).astype(cfg.dtype),
-        jnp.asarray(table), k_new, v_new, cache_len)
+        q, jnp.asarray(poisoned_k).astype(cfg.dtype)[None],
+        jnp.asarray(poisoned_v).astype(cfg.dtype)[None],
+        jnp.asarray(table), k_new, v_new, cache_len, 0)
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all()), \
         "sentinel page NaN reached the kernel output"
     assert bool((out == clean).all()), "poisoned dead pages moved output"

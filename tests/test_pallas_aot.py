@@ -7,9 +7,16 @@ it is not attached to, so this AOT-compiles each kernel for ``v5e:2x2``
 with ``interpret=False`` at the two serving geometries, Llama-2-7B MHA
 (32:32) and Llama-3-8B GQA (32:8), head_dim 128. It proves compilation
 only; numerics on the chip are ``chip_smoke.py``'s kernels phase.
+
+The last test compiles what the engine really runs around the ragged
+kernel — its fused decode tick and its verify step, pool donated — and
+reads the optimised HLO: the pool must reach the kernel as the layer
+scan's carry itself, with no per-layer plane and no copy of the pool
+materialised (PR 26: two such planes cost 8 ms of a 35 ms decode step).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,13 +55,13 @@ def _paged_shapes(q_heads, kv_heads, g_len, int8):
     bf16 = jnp.bfloat16
     new = ((SLOTS, kv_heads, HEAD_DIM) if g_len == 1
            else (SLOTS, g_len, kv_heads, HEAD_DIM))
-    pool = ((NUM_PAGES, PAGE, kv_heads, HEAD_DIM),
+    pool = ((1, NUM_PAGES, PAGE, kv_heads, HEAD_DIM),
             jnp.int8 if int8 else bf16)
     shapes = [((SLOTS, g_len, q_heads, HEAD_DIM), bf16), pool, pool,
               ((SLOTS, PAGES_PER_SLOT), jnp.int32), (new, bf16),
-              (new, bf16), ((SLOTS,), jnp.int32)]
+              (new, bf16), ((SLOTS,), jnp.int32), ((), jnp.int32)]
     if int8:
-        shapes += [((NUM_PAGES, PAGE, kv_heads), jnp.float32)] * 2
+        shapes += [((1, NUM_PAGES, PAGE, kv_heads), jnp.float32)] * 2
     return shapes
 
 
@@ -96,27 +103,105 @@ def test_ragged_verify_compiles_for_v5e(v5e, q_heads, kv_heads):
              v5e, *_paged_shapes(q_heads, kv_heads, 5, False))
 
 
-def test_default_interpret_follows_the_lowering_target(v5e):
-    """``interpret=None`` inside the model's paged decode step: lowered
-    for a TPU from this CPU process it must hold the Mosaic kernel, not
-    an inlined interpreter — the way a first chipless attempt "compiled"
-    ``ragged=True`` and proved nothing."""
-    cfg = llama.config("llama3-8b", n_layers=1, max_seq_len=256)
+def _compile_paged_step(step, sharding, n_layers, num_pages, token_shape,
+                        donate=(), kv_int8=False):
+    """Compile ``step(params, cfg, token, pool, table, cache_len,
+    active)`` for v5e at Llama-3-8B widths (GQA 32:8), int8 weights and
+    a pool of ``num_pages`` pages. Returns (optimised HLO text, K/V pool
+    leaf shape)."""
+    cfg = llama.config("llama3-8b", n_layers=n_layers, max_seq_len=256,
+                       kv_int8=kv_int8)
     params = jax.eval_shape(lambda: llama.init_int8(cfg))
-    pool_shape = (cfg.n_layers, NUM_PAGES, PAGE, cfg.n_kv_heads,
+    pool_shape = (cfg.n_layers, num_pages, PAGE, cfg.n_kv_heads,
                   cfg.head_dim)
-    pool = {"k": jax.ShapeDtypeStruct(pool_shape, cfg.dtype),
-            "v": jax.ShapeDtypeStruct(pool_shape, cfg.dtype)}
-    abstract = (params, jax.ShapeDtypeStruct((SLOTS,), jnp.int32), pool,
+    kv = jax.ShapeDtypeStruct(pool_shape, jnp.int8 if kv_int8 else cfg.dtype)
+    pool = {"k": kv, "v": kv}
+    if kv_int8:
+        scale = jax.ShapeDtypeStruct(pool_shape[:-1], jnp.float32)
+        pool.update(ks=scale, vs=scale)
+    abstract = (params, jax.ShapeDtypeStruct(token_shape, jnp.int32), pool,
                 jax.ShapeDtypeStruct((SLOTS, PAGES_PER_SLOT), jnp.int32),
                 jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
                 jax.ShapeDtypeStruct((SLOTS,), jnp.bool_))
     abstract = jax.tree.map(
         lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                          sharding=v5e), abstract)
+                                          sharding=sharding), abstract)
+    compiled = jax.jit(
+        lambda params, *rest: step(params, cfg, *rest),
+        donate_argnums=donate).lower(*abstract).compile()
+    return compiled.as_text(), pool_shape
 
-    def step(params, token, pool, table, cache_len, active):
-        return llama.decode_step_paged(params, cfg, token, pool, table,
-                                       cache_len, active, ragged=True)
-    compiled = jax.jit(step).lower(*abstract).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+
+def test_default_interpret_follows_the_lowering_target(v5e):
+    """``interpret=None`` inside the model's paged decode step: lowered
+    for a TPU from this CPU process it must hold the Mosaic kernel, not
+    an inlined interpreter — the way a first chipless attempt "compiled"
+    ``ragged=True`` and proved nothing."""
+    hlo, _ = _compile_paged_step(
+        functools.partial(llama.decode_step_paged, ragged=True), v5e,
+        n_layers=1, num_pages=NUM_PAGES, token_shape=(SLOTS,))
+    assert "tpu_custom_call" in hlo
+
+
+def _decode_tick(params, cfg, token, pool, table, cache_len, active):
+    """The engine's fused greedy tick (GenerationEngine's paged
+    ``decode_k``): a scan of K=4 ragged decode steps."""
+    def one(carry, _):
+        token, pool, cache_len = carry
+        logits, pool, new_len = llama.decode_step_paged(
+            params, cfg, token, pool, table, cache_len, active,
+            ragged=True)
+        next_token = logits.argmax(axis=-1).astype(token.dtype)
+        return (jnp.where(active, next_token, token), pool,
+                jnp.where(active, new_len, cache_len)), next_token
+
+    (_, pool, cache_len), tokens = jax.lax.scan(
+        one, (token, pool, cache_len), None, length=4)
+    return tokens, pool, cache_len
+
+
+# 11 pages: no other tensor of these programs has a leading 11, so the
+# plane's and the pool's shapes below can only be the pool's
+POOL_READ_IN_PLACE = {
+    "decode-tick": (_decode_tick, (SLOTS,), (2, 4), False),
+    "decode-tick-int8": (_decode_tick, (SLOTS,), (2, 4), True),
+    "verify": (functools.partial(llama.verify_step_paged, ragged=True),
+               (SLOTS, 5), (2,), False),
+}
+
+
+@pytest.mark.parametrize("case", POOL_READ_IN_PLACE)
+def test_ragged_step_reads_the_stacked_pool_in_place(v5e, case):
+    """A pallas_call is opaque to XLA, so a layer slice taken outside
+    the kernel is materialised: one layer's plane of the whole pool, per
+    leaf, per layer, per step. The kernel picks the layer in its index
+    maps instead; this holds the compiled program to it. Nothing may
+    have the plane's shape, and the kernel must read the pool before the
+    layer's in-place append without XLA copying the pool to keep the two
+    apart (``copy(`` alone: a pool this small is also prefetched whole
+    into fast memory, copy-start / copy-done, which the real one never
+    fits). On an int8 pool the scale leaves must NOT reach the kernel
+    whole: XLA carries them in a layout of its own (their minor
+    dimension is Hkv), and a custom call handed a whole leaf has it
+    re-laid out, padded sixteenfold, every layer — so the kernel's
+    wrapper slices those (1/128 of a K plane)."""
+    step, token_shape, donate, kv_int8 = POOL_READ_IN_PLACE[case]
+    hlo, pool_shape = _compile_paged_step(
+        step, v5e, n_layers=2, num_pages=11, token_shape=token_shape,
+        donate=donate, kv_int8=kv_int8)
+
+    def dims(shape):
+        return "[" + ",".join(map(str, shape)) + "]"
+
+    def results(shape, opcode=""):
+        return re.findall(rf"^.*= \w+{re.escape(dims(shape))}\S* {opcode}.*$",
+                          hlo, re.M)
+
+    kernel, = re.findall(r"^.*custom_call_target=\"tpu_custom_call\".*$",
+                         hlo, re.M)
+    assert dims(pool_shape) in kernel
+    assert dims(pool_shape[:-1]) + "{" not in kernel
+    planes = results(pool_shape[1:])
+    assert not planes, planes[:2]
+    copies = results(pool_shape, r"copy\(")
+    assert not copies, copies[:2]

@@ -85,6 +85,24 @@ def _scenario(cache_lens, g_len=1, int8=False, head_dim=D, seed=0):
             jnp.asarray(cache_lens, jnp.int32)), scales, table
 
 
+def _stacked(args, scales=None, layer=0, depth=1):
+    """The kernel's operands for a one-plane scenario: each pool plane
+    becomes layer ``layer`` of a ``depth``-layer stacked pool whose other
+    layers are poison (NaN; 127 on int8 pages, whose scales are NaN), and
+    the layer index follows ``cache_len``. At depth 1 that is
+    ``plane[None]`` and layer 0 — what a caller holding one plane does."""
+    def stack(plane):
+        bad = jnp.full_like(plane, jnp.nan if jnp.issubdtype(
+            plane.dtype, jnp.floating) else 127)
+        return jnp.stack([plane if i == layer else bad
+                          for i in range(depth)])
+
+    q, k_pages, v_pages, *rest = args
+    return ((q, stack(k_pages), stack(v_pages), *rest,
+             jnp.asarray(layer, jnp.int32)),
+            {name: stack(plane) for name, plane in (scales or {}).items()})
+
+
 FILLS = [0, 5, P * PAGE, 17]            # empty / one partial / max / mixed
 
 
@@ -93,7 +111,7 @@ FILLS = [0, 5, P * PAGE, 17]            # empty / one partial / max / mixed
 def test_decode_identity_vs_gather_fill_patterns():
     args, _, _ = _scenario(FILLS)
     oracle = paged_decode_attention(*args)
-    out = ragged_paged_decode_attention(*args)
+    out = ragged_paged_decode_attention(*_stacked(args)[0])
     assert out.dtype == oracle.dtype
     assert bool((out == oracle).all())
 
@@ -104,7 +122,7 @@ def test_decode_identity_under_jit():
     args, _, _ = _scenario(FILLS)
     eager = paged_decode_attention(*args)
     jitted = jax.jit(paged_decode_attention)(*args)
-    ragged = jax.jit(ragged_paged_decode_attention)(*args)
+    ragged = jax.jit(ragged_paged_decode_attention)(*_stacked(args)[0])
     assert bool((eager == jitted).all())
     assert bool((jitted == ragged).all())
 
@@ -112,6 +130,7 @@ def test_decode_identity_under_jit():
 def test_decode_identity_int8_fused_dequant():
     args, scales, _ = _scenario([5, 33, 64], int8=True)
     oracle = paged_decode_attention(*args, **scales)
+    args, scales = _stacked(args, scales)
     out = ragged_paged_decode_attention(*args, **scales)
     assert bool((out == oracle).all())
 
@@ -121,14 +140,43 @@ def test_verify_identity_gamma_plus_one():
     rounding schedule — bit-equal to paged_verify_attention."""
     args, _, _ = _scenario([0, 7, 40], g_len=3)
     oracle = paged_verify_attention(*args)
-    out = ragged_paged_verify_attention(*args)
+    out = ragged_paged_verify_attention(*_stacked(args)[0])
     assert bool((out == oracle).all())
 
 
 def test_verify_identity_int8():
     args, scales, _ = _scenario([9, 21], g_len=2, int8=True)
     oracle = paged_verify_attention(*args, **scales)
+    args, scales = _stacked(args, scales)
     out = ragged_paged_verify_attention(*args, **scales)
+    assert bool((out == oracle).all())
+
+
+# -- the layer is picked inside the kernel -----------------------------------
+
+LAYER_CASES = {
+    "decode-bf16": (ragged_paged_decode_attention, paged_decode_attention,
+                    dict(cache_lens=FILLS)),
+    "decode-int8": (ragged_paged_decode_attention, paged_decode_attention,
+                    dict(cache_lens=[5, 33, 64], int8=True)),
+    "verify": (ragged_paged_verify_attention, paged_verify_attention,
+               dict(cache_lens=[0, 7, 40], g_len=3)),
+}
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("case", LAYER_CASES)
+def test_layer_picked_in_kernel_from_stacked_pool(case, layer):
+    """The kernel takes the pool as the layer scan carries it, all
+    layers stacked, and a TRACED layer index (the scan's ``idx``): it
+    must read exactly that layer. Every other layer is poisoned, so one
+    block fetched from a wrong plane turns the output NaN."""
+    kernel, oracle_fn, scenario = LAYER_CASES[case]
+    args, scales, _ = _scenario(**scenario)
+    oracle = oracle_fn(*args, **scales)
+    args, scales = _stacked(args, scales, layer=layer, depth=3)
+    out = jax.jit(kernel)(*args, **scales)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
     assert bool((out == oracle).all())
 
 
@@ -141,7 +189,7 @@ def test_sentinel_pages_never_dereferenced():
     page and ``0 * NaN`` rides through the V einsum — which is exactly
     why the kernel's ``pl.when`` skip is the stronger contract."""
     args, _, table = _scenario([5, 0, 37])
-    clean = ragged_paged_decode_attention(*args)
+    clean = ragged_paged_decode_attention(*_stacked(args)[0])
     q, k_pages, v_pages, table_dev, k_new, v_new, cache_len = args
     live = set(table[table != SENTINEL].tolist())
     dead = [p for p in range(NUM_PAGES) if p not in live]
@@ -153,7 +201,8 @@ def test_sentinel_pages_never_dereferenced():
     poison[dead] = np.nan
     v_poison = jnp.asarray(poison).astype(v_pages.dtype)
     out = ragged_paged_decode_attention(
-        q, k_poison, v_poison, table_dev, k_new, v_new, cache_len)
+        q, k_poison[None], v_poison[None], table_dev, k_new, v_new,
+        cache_len, 0)
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
     assert bool((out == clean).all())
 
@@ -189,6 +238,7 @@ def test_no_fallback_on_untileable_head_dim():
     assert not ragged_tileable(12, HQ, HKV, PAGE)
     args, _, _ = _scenario([5, 33], head_dim=12)
     oracle = paged_decode_attention(*args)
+    args = _stacked(args)[0]
     jaxpr = str(jax.make_jaxpr(ragged_paged_decode_attention)(*args))
     assert "pallas_call" in jaxpr
     out = ragged_paged_decode_attention(*args)
