@@ -307,6 +307,11 @@ class Container:
             "app_tpu_attn_kernel_total",
             "decode/verify dispatches per attention path "
             "(ragged|gather|dense) — which formulation served the tick")
+        metrics.new_updown_counter(
+            "app_tpu_step_counter_total",
+            "what a model module's decode steps counted (its "
+            "STEP_COUNTERS, e.g. moe.held_pairs), summed over ticks, per "
+            "model and counter")
         # speculative decode catalog (ISSUE 7): draft-verify acceptance —
         # goodput comes from accepted draft tokens, so the acceptance rate
         # and the adaptive gamma it drives are the first dashboards to read

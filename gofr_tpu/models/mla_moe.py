@@ -1,0 +1,592 @@
+"""Latent-attention decoder with a sparse expert layer, for /generate.
+
+The family of DeepSeek-V2/V3 and openPangu-Ultra-MoE: multi-head latent
+attention (MLA) over a *latent* cache, and a feed-forward layer that
+routes each token to ``top_k`` of many small experts beside a shared one.
+Everything that differs between members of the family is a field of
+``MlaMoeConfig`` (sandwich norm, how the router scores, shared experts,
+leading dense layers, the chip's share of the experts), so a later member
+is data.
+
+Equations (``h`` the normed input of a block, ``RMS(x; g) = x /
+sqrt(mean(x^2) + eps) * g``):
+
+- Layer, with ``sandwich_norm``: ``x = x + RMS(Attn(RMS(x; g_in));
+  g_post_attn)``, ``x = x + RMS(FFN(RMS(x; g_pre_mlp)); g_post_mlp)``;
+  without it the two outer norms are absent. ``FFN`` is a SwiGLU of
+  ``ffn_dim`` in the ``n_dense_layers`` leading layers and the expert
+  layer after them. Two stacks, each one ``lax.scan``.
+- Attention: ``c_q = RMS(W_DQ h)``; ``q = W_UQ c_q``, per head ``[q_n;
+  q_r]``; ``[c_kv; k_r] = W_DKV h``; ``c = RMS(c_kv)``; rotary (half-split
+  pairing) on ``q_r`` and on the one ``k_r`` all heads share; ``k_h =
+  [W_UK,h c; k_r]``, ``v_h = W_UV,h c``; scores over ``sqrt(qk_nope_dim +
+  qk_rope_dim)``. **The cache is ``[c; k_r]``**: one row of
+  ``kv_lora_rank + qk_rope_dim`` values a token a layer, padded to
+  whole lanes (``cache_leaves``, ``cache_row``). ``prefill`` expands ``k_h`` / ``v_h``; the decode
+  steps are *absorbed*: ``q~_h = W_UK,h^T q_n,h``, score ``= (q~_h . c_t
+  + q_r,h . k_r,t) / sqrt(..)``, ``u_h = sum_t p_t c_t``, ``o_h = W_UV,h
+  u_h`` — the cached rows are read as they are, never expanded to heads.
+- Expert layer: ``s = sigmoid(W_g h)`` (or softmax) over all
+  ``n_routed_experts``, in float32; ``T`` = the ``top_k`` largest; ``w_e =
+  routed_scale * s_e / sum_{j in T} s_j``; ``y = Shared(h) + sum_{e in T
+  and held} w_e Expert_e(h)``. This chip holds experts ``expert_rank *
+  n_held_experts ..`` of them (expert parallelism: one chip's share of a
+  layer). What absent experts would add is left out; nothing here stands
+  in for the other chips or for their exchange. No capacity, no dropped
+  token. ``prefill`` sorts the held (token, expert) pairs by expert and
+  runs blocks of one expert's rows, so its work follows the pairs;
+  a decode step has one token a slot, is bound by the experts' bytes and
+  not by rows, and runs every held expert over all rows in one batched
+  product (rows an expert was not chosen for weigh zero).
+
+Serving contract (``docs/tpu/model-serving.md``): ``init_cache``,
+``prefill``, ``decode_step``, ``decode_step_paged``, ``cache_leaves``
+and ``STEP_COUNTERS``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from gofr_tpu.ops import apply_rope, rms_norm, rope_table
+
+_NEG_INF = -1e30
+_LANES = 128
+
+# what a decode step counts, summed by the engine over a tick's steps and
+# its active rows (stats()["moe"][...], app_tpu_step_counter_total)
+STEP_COUNTERS = ("moe.routed_pairs", "moe.held_pairs", "moe.experts_hit",
+                 "moe.hot_expert_pairs", "moe.layer_steps")
+_N_COUNTERS = len(STEP_COUNTERS)
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab_size: int = 153600
+    dim: int = 7680
+    n_layers: int = 61
+    n_dense_layers: int = 3           # leading layers with a dense FFN
+    n_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    ffn_dim: int = 18432              # the dense layers' SwiGLU
+    moe_ffn_dim: int = 2048           # one expert's SwiGLU
+    n_routed_experts: int = 256       # the router's width
+    n_held_experts: int = 256         # how many of them live on this chip
+    expert_rank: int = 0              # which share: rank * held .. + held
+    top_k: int = 8
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scale: float = 2.5
+    scoring: str = "sigmoid"          # or "softmax"
+    sandwich_norm: bool = True
+    max_seq_len: int = 131072
+    rope_theta: float = 25.6e6
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring must be sigmoid|softmax, "
+                             f"got {self.scoring!r}")
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError("n_dense_layers must lie in 0..n_layers")
+        if (self.expert_rank + 1) * self.n_held_experts \
+                > self.n_routed_experts:
+            raise ValueError(
+                f"share {self.expert_rank} of {self.n_held_experts} experts "
+                f"lies outside the router's {self.n_routed_experts}")
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def cache_dim(self) -> int:
+        """Values a token leaves in a layer's cache: [c; k_r]."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def cache_row(self) -> int:
+        """A cached row: ``cache_dim`` padded with zeros to whole lanes.
+        A 576-wide minor dimension is not a whole number of the TPU's
+        128 lanes, so the runtime's compact layout would make the
+        *pages* the pool's minor dimension, and XLA would re-lay the
+        whole pool out twice a tick (described-chip compile, PERF.md
+        PR 27)."""
+        return -(-self.cache_dim // _LANES) * _LANES
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+PRESETS: Dict[str, MlaMoeConfig] = {
+    # tiny: unit tests and the benchmark's CPU rehearsal
+    "tiny": MlaMoeConfig(
+        vocab_size=256, dim=64, n_layers=3, n_dense_layers=1, n_heads=4,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, ffn_dim=128, moe_ffn_dim=32, n_routed_experts=32,
+        n_held_experts=32, top_k=4, max_seq_len=128),
+    "pangu-ultra-moe": MlaMoeConfig(),
+}
+
+
+def config(preset: str = "tiny", **overrides) -> MlaMoeConfig:
+    return dataclasses.replace(PRESETS[preset], **overrides)
+
+
+def cache_leaves(cfg: MlaMoeConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+    """What one token leaves in the cache, a layer: name -> (trailing
+    shape, dtype). The page pool and ``init_cache`` build from this."""
+    return {"ckv": ((cfg.cache_row,), cfg.dtype)}
+
+
+# -- parameters ---------------------------------------------------------------
+
+def init(cfg: MlaMoeConfig, key: jax.Array) -> Dict[str, Any]:
+    """Seeded random parameters in the served dtype: matmul weights
+    ~N(0, 1/fan_in) (fan-in is every weight's second-last axis), gains of
+    one. Jitted with the key as an argument it is one program for every
+    seed, each draw fused into its output leaf."""
+    dt = cfg.dtype
+    d, heads = cfg.dim, cfg.n_heads
+    count = iter(range(1 << 16))
+
+    def dense(*shape):
+        leaf = jax.random.normal(jax.random.fold_in(key, next(count)),
+                                 shape, jnp.float32)
+        return (leaf / math.sqrt(shape[-2])).astype(dt)
+
+    def ones(*shape):
+        return jnp.ones(shape, dt)
+
+    def block(n):
+        norms = ["in_norm", "pre_mlp_norm"]
+        if cfg.sandwich_norm:
+            norms += ["post_attn_norm", "post_mlp_norm"]
+        out = {name: ones(n, d) for name in norms}
+        out["attn"] = {
+            "w_dq": dense(n, d, cfg.q_lora_rank),
+            "q_norm": ones(n, cfg.q_lora_rank),
+            "w_uq_n": dense(n, cfg.q_lora_rank, heads * cfg.qk_nope_dim),
+            "w_uq_r": dense(n, cfg.q_lora_rank, heads * cfg.qk_rope_dim),
+            "w_dkv": dense(n, d, cfg.cache_dim),
+            "kv_norm": ones(n, cfg.kv_lora_rank),
+            "w_uk": dense(n, heads, cfg.kv_lora_rank, cfg.qk_nope_dim),
+            "w_uv": dense(n, heads, cfg.kv_lora_rank, cfg.v_head_dim),
+            "w_o": dense(n, heads * cfg.v_head_dim, d)}
+        return out
+
+    def swiglu(*lead, width):
+        return {"w_gate": dense(*lead, d, width),
+                "w_up": dense(*lead, d, width),
+                "w_down": dense(*lead, width, d)}
+
+    params: Dict[str, Any] = {
+        "tok_emb": (jax.random.normal(jax.random.fold_in(key, next(count)),
+                                      (cfg.vocab_size, d), jnp.float32)
+                    / math.sqrt(d)).astype(dt),
+        "out_norm": ones(d),
+        "lm_head": dense(d, cfg.vocab_size)}
+    if cfg.n_dense_layers:
+        n = cfg.n_dense_layers
+        params["dense"] = dict(block(n), **swiglu(n, width=cfg.ffn_dim))
+    if cfg.n_moe_layers:
+        n = cfg.n_moe_layers
+        moe = block(n)
+        moe["router"] = dense(n, d, cfg.n_routed_experts)
+        moe["experts"] = swiglu(n, cfg.n_held_experts,
+                                width=cfg.moe_ffn_dim)
+        if cfg.n_shared_experts:
+            moe["shared"] = swiglu(
+                n, width=cfg.n_shared_experts * cfg.moe_ffn_dim)
+        params["moe"] = moe
+    return params
+
+
+def init_cache(cfg: MlaMoeConfig, batch: int,
+               max_len: Optional[int] = None) -> Dict[str, jnp.ndarray]:
+    """Static-shape latent cache: ``ckv`` (L, B, T, cache_row)."""
+    t_max = max_len or cfg.max_seq_len
+    return {name: jnp.zeros((cfg.n_layers, batch, t_max, *tail), dtype)
+            for name, (tail, dtype) in cache_leaves(cfg).items()}
+
+
+# -- attention ----------------------------------------------------------------
+
+def _latent(attn, h, cfg: MlaMoeConfig, cos, sin, positions):
+    """h (B, S, D) -> q_n (B,S,H,dn), q_r (B,S,H,dr) rotated, and the
+    cached row [c; k_r; zeros] (B, S, cache_row). W_UQ is held as its
+    two column blocks, the heads' nope and rope parts."""
+    b, s, _ = h.shape
+    c_q = rms_norm(h @ attn["w_dq"], attn["q_norm"], cfg.norm_eps)
+    q_n = (c_q @ attn["w_uq_n"]).reshape(b, s, cfg.n_heads, cfg.qk_nope_dim)
+    q_r = (c_q @ attn["w_uq_r"]).reshape(b, s, cfg.n_heads, cfg.qk_rope_dim)
+    q_r = apply_rope(q_r, cos, sin, positions)
+    down = h @ attn["w_dkv"]
+    c = rms_norm(down[..., :cfg.kv_lora_rank], attn["kv_norm"],
+                 cfg.norm_eps)
+    k_r = apply_rope(down[..., None, cfg.kv_lora_rank:], cos, sin,
+                     positions)[:, :, 0]
+    pad = jnp.zeros((b, s, cfg.cache_row - cfg.cache_dim), c.dtype)
+    return q_n, q_r, jnp.concatenate([c, k_r, pad], axis=-1)
+
+
+def expanded_attention(attn, q_n, q_r, rows, cfg: MlaMoeConfig):
+    """Causal self-attention over a whole prompt with per-head keys and
+    values expanded from the cached rows. q_n (B,S,H,dn), q_r (B,S,H,dr),
+    rows (B,S,cache_row) -> (B, S, H * v_head_dim). Heads go in blocks
+    of 16, one after the other: the (B, heads, S, S) float32 scores of
+    all 128 at once are 2 GiB for 16 prompts of 512, a block's 0.25."""
+    b, s, heads = q_n.shape[:3]
+    c = rows[..., :cfg.kv_lora_rank]
+    k_r = rows[..., cfg.kv_lora_rank:cfg.cache_dim]
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    block = math.gcd(heads, 16)
+
+    def blocks(x, axis):                 # heads -> (blocks, block) in front
+        x = x.reshape(*x.shape[:axis], heads // block, block,
+                      *x.shape[axis + 1:])
+        return jnp.moveaxis(x, axis, 0)
+
+    def one_block(args):
+        w_uk, w_uv, q_n, q_r = args
+        k_n = jnp.einsum("bsc,hcd->bshd", c, w_uk)
+        v = jnp.einsum("bsc,hcd->bshd", c, w_uv)
+        scores = (jnp.einsum("bshd,bthd->bhst", q_n, k_n,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bshd,btd->bhst", q_r, k_r,
+                               preferred_element_type=jnp.float32))
+        scores = scores * (cfg.qk_head_dim ** -0.5)
+        scores = jnp.where(causal[None, None], scores, _NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+        return jnp.einsum("bhst,bthd->bshd", probs, v)
+
+    out = lax.map(one_block, (blocks(attn["w_uk"], 0), blocks(attn["w_uv"], 0),
+                              blocks(q_n, 2), blocks(q_r, 2)))
+    return jnp.moveaxis(out, 0, 2).reshape(b, s, heads * cfg.v_head_dim)
+
+
+def absorbed_attention(attn, q_n, q_r, view, valid, cfg: MlaMoeConfig):
+    """One query a row against cached rows read as they are. q_n
+    (B,H,dn), q_r (B,H,dr), view (B,T,cache_row), valid (B,T) bool ->
+    (B, H * v_head_dim). W_UK is absorbed into the query and W_UV applied
+    to the weighted latent, so no per-head key or value of a cached token
+    is ever formed."""
+    b = view.shape[0]
+    q_abs = jnp.einsum("bhd,hcd->bhc", q_n, attn["w_uk"])
+    pad = jnp.zeros((*q_r.shape[:2], cfg.cache_row - cfg.cache_dim),
+                    q_r.dtype)
+    q_full = jnp.concatenate([q_abs, q_r, pad], axis=-1)   # (B,H,cache_row)
+    scores = jnp.einsum("bhc,btc->bht", q_full, view,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (cfg.qk_head_dim ** -0.5)
+    scores = jnp.where(valid[:, None, :], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(view.dtype)
+    u = jnp.einsum("bht,btc->bhc", probs, view[..., :cfg.kv_lora_rank])
+    out = jnp.einsum("bhc,hcd->bhd", u, attn["w_uv"])
+    return out.reshape(b, cfg.n_heads * cfg.v_head_dim)
+
+
+# -- feed-forward ---------------------------------------------------------------
+
+def _swiglu(w, x):
+    gate = jax.nn.silu((x @ w["w_gate"]).astype(jnp.float32))
+    up = (x @ w["w_up"]).astype(jnp.float32)
+    return (gate * up).astype(x.dtype) @ w["w_down"]
+
+
+def route(cfg: MlaMoeConfig, router, h):
+    """h (T, D) -> (ids (T, top_k) int32 over all routed experts, weights
+    (T, top_k) float32). Scores in float32 whatever h's dtype."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    top, ids = lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top = top / top.sum(axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), top * cfg.routed_scale
+
+
+def _held(cfg: MlaMoeConfig, ids, valid):
+    """Global expert ids (T, K) -> (index among the held experts, is the
+    pair this chip's). ``valid`` (T,) bool drops rows that carry no
+    token (padding, frozen slots)."""
+    local = ids - cfg.expert_rank * cfg.n_held_experts
+    held = (local >= 0) & (local < cfg.n_held_experts)
+    if valid is not None:
+        held = held & valid[:, None]
+    return local, held
+
+
+def experts_batched(cfg: MlaMoeConfig, experts, h, ids, weights, valid=None):
+    """Every held expert over every row in one batched product; a row
+    weighs zero for an expert it did not choose. For a decode step: few
+    rows, and the cost is the experts' bytes. Returns (y (T, D) float32,
+    pairs routed to each held expert (E,) int32)."""
+    local, held = _held(cfg, ids, valid)
+    onehot = (local[..., None] == jnp.arange(cfg.n_held_experts)) \
+        & held[..., None]                                   # (T, K, E)
+    combine = (onehot * weights[..., None]).sum(axis=1)     # (T, E) f32
+    gate = jax.nn.silu(jnp.einsum(
+        "td,edf->etf", h, experts["w_gate"]).astype(jnp.float32))
+    up = jnp.einsum("td,edf->etf", h, experts["w_up"]).astype(jnp.float32)
+    out = jnp.einsum("etf,efd->etd", (gate * up).astype(h.dtype),
+                     experts["w_down"], preferred_element_type=jnp.float32)
+    y = jnp.einsum("te,etd->td", combine, out)
+    return y, onehot.sum(axis=(0, 1)).astype(jnp.int32)
+
+
+def _block_rows(cfg: MlaMoeConfig, tokens: int) -> int:
+    """Rows a block of the grouped product holds: the pairs one expert
+    expects under even routing, as a power of two in 8..256."""
+    expect = max(1, tokens * cfg.top_k // cfg.n_routed_experts)
+    return min(256, max(8, 1 << (expect - 1).bit_length()))
+
+
+def experts_grouped(cfg: MlaMoeConfig, experts, h, ids, weights, valid=None):
+    """The held experts' part with work that follows the routed pairs.
+    The held (token, expert) pairs are sorted by expert; each expert's
+    run is cut into blocks of ``_block_rows`` rows, and a loop over the
+    blocks *that exist* gathers a block's rows, runs its one expert and
+    adds the weighted result to its tokens. Nothing is sized by a
+    capacity: however the router spreads the tokens, each pair is
+    computed. Returns (y (T, D) float32, pairs per held expert (E,))."""
+    tokens, k = ids.shape
+    n_held, block = cfg.n_held_experts, _block_rows(cfg, tokens)
+    local, held = _held(cfg, ids, valid)
+    flat = jnp.where(held, local, n_held).reshape(-1)       # absent: last
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    counts = (flat[:, None] == jnp.arange(n_held)).sum(0).astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts                    # in sorted order
+    blocks = -(-counts // block)
+    block_ends = jnp.cumsum(blocks)
+    flat_w = weights.reshape(-1)
+
+    def one_block(i, y):
+        e = jnp.searchsorted(block_ends, i, side="right").astype(jnp.int32)
+        first = starts[e] + (i - (block_ends[e] - blocks[e])) * block
+        rows = first + jnp.arange(block, dtype=jnp.int32)
+        live = rows < starts[e] + counts[e]
+        pair = order[jnp.minimum(rows, tokens * k - 1)]
+        token = pair // k
+        w = {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
+             for name, leaf in experts.items()}
+        out = _swiglu(w, h[token]).astype(jnp.float32)
+        scale = jnp.where(live, flat_w[pair], 0.0)
+        return y.at[token].add(out * scale[:, None])
+
+    y = lax.fori_loop(0, block_ends[-1], one_block,
+                      jnp.zeros((tokens, h.shape[-1]), jnp.float32))
+    return y, counts
+
+
+def moe_ffn(cfg: MlaMoeConfig, layer, h, valid=None, grouped=False):
+    """The expert layer on h (T, D): shared expert + this chip's routed
+    part. Returns (y (T, D) in h's dtype, the step counters (5,) int32
+    in ``STEP_COUNTERS`` order, over the ``valid`` rows, the experts
+    chosen (T, top_k))."""
+    ids, weights = route(cfg, layer["router"], h)
+    run = experts_grouped if grouped else experts_batched
+    y, per_expert = run(cfg, layer["experts"], h, ids, weights, valid)
+    if "shared" in layer:
+        y = y + _swiglu(layer["shared"], h).astype(jnp.float32)
+    rows = (jnp.int32(h.shape[0]) if valid is None
+            else valid.sum().astype(jnp.int32))
+    counters = jnp.stack([rows * cfg.top_k, per_expert.sum(),
+                          (per_expert > 0).sum().astype(jnp.int32),
+                          per_expert.max(), jnp.int32(1)])
+    return y.astype(h.dtype), counters, ids
+
+
+# -- the two stacks -------------------------------------------------------------
+
+def _stacks(params, cfg: MlaMoeConfig):
+    """(name, stacked layers, index of the stack's first layer)."""
+    out = []
+    if cfg.n_dense_layers:
+        out.append(("dense", params["dense"], 0))
+    if cfg.n_moe_layers:
+        out.append(("moe", params["moe"], cfg.n_dense_layers))
+    return out
+
+
+def _layer(cfg: MlaMoeConfig, kind: str, layer, x, attend, valid, grouped):
+    """One layer on x (B, S, D). ``attend(attn_params, h)`` gives the
+    attention output before W_O and whatever it carries. Returns (x,
+    carried, counters, experts chosen (B, S, top_k); zeros if dense)."""
+    b, s, d = x.shape
+    h = rms_norm(x, layer["in_norm"], cfg.norm_eps)
+    attn, carried = attend(layer["attn"], h)
+    attn = attn @ layer["attn"]["w_o"]
+    if cfg.sandwich_norm:
+        attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
+    x = x + attn
+    h = rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
+    if kind == "dense":
+        y = _swiglu(layer, h)
+        counters = jnp.zeros((_N_COUNTERS,), jnp.int32)
+        ids = jnp.zeros((b, s, cfg.top_k), jnp.int32)
+    else:
+        y, counters, ids = moe_ffn(
+            cfg, layer, h.reshape(b * s, d),
+            None if valid is None else valid.reshape(b * s), grouped)
+        y, ids = y.reshape(b, s, d), ids.reshape(b, s, cfg.top_k)
+    if cfg.sandwich_norm:
+        y = rms_norm(y, layer["post_mlp_norm"], cfg.norm_eps)
+    return x + y, carried, counters, ids
+
+
+def _head(params, cfg: MlaMoeConfig, x):
+    x = rms_norm(x, params["out_norm"], cfg.norm_eps)
+    return (x @ params["lm_head"]).astype(jnp.float32)
+
+
+def prefill(params: Dict[str, Any], cfg: MlaMoeConfig, tokens: jnp.ndarray,
+            cache: Dict[str, jnp.ndarray],
+            lengths: Optional[jnp.ndarray] = None, routes: bool = False):
+    """Run the prompts, fill the cache. tokens (B, S) right-padded to
+    ``lengths``; returns (last-token logits (B, V), cache with rows
+    [0, S) written, cache_len (B,)). Attention is expanded; the expert
+    layer is grouped, and padding rows are routed nowhere. ``routes``
+    adds the experts chosen, (expert layers, B, S, top_k), for a
+    comparison of the routing with a reference's."""
+    b, s = tokens.shape
+    cos, sin = rope_table(cfg.max_seq_len, cfg.qk_rope_dim, cfg.rope_theta)
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    valid = (None if lengths is None
+             else positions < lengths.astype(jnp.int32)[:, None])
+    x = params["tok_emb"][tokens]
+
+    def attend(attn, h):
+        q_n, q_r, rows = _latent(attn, h, cfg, cos, sin, positions)
+        return expanded_attention(attn, q_n, q_r, rows, cfg), rows
+
+    written, chosen = [], None
+    for kind, stack, _ in _stacks(params, cfg):
+        def body(x, layer, kind=kind):
+            x, rows, _, ids = _layer(cfg, kind, layer, x, attend, valid,
+                                     True)
+            return x, (rows, ids)
+
+        x, (rows, ids) = lax.scan(body, x, stack)
+        written.append(rows)                                # (n, B, S, C)
+        if kind == "moe":
+            chosen = ids
+    rows = jnp.concatenate(written, axis=0)
+    new_cache = {"ckv": lax.dynamic_update_slice_in_dim(
+        cache["ckv"], rows.astype(cache["ckv"].dtype), 0, axis=2)}
+    if lengths is None:
+        last = x[:, -1]
+        cache_len = jnp.full((b,), s, jnp.int32)
+    else:
+        last = x[jnp.arange(b), lengths - 1]
+        cache_len = lengths.astype(jnp.int32)
+    out = (_head(params, cfg, last), new_cache, cache_len)
+    return out + (chosen,) if routes else out
+
+
+def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
+            append, view_of):
+    """One absorbed decode step. ``append(leaf, idx, row)`` writes layer
+    ``idx``'s new row for every sequence, ``view_of(leaf, idx)`` gives
+    the (B, T, cache_row) rows attention reads. The new row is written
+    first and read back with the rest: position ``cache_len`` is valid."""
+    cos, sin = rope_table(cfg.max_seq_len, cfg.qk_rope_dim, cfg.rope_theta)
+    positions = cache_len[:, None]
+    x = params["tok_emb"][token][:, None, :]                # (B, 1, D)
+    counters = jnp.zeros((_N_COUNTERS,), jnp.int32)
+
+    for kind, stack, first in _stacks(params, cfg):
+        n = jax.tree.leaves(stack)[0].shape[0]
+
+        def body(carry, layer_and_idx, kind=kind):
+            x, leaf, counters = carry
+            layer, idx = layer_and_idx
+
+            def attend(attn, h):
+                q_n, q_r, row = _latent(attn, h, cfg, cos, sin, positions)
+                grown = append(leaf, idx, row[:, 0])
+                view = view_of(grown, idx)
+                valid = (jnp.arange(view.shape[1])[None, :]
+                         <= cache_len[:, None])
+                out = absorbed_attention(attn, q_n[:, 0], q_r[:, 0], view,
+                                         valid, cfg)
+                return out[:, None, :], grown
+
+            x, leaf, counted, _ = _layer(
+                cfg, kind, layer, x, attend,
+                None if active is None else active[:, None], False)
+            return (x, leaf, counters + counted), None
+
+        (x, leaf, counters), _ = lax.scan(
+            body, (x, leaf, counters),
+            (stack, first + jnp.arange(n, dtype=jnp.int32)))
+    return _head(params, cfg, x[:, 0]), leaf, counters
+
+
+def decode_step(params: Dict[str, Any], cfg: MlaMoeConfig,
+                token: jnp.ndarray, cache: Dict[str, jnp.ndarray],
+                cache_len: jnp.ndarray, window: Optional[int] = None
+                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], jnp.ndarray]:
+    """One decode step over the dense latent cache (L, B, T, cache_row).
+    ``window`` statically bounds the rows attention reads."""
+    batch = jnp.arange(token.shape[0])
+
+    def append(leaf, idx, row):
+        return leaf.at[idx, batch, cache_len].set(row, mode="drop")
+
+    def view_of(leaf, idx):
+        view = lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False)
+        return view if window is None else view[:, :window]
+
+    logits, leaf, _ = _decode(params, cfg, token, cache["ckv"], cache_len,
+                              None, append, view_of)
+    return logits, {"ckv": leaf}, cache_len + 1
+
+
+def decode_step_paged(params: Dict[str, Any], cfg: MlaMoeConfig,
+                      token: jnp.ndarray, pool: Dict[str, jnp.ndarray],
+                      page_table: jnp.ndarray, cache_len: jnp.ndarray,
+                      active: jnp.ndarray, counters: bool = False):
+    """One decode step over the latent page pool: ``pool["ckv"]`` (L,
+    num_pages, page, cache_row), ``page_table`` (B, P) with ``num_pages``
+    as the unallocated sentinel, ``active`` (B,) bool gating the append
+    (an inactive slot's page may belong to another stream by now: its
+    row goes to the sentinel page and is dropped). Per layer the table's
+    pages are gathered as they are, (B, P * page, cache_row), and
+    attended in the absorbed form: plain XLA, a gather and two products.
+    Returns (logits, pool, cache_len + 1), and with ``counters`` the
+    step's ``STEP_COUNTERS`` over the active rows as a fourth."""
+    num_pages, page = pool["ckv"].shape[1:3]
+    page_col = cache_len // page
+    page_row = jnp.take_along_axis(page_table, page_col[:, None], axis=1,
+                                   mode="clip")[:, 0]
+    dest_row = jnp.where(active, page_row, num_pages)       # sentinel: drop
+    offset = cache_len % page
+
+    def append(leaf, idx, row):
+        return leaf.at[idx, dest_row, offset].set(row, mode="drop")
+
+    def view_of(leaf, idx):
+        # one gather straight out of the stacked leaf: a layer's plane
+        # sliced out first is a copy of the whole pool a layer a step.
+        # Sentinel ids clamp to a real page, masked by cache_len
+        pages = leaf[idx, page_table]                   # (B, P, page, C)
+        return pages.reshape(pages.shape[0], -1, pages.shape[-1])
+
+    logits, leaf, counted = _decode(params, cfg, token, pool["ckv"],
+                                    cache_len, active, append, view_of)
+    out = (logits, {"ckv": leaf}, cache_len + 1)
+    return out + (counted,) if counters else out

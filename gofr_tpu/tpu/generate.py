@@ -398,6 +398,7 @@ class GenerationEngine:
                  kv_page_reserve: Optional[int] = None,
                  page_pool=None,
                  ragged_attn: str = "auto",
+                 max_group_tokens: Optional[int] = None,
                  model_module=None,
                  model_name: str = "generate",
                  draft_cfg=None, draft_params=None,
@@ -419,32 +420,64 @@ class GenerationEngine:
         self._jax = jax
         self._jnp = jnp
         # the served model module: llama by default; anything exposing the
-        # llama serving contract (init_cache/prefill/decode_step with a
-        # compatible Config) plugs in — models/moe.py is the first taker
+        # serving contract (docs/tpu/model-serving.md: init_cache /
+        # prefill / decode_step[_paged] with a compatible Config, and
+        # optionally cache_leaves and STEP_COUNTERS) plugs in —
+        # models/moe.py and models/mla_moe.py are the takers
         self._llama = llama if model_module is None else model_module
         self.model_name = str(model_name)
+        named = getattr(self._llama, "__name__", repr(self._llama))
         if model_module is not None and model_module is not llama:
-            missing = [name for name in ("init_cache", "prefill",
-                                         "decode_step")
+            wanted = ("init_cache", "prefill",
+                      "decode_step_paged" if paged_kv else "decode_step")
+            missing = [name for name in wanted
                        if not hasattr(model_module, name)]
             if missing:
                 raise ValueError(
-                    f"model_module lacks serving entry points {missing}")
+                    f"model_module {named} lacks the serving entry "
+                    f"point(s) {', '.join(missing)}"
+                    + (" (paged_kv=True decodes through decode_step_paged)"
+                       if "decode_step_paged" in missing else ""))
             if mesh is not None:
                 raise ValueError(
-                    "model_module: sharding specs are llama-specific; "
-                    "custom model modules serve unsharded (mesh=None)")
-            if paged_kv and not hasattr(model_module, "decode_step_paged"):
-                raise ValueError(
-                    "paged_kv requires the model module to implement "
-                    "decode_step_paged")
+                    f"model_module {named}: the sharding specs are "
+                    f"llama's; other model modules serve unsharded "
+                    f"(mesh=None)")
             if prefix_cache:
                 raise ValueError(
-                    "prefix_cache requires the llama model module")
+                    f"model_module {named}: prefix_cache needs llama's "
+                    f"suffix prefill over k/v pages")
             if draft_cfg is not None:
                 raise ValueError(
-                    "speculative decode requires the llama model module "
-                    "(the target verify step)")
+                    f"model_module {named}: speculative decode needs "
+                    f"llama's verify step over k/v leaves")
+        # what one token leaves in the module's cache (cache_leaves; None:
+        # the pool's own k/v form). kv_wire ships k/v pages (export,
+        # adoption, session migration): a module whose cache leaves are
+        # of another form cannot use it
+        cache_leaves = getattr(self._llama, "cache_leaves", None)
+        self._leaf_specs: Optional[Dict[str, tuple]] = (
+            cache_leaves(cfg) if cache_leaves else None)
+        self._kv_wire_refusal: Optional[str] = (
+            None if self._leaf_specs is None
+            or {"k", "v"} <= set(self._leaf_specs) else
+            f"model_module {named}: its cache leaves "
+            f"{sorted(self._leaf_specs)} are not k/v, which kv_wire "
+            f"(prefill export, adoption, session migration) ships")
+        # what a decode step of this module counts (STEP_COUNTERS: names
+        # like "moe.held_pairs"); the tick sums them over its K steps and
+        # active rows and returns them beside the tokens. A module that
+        # declares none compiles to the tick it always compiled to
+        self._step_counters: Tuple[str, ...] = tuple(
+            getattr(self._llama, "STEP_COUNTERS", ()))
+        self._step_totals = [0] * len(self._step_counters)
+        # upper bound on rows x bucket of one admission group (None: a
+        # group is bounded by max_slots alone). A group over it is split
+        # in arrival order, and the warm-up skips the rungs no group can
+        # reach: the prefill's temporaries, not the cache, are what a
+        # small-cache model runs out of
+        self.max_group_tokens = (None if max_group_tokens is None
+                                 else max(1, int(max_group_tokens)))
         self.cfg = cfg
         self.mesh = mesh
         if mesh is not None and "dp" in mesh.shape:
@@ -565,7 +598,7 @@ class GenerationEngine:
         self._pool = None
         self._table = None
         if self.paged:
-            from gofr_tpu.tpu.page_pool import PagePool
+            from gofr_tpu.tpu.page_pool import PagePool, kv_leaf_specs
             self.pages_per_slot = self.max_len // self.kv_page
             if page_pool is not None:
                 # multi-model tenancy: co-resident engines with the same
@@ -575,22 +608,27 @@ class GenerationEngine:
                     raise ValueError(
                         f"shared page_pool page size {page_pool.page} != "
                         f"engine kv_page {self.kv_page}")
-                if PagePool._page_bytes(cfg, self.kv_page) \
-                        != page_pool.page_bytes:
+                mine = self._leaf_specs or kv_leaf_specs(cfg)
+                if (mine != page_pool.leaf_specs
+                        or cfg.n_layers != page_pool.cfg.n_layers):
                     raise ValueError(
-                        "shared page_pool KV geometry does not match this "
-                        "engine's config (layers/kv-heads/head-dim/dtype "
-                        "must agree; heterogeneous models need their own "
-                        "pools carved from an HBMBudget)")
+                        f"shared page_pool holds {page_pool.cfg.n_layers} "
+                        f"layers of cache leaves {page_pool.leaf_specs}, "
+                        f"this engine's model {cfg.n_layers} of {mine}: "
+                        f"layers and leaves (names, per-token shapes, "
+                        f"dtypes) must agree; heterogeneous models need "
+                        f"their own pools carved from an HBMBudget")
                 self._pool = page_pool
             elif kv_pages is not None:
                 self._pool = PagePool(cfg, page=self.kv_page,
                                       num_pages=int(kv_pages), mesh=mesh,
-                                      metrics=metrics)
+                                      metrics=metrics,
+                                      leaf_specs=self._leaf_specs)
             elif kv_pool_bytes is not None:
                 self._pool = PagePool(cfg, page=self.kv_page,
                                       budget_bytes=int(kv_pool_bytes),
-                                      mesh=mesh, metrics=metrics)
+                                      mesh=mesh, metrics=metrics,
+                                      leaf_specs=self._leaf_specs)
             else:
                 # capacity parity with the dense cache by default; real
                 # deployments size by HBM budget and admit MORE slots than
@@ -598,7 +636,7 @@ class GenerationEngine:
                 self._pool = PagePool(
                     cfg, page=self.kv_page,
                     num_pages=max_slots * self.pages_per_slot, mesh=mesh,
-                    metrics=metrics)
+                    metrics=metrics, leaf_specs=self._leaf_specs)
             # reserve watermark: pages admission must leave free for
             # in-flight decode growth of already-admitted slots
             self._kv_reserve = (int(kv_page_reserve)
@@ -1138,24 +1176,43 @@ class GenerationEngine:
             jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
                                     self.cfg)
             step_kw = {"ragged": True} if self._ragged else {}
+            counted = bool(self._step_counters)
+            if counted:
+                step_kw["counters"] = True
             from jax import lax
+
+            def step(params, token, pool, table, cache_len, active):
+                """(logits, pool, new_len, what the step counted: the
+                per-step sums the scan stacks, () for a module that
+                declares no STEP_COUNTERS)."""
+                out = llama.decode_step_paged(
+                    params, cfg, token, pool, table, cache_len, active,
+                    **step_kw)
+                return out if counted else out + ((),)
+
+            def with_counts(outs, counts):
+                # a module with counters returns their sums over the K
+                # steps as one more output; the others' ticks are the
+                # program they always were
+                return outs + (counts.sum(axis=0),) if counted else outs
 
             if not sampled:
                 def decode_k(params, token, pool, table, cache_len, active):
                     def one(carry, _):
                         token, pool, cache_len = carry
-                        logits, pool2, new_len = llama.decode_step_paged(
-                            params, cfg, token, pool, table, cache_len,
-                            active, **step_kw)
+                        logits, pool2, new_len, counts = step(
+                            params, token, pool, table, cache_len, active)
                         next_token = logits.argmax(axis=-1).astype(
                             token.dtype)
                         new_len = jnp.where(active, new_len, cache_len)
                         next_token = jnp.where(active, next_token, token)
-                        return (next_token, pool2, new_len), next_token
+                        return (next_token, pool2, new_len), (next_token,
+                                                              counts)
 
-                    (token, pool, cache_len), tokens = lax.scan(
+                    (token, pool, cache_len), (tokens, counts) = lax.scan(
                         one, (token, pool, cache_len), None, length=k_steps)
-                    return tokens, pool, cache_len   # tokens: (K, B)
+                    # tokens: (K, B)
+                    return with_counts((tokens, pool, cache_len), counts)
 
                 fn = jax.jit(decode_k, donate_argnums=(2, 4))
             else:
@@ -1165,9 +1222,8 @@ class GenerationEngine:
                                      active, temps, top_ks, top_ps, keys):
                     def one(carry, _):
                         token, pool, cache_len, keys = carry
-                        logits, pool2, new_len = llama.decode_step_paged(
-                            params, cfg, token, pool, table, cache_len,
-                            active, **step_kw)
+                        logits, pool2, new_len, counts = step(
+                            params, token, pool, table, cache_len, active)
                         next_token, new_keys = sample_batch(
                             logits, temps, top_ks, top_ps, keys)
                         next_token = next_token.astype(token.dtype)
@@ -1175,12 +1231,13 @@ class GenerationEngine:
                         next_token = jnp.where(active, next_token, token)
                         keys = jnp.where(active[:, None], new_keys, keys)
                         return (next_token, pool2, new_len,
-                                keys), next_token
+                                keys), (next_token, counts)
 
-                    (token, pool, cache_len, keys), tokens = lax.scan(
-                        one, (token, pool, cache_len, keys), None,
-                        length=k_steps)
-                    return tokens, pool, cache_len, keys
+                    (token, pool, cache_len, keys), (tokens, counts) = \
+                        lax.scan(one, (token, pool, cache_len, keys), None,
+                                 length=k_steps)
+                    return with_counts((tokens, pool, cache_len, keys),
+                                       counts)
 
                 fn = jax.jit(decode_k_sampled, donate_argnums=(2, 4, 9))
             self._decode_paged_fns[(k_steps, sampled, pw)] = fn
@@ -1731,12 +1788,11 @@ class GenerationEngine:
                     for pw in widths:
                         table = jnp.full((self.max_slots, pw),
                                          self._pool.sentinel, jnp.int32)
-                        tokens, leaves, cache_len = self._decode_paged_fn(
-                            k, pw=pw)(
+                        out = self._decode_paged_fn(k, pw=pw)(
                             self.params, self.last_token,
                             self._pool.leaves, table, self.cache_len,
                             active)
-                        self._pool.leaves, self.cache_len = leaves, cache_len
+                        self._pool.leaves, self.cache_len = out[1], out[2]
                         if sampling:
                             out = self._decode_paged_fn(
                                 k, sampled=True, pw=pw)(
@@ -1745,7 +1801,7 @@ class GenerationEngine:
                                 active, self.temps, self.top_ks,
                                 self.top_ps, self.sample_keys)
                             (_, self._pool.leaves, self.cache_len,
-                             self.sample_keys) = out
+                             self.sample_keys) = out[:4]
             else:
                 for k in rungs:
                     for window in window_rungs:
@@ -1795,8 +1851,14 @@ class GenerationEngine:
                              self.cache_len, self.last_token,
                              self.sample_keys) = out
             for lb in self.prompt_buckets:
-                for n in prompt_counts:
-                    nb = next(x for x in self._n_ladder if x >= n)
+                # rungs over the admission bound are unreachable, and
+                # counts that share a rung warm it once
+                reachable = dict.fromkeys(
+                    next(x for x in self._n_ladder if x >= n)
+                    for n in prompt_counts)
+                for nb in reachable:
+                    if nb > self._group_rows(lb):
+                        continue
                     toks = jnp.zeros((nb, lb), jnp.int32)
                     lens = jnp.ones((nb,), jnp.int32)
                     zeros_f = jnp.zeros((nb,), jnp.float32)
@@ -2052,6 +2114,8 @@ class GenerationEngine:
         can run dense with ``max_len`` = largest bucket while its decode
         peers run paged."""
         from gofr_tpu.tpu import kv_wire
+        if self._kv_wire_refusal:
+            raise ValueError(self._kv_wire_refusal)
         sampling = sampling or Sampling()
         prompt, bucket = self._validate(prompt_ids, 1)
         page = self.kv_page
@@ -2170,6 +2234,8 @@ class GenerationEngine:
         delivery."""
         from gofr_tpu.tpu import kv_wire
         from gofr_tpu.tpu.sched import CLASS_MIGRATED
+        if self._kv_wire_refusal:
+            raise ValueError(self._kv_wire_refusal)
         if dedupe is not None:
             prior = self._adopt_ledger_get(dedupe)
             if prior is not None:
@@ -2407,6 +2473,8 @@ class GenerationEngine:
         does not ship), ``TimeoutError`` when in-flight ticks fail to
         drain in ``timeout_s``."""
         from gofr_tpu.tpu import kv_wire
+        if self._kv_wire_refusal:
+            raise ValueError(self._kv_wire_refusal)
         if not self.paged:
             raise ValueError("export_session needs paged_kv=True (the "
                              "session ships as page-pool rows)")
@@ -2931,6 +2999,15 @@ class GenerationEngine:
                           ragged_attn=self.ragged_attn)
         return GenerationEngine(self.cfg, self.params, **kwargs)
 
+    def _group_rows(self, bucket: int) -> int:
+        """Most rows one admission group of ``bucket`` may hold: the
+        largest count rung whose rows x bucket stays within
+        ``max_group_tokens`` (one row always may), max_slots without."""
+        if self.max_group_tokens is None:
+            return self._n_ladder[-1]
+        return max([n for n in self._n_ladder
+                    if n * bucket <= self.max_group_tokens] or [1])
+
     def _admit_room(self, taken: int) -> bool:
         """True while admission may claim another slot this pass:
         free slots remain beyond the ``taken`` already claimed, and the
@@ -3000,6 +3077,11 @@ class GenerationEngine:
                 "accepted": self._spec_accepted,
                 "acceptance_rate": round(rate, 6),
             }
+        # what the module's decode steps counted, nested by the dotted
+        # name: "moe.held_pairs" is stats()["moe"]["held_pairs"]
+        for name, total in zip(self._step_counters, self._step_totals):
+            group, _, key = name.rpartition(".")
+            (out.setdefault(group, {}) if group else out)[key] = total
         out["classes"] = {
             "weights": self._pending.weights(),
             "depths": self._pending.depths(),
@@ -3549,6 +3631,9 @@ class GenerationEngine:
             self._note_spec(proposed, accepted)
         else:
             self._ticks_inflight -= 1
+            if isinstance(host, tuple):
+                host, counts = host
+                self._note_step_counters(counts)
             plan = faults.active()
             if plan.enabled and entry.payload \
                     and plan.should("nan_logits"):
@@ -3577,6 +3662,17 @@ class GenerationEngine:
                           else entry.payload),
                 step=self._steps, at=time.time())
             self.telemetry.note_tick(entry.anatomy)
+
+    def _note_step_counters(self, counts) -> None:
+        """Add one tick's STEP_COUNTERS sums to the running totals
+        (``stats()``) and to ``app_tpu_step_counter_total``."""
+        for i, name in enumerate(self._step_counters):
+            value = int(counts[i])
+            self._step_totals[i] += value
+            if self.metrics is not None and value:
+                self.metrics.delta_updown_counter(
+                    "app_tpu_step_counter_total", float(value),
+                    model=self.model_name, counter=name)
 
     def _note_spec(self, proposed: int, accepted: int) -> None:
         """Acceptance accounting plus the adaptive-γ controller: every
@@ -3765,7 +3861,14 @@ class GenerationEngine:
         # (otherwise later groups' callers would hang unresolved).
         staged: List[Tuple[int, int, int, bool, Any,
                            List[Tuple[int, int, int]]]] = []
-        for (p_rung, bucket, biased), group in sorted(by_group.items()):
+        # a group over the admission bound goes as several, in the order
+        # its requests arrived
+        bounded = []
+        for key, whole in sorted(by_group.items()):
+            rows = self._group_rows(key[1])
+            bounded += [(key, whole[at:at + rows])
+                        for at in range(0, len(whole), rows)]
+        for (p_rung, bucket, biased), group in bounded:
             nb = next(x for x in self._n_ladder if x >= len(group))
             plen = p_rung * self._prefix.page if p_rung else 0
             padded = np.zeros((nb, bucket), np.int32)
@@ -4289,6 +4392,9 @@ class GenerationEngine:
         pw = self._pick_page_width(window) if self.paged else 0
 
         def dispatch():
+            # what the module's steps counted (STEP_COUNTERS), as the one
+            # extra output of the unconstrained paged tick: () elsewhere
+            counts_dev = ()
             if self.paged:
                 # pool lock: see the admission dispatch — co-resident
                 # engines' donations must not interleave with ours
@@ -4309,18 +4415,21 @@ class GenerationEngine:
                             table, self.cache_len, dev_bias["active"],
                             dev_bias["bias"])
                     elif sampled:
-                        (tokens_dev, leaves, self.cache_len,
-                         self.sample_keys) = self._decode_paged_fn(
+                        out = self._decode_paged_fn(
                             k, sampled=True, pw=pw)(
                             self.params, self.last_token, self._pool.leaves,
                             table, self.cache_len, self._mask_dev,
                             self.temps, self.top_ks, self.top_ps,
                             self.sample_keys)
+                        (tokens_dev, leaves, self.cache_len,
+                         self.sample_keys) = out[:4]
+                        counts_dev = out[4:]
                     else:
-                        (tokens_dev, leaves,
-                         self.cache_len) = self._decode_paged_fn(k, pw=pw)(
+                        out = self._decode_paged_fn(k, pw=pw)(
                             self.params, self.last_token, self._pool.leaves,
                             table, self.cache_len, self._mask_dev)
+                        tokens_dev, leaves, self.cache_len = out[:3]
+                        counts_dev = out[3:]
                     self._pool.leaves = leaves
             elif biased and sampled:
                 (tokens_dev, self.cache, self.cache_len,
@@ -4348,7 +4457,7 @@ class GenerationEngine:
                     self.params, self.last_token, self.cache,
                     self.cache_len, self._mask_dev)
             self.last_token = tokens_dev[-1]
-            return tokens_dev
+            return tokens_dev, counts_dev
 
         step_span = self._step_span("tpu.engine.step", snapshot,
                                     k=k, window=window or self.max_len,
@@ -4363,9 +4472,9 @@ class GenerationEngine:
                     else (k, sampled, window) in self._decode_fns)
         if warm:
             with self._profile_step("tpu.engine.step"):
-                tokens_dev = dispatch()
+                tokens_dev, counts_dev = dispatch()
         else:
-            tokens_dev = await self._off_loop(loop, dispatch)
+            tokens_dev, counts_dev = await self._off_loop(loop, dispatch)
         self._steps += 1
         if self.metrics is not None:
             exemplar = next(
@@ -4392,8 +4501,13 @@ class GenerationEngine:
                         min(1.0, filled / (held * self.kv_page)),
                         model=self.model_name)
 
-        def fetch(dev=tokens_dev):
-            return np.asarray(dev)
+        if counts_dev:
+            def fetch(dev=(tokens_dev, counts_dev[0])):
+                # tokens and the steps' counters land in the one fetch
+                return self._jax.device_get(dev)
+        else:
+            def fetch(dev=tokens_dev):
+                return np.asarray(dev)
 
         # executable-family name for the roofline ledger (ISSUE 17):
         # mirrors the warm-key above, so device time lands on the same

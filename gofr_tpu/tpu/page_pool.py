@@ -3,7 +3,10 @@
 One pool backs every KV byte of the paged serving path: prefill output,
 the radix prefix cache, and decode appends all address the same
 ``(L, num_pages, page, Hkv, Dh)`` arrays (int8 caches add the scale
-planes ``(L, num_pages, page, Hkv)``). The dense engine kept one
+planes ``(L, num_pages, page, Hkv)``). A pool of another cache form is
+given its ``leaf_specs`` (name -> per-token trailing shape, dtype: what
+a model module's ``cache_leaves(cfg)`` answers; a latent-attention
+module's is one ``(L, num_pages, page, C)`` leaf). The dense engine kept one
 ``(max_slots, max_len, ...)`` cache whose HBM cost was the *worst-case*
 sequence length times the slot count; here HBM is ``num_pages × page``
 tokens regardless of ``max_len``, and slot count scales with the actual
@@ -42,6 +45,19 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 __all__ = ["PagePool", "HBMBudget"]
 
 
+def kv_leaf_specs(cfg) -> Dict[str, tuple]:
+    """What one token leaves in a layer of a k/v cache: name -> (trailing
+    shape, dtype[, fill]). The pool's form when it is given no other."""
+    import jax.numpy as jnp
+
+    tail = (cfg.n_kv_heads, cfg.head_dim)
+    if cfg.kv_int8:
+        return {"k": (tail, jnp.int8), "v": (tail, jnp.int8),
+                "ks": (tail[:-1], jnp.float32, 1.0),
+                "vs": (tail[:-1], jnp.float32, 1.0)}
+    return {"k": (tail, cfg.dtype), "v": (tail, cfg.dtype)}
+
+
 class PagePool:
     """Refcounted device page pool shared by prefill, prefix cache, and
     decode. ``num_pages`` may be given directly or derived from
@@ -50,7 +66,8 @@ class PagePool:
     def __init__(self, cfg, page: int = 32,
                  num_pages: Optional[int] = None,
                  budget_bytes: Optional[int] = None,
-                 mesh=None, metrics=None):
+                 mesh=None, metrics=None,
+                 leaf_specs: Optional[Dict[str, tuple]] = None):
         import threading
 
         import jax
@@ -72,7 +89,13 @@ class PagePool:
         self.mesh = mesh
         self.metrics = metrics
         self.page = int(page)
-        self.page_bytes = self._page_bytes(cfg, self.page)
+        self.leaf_specs = dict(leaf_specs or kv_leaf_specs(cfg))
+        if mesh is not None and set(self.leaf_specs) - {"k", "v", "ks",
+                                                        "vs"}:
+            raise ValueError(
+                f"PagePool: a mesh shards k/v leaves by kv-head; leaves "
+                f"{sorted(self.leaf_specs)} have no sharding rule")
+        self.page_bytes = self._page_bytes(cfg, self.page, self.leaf_specs)
         if num_pages is not None:
             self.num_pages = int(num_pages)
         elif budget_bytes is not None:
@@ -96,31 +119,27 @@ class PagePool:
         return self.num_pages
 
     @staticmethod
-    def _page_bytes(cfg, page: int) -> int:
+    def _page_bytes(cfg, page: int,
+                    leaf_specs: Optional[Dict[str, tuple]] = None) -> int:
         """HBM bytes one page occupies across every cache leaf."""
+        import math
+
         import jax.numpy as jnp
 
-        kv = cfg.n_layers * page * cfg.n_kv_heads * cfg.head_dim
-        if cfg.kv_int8:
-            scales = cfg.n_layers * page * cfg.n_kv_heads * 4
-            return 2 * (kv + scales)          # int8 k+v, f32 ks+vs
-        return 2 * kv * jnp.dtype(cfg.dtype).itemsize
+        per_token = sum(
+            math.prod(spec[0]) * jnp.dtype(spec[1]).itemsize
+            for spec in (leaf_specs or kv_leaf_specs(cfg)).values())
+        return cfg.n_layers * page * per_token
 
     def _init_leaves(self) -> None:
         import jax.numpy as jnp
 
-        cfg = self.cfg
-        shape = (cfg.n_layers, self.num_pages, self.page, cfg.n_kv_heads,
-                 cfg.head_dim)
+        lead = (self.cfg.n_layers, self.num_pages, self.page)
 
         def fresh():
-            if cfg.kv_int8:
-                return {"k": jnp.zeros(shape, jnp.int8),
-                        "v": jnp.zeros(shape, jnp.int8),
-                        "ks": jnp.ones(shape[:-1], jnp.float32),
-                        "vs": jnp.ones(shape[:-1], jnp.float32)}
-            return {"k": jnp.zeros(shape, cfg.dtype),
-                    "v": jnp.zeros(shape, cfg.dtype)}
+            return {name: jnp.full(lead + tuple(spec[0]),
+                                   spec[2] if len(spec) > 2 else 0, spec[1])
+                    for name, spec in self.leaf_specs.items()}
 
         if self.mesh is None:
             self.leaves = fresh()
@@ -131,8 +150,9 @@ class PagePool:
         # the mesh has left never fits whole on one device first.
         from gofr_tpu.parallel.sharding import (
             llama_prefix_pool_specs, named_shardings, prune_specs)
-        specs = prune_specs(llama_prefix_pool_specs(kv_int8=cfg.kv_int8),
-                            self.mesh)
+        specs = prune_specs(
+            llama_prefix_pool_specs(kv_int8="ks" in self.leaf_specs),
+            self.mesh)
         self.leaves = self._jax.jit(
             fresh, out_shardings=named_shardings(self.mesh, specs))()
 
@@ -266,6 +286,7 @@ class PagePool:
             "used_pages": self.used_pages,
             "free_pages": self.free_pages,
             "page_bytes": self.page_bytes,
+            "bytes_per_token": self.page_bytes // self.page,
             "pool_bytes": self.pool_bytes,
             "occupancy": (round(self.used_pages / self.num_pages, 6)
                           if self.num_pages else 0.0),
