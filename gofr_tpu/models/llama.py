@@ -534,7 +534,7 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     prefetch — so ``page_table`` may carry the slot's *full* table (no
     ladder rung slicing) and int8 dequant happens in-kernel from the
     scale planes. The kernel takes the carried leaves WHOLE plus the
-    scan's layer index and picks the layer in its index maps: a
+    scan's layer index and picks the layer in its page copies: a
     ``dynamic_index_in_dim`` here cannot fuse into a pallas_call the way
     it fuses into the gather, so XLA would copy one layer's plane of
     the entire pool per leaf, per layer, per step (8 ms of a 35 ms step

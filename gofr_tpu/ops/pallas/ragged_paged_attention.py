@@ -9,50 +9,89 @@ compiled executable (graftcheck's GT003 page-width hazard class exists
 because of it) and HBM bandwidth is spent rebuilding views the kernel
 could walk in place. This kernel walks them in place:
 
-- Grid ``(slot, kv-page-block)``; the page table and per-slot fill ride
-  **scalar prefetch**, so each program's K/V BlockSpec index map reads
-  its slot's *actual* pool row directly from the table — no materialized
-  gather, no static width ladder, one executable for every fill level.
+- Grid ``(slot,)``: **one program a slot, which walks that slot's live
+  pages and nothing else.** The page table, the per-slot fill and the
+  layer ride **scalar prefetch**; the pool leaves stay in HBM
+  (``memory_space=ANY``) and the body copies pages itself
+  (``make_async_copy`` into a VMEM ring with DMA semaphores), a block of
+  pages at a time, several blocks in flight while one is computed. The
+  loop bound is ``ceil(length / page)``: a dead table column costs
+  nothing, an inactive slot only folds its new token, a sentinel id is
+  never read. No materialized gather, no static width ladder, one
+  executable for every fill level. A deeper BlockSpec pipeline would
+  have been the short way to several pages in flight, but
+  ``pl.Buffered(3)`` is refused by this jax's TPU lowering, and a grid
+  step a table column costs a step for a dead column too; hence the
+  walk and its own ring (PERF.md, PR 28: 0.59 -> 0.06 ms a call at 16
+  slots x 64 columns on a v5e).
 - The pool arrives STACKED, ``(L, num_pages, page, Hkv, D)``, exactly as
-  the layer scan carries it, and the layer index is a third
-  scalar-prefetch operand that the same index maps read. A pallas_call
-  is opaque to XLA: a ``dynamic_index_in_dim`` taken outside it cannot
-  fuse into the kernel as it fuses into a gather, so XLA materializes
-  one layer's plane of the WHOLE pool (live or free) per operand, per
-  layer, per step — 8 ms of a 35 ms Mistral-7B decode step on a v5e
-  (PERF.md, PR 26). Picking the layer in the BlockSpec is what makes
-  "in place" true on the chip; tests/test_pallas_aot.py holds the
-  compiled engine tick to it.
-- TWO-PHASE page walk for token identity: the page-block axis runs the
-  table twice. Phase 0 streams K only and finishes the softmax
-  statistics (max and normalizer in VMEM scratch); phase 1 re-derives
-  each block's scores, materializes the *final* per-position
-  probabilities, and accumulates P·V. A single-pass online-softmax
-  kernel is cheaper but renormalizes probabilities with correction
-  factors the gather oracle never applies — its probs are rounded to the
-  cache dtype *after* global normalization, and at bf16 that rounding
-  difference walks greedy decode off the oracle's token stream within a
-  few ticks. Phase 1 reproduces the oracle's rounding points exactly
-  (scores rounded at the einsum boundary, probs rounded post-
-  normalization, cache/new contributions added in cache dtype), so
-  kernel vs gather is bit-equal up to f32 sum-order noise that the
-  dtype rounding absorbs. Cost: K streams twice, V once (V's index map
-  parks on one row during phase 0 so no dead fetches) — still far below
-  the gather path, which writes AND reads a materialized (B, P·page)
-  copy of both K and V every layer.
-- Pages past the slot's fill are clamped to the last valid row in the
-  index map (the pipeline elides re-fetching an unchanged block) and
-  their compute is skipped with ``pl.when`` — sentinel page ids are
-  never dereferenced, which the tests assert by poisoning unreferenced
-  pages with NaN.
+  the layer scan carries it, and the page copies index it with the
+  layer. A pallas_call is opaque to XLA: a ``dynamic_index_in_dim``
+  taken outside it cannot fuse into the kernel as it fuses into a
+  gather, so XLA materializes one layer's plane of the WHOLE pool (live
+  or free) per operand, per layer, per step — 8 ms of a 35 ms
+  Mistral-7B decode step on a v5e (PERF.md, PR 26). Picking the layer
+  inside the kernel is what makes "in place" true on the chip;
+  tests/test_pallas_aot.py holds the compiled engine tick to it.
+- ALL KV HEADS IN ONE PRODUCT. A page ``(page, Hkv, D)`` is read as
+  ``page * Hkv`` rows of ``D``, and every query row of every head is
+  multiplied with all of them: of the ``Hkv`` columns a token gets, a
+  row keeps its own head's and masks the rest. Picking one head out of
+  the sublane dimension costs a shuffle a token a head, and a DMA cannot
+  pick it either (bf16 heads are packed in pairs in a 32-bit sublane);
+  the MXU, at a few percent, has the room for the other heads' products,
+  P.V needs no picking (a masked probability is exactly 0), and a block
+  of pages is one ``(rows, D) x (D, block * page * Hkv)`` product. The
+  MXU takes its operands in the cache dtype: their products are exact
+  in float32, so only the order of the float32 sums differs from
+  float32 dots.
+- TWO-PHASE page walk for token identity. Phase 0 streams K and
+  finishes the softmax statistics (max and normalizer); phase 1 forms
+  the *final* per-position probabilities and accumulates P·V. A
+  single-pass online-softmax kernel is cheaper but renormalizes
+  probabilities with correction factors the gather oracle never
+  applies — its probs are rounded to the cache dtype *after* global
+  normalization, and at bf16 that rounding difference walks greedy
+  decode off the oracle's token stream within a few ticks. Phase 1
+  reproduces the oracle's rounding points exactly (scores rounded at
+  the einsum boundary, probs rounded post-normalization, cache/new
+  contributions added in cache dtype), so kernel vs gather is bit-equal
+  up to f32 sum-order noise that the dtype rounding absorbs in the
+  cases the CPU tests hold to the last bit (tens of tokens, 4 query
+  heads; over 32 query heads or hundreds of tokens an output's last bit
+  moves now and then, under this walk as under the grid kernel before
+  it: tests/test_ragged_attention.py ``_assert_identity``). **K
+  leaves HBM once**: phase 0 keeps its masked float32 scores in VMEM
+  (2 MiB at 16 slots x 2048 positions, GQA 32:8) and phase 1 reads V
+  alone. Where a table's scores pass ``_KEEP_SCORE_BYTES`` (the verify
+  variant at that table, any decode table much longer) phase 1 streams
+  K again and re-derives them: the same body, chosen by the shapes
+  (:func:`walk_sizes`), not by a flag.
+- The copies of one slot are ONE sequence, K blocks then V blocks, so
+  V's first blocks are in flight while phase 0 ends, and a program
+  starts the first copies of the next slot before it returns: the ring
+  is never cold but at the call's first slot. That, and the ring's
+  zero-fill at the first slot, are why the grid runs in order
+  (``arbitrary``): a dead page inside a partly live block is not
+  copied, and its stale rows meet probabilities that are exactly 0, so
+  they must be finite, which a former block's rows are and fresh VMEM
+  may not be. The tests poison every page outside a live prefix with
+  NaN, table entries past the prefix inside a partly live block
+  included.
 - int8 pools dequantize **in-kernel** from the scale planes that live
-  beside the pages (k/v scaled to f32 before the dots — the same math
-  as the gather path's post-einsum score folding, without ever
+  beside the pages: a page's scales ride the ring as one lane row, in
+  the order of its score columns (the same math as the gather path's
+  post-einsum score folding and pre-einsum value folding, without ever
   materializing a converted cache copy).
 - The γ+1-token query variant (:func:`ragged_paged_verify_attention`)
   backs speculative verify: G queries at positions ``cache_len + g``
   attend the paged cache plus each other causally, so verify stops
   paying prefill-shaped attention.
+
+Measured on the chip through the engine: bf16 pools at GQA 32:8, decode
+(the benchmark's ``mistral7b.batch``). int8 pools, the verify variant
+and MHA 32:32 have been timed alone and compared with the oracle there,
+not served (PERF.md, PR 28).
 
 Post-mortem context (ops/pallas/decode_attention): the dense flash
 prototype lost 5x *inside* the per-layer scan because each pallas_call
@@ -72,6 +111,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from gofr_tpu.ops.pallas.select import lower_for_target
@@ -105,142 +145,252 @@ def _round_to(x, dtype):
     return lax.bitcast_convert_type(bits, jnp.float32)
 
 
-def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, kn_ref,
-                   vn_ref, *rest, page: int, num_pi: int, kv_heads: int,
-                   group: int, g_len: int, int8: bool, sm_scale: float):
-    """One (slot, walk-step) program on the doubled page-block axis.
+# the walk's sizes come from these three and the shapes the call sees
+_BLOCK_SCORE_BYTES = 256 * 1024   # float32 scores of one block: 64 vregs
+_RING_BYTES = 2 << 20             # page copies in flight, K or V
+_KEEP_SCORE_BYTES = 8 << 20       # a slot's masked scores kept for phase 1
+_NEVER = 1 << 30                  # ``lim`` of a column of another head
 
-    Steps ``[0, num_pi)`` are phase 0 (K only): accumulate the softmax
-    max and normalizer over the slot's live pages, then fold the G new
-    tokens' scores so the statistics are FINAL. Steps
-    ``[num_pi, 2*num_pi)`` are phase 1: re-derive each block's scores,
-    form the oracle's exact per-position probabilities (rounded to the
-    cache dtype after normalization, just like the gather path's
-    ``probs.astype(q.dtype)``), and accumulate P·V; the last step adds
-    the new tokens' contribution and writes the output. ``rest`` is
-    (ks, vs, out, acc, m, l) on int8 pools — the scale-plane blocks ride
-    the same index maps as their pages — and (out, acc, m, l) on bf16
-    pools, so bf16 never fetches a dead operand. ``layer_ref`` is read by
-    the index maps alone: the layer dimension is squeezed out of every
-    pool block, so the body sees one layer's ``(1, page, Hkv, D)`` page.
 
-    Everything is per kv-head with the head on a LEADING axis (q, the new
-    K/V, the output and the scratch all arrive head-major from the
-    wrapper), and every mask is built at the shape it is applied at:
-    Mosaic tiles the last two dims, so a head picked off a leading axis
-    is a plain tile load, while reshaping heads out of the sublane dim or
-    broadcasting/tiling an i1 vector is a layout change it refuses."""
+def walk_sizes(page: int, kv_heads: int, head_dim: int, rows_all: int,
+               itemsize: int, table_width: int):
+    """``(block_pages, ring_blocks, keep_scores)`` of the page walk.
+
+    A block is ``block_pages`` pages scored in one product,
+    ``(rows_all, D) x (D, block_pages * page * kv_heads)``: as many as
+    keep the block's float32 scores within ``_BLOCK_SCORE_BYTES``.
+    The ring holds ``ring_blocks`` blocks of page copies, about
+    ``_RING_BYTES`` of them and at least two. ``keep_scores`` says
+    whether a slot's masked scores over the whole table fit in
+    ``_KEEP_SCORE_BYTES`` of VMEM, so that phase 1 reads V alone; where
+    they do not, phase 1 streams K a second time."""
+    cols = page * kv_heads
+    block_pages = max(1, min(table_width,
+                             _BLOCK_SCORE_BYTES // (rows_all * cols * 4)))
+    block_bytes = block_pages * cols * head_dim * itemsize
+    ring_blocks = max(2, min(8, _RING_BYTES // block_bytes))
+    blocks = -(-table_width // block_pages)
+    keep = rows_all * blocks * block_pages * cols * 4 <= _KEEP_SCORE_BYTES
+    return block_pages, ring_blocks, keep
+
+
+def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   kn_ref, vn_ref, lim_ref, new_ok_ref, *rest,
+                   block_pages: int, ring_blocks: int, keep_scores: bool,
+                   int8: bool, sm_scale: float):
+    """One slot's program: walk its live pages, and nothing else.
+
+    ``k_hbm`` / ``v_hbm`` are the stacked pool leaves, left in HBM; the
+    body copies the live pages of this layer into a VMEM ring itself,
+    ``ring_blocks`` blocks of ``block_pages`` pages, and keeps the ring
+    full while it computes. The copies of one slot are a single
+    sequence of *items*: the K blocks in table order (phase 0), then
+    the V blocks (phase 1), so V's first blocks are in flight while
+    phase 0 ends, and the first items of the NEXT slot are started
+    before this program returns (the grid runs in order on one core).
+    Where the scores are not kept, phase 1's items alternate K and V.
+
+    Every kv-head is scored at once: a page ``(page, Hkv, D)`` is read
+    as ``(page * Hkv, D)`` rows, token-major, so ``q (R, D)`` (all
+    query rows of all heads) times its transpose gives ``(R, page *
+    Hkv)`` scores of which a row's own head holds one column in
+    ``Hkv``; the others are masked, like the positions past the slot's
+    length, by one comparison with ``lim_ref`` (column's token offset in
+    the block, or ``_NEVER`` for another head's column). Picking a head
+    out of the sublane dimension costs a shuffle a token a head; the
+    MXU has the room for the other heads' products and P.V needs no
+    picking either, since a masked probability is exactly 0.
+
+    Rounding points are the oracle's (module docstring): the MXU takes
+    its operands in the cache dtype, whose products are exact in
+    float32, so only the order of the float32 sums differs."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if int8:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+        ks_hbm, vs_hbm, *rest = rest
+    o_ref, ring, sems, *rest = rest
+    if keep_scores:
+        score_ref, *rest = rest
+    if int8:
+        scale_ring, = rest
 
     b = pl.program_id(0)
-    pi = pl.program_id(1)
-    pj = lax.rem(pi, num_pi)                   # page index within a phase
-    length = len_ref[b]                        # valid tokens, excl. new
+    num_slots = pl.num_programs(0)
+    table_width = table_ref.shape[1]
+    num_pages, page, kv_heads, head_dim = k_hbm.shape[1:]
+    rows_all = q_ref.shape[1]
+    cols = page * kv_heads
+    width = block_pages * cols                 # score columns of a block
+    block_tokens = block_pages * page
+    layer = layer_ref[0]
+    # valid tokens, excl. new; the table holds no more than its columns
+    length = jnp.minimum(len_ref[b], table_width * page)
     cdt = o_ref.dtype                          # the oracle's cache dtype
-    rows = g_len * group                       # query rows per kv-head
 
     def _round(x):
         return _round_to(x, cdt)
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def walk(slot_b):
+        pages = jnp.minimum(lax.div(len_ref[slot_b] + page - 1, page),
+                            table_width)
+        blocks = lax.div(pages + block_pages - 1, block_pages)
+        return pages, blocks, blocks * (2 if keep_scores else 3)
 
-    def scale_row(s_ref, h):
-        # (page, Hkv) scale block -> head h's scales as a (1, page) lane
-        # row. The column sits on sublanes; an exact select-and-sum over
-        # the diagonal moves it to lanes without a transpose.
-        col = s_ref[0][:, h:h + 1]                          # (page, 1)
-        eye = (lax.broadcasted_iota(jnp.int32, (page, page), 0)
-               == lax.broadcasted_iota(jnp.int32, (page, page), 1))
-        return jnp.where(eye, col, 0.0).sum(axis=0, keepdims=True)
+    def for_copies(slot_b, item, pages, blocks, do):
+        """``do`` each page copy of ``item`` of slot ``slot_b``: live
+        pages only, so a sentinel id is never read, let alone fetched."""
+        in_phase0 = item < blocks
+        if keep_scores:
+            is_k, j = in_phase0, jnp.where(in_phase0, item, item - blocks)
+        else:
+            turn = item - blocks
+            is_k = jnp.logical_or(in_phase0, lax.rem(turn, 2) == 0)
+            j = jnp.where(in_phase0, item, lax.div(turn, 2))
+        at = lax.rem(item, ring_blocks)
+        first = j * block_pages
+        sources = [(k_hbm, ks_hbm if int8 else None),
+                   (v_hbm, vs_hbm if int8 else None)]
+        for wants_k, (pages_hbm, scales_hbm) in zip((True, False), sources):
+            @pl.when(is_k == wants_k)
+            def _(pages_hbm=pages_hbm, scales_hbm=scales_hbm):
+                def one(c, carry):
+                    pid = jnp.minimum(table_ref[slot_b, first + c],
+                                      num_pages - 1)
+                    do(pltpu.make_async_copy(
+                        pages_hbm.at[layer, pid], ring.at[at, c],
+                        sems.at[at]))
+                    if int8:
+                        do(pltpu.make_async_copy(
+                            scales_hbm.at[pid], scale_ring.at[at, c],
+                            sems.at[at]))
+                    return carry
 
-    def block_scores(h):
+                lax.fori_loop(0, jnp.minimum(pages - first, block_pages),
+                              one, 0)
+
+    def start_first_items(slot_b):
+        pages, blocks, items = walk(slot_b)
+
+        def one(item, carry):
+            for_copies(slot_b, item, pages, blocks,
+                       lambda copy: copy.start())
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(items, ring_blocks - 1), one, 0)
+
+    @pl.when(b == 0)
+    def _first_program():
+        # a dead page inside a partly live block is not fetched, and its
+        # stale rows meet probabilities that are exactly 0: they must be
+        # finite, which rows of an earlier block are and fresh VMEM may
+        # not be
+        ring[...] = jnp.zeros_like(ring)
+        if int8:
+            scale_ring[...] = jnp.zeros_like(scale_ring)
+        start_first_items(b)
+
+    pages, blocks, items = walk(b)
+
+    def arrive(item):
+        """Keep the ring full, then wait for ``item``; its ring place."""
+        ahead = item + ring_blocks - 1
+
+        @pl.when(ahead < items)
+        def _():
+            for_copies(b, ahead, pages, blocks, lambda copy: copy.start())
+
+        for_copies(b, item, pages, blocks, lambda copy: copy.wait())
+        return lax.rem(item, ring_blocks)
+
+    def flat(at, dtype):
+        # (block_pages, page, Hkv, D) -> (width, D): free in float32,
+        # where a token's (Hkv, D) is whole tiles
+        return ring[at].astype(jnp.float32) \
+            .reshape(width, head_dim).astype(dtype)
+
+    def scale_row(at):
+        return jnp.concatenate(
+            [scale_ring[at, c] for c in range(block_pages)], axis=-1)
+
+    q_all = q_ref[0]                                        # (R, D)
+
+    def scores_of(keys):
         # rounding order matches the oracle exactly: dot -> cache-dtype
-        # round -> * sm_scale -> (* k_scale on int8) -> length mask.
-        # q stays UNSCALED: the oracle applies sm_scale after the
-        # (rounded) score einsum.
-        q_h = q_ref[0, h].astype(jnp.float32)               # (rows, D)
-        k_h = k_ref[0][:, h, :].astype(jnp.float32)         # (page, D)
-        s_h = _round(jnp.dot(q_h, k_h.T,
-                             preferred_element_type=jnp.float32)) * sm_scale
+        # round -> * sm_scale -> (* k_scale on int8) -> mask. q stays
+        # UNSCALED: the oracle applies sm_scale after the (rounded)
+        # score einsum.
+        return _round(lax.dot_general(
+            q_all, keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)) * sm_scale
+
+    def block_scores(at, j):
+        s = scores_of(flat(at, q_all.dtype))
         if int8:
             # fused dequant, oracle formulation: the int8 scores are
             # exact through the rounded dot, and the per-vector scale
             # folds into f32 AFTER — never a converted cache copy
-            s_h = s_h * scale_row(ks_ref, h)
-        pos = pj * page + lax.broadcasted_iota(jnp.int32, (rows, page), 1)
-        return jnp.where(pos < length, s_h, _NEG_INF)
+            s = s * scale_row(at)
+        return jnp.where(lim_ref[...] < length - j * block_tokens, s,
+                         _NEG_INF)
 
-    def new_scores(h):
-        # the G new tokens (positions length..length+G-1, causal among
-        # themselves: key u attends to query s iff u <= s); their K
-        # arrives unquantized even on int8 pools (oracle contract).
-        # Row r of a head is query r // group, and u <= r // group is
-        # u * group <= r.
-        q_h = q_ref[0, h].astype(jnp.float32)
-        k_new = kn_ref[0, h].astype(jnp.float32)            # (G, D)
-        s_new = _round(jnp.dot(q_h, k_new.T,
-                               preferred_element_type=jnp.float32)) * sm_scale
-        r_pos = lax.broadcasted_iota(jnp.int32, (rows, g_len), 0)
-        u_pos = lax.broadcasted_iota(jnp.int32, (rows, g_len), 1)
-        return jnp.where(u_pos * group <= r_pos, s_new, _NEG_INF)
-
-    def fold_stats(h, scores):
-        m_prev, l_prev = m_ref[h], l_ref[h]
+    def fold_stats(stats, scores):
+        m_prev, l_prev = stats
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        m_ref[h] = m_new
-        l_ref[h] = (l_prev * jnp.exp(m_prev - m_new)
-                    + jnp.exp(scores - m_new).sum(axis=-1, keepdims=True))
+        return m_new, (l_prev * jnp.exp(m_prev - m_new)
+                       + jnp.exp(scores - m_new).sum(axis=-1, keepdims=True))
+
+    def score_cols(j):
+        return pl.ds(pl.multiple_of(j * width, width), width)
 
     # -- phase 0: softmax statistics over the live pages ------------------
-    @pl.when(jnp.logical_and(pi < num_pi, pj * page < length))
-    def _stats_step():
-        for h in range(kv_heads):
-            fold_stats(h, block_scores(h))
+    def stats_step(j, stats):
+        s = block_scores(arrive(j), j)
+        if keep_scores:
+            score_ref[:, score_cols(j)] = s
+        return fold_stats(stats, s)
 
-    @pl.when(pi == num_pi - 1)
-    def _stats_finish():
-        # fold the new tokens' scores: m/l are FINAL after this step (the
-        # causal diagonal guarantees l >= 1, so phase 1 never divides by
-        # zero)
-        for h in range(kv_heads):
-            fold_stats(h, new_scores(h))
+    stats = lax.fori_loop(
+        0, blocks, stats_step,
+        (jnp.full((rows_all, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((rows_all, 1), jnp.float32)))
+    # the G new tokens (positions length..length+G-1, causal among
+    # themselves); their K arrives unquantized even on int8 pools (oracle
+    # contract). Folding them makes m/l FINAL (the causal diagonal
+    # guarantees l >= 1, so phase 1 never divides by zero)
+    s_new = jnp.where(new_ok_ref[...] > 0, scores_of(kn_ref[0]), _NEG_INF)
+    m_fin, l_fin = fold_stats(stats, s_new)
 
-    # -- phase 1: oracle-identical probabilities, P·V accumulation --------
-    @pl.when(jnp.logical_and(pi >= num_pi, pj * page < length))
-    def _value_step():
-        for h in range(kv_heads):
-            p = jnp.exp(block_scores(h) - m_ref[h]) / l_ref[h]
-            if int8:
-                # oracle int8 V path: normalized probs stay f32 and the
-                # per-vector scale folds in pre-einsum (precision over
-                # bandwidth — see decode_attention_cached)
-                p = p * scale_row(vs_ref, h)
-            else:
-                p = _round(p)                  # probs.astype(q.dtype)
-            v_h = v_ref[0][:, h, :].astype(jnp.float32)     # (page, D)
-            acc_ref[h] += jnp.dot(p, v_h,
-                                  preferred_element_type=jnp.float32)
+    # -- phase 1: oracle-identical probabilities, P.V accumulation --------
+    def value_step(j, acc):
+        if keep_scores:
+            s = score_ref[:, score_cols(j)]
+            at = arrive(blocks + j)
+        else:
+            s = block_scores(arrive(blocks + 2 * j), j)
+            at = arrive(blocks + 2 * j + 1)
+        p = jnp.exp(s - m_fin) / l_fin
+        if int8:
+            # oracle int8 V path: normalized probs stay f32 and the
+            # per-vector scale folds in pre-einsum (precision over
+            # bandwidth — see decode_attention_cached)
+            p, v = p * scale_row(at), flat(at, jnp.float32)
+        else:
+            p, v = _round(p).astype(cdt), flat(at, cdt)  # probs.astype
+        return acc + jnp.dot(p, v, preferred_element_type=jnp.float32)
 
-    @pl.when(pi == 2 * num_pi - 1)
-    def _finish():
-        for h in range(kv_heads):
-            p_new = _round(jnp.exp(new_scores(h) - m_ref[h]) / l_ref[h])
-            v_new = vn_ref[0, h].astype(jnp.float32)        # (G, D)
-            pv = jnp.dot(p_new, v_new, preferred_element_type=jnp.float32)
-            # the oracle snaps the cache and new-token einsum outputs,
-            # adds them in f32 and snaps the sum (ops/attention._snap
-            # schedule)
-            o_ref[0, h] = _round(_round(acc_ref[h]) + _round(pv)) \
-                .astype(o_ref.dtype)
+    acc = lax.fori_loop(0, blocks, value_step,
+                        jnp.zeros((rows_all, head_dim), jnp.float32))
+
+    @pl.when(b + 1 < num_slots)
+    def _next_program():
+        start_first_items(b + 1)
+
+    p_new = _round(jnp.exp(s_new - m_fin) / l_fin).astype(cdt)
+    pv = jnp.dot(p_new, vn_ref[0], preferred_element_type=jnp.float32)
+    # the oracle snaps the cache and new-token einsum outputs, adds them
+    # in f32 and snaps the sum (ops/attention._snap schedule)
+    o_ref[0] = _round(_round(acc) + _round(pv)).astype(o_ref.dtype)
 
 
 def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
@@ -257,7 +407,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     re-lay the WHOLE leaf out, padded sixteenfold, every layer (AOT at
     Mistral-7B sizes: two 452 MB copies a layer). One layer's scale
     plane is 1/128 of its K plane, so slicing it costs what it always
-    did."""
+    did; the slice comes out as one lane row a page, token-major like
+    the scores it scales."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -265,82 +416,84 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     _, num_pages, page, kv_heads, _ = k_pages.shape
     group = q_heads // kv_heads
     rows = g_len * group
-    num_pi = page_table.shape[1]
+    rows_all = kv_heads * rows
+    cols = page * kv_heads
     int8 = bool(scale_pages)
+    block_pages, ring_blocks, keep_scores = walk_sizes(
+        page, kv_heads, head_dim, rows_all, k_pages.dtype.itemsize,
+        page_table.shape[1])
+    width = block_pages * cols
     table = page_table.astype(jnp.int32)
     lens = cache_len.astype(jnp.int32)
-    # head-major operands (see _ragged_kernel): q-head kv*group + j of
-    # query g becomes row g*group + j of kv-head kv. q and the new K/V
-    # are a few KB per slot, so the transposes cost nothing next to the
-    # pool walk; the pool itself is read in place, the layer picked by
-    # the index maps (module docstring).
+    # head-major rows (see _ragged_kernel): q-head kv*group + j of query
+    # g becomes row kv*rows + g*group + j; new token u of kv-head kv is
+    # column kv*G + u. q and the new K/V are a few KB per slot, so the
+    # transposes cost nothing next to the pool walk; the pool itself is
+    # read in place.
     q_hm = q.reshape(batch, g_len, kv_heads, group, head_dim) \
-        .transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads, rows, head_dim)
-    kn_hm = k_new.transpose(0, 2, 1, 3)            # (B, Hkv, G, D)
-    vn_hm = v_new.transpose(0, 2, 1, 3)
+        .transpose(0, 2, 1, 3, 4).reshape(batch, rows_all, head_dim)
+    kn_hm = k_new.transpose(0, 2, 1, 3).reshape(
+        batch, kv_heads * g_len, head_dim)
+    vn_hm = v_new.transpose(0, 2, 1, 3).reshape(
+        batch, kv_heads * g_len, head_dim)
+    # the masks' shape-only halves, built here so the body divides nothing
+    row_head = np.arange(rows_all)[:, None] // rows
+    col = np.arange(width)[None, :]
+    lim = np.where(col % kv_heads == row_head, col // kv_heads, _NEVER)
+    new_col = np.arange(kv_heads * g_len)[None, :]
+    new_ok = np.logical_and(
+        new_col // g_len == row_head,
+        # row r of a head is query r // group: key u <= r // group
+        (new_col % g_len) * group <= np.arange(rows_all)[:, None] % rows)
 
-    def _row(b, pj, table_ref, len_ref):
-        # scalar-prefetch table walk: fetch this slot's ACTUAL pool row.
-        # Clamp pj to the last page holding valid tokens (the pipeline
-        # elides re-fetching an unchanged row, so the dead tail of the
-        # table is never streamed), then clamp a sentinel id in-bounds —
-        # its compute is skipped by pl.when, never attended.
-        length = len_ref[b]
-        last = jnp.maximum(lax.div(length + page - 1, page) - 1, 0)
-        pid = table_ref[b, jnp.minimum(pj, last)]
-        return jnp.minimum(pid, num_pages - 1)
+    def slot_block(b, *_):
+        return (b, 0, 0)
 
-    def k_index(b, pi, table_ref, len_ref, layer_ref):
-        # K streams in BOTH phases (scores are re-derived in phase 1)
-        return (layer_ref[0],
-                _row(b, lax.rem(pi, num_pi), table_ref, len_ref), 0, 0, 0)
-
-    def v_index(b, pi, table_ref, len_ref, layer_ref):
-        # V is only read in phase 1; during phase 0 the map parks on the
-        # row phase 1 fetches first, so no dead V block is ever streamed
-        pj = jnp.where(pi >= num_pi, lax.rem(pi, num_pi), 0)
-        return (layer_ref[0], _row(b, pj, table_ref, len_ref), 0, 0, 0)
-
-    def ks_index(*args):
-        return k_index(*args)[1:4]
-
-    def vs_index(*args):
-        return v_index(*args)[1:4]
-
-    def q_index(b, pi, table_ref, len_ref, layer_ref):
-        return (b, 0, 0, 0)
+    def whole(b, *_):
+        return (0, 0)
 
     kernel = functools.partial(
-        _ragged_kernel, page=page, num_pi=num_pi, kv_heads=kv_heads,
-        group=group, g_len=g_len, int8=int8, sm_scale=head_dim ** -0.5)
+        _ragged_kernel, block_pages=block_pages, ring_blocks=ring_blocks,
+        keep_scores=keep_scores, int8=int8, sm_scale=head_dim ** -0.5)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
-        pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
-        pl.BlockSpec((None, 1, page, kv_heads, head_dim), k_index),
-        pl.BlockSpec((None, 1, page, kv_heads, head_dim), v_index),
-        pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
-        pl.BlockSpec((1, kv_heads, g_len, head_dim), q_index),
+        pl.BlockSpec((1, rows_all, head_dim), slot_block),
+        in_hbm, in_hbm,
+        pl.BlockSpec((1, kv_heads * g_len, head_dim), slot_block),
+        pl.BlockSpec((1, kv_heads * g_len, head_dim), slot_block),
+        pl.BlockSpec((rows_all, width), whole),
+        pl.BlockSpec((rows_all, kv_heads * g_len), whole),
     ]
-    operands = [q_hm, k_pages, v_pages, kn_hm, vn_hm]
+    operands = [q_hm, k_pages, v_pages, kn_hm, vn_hm,
+                jnp.asarray(lim, jnp.int32), jnp.asarray(new_ok, jnp.int32)]
+    scratch = [
+        pltpu.VMEM((ring_blocks, block_pages, page, kv_heads, head_dim),
+                   k_pages.dtype),
+        pltpu.SemaphoreType.DMA((ring_blocks,)),
+    ]
+    if keep_scores:
+        blocks = -(-page_table.shape[1] // block_pages)
+        scratch.append(pltpu.VMEM((rows_all, blocks * width), jnp.float32))
     if int8:
-        in_specs += [pl.BlockSpec((1, page, kv_heads), ks_index),
-                     pl.BlockSpec((1, page, kv_heads), vs_index)]
-        operands += [lax.dynamic_index_in_dim(s, layer[0], 0, keepdims=False)
-                     for s in scale_pages]
+        in_specs += [in_hbm, in_hbm]
+        operands += [
+            lax.dynamic_index_in_dim(s, layer[0], 0, keepdims=False)
+            .reshape(num_pages, 1, cols) for s in scale_pages]
+        scratch.append(pltpu.VMEM((ring_blocks, block_pages, 1, cols),
+                                  jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(batch, 2 * num_pi),
+        grid=(batch,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kv_heads, rows, head_dim), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((kv_heads, rows, head_dim), jnp.float32),
-            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
-            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, rows_all, head_dim), slot_block),
+        scratch_shapes=scratch,
     )
     compiler_params = None
     if not interpret:
+        # in order, on one core: the ring and its first copies pass from
+        # a slot's program to the next
         compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

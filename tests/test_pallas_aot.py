@@ -8,11 +8,19 @@ with ``interpret=False`` at the two serving geometries, Llama-2-7B MHA
 (32:32) and Llama-3-8B GQA (32:8), head_dim 128. It proves compilation
 only; numerics on the chip are ``chip_smoke.py``'s kernels phase.
 
-The last test compiles what the engine really runs around the ragged
+The ragged kernel is also compiled at the call shape of the benchmark's
+``mistral7b.batch`` cell (16 slots, a table of 64 columns, page 32), where
+its page walk has its real sizes: the ring of page copies and the kept
+scores must fit the chip's scoped VMEM, which the compiler checks and
+the test's arithmetic repeats.
+
+The last tests compile what the engine really runs around the ragged
 kernel — its fused decode tick and its verify step, pool donated — and
-reads the optimised HLO: the pool must reach the kernel as the layer
+read the optimised HLO: the pool must reach the kernel as the layer
 scan's carry itself, with no per-layer plane and no copy of the pool
-materialised (PR 26: two such planes cost 8 ms of a 35 ms decode step).
+materialised (PR 26: two such planes cost 8 ms of a 35 ms decode step),
+and the layer body must hold the kernel exactly once (the benchmark
+counts decode steps as kernel calls over layers).
 """
 
 import functools
@@ -28,6 +36,7 @@ from gofr_tpu.ops.pallas import (flash_attention, flash_decode_attention,
                                  ragged_paged_decode_attention,
                                  ragged_paged_verify_attention,
                                  ragged_tileable)
+from gofr_tpu.ops.pallas.ragged_paged_attention import walk_sizes
 
 HEAD_DIM, PAGE, SLOTS, PAGES_PER_SLOT, NUM_PAGES = 128, 32, 2, 2, 8
 GEOMETRIES = [(32, 32), (32, 8)]
@@ -51,17 +60,19 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _paged_shapes(q_heads, kv_heads, g_len, int8):
+def _paged_shapes(q_heads, kv_heads, g_len, int8, slots=SLOTS,
+                  pages_per_slot=PAGES_PER_SLOT, num_pages=NUM_PAGES,
+                  layers=1):
     bf16 = jnp.bfloat16
-    new = ((SLOTS, kv_heads, HEAD_DIM) if g_len == 1
-           else (SLOTS, g_len, kv_heads, HEAD_DIM))
-    pool = ((1, NUM_PAGES, PAGE, kv_heads, HEAD_DIM),
+    new = ((slots, kv_heads, HEAD_DIM) if g_len == 1
+           else (slots, g_len, kv_heads, HEAD_DIM))
+    pool = ((layers, num_pages, PAGE, kv_heads, HEAD_DIM),
             jnp.int8 if int8 else bf16)
-    shapes = [((SLOTS, g_len, q_heads, HEAD_DIM), bf16), pool, pool,
-              ((SLOTS, PAGES_PER_SLOT), jnp.int32), (new, bf16),
-              (new, bf16), ((SLOTS,), jnp.int32), ((), jnp.int32)]
+    shapes = [((slots, g_len, q_heads, HEAD_DIM), bf16), pool, pool,
+              ((slots, pages_per_slot), jnp.int32), (new, bf16),
+              (new, bf16), ((slots,), jnp.int32), ((), jnp.int32)]
     if int8:
-        shapes += [((1, NUM_PAGES, PAGE, kv_heads), jnp.float32)] * 2
+        shapes += [((layers, num_pages, PAGE, kv_heads), jnp.float32)] * 2
     return shapes
 
 
@@ -101,6 +112,45 @@ def test_ragged_verify_compiles_for_v5e(v5e, q_heads, kv_heads):
     _compile(functools.partial(ragged_paged_verify_attention,
                                interpret=False),
              v5e, *_paged_shapes(q_heads, kv_heads, 5, False))
+
+
+# the benchmark's mistral7b.batch call: 16 slots, 64 table columns of page
+# 32 (max_len 2048), a pool of about a thousand pages a layer
+CELL_SLOTS, CELL_COLUMNS, CELL_PAGES, SCOPED_VMEM = 16, 64, 1024, 16 << 20
+CELL_CALLS = {
+    "gqa-bf16": (ragged_paged_decode_attention, 32, 8, 1, False),
+    "gqa-int8": (ragged_paged_decode_attention, 32, 8, 1, True),
+    "mha-bf16": (ragged_paged_decode_attention, 32, 32, 1, False),
+    "verify-gqa": (ragged_paged_verify_attention, 32, 8, 5, False),
+    "verify-mha": (ragged_paged_verify_attention, 32, 32, 5, False),
+}
+
+
+@pytest.mark.parametrize("case", CELL_CALLS)
+def test_ragged_compiles_at_the_cells_call_shape(v5e, case):
+    """The page walk at its real sizes. Mosaic refuses a kernel whose
+    scratch passes the scoped VMEM limit, so the compile is the check;
+    the arithmetic says what the scratch is: the ring of page copies,
+    the masked scores where they are kept (where they are not, phase 1
+    streams K again: both verify cases), the scale rows of an int8
+    pool, and the two constant masks the pipeline double-buffers."""
+    kernel, q_heads, kv_heads, g_len, int8 = CELL_CALLS[case]
+    rows_all, cols = q_heads * g_len, PAGE * kv_heads
+    block_pages, ring_blocks, keep = walk_sizes(
+        PAGE, kv_heads, HEAD_DIM, rows_all, 1 if int8 else 2, CELL_COLUMNS)
+    width = block_pages * cols
+    ring = ring_blocks * width * HEAD_DIM * (1 if int8 else 2)
+    scores = rows_all * -(-CELL_COLUMNS // block_pages) * width * 4
+    scratch = (ring + (scores if keep else 0)
+               + (ring_blocks * width * 4 if int8 else 0)
+               + 2 * rows_all * (width + kv_heads * g_len) * 4)
+    assert 2 <= ring_blocks
+    assert keep == (g_len == 1), (case, scores)
+    assert scratch < SCOPED_VMEM, (case, scratch)
+    _compile(functools.partial(kernel, interpret=False), v5e,
+             *_paged_shapes(q_heads, kv_heads, g_len, int8,
+                            slots=CELL_SLOTS, pages_per_slot=CELL_COLUMNS,
+                            num_pages=CELL_PAGES, layers=2))
 
 
 def _compile_paged_step(step, sharding, n_layers, num_pages, token_shape,
@@ -170,6 +220,27 @@ POOL_READ_IN_PLACE = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _engine_program(sharding, case):
+    step, token_shape, donate, kv_int8 = POOL_READ_IN_PLACE[case]
+    return _compile_paged_step(
+        step, sharding, n_layers=2, num_pages=11, token_shape=token_shape,
+        donate=donate, kv_int8=kv_int8)
+
+
+@pytest.mark.parametrize("case", POOL_READ_IN_PLACE)
+def test_layer_body_holds_the_kernel_exactly_once(v5e, case):
+    """One ``tpu_custom_call`` a layer a step and none elsewhere in the
+    program: the benchmark counts decode steps as the trace's
+    ``tpu_custom_call`` events over ``num_hidden_layers``
+    (``benchmark/layer_metrics/decode_step_ms.json``), so a kernel split
+    in two calls would halve the step time it reports. The layer scan's
+    body is compiled once, whatever the layer count and the tick's K, so
+    the program's text holds the call once."""
+    hlo, _ = _engine_program(v5e, case)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("case", POOL_READ_IN_PLACE)
 def test_ragged_step_reads_the_stacked_pool_in_place(v5e, case):
     """A pallas_call is opaque to XLA, so a layer slice taken outside
@@ -185,10 +256,7 @@ def test_ragged_step_reads_the_stacked_pool_in_place(v5e, case):
     dimension is Hkv), and a custom call handed a whole leaf has it
     re-laid out, padded sixteenfold, every layer — so the kernel's
     wrapper slices those (1/128 of a K plane)."""
-    step, token_shape, donate, kv_int8 = POOL_READ_IN_PLACE[case]
-    hlo, pool_shape = _compile_paged_step(
-        step, v5e, n_layers=2, num_pages=11, token_shape=token_shape,
-        donate=donate, kv_int8=kv_int8)
+    hlo, pool_shape = _engine_program(v5e, case)
 
     def dims(shape):
         return "[" + ",".join(map(str, shape)) + "]"
