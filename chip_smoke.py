@@ -65,7 +65,7 @@ SIZES: Dict[str, Dict[str, Any]] = {
         # kernels: (q_heads, kv_heads) pairs at head_dim 128 — Llama-2-7B
         # MHA and Llama-3-8B GQA
         "heads": ((32, 32), (32, 8)), "head_dim": 128, "flash_seq": 1024,
-        "kernel_slots": 8, "dense_len": 512,
+        "kernel_slots": 8,
     },
     # CPU rehearsal and the tier-1 test: same code, toy widths
     "tiny": {
@@ -76,7 +76,7 @@ SIZES: Dict[str, Dict[str, Any]] = {
         "kv_page": 8, "prompt_buckets": (8, 16), "steps_per_tick": 2,
         "max_new_tokens": 6,
         "heads": ((4, 2),), "head_dim": 16, "flash_seq": 32,
-        "kernel_slots": 4, "dense_len": 32,
+        "kernel_slots": 4,
     },
 }
 
@@ -219,13 +219,11 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
     it with its oracle. ``interpret`` exists for the CPU rehearsal only;
     the script itself always compiles."""
     import jax
-    import jax.numpy as jnp
 
-    from gofr_tpu.ops.attention import (decode_attention_cached,
-                                        paged_decode_attention,
+    from gofr_tpu.ops.attention import (paged_decode_attention,
                                         paged_verify_attention,
                                         prefill_attention)
-    from gofr_tpu.ops.pallas import (flash_attention, flash_decode_attention,
+    from gofr_tpu.ops.pallas import (flash_attention,
                                      ragged_paged_decode_attention,
                                      ragged_paged_verify_attention)
 
@@ -272,17 +270,6 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
         compare(f"ragged_verify {tag} g=5",
                 one_plane(ragged_paged_verify_attention),
                 paged_verify_attention, args)
-        # dense flash-decode reads a per-slot cache, not the pool
-        t_max = spec["dense_len"]
-        fills = jnp.asarray(([0, 1, t_max // 2 + 3, t_max]
-                             * slots)[:slots], jnp.int32)
-        compare(f"flash_decode {tag}", flash_decode_attention,
-                decode_attention_cached,
-                (_bf16(rng, slots, 1, q_heads, head_dim),
-                 _bf16(rng, slots, t_max, kv_heads, head_dim),
-                 _bf16(rng, slots, t_max, kv_heads, head_dim),
-                 _bf16(rng, slots, kv_heads, head_dim),
-                 _bf16(rng, slots, kv_heads, head_dim), fills))
     run.emit("kernels", setup_s=time.perf_counter() - started,
              interpret=interpret, kernels=results)
 

@@ -12,7 +12,7 @@ Checks:
 - **jit-per-call** (``hazard=fresh-jit``): ``jax.jit(f)(x)`` inside a
   function body builds a *new* wrapper — and a new compile cache entry —
   on every invocation. Cache the jitted callable (module level, a
-  factory-held dict like ``GenerationEngine._decode_fns``, or a closure
+  factory-held dict like ``GenerationEngine._tick_fns``, or a closure
   built once).
 - **unhashable static** (``hazard=unhashable-static``): a list/dict/set
   literal passed at a ``static_argnums`` position of a known-jitted

@@ -11,7 +11,7 @@ Traced bodies are resolved module-locally: functions decorated with
 ``@jax.jit`` / ``@partial(jax.jit, ...)``, functions wrapped by a
 ``jax.jit(fn)`` call in the same scope, and every ``def`` nested inside
 a traced body (``lax.scan`` step functions — see
-``GenerationEngine._decode_fn``'s ``one``).
+``GenerationEngine._tick_fn``'s ``one``).
 
 Flags inside a traced body:
 

@@ -14,8 +14,8 @@ table plus the intraprocedural value-flow pass (``dataflow.py``):
 
 1. **Find donating callables.** ``jax.jit(fn, donate_argnums=...)``
    results are tracked wherever the repo puts them: a local (``step =
-   jax.jit(...)``), an instance attribute (``self._decode_fn = ...``),
-   a cache table (``self._decode_fns[key] = jax.jit(...)`` — every
+   jax.jit(...)``), an instance attribute (``self._step_fn = ...``),
+   a cache table (``self._tick_fns[key] = jax.jit(...)`` — every
    subscript of that table donates), and factory functions that
    ``return jax.jit(...)`` (or build it into a local and return that),
    resolved cross-module through the project graph. Attribute and

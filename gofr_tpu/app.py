@@ -480,9 +480,10 @@ class App:
 
         # workload capture plane (ISSUE 17): bounded shape-only traffic
         # recorder (TRAFFIC_REC_ENABLED, default on) feeding
-        # /debug/workloadz and the bench.py replay harness. Built before
-        # the batcher so the enqueue hook can ride its constructor; the
-        # engine admission hook attaches via attach_workload.
+        # /debug/workloadz and the replay harness (tpu/workload.py).
+        # Built before the batcher so the enqueue hook can ride its
+        # constructor; the engine admission hook attaches via
+        # attach_workload.
         from gofr_tpu.tpu.workload import new_traffic_recorder
         self.container.workload = new_traffic_recorder(
             self.config, metrics=self.container.metrics)

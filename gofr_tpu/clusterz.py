@@ -20,8 +20,8 @@ replica.
   ``?local=1``) it falls back to this process's own flight records, so
   a replica can always answer for its local half.
 
-Both builders are app-independent — ``bench.py``, the smoke scripts, and
-tests call them without an App; ``enable_clusterz``/``enable_tracez``
+Both builders are app-independent — the smoke scripts and tests call
+them without an App; ``enable_clusterz``/``enable_tracez``
 are the thin HTTP bindings.
 """
 

@@ -16,8 +16,8 @@ bytes_limit`` when the platform reports a limit (TPU/GPU), and falls
 back to KV-pool occupancy (the serving-pressure proxy that also works
 on CPU). ``enable_hbmz`` wires it as ``watchdog.hbm_fn``.
 
-:func:`build_hbmz` is app-independent — ``bench.py`` and tests call it
-with a bare container or engine; ``enable_hbmz`` is the HTTP binding.
+:func:`build_hbmz` is app-independent — tests call it with a bare
+container or engine; ``enable_hbmz`` is the HTTP binding.
 """
 
 from __future__ import annotations

@@ -58,12 +58,6 @@ class LlamaConfig:
     # skipping — required beyond ~8K context on one core; sequence lengths
     # Mosaic cannot tile take the dense einsum (_prefill_attend)
     use_flash: bool = False
-    # pallas decode attention (ops/pallas/decode_attention): numerics
-    # verified, but MEASURED ~5x SLOWER at 7B geometry — a
-    # pallas_call per layer inside the decode scan breaks XLA's weight
-    # prefetch pipeline. Default off; kept as the starting point for a
-    # fused whole-step kernel (see that module's post-mortem).
-    use_flash_decode: bool = False
     # int8 KV cache (ops/quant.quantize_kv): per-(token, head) scales,
     # halving the cache's HBM *footprint* — the capacity lever for longer
     # contexts / more slots per chip. MEASURED (v5e, 7B geometry,
@@ -72,17 +66,9 @@ class LlamaConfig:
     # so the "saved" bytes come back as a materialized converted copy
     # (bf16 full-window 300 tok/s vs int8 265; window-bounded 366 vs 260
     # standalone-tick numbers). Default off: use it when the cache must
-    # fit, not to go faster; a Pallas fused dequant-attention kernel is
-    # the known fix (same conclusion as ops/pallas/decode_attention).
-    # Mutually exclusive with use_flash_decode (the flash kernel reads a
-    # bf16 cache) — enforced in __post_init__.
+    # fit, not to go faster; on the paged path the ragged kernel
+    # (ops/pallas/ragged_paged_attention) dequantizes in-kernel.
     kv_int8: bool = False
-
-    def __post_init__(self):
-        if self.kv_int8 and self.use_flash_decode:
-            raise ValueError(
-                "kv_int8 and use_flash_decode are mutually exclusive: the "
-                "pallas decode kernel reads a bf16 cache")
 
     @property
     def head_dim(self) -> int:
@@ -244,15 +230,6 @@ def _prefill_attend(cfg: LlamaConfig, seq_len: int):
         f"with a {cfg.n_heads * seq_len * seq_len * 4 / 2**30:.2f} GB "
         f"score tensor per sequence", stacklevel=3)
     return prefill_attention
-
-
-def _flash_decode(cfg: LlamaConfig, t_max: int) -> bool:
-    """Does a decode step over a ``t_max``-row cache view run the Pallas
-    flash-decode kernel (``cfg.use_flash_decode`` and a tileable view)?"""
-    if not cfg.use_flash_decode or cfg.kv_int8:
-        return False
-    from gofr_tpu.ops.pallas import decode_shapes_tileable
-    return decode_shapes_tileable(t_max, 128, cfg.head_dim, cfg.n_heads)
 
 
 def _qkv(layer, x, cfg, cos, sin, positions):
@@ -453,16 +430,11 @@ def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
             views = [v[:, :window] for v in views]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
-        if _flash_decode(cfg, views[0].shape[1]):
-            from gofr_tpu.ops.pallas import flash_decode_attention
-            attn = flash_decode_attention(q, views[0], views[1], k[:, 0],
-                                          v[:, 0], cache_len)
-        else:
-            k_scale = views[2] if int8 else None
-            v_scale = views[3] if int8 else None
-            attn = decode_attention_cached(q, views[0], views[1], k[:, 0],
-                                           v[:, 0], cache_len,
-                                           k_scale=k_scale, v_scale=v_scale)
+        k_scale = views[2] if int8 else None
+        v_scale = views[3] if int8 else None
+        attn = decode_attention_cached(q, views[0], views[1], k[:, 0],
+                                       v[:, 0], cache_len,
+                                       k_scale=k_scale, v_scale=v_scale)
         x = x + qmm(attn.reshape(b, 1, -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
         x = x + _ffn(layer, h)
@@ -539,8 +511,7 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     it fuses into the gather, so XLA would copy one layer's plane of
     the entire pool per leaf, per layer, per step (8 ms of a 35 ms step
     on a v5e, PERF.md PR 26). It reads the pool as it was before this
-    layer's append; the new row arrives beside it. Takes priority over
-    ``cfg.use_flash_decode`` and, unlike it, supports int8.
+    layer's append; the new row arrives beside it. Supports int8.
     Token-identical to the gather path, which remains the correctness
     oracle. Whether the geometry suits the kernel is the caller's call
     (ops.pallas.ragged_tileable); nothing in here falls back.
@@ -571,11 +542,6 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
             attn = ragged_paged_decode_attention(
                 q, pools[0], pools[1], page_table, k[:, 0], v[:, 0],
                 cache_len, idx, *pools[2:])
-        elif _flash_decode(cfg, page_table.shape[1] * page):
-            from gofr_tpu.ops.pallas import flash_decode_attention
-            views = _gather_layer_pages(pools, idx, page_table)
-            attn = flash_decode_attention(q, views[0], views[1], k[:, 0],
-                                          v[:, 0], cache_len)
         else:
             views = _gather_layer_pages(pools, idx, page_table)
             k_scale = views[2] if int8 else None

@@ -1,12 +1,10 @@
 """Pallas TPU kernels for the hot ops (see pallas_guide.md)."""
 
-from gofr_tpu.ops.pallas.decode_attention import flash_decode_attention
 from gofr_tpu.ops.pallas.flash_attention import flash_attention
 from gofr_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_decode_attention, ragged_paged_verify_attention)
-from gofr_tpu.ops.pallas.select import (decode_shapes_tileable,
-                                        flash_tileable, ragged_tileable)
+from gofr_tpu.ops.pallas.select import flash_tileable, ragged_tileable
 
-__all__ = ["flash_attention", "flash_decode_attention",
-           "ragged_paged_decode_attention", "ragged_paged_verify_attention",
-           "flash_tileable", "decode_shapes_tileable", "ragged_tileable"]
+__all__ = ["flash_attention", "ragged_paged_decode_attention",
+           "ragged_paged_verify_attention", "flash_tileable",
+           "ragged_tileable"]

@@ -93,13 +93,15 @@ Measured on the chip through the engine: bf16 pools at GQA 32:8, decode
 and MHA 32:32 have been timed alone and compared with the oracle there,
 not served (PERF.md, PR 28).
 
-Post-mortem context (ops/pallas/decode_attention): the dense flash
-prototype lost 5x *inside* the per-layer scan because each pallas_call
-is an opaque boundary to XLA's weight-prefetch pipeline. The economics
-here differ — this kernel *replaces* a per-layer HBM gather
-materialization instead of competing with a fused einsum — but the same
-rule applies: judge it on the full decode tick (bench.py
-``llama_ragged_attn``), never the standalone op. The gather formulation
+Post-mortem context: a dense flash-decode kernel over the per-slot cache
+(deleted in PR 29) lost 5x *inside* the per-layer scan, 640 vs 131 ms a
+tick at 7B geometry (2026-07-30), because each pallas_call is an opaque
+boundary to XLA's weight-prefetch pipeline. The economics here differ —
+this kernel *replaces* a per-layer HBM gather materialization instead of
+competing with a fused einsum — but the same rule applies: judge it on
+the full decode tick (the benchmark's ``mistral7b.batch``:
+``attn_kernel_ms_per_call`` beside ``decode_step_ms``), never the
+standalone op. The gather formulation
 stays the correctness oracle; choosing it over this kernel is the
 caller's decision (ops/pallas/select), never taken in here.
 """

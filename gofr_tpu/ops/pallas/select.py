@@ -24,8 +24,7 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
-__all__ = ["flash_tileable", "decode_shapes_tileable", "ragged_tileable",
-           "lower_for_target"]
+__all__ = ["flash_tileable", "ragged_tileable", "lower_for_target"]
 
 
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
@@ -35,16 +34,6 @@ def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
     block_q, block_k = min(block_q, seq_len), min(block_k, seq_len)
     return (seq_len % block_q == 0 and seq_len % block_k == 0
             and head_dim % 128 == 0 and seq_len >= 128)
-
-
-def decode_shapes_tileable(t_max: int, block_k: int, head_dim: int,
-                           q_heads: int) -> bool:
-    """Dense flash-decode tiling predicate (ops/pallas/decode_attention):
-    the KV window must split into whole lane-aligned blocks and heads
-    must fill a sublane."""
-    block_k = min(block_k, t_max)
-    return (t_max % block_k == 0 and head_dim % 128 == 0
-            and t_max >= 128 and q_heads % 8 == 0)
 
 
 def ragged_tileable(head_dim: int, q_heads: int, kv_heads: int,

@@ -52,6 +52,8 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import deque
+from contextlib import nullcontext
+from types import SimpleNamespace
 from typing import Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
@@ -796,23 +798,18 @@ class GenerationEngine:
         self._compile_events: List[Tuple[float, str, str]] = []
         self._compiles_by_class = {"warmup": 0, "serving": 0}
 
-        self._prefill_fns: Dict[Tuple[int, int], Any] = {}
+        # prefill keyed (nb, bucket, biased); the biased form takes the
+        # grammar's start-state rows as one more operand (ISSUE 11)
+        self._prefill_fns: Dict[Tuple[int, int, bool], Any] = {}
         self._insert_fns: Dict[Tuple[int, int], Any] = {}
-        self._decode_fns: Dict[int, Any] = {}
-        # paged-path executable families: insert keyed (nb, bucket, plen),
-        # decode keyed (k, sampled, page-gather width)
+        # paged-path insert keyed (nb, bucket, plen)
         self._insert_paged_fns: Dict[Tuple[int, int, int], Any] = {}
-        self._decode_paged_fns: Dict[Tuple[int, bool, int], Any] = {}
-        # constrained-decoding executable families (ISSUE 11): separate
-        # dicts so unconstrained serving keeps its warm keys and dispatch
-        # paths byte-identical. The biased variants take the active mask
-        # as int32 (coalescer-eligible: the per-tick bias slab and the
-        # mask ride ONE TransferCoalescer frame) plus an additive float32
-        # logit-bias matrix applied before argmax/sampling.
-        self._prefill_bias_fns: Dict[Tuple[int, int], Any] = {}
-        self._decode_bias_fns: Dict[Tuple[int, bool, Optional[int]],
-                                    Any] = {}
-        self._decode_paged_bias_fns: Dict[Tuple[int, bool, int], Any] = {}
+        # every decode tick, keyed (k, sampled, biased, width): width is
+        # the dense window rung (None: the whole cache) or the paged
+        # page-gather width (_tick_width). Unconstrained serving never
+        # builds a biased key, so its warm keys are its own
+        self._tick_fns: Dict[Tuple[int, bool, bool, Optional[int]],
+                             Any] = {}
         # prefix KV reuse (ISSUE 4): page-granular prefix store + the
         # suffix-only prefill/insert executable families keyed
         # (nb, prefix_pages, suffix_bucket). The prefix-pages ladder
@@ -821,10 +818,9 @@ class GenerationEngine:
         self._suffix_prefill_fns: Dict[Tuple[int, int, int], Any] = {}
         self._suffix_insert_fns: Dict[Tuple[int, int, int], Any] = {}
         # speculative-decode families: one fused draft-propose/target-verify
-        # executable per (γ rung, window) — the "(nb, γ) verify rung" of
+        # executable per (γ rung, width) — the "(nb, γ) verify rung" of
         # ISSUE 7 — plus KV-only draft prefill/insert per (nb, bucket)
         self._spec_fns: Dict[Tuple[int, Optional[int]], Any] = {}
-        self._spec_paged_fns: Dict[Tuple[int, int], Any] = {}
         self._draft_prefill_fns: Dict[Tuple[int, int], Any] = {}
         self._draft_insert_fns: Dict[Tuple[int, int], Any] = {}
         # disaggregated serving (ISSUE 8): page-adoption scatter keyed by
@@ -879,7 +875,22 @@ class GenerationEngine:
                     max(self.prompt_buckets))
 
     # -- compiled steps -----------------------------------------------------
-    def _prefill_fn(self, nb: int, lb: int):
+    @property
+    def _kv(self):
+        """The cache leaves the donating executables consume and return:
+        the pool's on the paged path, the dense cache otherwise. The one
+        name both kinds (and ``prewarm_operating_point``'s dummy state)
+        are read and written back by."""
+        return self._pool.leaves if self.paged else self.cache
+
+    @_kv.setter
+    def _kv(self, leaves) -> None:
+        if self.paged:
+            self._pool.leaves = leaves
+        else:
+            self.cache = leaves
+
+    def _prefill_fn(self, nb: int, lb: int, biased: bool = False):
         """Pure-compute prompt forward for ``nb`` prompts of bucket ``lb``:
         (params, tokens (nb,lb), lengths (nb,), temps, top_ks, top_ps,
         seeds) → (first_tokens (nb,), small cache dict (leaves
@@ -888,27 +899,83 @@ class GenerationEngine:
         resolve to argmax in-program, ops/sampling); ``keys`` are the
         advanced per-row PRNG keys decode continues from. No cache
         involvement, so it can be dispatched while decode ticks are in
-        flight."""
-        fn = self._prefill_fns.get((nb, lb))
+        flight.
+
+        ``biased`` (constrained requests, ISSUE 11) takes one more
+        operand, a per-row additive logit-bias matrix (nb, vocab) applied
+        before the first token is sampled — the grammar's start-state
+        mask steers the first token exactly like every decode step
+        after it."""
+        fn = self._prefill_fns.get((nb, lb, biased))
         if fn is None:
             jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
                                     self.cfg)
             from gofr_tpu.ops.sampling import sample_batch
 
             def prefill_batch(params, tokens, lengths, temps, top_ks,
-                              top_ps, seeds):
+                              top_ps, seeds, bias=None):
                 small = llama.init_cache(cfg, nb, lb)
                 logits, small, _ = llama.prefill(params, cfg, tokens, small,
                                                  lengths=lengths)
                 keys = jax.vmap(jax.random.PRNGKey)(seeds)
+                if biased:
+                    logits = logits + bias
                 first, keys = sample_batch(logits, temps, top_ks, top_ps,
                                            keys)
                 return first, small, keys
 
             fn = jax.jit(prefill_batch)
-            self._prefill_fns[(nb, lb)] = fn
-            self._note_compile("prefill", (nb, lb))
+            self._prefill_fns[(nb, lb, biased)] = fn
+            self._note_compile("prefill_bias" if biased else "prefill",
+                               (nb, lb))
         return fn
+
+    def _padding_group(self, nb: int, lb: int) -> Dict[str, Any]:
+        """An admission group of ``nb`` x ``lb`` that is all padding,
+        under the names ``_admit_pending`` uploads a real one by: what
+        the warm-ups compile prefill and insert with. Every row's slot
+        index is ``max_slots`` and every page id the sentinel, so the
+        insert drops all of it."""
+        jnp = self._jnp
+        group = dict(padded=jnp.zeros((nb, lb), jnp.int32),
+                     lengths=jnp.ones((nb,), jnp.int32),
+                     slots=jnp.full((nb,), self.max_slots, jnp.int32),
+                     temps=jnp.zeros((nb,), jnp.float32),
+                     top_ks=jnp.zeros((nb,), jnp.int32),
+                     top_ps=jnp.ones((nb,), jnp.float32),
+                     seeds=jnp.zeros((nb,), jnp.uint32))
+        if self.paged:
+            group["flat_ids"] = jnp.full((nb * (lb // self.kv_page),),
+                                         self._pool.sentinel, jnp.int32)
+        return group
+
+    def _run_prefill(self, nb: int, lb: int, dev: Dict[str, Any]):
+        """Call the prefill of an ``nb`` x ``lb`` group on its device
+        arrays ``dev``: the biased one where the group carries grammar
+        rows (``dev["bias"]``). Returns (first, small, keys)."""
+        bias = dev.get("bias")
+        return self._prefill_fn(nb, lb, bias is not None)(
+            self.params, dev["padded"], dev["lengths"], dev["temps"],
+            dev["top_ks"], dev["top_ps"], dev["seeds"],
+            *([] if bias is None else [bias]))
+
+    def _run_insert(self, nb: int, lb: int, plen: int, dev: Dict[str, Any],
+                    first, small, keys, state=None) -> None:
+        """Call the insert that publishes a prefill (``first``, ``small``,
+        ``keys``) of the group ``dev`` into the cache and the claimed
+        rows' slot state, and write its outputs back: into the engine's
+        own state, or into a dummy ``state`` (``_run_tick``). On the paged
+        path the caller holds the pool lock across the prefill and this
+        (a suffix prefill reads the leaves this donates)."""
+        s = self if state is None else state
+        fn = (self._insert_paged_fn(nb, lb, plen) if self.paged
+              else self._insert_fn(nb, lb))
+        page_ids = [dev["flat_ids"]] if self.paged else []
+        (s._kv, s.cache_len, s.last_token, s.temps, s.top_ks, s.top_ps,
+         s.sample_keys) = fn(
+            s._kv, small, *page_ids, dev["slots"], dev["lengths"], first,
+            s.cache_len, s.last_token, s.temps, s.top_ks, s.top_ps,
+            s.sample_keys, dev["temps"], dev["top_ks"], dev["top_ps"], keys)
 
     def _insert_fn(self, nb: int, lb: int):
         """Cheap scatter publishing a prefill into the big cache, including
@@ -1016,72 +1083,181 @@ class GenerationEngine:
             self._note_compile("suffix_insert", (nb, p, lb))
         return fn
 
-    def _decode_fn(self, k_steps: int, sampled: bool = False,
-                   window: Optional[int] = None):
-        """Decode-tick executable. The greedy variant is the serving hot
-        path and is byte-identical to the pre-sampling design; the sampled
-        variant additionally carries per-slot (temps, top_ks, top_ps, keys)
-        and advances keys only for rows active in the tick, so a slot's
-        token stream is a pure function of its seed (ops/sampling).
-        ``window`` (a rung of the attention-window ladder, None = full)
-        statically bounds the cache positions attention streams."""
-        fn = self._decode_fns.get((k_steps, sampled, window))
-        if fn is None:
+    def _tick_width(self, window: Optional[int]) -> Optional[int]:
+        """The width a tick's executable is keyed by, from a window rung:
+        the rung itself on the dense cache, the page-gather width on the
+        pool."""
+        return self._pick_page_width(window) if self.paged else window
+
+    def _tick_operands(self, sampled: bool, biased: bool) -> Tuple[str, ...]:
+        """A decode tick's operands in call order. The order is written
+        here and nowhere else: ``_tick_fn`` unpacks and donates by it,
+        ``_run_tick`` packs by it."""
+        return (("params", "token", "cache")
+                + (("table",) if self.paged else ())
+                + ("cache_len", "active")
+                + (("bias",) if biased else ())
+                + (("temps", "top_ks", "top_ps", "keys") if sampled else ()))
+
+    def _tick_names(self, k: int, biased: bool,
+                    width: Optional[int]) -> Tuple[str, str]:
+        """(compile-ledger kind, roofline-ledger family) of a tick:
+        ``decode_paged_bias``, ``decode_paged_bias[k=1,pw=8]``. Device
+        time lands on the granularity the jit cache is keyed by."""
+        kind = ("decode" + ("_paged" if self.paged else "")
+                + ("_bias" if biased else ""))
+        at = (f"pw={width}" if self.paged
+              else f"w={width or self.max_len}")
+        return kind, f"{kind}[k={k},{at}]"
+
+    def _tick_fn(self, k_steps: int, sampled: bool, biased: bool,
+                 width: Optional[int]):
+        """Decode-tick executable: ``k_steps`` fused steps in one
+        ``lax.scan``, built from three parts chosen here.
+
+        The step: on the dense cache ``decode_step`` with ``width`` (a
+        rung of the attention-window ladder, None = full) statically
+        bounding the cache positions attention streams; on the pool
+        (ISSUE 6) ``decode_step_paged`` through a ``(max_slots, width)``
+        page-table slice, ``width`` being the page-gather width — the
+        window rung demoted to ``ceil(rung / kv_page)`` table columns, a
+        static ladder value. With the ragged kernel active ``width`` is
+        always ``pages_per_slot`` (the ladder is retired) and the step
+        attends pool pages in place. Inactive rows scatter to the
+        sentinel page id and drop. A module that declares
+        ``STEP_COUNTERS`` returns their sums over the K steps as one
+        more output; the others' ticks are the program they always were.
+
+        The choice: argmax (the greedy serving hot path), or
+        ``sample_batch``, which additionally carries per-slot (temps,
+        top_ks, top_ps, keys) and advances keys only for rows active in
+        the tick, so a slot's token stream is a pure function of its seed
+        (ops/sampling).
+
+        The bias (constrained decoding, ISSUE 11): an additive
+        (max_slots, vocab) float32 matrix — the grammar masks, 0 for
+        allowed tokens and NEG_BIAS for the rest — applied before the
+        choice. The active mask then arrives as int32 so mask + bias
+        share one coalesced H2D frame; the executable converts to bool
+        in-program (bit-exact). Constrained slots only ride k=1 ticks
+        (their mask is valid for exactly the next position), so
+        ``k_steps`` is 1 on the serving path.
+
+        Operands: ``_tick_operands``. Outputs: (tokens (K, B), cache,
+        cache_len[, keys][, counters])."""
+        key = (k_steps, sampled, biased, width)
+        if key not in self._tick_fns:
             jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
                                     self.cfg)
             from jax import lax
 
-            if not sampled:
-                def decode_k(params, token, cache, cache_len, active):
-                    def one(carry, _):
-                        token, cache, cache_len = carry
-                        logits, cache, new_len = llama.decode_step(
-                            params, cfg, token, cache, cache_len,
-                            window=window)
-                        next_token = logits.argmax(axis=-1).astype(
-                            token.dtype)
-                        # freeze inactive slots: cache_len stays put and the
-                        # carried token is unchanged (ADVICE r1: no unbounded
-                        # cache_len growth on idle slots)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        return (next_token, cache, new_len), next_token
+            from gofr_tpu.ops.sampling import sample_batch
+            names = self._tick_operands(sampled, biased)
+            counted = self.paged and bool(self._step_counters)
+            if self.paged:
+                step_kw = {"ragged": True} if self._ragged else {}
+                if counted:
+                    step_kw["counters"] = True
 
-                    (token, cache, cache_len), tokens = lax.scan(
-                        one, (token, cache, cache_len), None, length=k_steps)
-                    return tokens, cache, cache_len   # tokens: (K, B)
-
-                fn = jax.jit(decode_k, donate_argnums=(2, 3))
+                def step(params, token, pool, table, cache_len, active):
+                    """(logits, pool, new_len, what the step counted: the
+                    per-step sums the scan stacks, () for a module that
+                    declares no STEP_COUNTERS)."""
+                    out = llama.decode_step_paged(
+                        params, cfg, token, pool, table, cache_len, active,
+                        **step_kw)
+                    return out if counted else out + ((),)
             else:
-                from gofr_tpu.ops.sampling import sample_batch
+                def step(params, token, cache, table, cache_len, active):
+                    return llama.decode_step(
+                        params, cfg, token, cache, cache_len,
+                        window=width) + ((),)
 
-                def decode_k_sampled(params, token, cache, cache_len,
-                                     active, temps, top_ks, top_ps, keys):
-                    def one(carry, _):
-                        token, cache, cache_len, keys = carry
-                        logits, cache, new_len = llama.decode_step(
-                            params, cfg, token, cache, cache_len,
-                            window=window)
+            def tick(*operands):
+                o = dict(zip(names, operands))
+                params, table = o["params"], o.get("table")
+                active = o["active"].astype(bool) if biased else o["active"]
+
+                def one(carry, _):
+                    token, cache, cache_len, *keys = carry
+                    logits, cache, new_len, counts = step(
+                        params, token, cache, table, cache_len, active)
+                    if biased:
+                        logits = logits + o["bias"]
+                    if sampled:
                         next_token, new_keys = sample_batch(
-                            logits, temps, top_ks, top_ps, keys)
-                        next_token = next_token.astype(token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
+                            logits, o["temps"], o["top_ks"], o["top_ps"],
+                            keys[0])
+                    else:
+                        next_token = logits.argmax(axis=-1)
+                    next_token = next_token.astype(token.dtype)
+                    # freeze inactive slots: cache_len stays put and the
+                    # carried token is unchanged (ADVICE r1: no unbounded
+                    # cache_len growth on idle slots)
+                    new_len = jnp.where(active, new_len, cache_len)
+                    next_token = jnp.where(active, next_token, token)
+                    if sampled:
                         # inactive rows keep their key: emitted-token index
                         # == number of participating steps, so sequences
                         # are seed-deterministic under any tick batching
-                        keys = jnp.where(active[:, None], new_keys, keys)
-                        return (next_token, cache, new_len, keys), next_token
+                        keys = [jnp.where(active[:, None], new_keys,
+                                          keys[0])]
+                    return (next_token, cache, new_len, *keys), (next_token,
+                                                                 counts)
 
-                    (token, cache, cache_len, keys), tokens = lax.scan(
-                        one, (token, cache, cache_len, keys), None,
-                        length=k_steps)
-                    return tokens, cache, cache_len, keys
+                carry = (o["token"], o["cache"], o["cache_len"]) + (
+                    (o["keys"],) if sampled else ())
+                (_, *state), (tokens, counts) = lax.scan(
+                    one, carry, None, length=k_steps)
+                outs = (tokens, *state)
+                return outs + (counts.sum(axis=0),) if counted else outs
 
-                fn = jax.jit(decode_k_sampled, donate_argnums=(2, 3, 8))
-            self._decode_fns[(k_steps, sampled, window)] = fn
-            self._note_compile("decode", (k_steps, sampled, window))
-        return fn
+            # the benchmark's trace readers find the decode executables
+            # by this name (jit_decode_k...)
+            tick.__name__ = "decode_k_sampled" if sampled else "decode_k"
+            donated = ("cache", "cache_len") + (("keys",) if sampled else ())
+            self._tick_fns[key] = jax.jit(
+                tick, donate_argnums=tuple(names.index(n) for n in donated))
+            self._note_compile(self._tick_names(k_steps, biased, width)[0],
+                               (k_steps, sampled, width))
+        return self._tick_fns[key]
+
+    def _run_tick(self, k: int, sampled: bool, width: Optional[int], active,
+                  bias=None, state=None):
+        """Call the tick ``(k, sampled, bias given, width)`` and write its
+        outputs back: the one place a decode executable is called.
+        ``active`` is the device mask (int32 beside a ``bias``, see
+        ``_tick_fn``). Without ``state`` the tick runs on the engine's own
+        slot state — on the pool under its lock (co-resident engines'
+        donations must not interleave with ours) and through the slots'
+        page table; ``prewarm_operating_point`` passes a dummy ``state``
+        (_kv, cache_len, last_token, temps, top_ks, top_ps,
+        sample_keys), which compiles the same executable and touches
+        nothing of the engine's. Returns (tokens (K, B) on the device,
+        the step counters' sums as a 1-tuple, or ())."""
+        biased = bias is not None
+        fn = self._tick_fn(k, sampled, biased, width)
+        own = state is None
+        s = self if own else state
+        with self._pool.lock if own and self.paged else nullcontext():
+            table = None
+            if self.paged:
+                table = (self._table_dev(width) if own else self._jnp.full(
+                    (self.max_slots, width), self._pool.sentinel,
+                    self._jnp.int32))
+            operands = dict(
+                params=self.params, token=s.last_token, cache=s._kv,
+                table=table, cache_len=s.cache_len, active=active, bias=bias,
+                temps=s.temps, top_ks=s.top_ks, top_ps=s.top_ps,
+                keys=s.sample_keys)
+            out = fn(*(operands[name]
+                       for name in self._tick_operands(sampled, biased)))
+            tokens_dev, s._kv, s.cache_len = out[:3]
+        rest = out[3:]
+        if sampled:
+            s.sample_keys, rest = rest[0], rest[1:]
+        s.last_token = tokens_dev[-1]
+        return tokens_dev, rest
 
     def _insert_paged_fn(self, nb: int, lb: int, plen: int):
         """Paged-path insert: scatters a prefill's small cache directly
@@ -1159,251 +1335,6 @@ class GenerationEngine:
             self._note_compile("adopt", n_pages)
         return fn
 
-    def _decode_paged_fn(self, k_steps: int, sampled: bool = False,
-                         pw: int = 1):
-        """Paged decode-tick executable (ISSUE 6): same contract as
-        ``_decode_fn`` but attention gathers each slot's KV out of the
-        shared page pool through a ``(max_slots, pw)`` page-table slice
-        instead of indexing a dense cache row. ``pw`` is the page-gather
-        width — the window rung demoted to ``ceil(rung / kv_page)`` table
-        columns, a static ladder value. With the ragged kernel active,
-        ``pw`` is always ``pages_per_slot`` (the ladder is retired) and
-        the step attends pool pages in place — one executable per
-        (k, sampled) family. Inactive rows scatter to the sentinel page
-        id and drop."""
-        fn = self._decode_paged_fns.get((k_steps, sampled, pw))
-        if fn is None:
-            jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
-                                    self.cfg)
-            step_kw = {"ragged": True} if self._ragged else {}
-            counted = bool(self._step_counters)
-            if counted:
-                step_kw["counters"] = True
-            from jax import lax
-
-            def step(params, token, pool, table, cache_len, active):
-                """(logits, pool, new_len, what the step counted: the
-                per-step sums the scan stacks, () for a module that
-                declares no STEP_COUNTERS)."""
-                out = llama.decode_step_paged(
-                    params, cfg, token, pool, table, cache_len, active,
-                    **step_kw)
-                return out if counted else out + ((),)
-
-            def with_counts(outs, counts):
-                # a module with counters returns their sums over the K
-                # steps as one more output; the others' ticks are the
-                # program they always were
-                return outs + (counts.sum(axis=0),) if counted else outs
-
-            if not sampled:
-                def decode_k(params, token, pool, table, cache_len, active):
-                    def one(carry, _):
-                        token, pool, cache_len = carry
-                        logits, pool2, new_len, counts = step(
-                            params, token, pool, table, cache_len, active)
-                        next_token = logits.argmax(axis=-1).astype(
-                            token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        return (next_token, pool2, new_len), (next_token,
-                                                              counts)
-
-                    (token, pool, cache_len), (tokens, counts) = lax.scan(
-                        one, (token, pool, cache_len), None, length=k_steps)
-                    # tokens: (K, B)
-                    return with_counts((tokens, pool, cache_len), counts)
-
-                fn = jax.jit(decode_k, donate_argnums=(2, 4))
-            else:
-                from gofr_tpu.ops.sampling import sample_batch
-
-                def decode_k_sampled(params, token, pool, table, cache_len,
-                                     active, temps, top_ks, top_ps, keys):
-                    def one(carry, _):
-                        token, pool, cache_len, keys = carry
-                        logits, pool2, new_len, counts = step(
-                            params, token, pool, table, cache_len, active)
-                        next_token, new_keys = sample_batch(
-                            logits, temps, top_ks, top_ps, keys)
-                        next_token = next_token.astype(token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        keys = jnp.where(active[:, None], new_keys, keys)
-                        return (next_token, pool2, new_len,
-                                keys), (next_token, counts)
-
-                    (token, pool, cache_len, keys), (tokens, counts) = \
-                        lax.scan(one, (token, pool, cache_len, keys), None,
-                                 length=k_steps)
-                    return with_counts((tokens, pool, cache_len, keys),
-                                       counts)
-
-                fn = jax.jit(decode_k_sampled, donate_argnums=(2, 4, 9))
-            self._decode_paged_fns[(k_steps, sampled, pw)] = fn
-            self._note_compile("decode_paged", (k_steps, sampled, pw))
-        return fn
-
-    def _prefill_bias_fn(self, nb: int, lb: int):
-        """Constrained prefill (ISSUE 11): identical to ``_prefill_fn``
-        plus a per-row additive logit-bias matrix (nb, vocab) applied
-        before the first token is sampled — the grammar's start-state
-        mask steers the first token exactly like every decode step
-        after it."""
-        fn = self._prefill_bias_fns.get((nb, lb))
-        if fn is None:
-            jax, llama, cfg = self._jax, self._llama, self.cfg
-            from gofr_tpu.ops.sampling import sample_batch
-
-            def prefill_batch(params, tokens, lengths, temps, top_ks,
-                              top_ps, seeds, bias):
-                small = llama.init_cache(cfg, nb, lb)
-                logits, small, _ = llama.prefill(params, cfg, tokens, small,
-                                                 lengths=lengths)
-                keys = jax.vmap(jax.random.PRNGKey)(seeds)
-                first, keys = sample_batch(logits + bias, temps, top_ks,
-                                           top_ps, keys)
-                return first, small, keys
-
-            fn = jax.jit(prefill_batch)
-            self._prefill_bias_fns[(nb, lb)] = fn
-            self._note_compile("prefill_bias", (nb, lb))
-        return fn
-
-    def _decode_bias_fn(self, k_steps: int, sampled: bool = False,
-                        window: Optional[int] = None):
-        """Constrained decode tick: ``_decode_fn`` plus an additive
-        (max_slots, vocab) logit bias — the grammar masks, 0 for allowed
-        tokens and NEG_BIAS for the rest — applied before
-        argmax/sampling. The active mask arrives as int32 so mask + bias
-        share one coalesced H2D frame; the executable converts to bool
-        in-program (bit-exact). Constrained slots only ride k=1 ticks
-        (their mask is valid for exactly the next position), so
-        ``k_steps`` is 1 on the serving path."""
-        fn = self._decode_bias_fns.get((k_steps, sampled, window))
-        if fn is None:
-            jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
-                                    self.cfg)
-            from jax import lax
-
-            if not sampled:
-                def decode_k(params, token, cache, cache_len, active_i32,
-                             bias):
-                    active = active_i32.astype(bool)
-
-                    def one(carry, _):
-                        token, cache, cache_len = carry
-                        logits, cache, new_len = llama.decode_step(
-                            params, cfg, token, cache, cache_len,
-                            window=window)
-                        next_token = (logits + bias).argmax(axis=-1).astype(
-                            token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        return (next_token, cache, new_len), next_token
-
-                    (token, cache, cache_len), tokens = lax.scan(
-                        one, (token, cache, cache_len), None, length=k_steps)
-                    return tokens, cache, cache_len
-
-                fn = jax.jit(decode_k, donate_argnums=(2, 3))
-            else:
-                from gofr_tpu.ops.sampling import sample_batch
-
-                def decode_k_sampled(params, token, cache, cache_len,
-                                     active_i32, bias, temps, top_ks,
-                                     top_ps, keys):
-                    active = active_i32.astype(bool)
-
-                    def one(carry, _):
-                        token, cache, cache_len, keys = carry
-                        logits, cache, new_len = llama.decode_step(
-                            params, cfg, token, cache, cache_len,
-                            window=window)
-                        next_token, new_keys = sample_batch(
-                            logits + bias, temps, top_ks, top_ps, keys)
-                        next_token = next_token.astype(token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        keys = jnp.where(active[:, None], new_keys, keys)
-                        return (next_token, cache, new_len, keys), next_token
-
-                    (token, cache, cache_len, keys), tokens = lax.scan(
-                        one, (token, cache, cache_len, keys), None,
-                        length=k_steps)
-                    return tokens, cache, cache_len, keys
-
-                fn = jax.jit(decode_k_sampled, donate_argnums=(2, 3, 9))
-            self._decode_bias_fns[(k_steps, sampled, window)] = fn
-            self._note_compile("decode_bias", (k_steps, sampled, window))
-        return fn
-
-    def _decode_paged_bias_fn(self, k_steps: int, sampled: bool = False,
-                              pw: int = 1):
-        """Paged twin of ``_decode_bias_fn`` — same contract as
-        ``_decode_paged_fn`` plus the int32 active mask + additive bias
-        pair. Token-identity with the dense variant under a fixed
-        grammar is asserted by the constrained-decoding tests."""
-        fn = self._decode_paged_bias_fns.get((k_steps, sampled, pw))
-        if fn is None:
-            jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
-                                    self.cfg)
-            step_kw = {"ragged": True} if self._ragged else {}
-            from jax import lax
-
-            if not sampled:
-                def decode_k(params, token, pool, table, cache_len,
-                             active_i32, bias):
-                    active = active_i32.astype(bool)
-
-                    def one(carry, _):
-                        token, pool, cache_len = carry
-                        logits, pool2, new_len = llama.decode_step_paged(
-                            params, cfg, token, pool, table, cache_len,
-                            active, **step_kw)
-                        next_token = (logits + bias).argmax(axis=-1).astype(
-                            token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        return (next_token, pool2, new_len), next_token
-
-                    (token, pool, cache_len), tokens = lax.scan(
-                        one, (token, pool, cache_len), None, length=k_steps)
-                    return tokens, pool, cache_len
-
-                fn = jax.jit(decode_k, donate_argnums=(2, 4))
-            else:
-                from gofr_tpu.ops.sampling import sample_batch
-
-                def decode_k_sampled(params, token, pool, table, cache_len,
-                                     active_i32, bias, temps, top_ks,
-                                     top_ps, keys):
-                    active = active_i32.astype(bool)
-
-                    def one(carry, _):
-                        token, pool, cache_len, keys = carry
-                        logits, pool2, new_len = llama.decode_step_paged(
-                            params, cfg, token, pool, table, cache_len,
-                            active, **step_kw)
-                        next_token, new_keys = sample_batch(
-                            logits + bias, temps, top_ks, top_ps, keys)
-                        next_token = next_token.astype(token.dtype)
-                        new_len = jnp.where(active, new_len, cache_len)
-                        next_token = jnp.where(active, next_token, token)
-                        keys = jnp.where(active[:, None], new_keys, keys)
-                        return (next_token, pool2, new_len,
-                                keys), next_token
-
-                    (token, pool, cache_len, keys), tokens = lax.scan(
-                        one, (token, pool, cache_len, keys), None,
-                        length=k_steps)
-                    return tokens, pool, cache_len, keys
-
-                fn = jax.jit(decode_k_sampled, donate_argnums=(2, 4, 10))
-            self._decode_paged_bias_fns[(k_steps, sampled, pw)] = fn
-            self._note_compile("decode_paged_bias", (k_steps, sampled, pw))
-        return fn
-
     def _draft_prefill_fn(self, nb: int, lb: int):
         """KV-only draft prefill: runs the draft model over the FULL
         prompt bucket and returns its small cache — no sampling, no first
@@ -1443,7 +1374,7 @@ class GenerationEngine:
             self._note_compile("draft_insert", (nb, lb))
         return fn
 
-    def _spec_fn(self, g: int, window: Optional[int] = None):
+    def _spec_fn(self, g: int, width: Optional[int]):
         """Fused draft-propose/target-verify tick (ISSUE 7): the draft
         scans ``g + 1`` decode steps proposing ``g`` tokens (the extra
         step writes the last proposal's KV so a full acceptance leaves the
@@ -1456,25 +1387,53 @@ class GenerationEngine:
         matching and is token-identical to plain decode; sampled rows
         preserve the target DISTRIBUTION (not the plain-tick sample path —
         key consumption differs). Inactive rows freeze exactly like
-        ``_decode_fn``: their garbage KV writes land at frozen positions
+        ``_tick_fn``: their garbage KV writes land at frozen positions
         that are always overwritten before they can be attended.
 
-        Contract: (params, dparams, last_token, cache, dcache, cache_len,
-        active, temps, top_ks, top_ps, keys) → (tokens (g+1, B), accepts
-        (B,), cache, dcache, new_len, new_last, new_keys); row b commits
-        ``accepts[b] + 1`` tokens and cache_len advances by the same."""
-        fn = self._spec_fns.get((g, window))
-        if fn is None:
+        The verify step is the part that differs by cache kind (``width``
+        as in ``_tick_fn``). Dense: ``verify_step`` over the window rung,
+        which bounds the draft's steps too. Paged: the draft stays dense
+        (the draft model is small, a dense row per slot keeps it
+        independent of the target's paging) and the target verifies
+        through the page table via ``verify_step_paged`` — inactive rows
+        scatter to the sentinel page and drop; the table's ``width`` must
+        cover fill + g + 1 (``_pick_window`` → ``_pick_page_width``
+        guarantees it; a too-narrow table would silently clamp the
+        per-position gather).
+
+        Contract: (params, dparams, last_token, cache, dcache, [table,]
+        cache_len, active, temps, top_ks, top_ps, keys) → (tokens
+        (g+1, B), accepts (B,), cache, dcache, new_len, new_last,
+        new_keys); row b commits ``accepts[b] + 1`` tokens and cache_len
+        advances by the same."""
+        if (g, width) not in self._spec_fns:
             jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
                                     self.cfg)
             dcfg = self.draft_cfg
+            paged = self.paged
             from jax import lax
 
             from gofr_tpu.ops.sampling import (filtered_log_probs_batch,
                                                speculative_accept)
+            if paged:
+                step_kw = {"ragged": True} if self._ragged else {}
+                draft_kw = {}
+
+                def verify(params, tokens, pool, cache_len, active, table):
+                    return llama.verify_step_paged(
+                        params, cfg, tokens, pool, table, cache_len, active,
+                        **step_kw)
+            else:
+                draft_kw = {"window": width}
+
+                def verify(params, tokens, cache, cache_len, active):
+                    return llama.verify_step(
+                        params, cfg, tokens, cache, cache_len, window=width)
 
             def spec_tick(params, dparams, last_token, cache, dcache,
-                          cache_len, active, temps, top_ks, top_ps, keys):
+                          *operands):
+                *table, cache_len, active, temps, top_ks, top_ps, keys = \
+                    operands
                 split = jax.vmap(
                     lambda key: jax.random.split(key, g + 2))(keys)
                 draft_keys = jnp.moveaxis(split[:, :g + 1], 0, 1)
@@ -1483,7 +1442,7 @@ class GenerationEngine:
                 def draft_step(carry, step_keys):
                     token, dcache, dlen = carry
                     logits, dcache, new_len = llama.decode_step(
-                        dparams, dcfg, token, dcache, dlen, window=window)
+                        dparams, dcfg, token, dcache, dlen, **draft_kw)
                     q_logp = filtered_log_probs_batch(logits, temps,
                                                       top_ks, top_ps)
                     choice = jax.vmap(jax.random.categorical)(
@@ -1500,9 +1459,8 @@ class GenerationEngine:
                 q_logp = jnp.moveaxis(q_logps[:g], 0, 1)  # (B, g, V)
                 verify_tokens = jnp.concatenate(
                     [last_token[:, None], draft_tokens], axis=1)
-                t_logits, cache = llama.verify_step(
-                    params, cfg, verify_tokens, cache, cache_len,
-                    window=window)
+                t_logits, cache = verify(params, verify_tokens, cache,
+                                         cache_len, active, *table)
                 out, accepts, carry = speculative_accept(
                     t_logits, q_logp, draft_tokens, temps, top_ks, top_ps,
                     accept_keys)
@@ -1516,77 +1474,27 @@ class GenerationEngine:
                 return (out.T, accepts, cache, dcache, new_len, new_last,
                         new_keys)
 
-            fn = jax.jit(spec_tick, donate_argnums=(3, 4, 5, 10))
-            self._spec_fns[(g, window)] = fn
-            self._note_compile("spec", (g, window))
-        return fn
+            # cache, dcache, cache_len, keys: the table shifts the last two
+            self._spec_fns[(g, width)] = jax.jit(
+                spec_tick, donate_argnums=(3, 4, 5 + paged, 10 + paged))
+            self._note_compile("spec_paged" if paged else "spec",
+                               (g, width))
+        return self._spec_fns[(g, width)]
 
-    def _spec_paged_fn(self, g: int, pw: int):
-        """Paged-target variant of :meth:`_spec_fn`: the draft stays dense
-        (the draft model is small, a dense row per slot keeps it
-        independent of the target's paging), the target verifies through
-        the page table via ``verify_step_paged`` — inactive rows scatter
-        to the sentinel page and drop. ``pw`` must cover fill + g + 1
-        (``_pick_window`` → ``_pick_page_width`` guarantees it; a
-        too-narrow table would silently clamp the per-position gather)."""
-        fn = self._spec_paged_fns.get((g, pw))
-        if fn is None:
-            jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
-                                    self.cfg)
-            step_kw = {"ragged": True} if self._ragged else {}
-            dcfg = self.draft_cfg
-            from jax import lax
-
-            from gofr_tpu.ops.sampling import (filtered_log_probs_batch,
-                                               speculative_accept)
-
-            def spec_tick(params, dparams, last_token, pool, dcache, table,
-                          cache_len, active, temps, top_ks, top_ps, keys):
-                split = jax.vmap(
-                    lambda key: jax.random.split(key, g + 2))(keys)
-                draft_keys = jnp.moveaxis(split[:, :g + 1], 0, 1)
-                accept_keys = split[:, g + 1]
-
-                def draft_step(carry, step_keys):
-                    token, dcache, dlen = carry
-                    logits, dcache, new_len = llama.decode_step(
-                        dparams, dcfg, token, dcache, dlen)
-                    q_logp = filtered_log_probs_batch(logits, temps,
-                                                      top_ks, top_ps)
-                    choice = jax.vmap(jax.random.categorical)(
-                        step_keys, q_logp).astype(jnp.int32)
-                    proposal = jnp.where(temps > 0.0, choice,
-                                         logits.argmax(-1).astype(jnp.int32))
-                    new_len = jnp.where(active, new_len, dlen)
-                    proposal = jnp.where(active, proposal, token)
-                    return (proposal, dcache, new_len), (proposal, q_logp)
-
-                (_, dcache, _), (proposals, q_logps) = lax.scan(
-                    draft_step, (last_token, dcache, cache_len), draft_keys)
-                draft_tokens = proposals[:g].T
-                q_logp = jnp.moveaxis(q_logps[:g], 0, 1)
-                verify_tokens = jnp.concatenate(
-                    [last_token[:, None], draft_tokens], axis=1)
-                t_logits, pool = llama.verify_step_paged(
-                    params, cfg, verify_tokens, pool, table, cache_len,
-                    active, **step_kw)
-                out, accepts, carry = speculative_accept(
-                    t_logits, q_logp, draft_tokens, temps, top_ks, top_ps,
-                    accept_keys)
-                accepts = jnp.where(active, accepts, 0)
-                chosen = jnp.take_along_axis(
-                    out, accepts[:, None], axis=1)[:, 0].astype(jnp.int32)
-                new_last = jnp.where(active, chosen, last_token)
-                new_len = jnp.where(active, cache_len + accepts + 1,
-                                    cache_len)
-                new_keys = jnp.where(active[:, None], carry, keys)
-                return (out.T, accepts, pool, dcache, new_len, new_last,
-                        new_keys)
-
-            fn = jax.jit(spec_tick, donate_argnums=(3, 4, 6, 11))
-            self._spec_paged_fns[(g, pw)] = fn
-            self._note_compile("spec_paged", (g, pw))
-        return fn
+    def _run_spec(self, g: int, width: Optional[int], active):
+        """Call the speculative tick ``(g, width)`` on the engine's slot
+        state and write its outputs back (``_run_tick``'s twin: the pool
+        lock and the slots' table on the paged path). Returns (tokens
+        (g+1, B), accepts (B,)) on the device."""
+        fn = self._spec_fn(g, width)
+        with self._pool.lock if self.paged else nullcontext():
+            table = [self._table_dev(width)] if self.paged else []
+            (toks_dev, accepts_dev, self._kv, self._draft_cache,
+             self.cache_len, self.last_token, self.sample_keys) = fn(
+                self.params, self.draft_params, self.last_token, self._kv,
+                self._draft_cache, *table, self.cache_len, active,
+                self.temps, self.top_ks, self.top_ps, self.sample_keys)
+        return toks_dev, accepts_dev
 
     def _table_dev(self, pw: int):
         """Device copy of the first ``pw`` page-table columns, cached per
@@ -1779,77 +1687,21 @@ class GenerationEngine:
 
         def compile_all():
             active = jnp.zeros((self.max_slots,), bool)
-            if self.paged:
-                # window rungs demote to page-gather widths; dedup keeps
-                # the executable count <= the dense ladder's
-                widths = list(dict.fromkeys(
-                    self._pick_page_width(w) for w in window_rungs))
-                for k in rungs:
-                    for pw in widths:
-                        table = jnp.full((self.max_slots, pw),
-                                         self._pool.sentinel, jnp.int32)
-                        out = self._decode_paged_fn(k, pw=pw)(
-                            self.params, self.last_token,
-                            self._pool.leaves, table, self.cache_len,
-                            active)
-                        self._pool.leaves, self.cache_len = out[1], out[2]
-                        if sampling:
-                            out = self._decode_paged_fn(
-                                k, sampled=True, pw=pw)(
-                                self.params, self.last_token,
-                                self._pool.leaves, table, self.cache_len,
-                                active, self.temps, self.top_ks,
-                                self.top_ps, self.sample_keys)
-                            (_, self._pool.leaves, self.cache_len,
-                             self.sample_keys) = out[:4]
-            else:
-                for k in rungs:
-                    for window in window_rungs:
-                        tokens, cache, cache_len = self._decode_fn(
-                            k, window=window)(
-                            self.params, self.last_token, self.cache,
-                            self.cache_len, active)
-                        self.cache, self.cache_len = cache, cache_len
-                        if sampling:
-                            out = self._decode_fn(k, sampled=True,
-                                                  window=window)(
-                                self.params, self.last_token, self.cache,
-                                self.cache_len, active, self.temps,
-                                self.top_ks, self.top_ps, self.sample_keys)
-                            (_, self.cache, self.cache_len,
-                             self.sample_keys) = out
+            # on the pool the window rungs demote to page-gather widths;
+            # dedup keeps the executable count <= the dense ladder's
+            widths = list(dict.fromkeys(
+                self._tick_width(w) for w in window_rungs))
+            for k in rungs:
+                for width in widths:
+                    for sampled in (False, True) if sampling else (False,):
+                        self._run_tick(k, sampled, width, active)
             if self.spec:
                 # the speculative ladder: one fused draft+verify executable
                 # per (γ rung, window/width). Inactive-row garbage writes
                 # land at frozen positions that every later insert covers.
-                if self.paged:
-                    widths = list(dict.fromkeys(
-                        self._pick_page_width(w) for w in window_rungs))
-                    for g in self._g_ladder:
-                        for pw in widths:
-                            table = jnp.full((self.max_slots, pw),
-                                             self._pool.sentinel, jnp.int32)
-                            out = self._spec_paged_fn(g, pw)(
-                                self.params, self.draft_params,
-                                self.last_token, self._pool.leaves,
-                                self._draft_cache, table, self.cache_len,
-                                active, self.temps, self.top_ks,
-                                self.top_ps, self.sample_keys)
-                            (_, _, self._pool.leaves, self._draft_cache,
-                             self.cache_len, self.last_token,
-                             self.sample_keys) = out
-                else:
-                    for g in self._g_ladder:
-                        for window in window_rungs:
-                            out = self._spec_fn(g, window)(
-                                self.params, self.draft_params,
-                                self.last_token, self.cache,
-                                self._draft_cache, self.cache_len,
-                                active, self.temps, self.top_ks,
-                                self.top_ps, self.sample_keys)
-                            (_, _, self.cache, self._draft_cache,
-                             self.cache_len, self.last_token,
-                             self.sample_keys) = out
+                for g in self._g_ladder:
+                    for width in widths:
+                        self._run_spec(g, width, active)
             for lb in self.prompt_buckets:
                 # rungs over the admission bound are unreachable, and
                 # counts that share a rung warm it once
@@ -1859,44 +1711,15 @@ class GenerationEngine:
                 for nb in reachable:
                     if nb > self._group_rows(lb):
                         continue
-                    toks = jnp.zeros((nb, lb), jnp.int32)
-                    lens = jnp.ones((nb,), jnp.int32)
-                    zeros_f = jnp.zeros((nb,), jnp.float32)
-                    zeros_i = jnp.zeros((nb,), jnp.int32)
-                    ones_f = jnp.ones((nb,), jnp.float32)
-                    seeds = jnp.zeros((nb,), jnp.uint32)
-                    first, small, keys = self._prefill_fn(nb, lb)(
-                        self.params, toks, lens, zeros_f, zeros_i, ones_f,
-                        seeds)
-                    slots = jnp.full((nb,), self.max_slots, jnp.int32)
-                    if self.paged:
-                        flat = jnp.full((nb * (lb // self.kv_page),),
-                                        self._pool.sentinel, jnp.int32)
-                        (leaves, self.cache_len, self.last_token,
-                         self.temps, self.top_ks, self.top_ps,
-                         self.sample_keys) = self._insert_paged_fn(
-                            nb, lb, 0)(
-                            self._pool.leaves, small, flat, slots, lens,
-                            first, self.cache_len, self.last_token,
-                            self.temps, self.top_ks, self.top_ps,
-                            self.sample_keys, zeros_f, zeros_i, ones_f,
-                            keys)
-                        self._pool.leaves = leaves
-                    else:
-                        (self.cache, self.cache_len, self.last_token,
-                         self.temps, self.top_ks, self.top_ps,
-                         self.sample_keys) = self._insert_fn(nb, lb)(
-                            self.cache, small, slots, lens, first,
-                            self.cache_len, self.last_token, self.temps,
-                            self.top_ks, self.top_ps, self.sample_keys,
-                            zeros_f, zeros_i, ones_f, keys)
+                    dev = self._padding_group(nb, lb)
+                    first, small, keys = self._run_prefill(nb, lb, dev)
+                    self._run_insert(nb, lb, 0, dev, first, small, keys)
                     if self.spec:
                         dsmall = self._draft_prefill_fn(nb, lb)(
-                            self.draft_params, toks, lens)
+                            self.draft_params, dev["padded"], dev["lengths"])
                         self._draft_cache = self._draft_insert_fn(nb, lb)(
-                            self._draft_cache, dsmall, slots)
-            self._jax.block_until_ready(
-                self._pool.leaves if self.paged else self.cache)
+                            self._draft_cache, dsmall, dev["slots"])
+            self._jax.block_until_ready(self._kv)
 
         def compile_locked():
             # warmup mutates the (possibly shared) pool leaves repeatedly;
@@ -1906,10 +1729,7 @@ class GenerationEngine:
             # serving) in the engine's compile ledger.
             self._warming += 1
             try:
-                if self.paged:
-                    with self._pool.lock:
-                        compile_all()
-                else:
+                with self._pool.lock if self.paged else nullcontext():
                     compile_all()
             finally:
                 self._warming -= 1
@@ -2716,12 +2536,14 @@ class GenerationEngine:
         engine state — every donated input is a freshly allocated dummy
         of the right shape, so it runs in an executor thread while the
         loop keeps ticking. The cost is transient memory for one dummy
-        cache (dense) or one dummy page-pool leaf set (paged) per
-        compile; on a memory-tight replica, prewarm during a quiet
+        cache (dense) or one dummy page-pool leaf set (paged) at a
+        time; on a memory-tight replica, prewarm during a quiet
         window. New prompt buckets are warmed across the whole
         admission-count ladder and new decode rungs across the whole
-        window/width ladder, so an applied move never compiles on the
-        serving path (the bench's zero-serve-time-compiles bar)."""
+        window/width ladder, greedy and sampled, and in the constrained
+        form where constrained traffic can reach them (every prefill,
+        the k=1 ticks), so an applied move never compiles on the serving
+        path (the zero-serve-time-compiles bar)."""
         buckets, k = self._op_shape_sig(point)
         bad = [b for b in buckets if b > self.max_len]
         if bad or not buckets:
@@ -2740,106 +2562,68 @@ class GenerationEngine:
         jnp = self._jnp
         loop = asyncio.get_running_loop()
 
-        def dummy_like(tree):
-            return {name: jnp.zeros(leaf.shape, leaf.dtype)
-                    for name, leaf in tree.items()}
-
-        def slot_state():
-            return (jnp.zeros((self.max_slots,), jnp.int32),   # cache_len
-                    jnp.zeros((self.max_slots,), jnp.int32),   # last_token
-                    jnp.zeros((self.max_slots,), jnp.float32),  # temps
-                    jnp.zeros((self.max_slots,), jnp.int32),   # top_ks
-                    jnp.ones((self.max_slots,), jnp.float32),  # top_ps
-                    jnp.zeros((self.max_slots, 2), jnp.uint32))
+        def dummy_state():
+            """Fresh slot state and a zeroed cache of the engine's shapes:
+            what the donating executables consume in the engine's stead."""
+            return SimpleNamespace(
+                _kv={name: jnp.zeros(leaf.shape, leaf.dtype)
+                     for name, leaf in self._kv.items()},
+                cache_len=jnp.zeros((self.max_slots,), jnp.int32),
+                last_token=jnp.zeros((self.max_slots,), jnp.int32),
+                temps=jnp.zeros((self.max_slots,), jnp.float32),
+                top_ks=jnp.zeros((self.max_slots,), jnp.int32),
+                top_ps=jnp.ones((self.max_slots,), jnp.float32),
+                sample_keys=jnp.zeros((self.max_slots, 2), jnp.uint32))
 
         def compile_new() -> int:
             compiled = 0
             for lb in buckets:
                 for nb in self._n_ladder:
-                    need_prefill = (nb, lb) not in self._prefill_fns
+                    missing = [biased for biased in (False, True)
+                               if (nb, lb, biased) not in self._prefill_fns]
                     need_insert = (
                         (nb, lb, 0) not in self._insert_paged_fns
                         if self.paged else
                         (nb, lb) not in self._insert_fns)
-                    if not need_prefill and not need_insert:
+                    if not missing and not need_insert:
                         continue
-                    toks = jnp.zeros((nb, lb), jnp.int32)
-                    lens = jnp.ones((nb,), jnp.int32)
-                    zeros_f = jnp.zeros((nb,), jnp.float32)
-                    zeros_i = jnp.zeros((nb,), jnp.int32)
-                    ones_f = jnp.ones((nb,), jnp.float32)
-                    seeds = jnp.zeros((nb,), jnp.uint32)
-                    first, small, keys = self._prefill_fn(nb, lb)(
-                        self.params, toks, lens, zeros_f, zeros_i,
-                        ones_f, seeds)
-                    compiled += 1 if need_prefill else 0
-                    if not need_insert:
-                        continue
-                    slots = jnp.full((nb,), self.max_slots, jnp.int32)
-                    (cache_len, last_token, temps, top_ks, top_ps,
-                     sample_keys) = slot_state()
-                    if self.paged:
-                        flat = jnp.full((nb * (lb // self.kv_page),),
-                                        self._pool.sentinel, jnp.int32)
-                        self._insert_paged_fn(nb, lb, 0)(
-                            dummy_like(self._pool.leaves), small, flat,
-                            slots, lens, first, cache_len, last_token,
-                            temps, top_ks, top_ps, sample_keys,
-                            zeros_f, zeros_i, ones_f, keys)
-                    else:
-                        self._insert_fn(nb, lb)(
-                            dummy_like(self.cache), small, slots, lens,
-                            first, cache_len, last_token, temps, top_ks,
-                            top_ps, sample_keys, zeros_f, zeros_i,
-                            ones_f, keys)
-                    compiled += 1
+                    dev = self._padding_group(nb, lb)
+                    with_bias = dict(dev, bias=jnp.zeros(
+                        (nb, self.cfg.vocab_size), jnp.float32))
+                    outs = {biased: self._run_prefill(
+                        nb, lb, with_bias if biased else dev)
+                        for biased in missing}
+                    compiled += len(missing)
+                    if need_insert:
+                        first, small, keys = (
+                            outs.get(False)
+                            or self._run_prefill(nb, lb, dev))
+                        self._run_insert(nb, lb, 0, dev, first, small, keys,
+                                         state=dummy_state())
+                        compiled += 1
             active = jnp.zeros((self.max_slots,), bool)
+            masks = {False: (active, None),
+                     True: (active.astype(jnp.int32),
+                            jnp.zeros((self.max_slots, self.cfg.vocab_size),
+                                      jnp.float32))}
+            widths = list(dict.fromkeys(
+                self._tick_width(w) for w in self._window_ladder))
+            state = None
             for rung in rungs:
-                if self.paged:
-                    widths = list(dict.fromkeys(
-                        self._pick_page_width(w)
-                        for w in self._window_ladder))
-                    for pw in widths:
-                        for sampled in (False, True):
-                            if (rung, sampled, pw) \
-                                    in self._decode_paged_fns:
+                for width in widths:
+                    for sampled in (False, True):
+                        # a constrained slot rides k=1 ticks only
+                        for biased in (False, True) if rung == 1 else (
+                                False,):
+                            if (rung, sampled, biased, width) \
+                                    in self._tick_fns:
                                 continue
-                            table = jnp.full(
-                                (self.max_slots, pw),
-                                self._pool.sentinel, jnp.int32)
-                            (cache_len, last_token, temps, top_ks,
-                             top_ps, sample_keys) = slot_state()
-                            fn = self._decode_paged_fn(
-                                rung, sampled=sampled, pw=pw)
-                            if sampled:
-                                fn(self.params, last_token,
-                                   dummy_like(self._pool.leaves), table,
-                                   cache_len, active, temps, top_ks,
-                                   top_ps, sample_keys)
-                            else:
-                                fn(self.params, last_token,
-                                   dummy_like(self._pool.leaves), table,
-                                   cache_len, active)
-                            compiled += 1
-                else:
-                    for window in self._window_ladder:
-                        for sampled in (False, True):
-                            if (rung, sampled, window) \
-                                    in self._decode_fns:
-                                continue
-                            (cache_len, last_token, temps, top_ks,
-                             top_ps, sample_keys) = slot_state()
-                            fn = self._decode_fn(rung, sampled=sampled,
-                                                 window=window)
-                            if sampled:
-                                fn(self.params, last_token,
-                                   dummy_like(self.cache), cache_len,
-                                   active, temps, top_ks, top_ps,
-                                   sample_keys)
-                            else:
-                                fn(self.params, last_token,
-                                   dummy_like(self.cache), cache_len,
-                                   active)
+                            # one dummy serves every tick: each call
+                            # writes its donated leaves back into it
+                            state = state or dummy_state()
+                            mask, bias = masks[biased]
+                            self._run_tick(rung, sampled, width, mask,
+                                           bias=bias, state=state)
                             compiled += 1
             return compiled
 
@@ -3339,7 +3123,7 @@ class GenerationEngine:
         if self.paged:
             # the page-gather width ladder is the paged path's analogue of
             # the attention-window ladder: one decode executable per
-            # (k, sampled, width), width always ladder-derived. With the
+            # (k, sampled, biased, width), width always ladder-derived. With the
             # ragged kernel active the set collapses to the single
             # full-table width — the width-rung recompile class is gone.
             out["paged_kv"] = {
@@ -3349,7 +3133,7 @@ class GenerationEngine:
                 "gather_widths": sorted({self._pick_page_width(w)
                                          for w in self._window_ladder}),
                 "decode_executables": sorted(
-                    str(key) for key in self._decode_paged_fns),
+                    str(key) for key in self._tick_fns),
                 "pool": self._pool.stats(),
             }
         if self.spec:
@@ -3359,9 +3143,7 @@ class GenerationEngine:
             out["speculative"] = {
                 "gamma_ladder": list(self._g_ladder),
                 "gamma_cap": self._gamma_cap,
-                "compiled_spec_fns": (len(self._spec_paged_fns)
-                                      if self.paged
-                                      else len(self._spec_fns)),
+                "compiled_spec_fns": len(self._spec_fns),
                 "compiled_draft_prefill_fns": len(self._draft_prefill_fns),
             }
         return out
@@ -4044,21 +3826,9 @@ class GenerationEngine:
                     # must not interleave between our read of the leaves
                     # handle and the write-back below (tenancy safety)
                     with self._pool.lock:
-                        if p == 0 and bias_rows is not None:
-                            first, small, keys = self._prefill_bias_fn(
-                                nb, bucket)(
-                                self.params, dev["padded"],
-                                dev["lengths"],
-                                dev["temps"], dev["top_ks"],
-                                dev["top_ps"], dev["seeds"],
-                                dev["bias"])
-                        elif p == 0:
-                            first, small, keys = self._prefill_fn(
-                                nb, bucket)(
-                                self.params, dev["padded"],
-                                dev["lengths"],
-                                dev["temps"], dev["top_ks"],
-                                dev["top_ps"], dev["seeds"])
+                        if p == 0:
+                            first, small, keys = self._run_prefill(
+                                nb, bucket, dev)
                         else:
                             # suffix prefill reads the SAME pool leaves the
                             # insert below donates — PjRt usage events order
@@ -4070,26 +3840,14 @@ class GenerationEngine:
                                 dev["lengths"], dev["temps"],
                                 dev["top_ks"], dev["top_ps"],
                                 dev["seeds"])
-                        (leaves, self.cache_len, self.last_token,
-                         self.temps, self.top_ks, self.top_ps,
-                         self.sample_keys) = \
-                            self._insert_paged_fn(nb, bucket, plen)(
-                                self._pool.leaves, small,
-                                dev["flat_ids"], dev["slots"],
-                                dev["lengths"], first,
-                                self.cache_len, self.last_token, self.temps,
-                                self.top_ks, self.top_ps, self.sample_keys,
-                                dev["temps"], dev["top_ks"],
-                                dev["top_ps"], keys)
-                        self._pool.leaves = leaves
+                        self._run_insert(nb, bucket, plen, dev, first, small,
+                                         keys)
                     self._pool.note_writes(
                         int((flat_ids != self._pool.sentinel).sum()))
                     return first
 
                 warm = ((nb, bucket, plen) in self._insert_paged_fns
-                        and ((nb, bucket) in (self._prefill_bias_fns
-                                              if biased
-                                              else self._prefill_fns)
+                        and ((nb, bucket, biased) in self._prefill_fns
                              if p_rung == 0 else
                              (nb, p_rung, bucket)
                              in self._suffix_prefill_fns))
@@ -4105,36 +3863,15 @@ class GenerationEngine:
                     if bias_rows is not None:
                         group["bias"] = bias_rows
                     dev = self._upload_group(group)
-                    if bias_rows is not None:
-                        first, small, keys = self._prefill_bias_fn(
-                            nb, bucket)(
-                            self.params, dev["padded"],
-                            dev["lengths"],
-                            dev["temps"], dev["top_ks"],
-                            dev["top_ps"], dev["seeds"], dev["bias"])
-                    else:
-                        first, small, keys = self._prefill_fn(nb, bucket)(
-                            self.params, dev["padded"],
-                            dev["lengths"],
-                            dev["temps"], dev["top_ks"],
-                            dev["top_ps"], dev["seeds"])
-                    (self.cache, self.cache_len, self.last_token, self.temps,
-                     self.top_ks, self.top_ps, self.sample_keys) = \
-                        self._insert_fn(nb, bucket)(
-                            self.cache, small, dev["slots"],
-                            dev["lengths"], first,
-                            self.cache_len, self.last_token, self.temps,
-                            self.top_ks, self.top_ps, self.sample_keys,
-                            dev["temps"], dev["top_ks"],
-                            dev["top_ps"], keys)
+                    first, small, keys = self._run_prefill(nb, bucket, dev)
+                    self._run_insert(nb, bucket, 0, dev, first, small, keys)
                     if publish_ids is not None:
                         # insert does not donate `small`, so the publish
                         # scatter can read it after the insert dispatch
                         self._prefix.publish(small, publish_ids, nb, bucket)
                     return first
 
-                warm = ((nb, bucket) in (self._prefill_bias_fns if biased
-                                         else self._prefill_fns)
+                warm = ((nb, bucket, biased) in self._prefill_fns
                         and (nb, bucket) in self._insert_fns
                         and (publish_ids is None
                              or self._prefix.publish_ready(nb, bucket)))
@@ -4282,6 +4019,17 @@ class GenerationEngine:
             out.setdefault(k, None)
         return out
 
+    def _active_mask(self, active):
+        """The tick's active mask on the device, kept resident: it is
+        uploaded again only when the active set changed (every H2D pays a
+        fixed per-transfer cost; most ticks are stable)."""
+        key = active.tobytes()
+        if getattr(self, "_mask_key", None) != key:
+            self._mask_dev = self._h2d.upload(active, self._jnp.asarray,
+                                              path="mask")
+            self._mask_key = key
+        return self._mask_dev
+
     async def _dispatch_tick(self, loop):
         """Choose K adaptively, dispatch one decode executable, return
         (device tokens handle, active snapshot) without syncing.
@@ -4298,7 +4046,6 @@ class GenerationEngine:
         # _loop's catch-all fails outstanding work and rebuilds device
         # state
         faults.active().raise_if("tick_exception")
-        jnp = self._jnp
         # constrained slots only join a tick when no token of theirs is in
         # flight: their grammar mask is valid for exactly the next
         # position, so pipelined ticks must not run ahead of the walker
@@ -4360,7 +4107,6 @@ class GenerationEngine:
             if slot.record is not None:
                 slot.record.rode_batch(len(eligible))
         window = self._pick_window(fills, k)
-        dev_bias = None
         if biased:
             # per-tick grammar masks: every constrained participant's
             # current-state bias row lands in a fresh (max_slots, vocab)
@@ -4369,108 +4115,27 @@ class GenerationEngine:
             # bias ship as ONE coalesced H2D frame (both 4-byte dtypes),
             # through the same _upload_group entry point as every other
             # dispatch — no new per-step device_put path.
-            bias = np.zeros((self.max_slots, self.cfg.vocab_size),
+            slab = np.zeros((self.max_slots, self.cfg.vocab_size),
                             np.float32)
             active_i32 = np.zeros((self.max_slots,), np.int32)
             active_i32[active] = 1
             for slot_idx, slot in eligible:
                 if slot.grammar is not None:
-                    bias[slot_idx, :] = slot.grammar.bias_row()
-            dev_bias = self._upload_group(dict(active=active_i32,
-                                               bias=bias))
+                    slab[slot_idx, :] = slot.grammar.bias_row()
+            dev = self._upload_group(dict(active=active_i32, bias=slab))
+            mask, bias = dev["active"], dev["bias"]
             self._constrained_ticks += 1
         else:
-            # keep the mask device-resident: re-upload only when the
-            # active set changed (every H2D pays a fixed per-transfer
-            # cost; most ticks are stable)
-            key = active.tobytes()
-            if getattr(self, "_mask_key", None) != key:
-                self._mask_dev = self._h2d.upload(active, jnp.asarray,
-                                                  path="mask")
-                self._mask_key = key
-
-        pw = self._pick_page_width(window) if self.paged else 0
+            mask, bias = self._active_mask(active), None
+        width = self._tick_width(window)
 
         def dispatch():
-            # what the module's steps counted (STEP_COUNTERS), as the one
-            # extra output of the unconstrained paged tick: () elsewhere
-            counts_dev = ()
-            if self.paged:
-                # pool lock: see the admission dispatch — co-resident
-                # engines' donations must not interleave with ours
-                with self._pool.lock:
-                    table = self._table_dev(pw)
-                    if biased and sampled:
-                        (tokens_dev, leaves, self.cache_len,
-                         self.sample_keys) = self._decode_paged_bias_fn(
-                            k, sampled=True, pw=pw)(
-                            self.params, self.last_token, self._pool.leaves,
-                            table, self.cache_len, dev_bias["active"],
-                            dev_bias["bias"], self.temps, self.top_ks,
-                            self.top_ps, self.sample_keys)
-                    elif biased:
-                        (tokens_dev, leaves, self.cache_len) = \
-                            self._decode_paged_bias_fn(k, pw=pw)(
-                            self.params, self.last_token, self._pool.leaves,
-                            table, self.cache_len, dev_bias["active"],
-                            dev_bias["bias"])
-                    elif sampled:
-                        out = self._decode_paged_fn(
-                            k, sampled=True, pw=pw)(
-                            self.params, self.last_token, self._pool.leaves,
-                            table, self.cache_len, self._mask_dev,
-                            self.temps, self.top_ks, self.top_ps,
-                            self.sample_keys)
-                        (tokens_dev, leaves, self.cache_len,
-                         self.sample_keys) = out[:4]
-                        counts_dev = out[4:]
-                    else:
-                        out = self._decode_paged_fn(k, pw=pw)(
-                            self.params, self.last_token, self._pool.leaves,
-                            table, self.cache_len, self._mask_dev)
-                        tokens_dev, leaves, self.cache_len = out[:3]
-                        counts_dev = out[3:]
-                    self._pool.leaves = leaves
-            elif biased and sampled:
-                (tokens_dev, self.cache, self.cache_len,
-                 self.sample_keys) = self._decode_bias_fn(
-                    k, sampled=True, window=window)(
-                    self.params, self.last_token, self.cache,
-                    self.cache_len, dev_bias["active"], dev_bias["bias"],
-                    self.temps, self.top_ks, self.top_ps,
-                    self.sample_keys)
-            elif biased:
-                tokens_dev, self.cache, self.cache_len = \
-                    self._decode_bias_fn(k, window=window)(
-                    self.params, self.last_token, self.cache,
-                    self.cache_len, dev_bias["active"], dev_bias["bias"])
-            elif sampled:
-                (tokens_dev, self.cache, self.cache_len,
-                 self.sample_keys) = self._decode_fn(
-                    k, sampled=True, window=window)(
-                    self.params, self.last_token, self.cache,
-                    self.cache_len, self._mask_dev, self.temps,
-                    self.top_ks, self.top_ps, self.sample_keys)
-            else:
-                tokens_dev, self.cache, self.cache_len = self._decode_fn(
-                    k, window=window)(
-                    self.params, self.last_token, self.cache,
-                    self.cache_len, self._mask_dev)
-            self.last_token = tokens_dev[-1]
-            return tokens_dev, counts_dev
+            return self._run_tick(k, sampled, width, mask, bias=bias)
 
         step_span = self._step_span("tpu.engine.step", snapshot,
                                     k=k, window=window or self.max_len,
                                     sampled=sampled, step=self._steps)
-        if biased:
-            warm = ((k, sampled, pw) in self._decode_paged_bias_fns
-                    if self.paged
-                    else (k, sampled, window) in self._decode_bias_fns)
-        else:
-            warm = ((k, sampled, pw) in self._decode_paged_fns
-                    if self.paged
-                    else (k, sampled, window) in self._decode_fns)
-        if warm:
+        if (k, sampled, biased, width) in self._tick_fns:
             with self._profile_step("tpu.engine.step"):
                 tokens_dev, counts_dev = dispatch()
         else:
@@ -4509,12 +4174,8 @@ class GenerationEngine:
             def fetch(dev=tokens_dev):
                 return np.asarray(dev)
 
-        # executable-family name for the roofline ledger (ISSUE 17):
-        # mirrors the warm-key above, so device time lands on the same
-        # granularity the compiler cache is keyed by
-        tag = "_bias" if biased else ""
-        family = (f"decode_paged{tag}[k={k},pw={pw}]" if self.paged
-                  else f"decode{tag}[k={k},w={window or self.max_len}]")
+        # executable-family name for the roofline ledger (ISSUE 17)
+        family = self._tick_names(k, biased, width)[1]
         return "tick", fetch, snapshot, step_span, family
 
     async def _dispatch_spec(self, loop, eligible, g: int):
@@ -4523,7 +4184,6 @@ class GenerationEngine:
         worst case — ``_publish`` refunds the rejected remainder), run the
         fused draft+verify executable, and hand back a fetch that lands
         both the (g+1, B) token matrix and the per-slot accept counts."""
-        jnp = self._jnp
         if self.paged:
             covered = self._cover_pages(eligible, g + 1)
             if not covered:
@@ -4546,43 +4206,16 @@ class GenerationEngine:
             if slot.record is not None:
                 slot.record.rode_batch(len(eligible))
         window = self._pick_window(fills, g + 1)
-        key = active.tobytes()
-        if getattr(self, "_mask_key", None) != key:
-            self._mask_dev = self._h2d.upload(active, jnp.asarray,
-                                              path="mask")
-            self._mask_key = key
-        pw = self._pick_page_width(window) if self.paged else 0
+        mask = self._active_mask(active)
+        width = self._tick_width(window)
 
         def dispatch():
-            if self.paged:
-                # pool lock: see the admission dispatch — co-resident
-                # engines' donations must not interleave with ours
-                with self._pool.lock:
-                    table = self._table_dev(pw)
-                    (toks_dev, accepts_dev, leaves, self._draft_cache,
-                     self.cache_len, self.last_token,
-                     self.sample_keys) = self._spec_paged_fn(g, pw)(
-                        self.params, self.draft_params, self.last_token,
-                        self._pool.leaves, self._draft_cache, table,
-                        self.cache_len, self._mask_dev, self.temps,
-                        self.top_ks, self.top_ps, self.sample_keys)
-                    self._pool.leaves = leaves
-            else:
-                (toks_dev, accepts_dev, self.cache, self._draft_cache,
-                 self.cache_len, self.last_token,
-                 self.sample_keys) = self._spec_fn(g, window)(
-                    self.params, self.draft_params, self.last_token,
-                    self.cache, self._draft_cache, self.cache_len,
-                    self._mask_dev, self.temps, self.top_ks, self.top_ps,
-                    self.sample_keys)
-            return toks_dev, accepts_dev
+            return self._run_spec(g, width, mask)
 
         step_span = self._step_span("tpu.engine.spec", snapshot,
                                     gamma=g, window=window or self.max_len,
                                     step=self._steps)
-        warm = ((g, pw) in self._spec_paged_fns if self.paged
-                else (g, window) in self._spec_fns)
-        if warm:
+        if (g, width) in self._spec_fns:
             pair = dispatch()
         else:
             pair = await self._off_loop(loop, dispatch)
@@ -4600,7 +4233,7 @@ class GenerationEngine:
         def fetch(pair=pair):
             return np.asarray(pair[0]), np.asarray(pair[1])
 
-        family = (f"spec_paged[g={g},pw={pw}]" if self.paged
+        family = (f"spec_paged[g={g},pw={width}]" if self.paged
                   else f"spec[g={g},w={window or self.max_len}]")
         return "spec", fetch, (snapshot, g), step_span, family
 

@@ -1,7 +1,7 @@
 """Workload capture & replay plane (ISSUE 17).
 
-The ROADMAP's SLO-driven auto-tuning is gated on "bench.py replaying
-recorded traffic shapes as the eval harness" — which needs a traffic
+The ROADMAP's SLO-driven auto-tuning is gated on replaying recorded
+traffic shapes as the eval harness — which needs a traffic
 recorder first. This module is that substrate, in three pieces:
 
 - :class:`TrafficRecorder` — a bounded, **shape-only** ring of admitted

@@ -8,7 +8,7 @@ SLO-class and finish-reason mixes, the prefix-reuse rate, and the
 batcher plane's enqueue pulse — plus the per-executable device-time
 roofline table from whichever engine/executor is mounted. With
 ``?trace=1`` the page returns the versioned compact trace export
-instead, the artifact ``bench.py llama_replay`` replays.
+instead, the artifact ``tpu/workload.replay_trace`` replays.
 
 Registered like its siblings — ``app.enable_workloadz()`` — never on by
 default, and rendering never syncs the device stream. Shape only: the

@@ -36,7 +36,7 @@ def test_kernels_phase_at_tiny(run, capsys):
     (line,) = _phase_lines(capsys)
     assert line["ok"] and line["interpret"] and line["platform"] == "cpu"
     assert {name.split()[0] for name in line["kernels"]} == {
-        "flash_attention", "ragged_decode", "ragged_verify", "flash_decode"}
+        "flash_attention", "ragged_decode", "ragged_verify"}
     # the interpreter reproduces the oracle's rounding exactly
     assert line["kernels"]["ragged_decode 4:2 int8"]["max_abs_err"] == 0.0
 

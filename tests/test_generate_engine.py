@@ -217,10 +217,11 @@ def test_engine_warmup_precompiles(setup):
     async def main():
         engine = _make_engine(cfg, params, steps_per_tick=4)
         await engine.warmup(prompt_counts=(1, 2))
-        assert sorted(engine._decode_fns) == [(1, False, None),
-                                              (2, False, None),
-                                              (4, False, None)]
-        assert set(engine._prefill_fns) == {(1, 8), (1, 16), (2, 8), (2, 16)}
+        assert sorted(engine._tick_fns) == [(1, False, False, None),
+                                            (2, False, False, None),
+                                            (4, False, False, None)]
+        assert set(engine._prefill_fns) == {
+            (1, 8, False), (1, 16, False), (2, 8, False), (2, 16, False)}
         await engine.start()
         try:
             out = await asyncio.wait_for(
@@ -242,13 +243,13 @@ def test_warmup_defaults_to_startup_window_subset(setup):
                               prompt_buckets=(8, 16), steps_per_tick=4)
         assert engine._window_ladder == [128, 256, None]
         await engine.warmup(prompt_counts=(1,))
-        warmed = {w for (_, _, w) in engine._decode_fns}
+        warmed = {w for (_, _, _, w) in engine._tick_fns}
         assert warmed == {128}, warmed   # bucket 16 + k 4 fits rung 128
 
         full = _make_engine(cfg, params, max_len=512,
                             prompt_buckets=(8, 16), steps_per_tick=4)
         await full.warmup(prompt_counts=(1,), windows="all")
-        assert {w for (_, _, w) in full._decode_fns} == {128, 256, None}
+        assert {w for (_, _, _, w) in full._tick_fns} == {128, 256, None}
     asyncio.run(main())
 
 
@@ -341,10 +342,10 @@ def test_loop_failure_fails_futures_and_recovers(setup):
         boom = {"armed": True}
         real = engine._prefill_fn
 
-        def exploding(nb, lb):
+        def exploding(nb, lb, biased=False):
             if boom["armed"]:
                 raise RuntimeError("injected prefill failure")
-            return real(nb, lb)
+            return real(nb, lb, biased)
 
         engine._prefill_fn = exploding
         await engine.start()
@@ -372,11 +373,11 @@ def test_tick_failure_resets_device_state_and_recovers(setup):
 
     async def main():
         engine = _make_engine(cfg, params)
-        real = engine._decode_fn
+        real = engine._tick_fn
         boom = {"armed": True}
 
-        def exploding(k, sampled=False, window=None):
-            fn = real(k, sampled, window)
+        def exploding(k, sampled, biased, width):
+            fn = real(k, sampled, biased, width)
 
             def wrapped(*args):
                 out = fn(*args)   # consumes the donated cache for real
@@ -385,7 +386,7 @@ def test_tick_failure_resets_device_state_and_recovers(setup):
                 return out
             return wrapped
 
-        engine._decode_fn = exploding
+        engine._tick_fn = exploding
         await engine.start()
         try:
             with pytest.raises(RuntimeError, match="post-dispatch"):
@@ -483,7 +484,7 @@ def test_window_ladder_token_identical(setup):
                                  np.asarray([prompt], np.int32), 6)
             assert out == [int(t) for t in np.asarray(ref)[0]]
             # the sub-full rung was used (fills stayed far below 128)
-            assert any(key[2] == 128 for key in engine2._decode_fns)
+            assert any(key[3] == 128 for key in engine2._tick_fns)
         finally:
             await engine2.stop()
     asyncio.run(main())

@@ -386,8 +386,8 @@ def test_admission_bound_splits_a_group_and_keeps_its_order(model):
         groups = []
         prefill_fn = engine._prefill_fn
 
-        def spy(nb, lb):
-            fn = prefill_fn(nb, lb)
+        def spy(nb, lb, biased=False):
+            fn = prefill_fn(nb, lb, biased)
 
             def call(params, tokens, *rest):
                 groups.append((nb, lb, [int(t) for t in tokens[:, 0]]))
@@ -425,7 +425,7 @@ def test_warmup_skips_the_rungs_the_bound_makes_unreachable(model):
         engine, _ = engine_for(mla_moe, cfg, params, max_slots=8,
                                max_group_tokens=bound)
         await engine.warmup(prompt_counts=tuple(engine._n_ladder), ks=(1,))
-        return sorted(engine._prefill_fns)
+        return sorted((n, b) for n, b, _ in engine._prefill_fns)
 
     assert asyncio.run(warm(None)) == [(n, b) for n in (1, 2, 4, 8)
                                        for b in (8, 16)]
@@ -472,7 +472,8 @@ def test_llama_tick_has_no_counters_and_three_outputs():
     engine, _ = engine_for(llama, cfg, params)
     assert engine._step_counters == ()
     out = jax.eval_shape(
-        engine._decode_paged_fn(2, pw=8), engine.params, engine.last_token,
+        engine._tick_fn(2, False, False, 8), engine.params,
+        engine.last_token,
         engine._pool.leaves, jnp.zeros((4, 8), jnp.int32), engine.cache_len,
         jnp.zeros((4,), bool))
     assert len(out) == 3 and out[0].shape == (2, 4)

@@ -31,8 +31,7 @@ import jax.numpy as jnp
 import pytest
 
 from gofr_tpu.models import llama
-from gofr_tpu.ops.pallas import (flash_attention, flash_decode_attention,
-                                 flash_tileable, decode_shapes_tileable,
+from gofr_tpu.ops.pallas import (flash_attention, flash_tileable,
                                  ragged_paged_decode_attention,
                                  ragged_paged_verify_attention,
                                  ragged_tileable)
@@ -84,18 +83,6 @@ def test_flash_attention_compiles_for_v5e(v5e, q_heads, kv_heads):
              ((1, 1024, q_heads, HEAD_DIM), bf16),
              ((1, 1024, kv_heads, HEAD_DIM), bf16),
              ((1, 1024, kv_heads, HEAD_DIM), bf16))
-
-
-@pytest.mark.parametrize("q_heads,kv_heads", GEOMETRIES)
-def test_flash_decode_compiles_for_v5e(v5e, q_heads, kv_heads):
-    assert decode_shapes_tileable(256, 128, HEAD_DIM, q_heads)
-    bf16 = jnp.bfloat16
-    _compile(functools.partial(flash_decode_attention, interpret=False),
-             v5e, ((SLOTS, 1, q_heads, HEAD_DIM), bf16),
-             ((SLOTS, 256, kv_heads, HEAD_DIM), bf16),
-             ((SLOTS, 256, kv_heads, HEAD_DIM), bf16),
-             ((SLOTS, kv_heads, HEAD_DIM), bf16),
-             ((SLOTS, kv_heads, HEAD_DIM), bf16), ((SLOTS,), jnp.int32))
 
 
 @pytest.mark.parametrize("q_heads,kv_heads", GEOMETRIES)

@@ -212,7 +212,7 @@ def test_stream_engine_failure_raises(setup):
         engine = _make_engine(cfg, params)
         real = engine._prefill_fn
 
-        def exploding(nb, lb):
+        def exploding(nb, lb, biased=False):
             raise RuntimeError("injected stream failure")
 
         engine._prefill_fn = exploding
@@ -314,10 +314,10 @@ def test_multibucket_admission_failure_fails_all(setup):
         boom = {"armed": True}
         real = engine._prefill_fn
 
-        def exploding(nb, lb):
+        def exploding(nb, lb, biased=False):
             if boom["armed"]:
                 raise RuntimeError("injected admission failure")
-            return real(nb, lb)
+            return real(nb, lb, biased)
 
         engine._prefill_fn = exploding
         await engine.start()
