@@ -90,6 +90,59 @@ _ADOPT_LEDGER_TTL_S = 120.0
 _ADOPT_LEDGER_CAP = 256
 
 
+def weight_formats(fn, operands, donate_argnums=()):
+    """The layouts the compiler picks for the leaves of ``fn``'s first
+    operand when they are left to it: (that operand's tree of
+    ``Format``, the compiled program's temporaries in bytes or None,
+    the compiled program, which takes that operand in those formats).
+
+    ``operands`` may be abstract (``jax.ShapeDtypeStruct``), each leaf of
+    the first with its ``sharding``; every other operand keeps the layout
+    it arrives in. An executable's parameter layouts are fixed when it is
+    compiled, so a layer loop that wants a stacked weight with another
+    dimension minor transposes the whole stack at every call. Compiled
+    with ``Layout.AUTO`` the program states the layout it reads in
+    place."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    auto = jax.tree.map(lambda leaf: Format(Layout.AUTO, leaf.sharding),
+                        operands[0])
+    compiled = jax.jit(
+        fn, in_shardings=(auto,) + (None,) * (len(operands) - 1),
+        donate_argnums=donate_argnums).lower(*operands).compile()
+    analysis = compiled.memory_analysis()
+    return (compiled.input_formats[0][0],
+            getattr(analysis, "temp_size_in_bytes", None), compiled)
+
+
+def _relay(leaves, wanted):
+    """``leaves`` as new arrays in the ``Format``s ``wanted``, by one
+    program compiled in this process. An executable that writes a result
+    in another layout than the default must not come from the persistent
+    compilation cache: loaded from there its result reports the default
+    layout (jax 0.9.0: on XLA:CPU, where the data is not in it and
+    reads wrongly through ``jit``, and on a v5e), where one compiled
+    here reports what it is. So the program goes under a name of its
+    own, which no ``jax.device_put`` of another process has cached, and
+    is not written (the floor on compile times decides that, and is put
+    back)."""
+    import jax
+
+    def relaid(*xs):
+        return xs
+
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    try:
+        # graftcheck: ignore[GT003] — once an engine, and a program of its
+        # own each time is the point: nothing cached is to be found
+        return jax.jit(relaid, out_shardings=tuple(wanted))(*leaves)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+
+
 class BrownoutShed(RuntimeError):
     """Admission refused by the brownout ladder (slo.BrownoutLadder):
     the replica is shedding this SLO class to protect interactive
@@ -596,6 +649,13 @@ class GenerationEngine:
                 params, mesh, prune_specs(specs, mesh))
         else:
             self.params = jax.device_put(params)
+        # where a one-device engine's operands live (_jit); a mesh's
+        # operands carry their own shardings
+        self._placement = None
+        if mesh is None:
+            held = jax.tree.leaves(self.params)[0].sharding
+            if len(held.device_set) == 1:
+                self._placement = held
         self.cache = None
         self._pool = None
         self._table = None
@@ -687,6 +747,10 @@ class GenerationEngine:
         if logger is not None:
             logger.info("engine %s attention: %s", self.model_name,
                         self.attention_paths())
+        self._lay_out_weights()
+        if logger is not None:
+            logger.info("engine %s weights: %s", self.model_name,
+                        self._weights)
         self.cache_len = jnp.zeros((max_slots,), jnp.int32)
         self.last_token = jnp.zeros((max_slots,), jnp.int32)
         # per-slot sampling state (ops/sampling): scattered at admission,
@@ -890,6 +954,18 @@ class GenerationEngine:
         else:
             self.cache = leaves
 
+    def _jit(self, fn, donate_argnums=()):
+        """``jax.jit`` for an executable of the engine. On one device
+        every operand is stated to live there, so a program is compiled
+        once whatever its operands' history: ``jit`` keeps a compiled
+        program per set of committed operands, a re-laid weight is
+        committed and so is every output of a program that took one,
+        while fresh slot state and uploads are not, and the second call
+        of a tick would otherwise compile it again, on the serving
+        path. A weight's own layout is taken as it is found."""
+        return self._jax.jit(fn, donate_argnums=donate_argnums,
+                             in_shardings=self._placement)
+
     def _prefill_fn(self, nb: int, lb: int, biased: bool = False):
         """Pure-compute prompt forward for ``nb`` prompts of bucket ``lb``:
         (params, tokens (nb,lb), lengths (nb,), temps, top_ks, top_ps,
@@ -924,7 +1000,7 @@ class GenerationEngine:
                                            keys)
                 return first, small, keys
 
-            fn = jax.jit(prefill_batch)
+            fn = self._jit(prefill_batch)
             self._prefill_fns[(nb, lb, biased)] = fn
             self._note_compile("prefill_bias" if biased else "prefill",
                                (nb, lb))
@@ -983,7 +1059,6 @@ class GenerationEngine:
         ``max_slots`` (out of bounds → dropped)."""
         fn = self._insert_fns.get((nb, lb))
         if fn is None:
-            jax = self._jax
 
             def insert(cache, small, slots, lengths, first,
                        cache_len, last_token, temps, top_ks, top_ps,
@@ -1002,7 +1077,7 @@ class GenerationEngine:
                 return (cache, cache_len, last_token, temps,
                         top_ks, top_ps, sample_keys)
 
-            fn = jax.jit(insert, donate_argnums=(0, 5, 6, 7, 8, 9, 10))
+            fn = self._jit(insert, donate_argnums=(0, 5, 6, 7, 8, 9, 10))
             self._insert_fns[(nb, lb)] = fn
             self._note_compile("insert", (nb, lb))
         return fn
@@ -1037,7 +1112,7 @@ class GenerationEngine:
                                            keys)
                 return first, small, keys
 
-            fn = jax.jit(suffix_prefill)
+            fn = self._jit(suffix_prefill)
             self._suffix_prefill_fns[(nb, p, lb)] = fn
             self._note_compile("suffix_prefill", (nb, p, lb))
         return fn
@@ -1050,7 +1125,6 @@ class GenerationEngine:
         never donated (in-flight suffix prefills may still read it)."""
         fn = self._suffix_insert_fns.get((nb, p, lb))
         if fn is None:
-            jax = self._jax
             plen = p * self._prefix.page
 
             def insert(cache, pool, page_ids, small, slots, lengths, first,
@@ -1077,7 +1151,7 @@ class GenerationEngine:
                 return (cache, cache_len, last_token, temps,
                         top_ks, top_ps, sample_keys)
 
-            fn = jax.jit(insert,
+            fn = self._jit(insert,
                          donate_argnums=(0, 7, 8, 9, 10, 11, 12))
             self._suffix_insert_fns[(nb, p, lb)] = fn
             self._note_compile("suffix_insert", (nb, p, lb))
@@ -1113,7 +1187,7 @@ class GenerationEngine:
     def _tick_fn(self, k_steps: int, sampled: bool, biased: bool,
                  width: Optional[int]):
         """Decode-tick executable: ``k_steps`` fused steps in one
-        ``lax.scan``, built from three parts chosen here.
+        ``lax.scan``, built from three parts (``_tick_program``).
 
         The step: on the dense cache ``decode_step`` with ``width`` (a
         rung of the attention-window ladder, None = full) statically
@@ -1147,80 +1221,169 @@ class GenerationEngine:
         cache_len[, keys][, counters])."""
         key = (k_steps, sampled, biased, width)
         if key not in self._tick_fns:
-            jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
-                                    self.cfg)
-            from jax import lax
-
-            from gofr_tpu.ops.sampling import sample_batch
-            names = self._tick_operands(sampled, biased)
-            counted = self.paged and bool(self._step_counters)
-            if self.paged:
-                step_kw = {"ragged": True} if self._ragged else {}
-                if counted:
-                    step_kw["counters"] = True
-
-                def step(params, token, pool, table, cache_len, active):
-                    """(logits, pool, new_len, what the step counted: the
-                    per-step sums the scan stacks, () for a module that
-                    declares no STEP_COUNTERS)."""
-                    out = llama.decode_step_paged(
-                        params, cfg, token, pool, table, cache_len, active,
-                        **step_kw)
-                    return out if counted else out + ((),)
+            if key in self._decided:
+                # the steady tick was compiled when it decided the
+                # weights' layouts: that program is the tick
+                self._tick_fns[key] = self._decided.pop(key)
             else:
-                def step(params, token, cache, table, cache_len, active):
-                    return llama.decode_step(
-                        params, cfg, token, cache, cache_len,
-                        window=width) + ((),)
-
-            def tick(*operands):
-                o = dict(zip(names, operands))
-                params, table = o["params"], o.get("table")
-                active = o["active"].astype(bool) if biased else o["active"]
-
-                def one(carry, _):
-                    token, cache, cache_len, *keys = carry
-                    logits, cache, new_len, counts = step(
-                        params, token, cache, table, cache_len, active)
-                    if biased:
-                        logits = logits + o["bias"]
-                    if sampled:
-                        next_token, new_keys = sample_batch(
-                            logits, o["temps"], o["top_ks"], o["top_ps"],
-                            keys[0])
-                    else:
-                        next_token = logits.argmax(axis=-1)
-                    next_token = next_token.astype(token.dtype)
-                    # freeze inactive slots: cache_len stays put and the
-                    # carried token is unchanged (ADVICE r1: no unbounded
-                    # cache_len growth on idle slots)
-                    new_len = jnp.where(active, new_len, cache_len)
-                    next_token = jnp.where(active, next_token, token)
-                    if sampled:
-                        # inactive rows keep their key: emitted-token index
-                        # == number of participating steps, so sequences
-                        # are seed-deterministic under any tick batching
-                        keys = [jnp.where(active[:, None], new_keys,
-                                          keys[0])]
-                    return (next_token, cache, new_len, *keys), (next_token,
-                                                                 counts)
-
-                carry = (o["token"], o["cache"], o["cache_len"]) + (
-                    (o["keys"],) if sampled else ())
-                (_, *state), (tokens, counts) = lax.scan(
-                    one, carry, None, length=k_steps)
-                outs = (tokens, *state)
-                return outs + (counts.sum(axis=0),) if counted else outs
-
-            # the benchmark's trace readers find the decode executables
-            # by this name (jit_decode_k...)
-            tick.__name__ = "decode_k_sampled" if sampled else "decode_k"
-            donated = ("cache", "cache_len") + (("keys",) if sampled else ())
-            self._tick_fns[key] = jax.jit(
-                tick, donate_argnums=tuple(names.index(n) for n in donated))
+                tick, donated = self._tick_program(*key)
+                self._tick_fns[key] = self._jit(tick,
+                                                donate_argnums=donated)
             self._note_compile(self._tick_names(k_steps, biased, width)[0],
                                (k_steps, sampled, width))
         return self._tick_fns[key]
+
+    def _lay_out_weights(self) -> None:
+        """Put each leaf of ``self.params`` into the layout the steady
+        decode tick reads it in, once, before anything is compiled.
+
+        The steady tick (greedy, the widest K, the full width) is
+        compiled on shapes alone with the weights' layouts left to the
+        compiler (``weight_formats``); each leaf whose layout differs is
+        put into the one the tick stated, and every executable built
+        afterwards is compiled for the leaves as they then are: ``jit``
+        takes a committed array's layout as it finds it. The steady tick
+        decides alone; prefill, insert, the shorter and the sampled
+        ticks take what they are given. Shapes, dtypes, names and values
+        stay, and the caller's tree is neither consumed nor donated: a
+        re-laid leaf is a new array, and the engine holds the old one
+        no longer. A mesh engine goes through the same
+        call with its ``NamedSharding``s; the draft's weights are not
+        the steady tick's operands and keep their layouts. Where the
+        tick wants the default layouts (the CPU's compiler does) nothing
+        moves and every program is the one it was. On one device the
+        program that decided is kept and is the steady tick when
+        ``_tick_fn`` is asked for it: one program fewer to trace and
+        load at warm-up, and the one program in which every layout is
+        the compiler's own choice.
+
+        ``stats()["weights"]``: the leaves and bytes moved, and the
+        deciding program's temporaries (``memory_analysis()``)."""
+        jax, jnp = self._jax, self._jnp
+
+        def abstract(leaf):
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype,
+                sharding=self._placement or leaf.sharding)
+
+        key = (self._k_ladder[-1], False, False, self._tick_width(None))
+        width = key[-1]
+        tick, donated = self._tick_program(*key)
+        row = (self.max_slots,)
+        operands = dict(
+            params=jax.tree.map(abstract, self.params),
+            token=jax.ShapeDtypeStruct(row, jnp.int32),
+            cache=jax.tree.map(abstract, self._kv),
+            table=jax.ShapeDtypeStruct(row + (width,), jnp.int32),
+            cache_len=jax.ShapeDtypeStruct(row, jnp.int32),
+            active=jax.ShapeDtypeStruct(row, jnp.bool_))
+        formats, temp_bytes, compiled = weight_formats(
+            tick, tuple(operands[name]
+                        for name in self._tick_operands(False, False)),
+            donated)
+        # on one device that program is the steady tick (_tick_fn). A
+        # mesh's is not: it fixed a sharding for every operand that came
+        # without one, and state a later program returns has another
+        self._decided = {key: compiled} if self._placement else {}
+        leaves, treedef = jax.tree.flatten(self.params)
+        self._weights = {"relaid_leaves": 0, "relaid_bytes": 0,
+                         "temp_bytes": temp_bytes}
+        differ = {i: wanted for i, wanted
+                  in enumerate(treedef.flatten_up_to(formats))
+                  if leaves[i].format.layout != wanted.layout}
+        puts = _relay([leaves[i] for i in differ],
+                      differ.values()) if differ else ()
+        for (i, wanted), put in zip(differ.items(), puts):
+            if put.format.layout == wanted.layout:
+                self._weights["relaid_leaves"] += 1
+                self._weights["relaid_bytes"] += put.nbytes
+                leaves[i] = put
+            else:
+                # it says one layout and may lie in another: not served
+                # from, and the deciding program, which wants it, is not
+                # the tick
+                self._decided = {}
+                if self.logger is not None:
+                    self.logger.warn(
+                        "engine %s weights: a leaf asked for as %s came "
+                        "back as %s and keeps its layout", self.model_name,
+                        wanted.layout, put.format.layout)
+        self.params = treedef.unflatten(leaves)
+
+    def _tick_program(self, k_steps: int, sampled: bool, biased: bool,
+                      width: Optional[int]):
+        """``_tick_fn``'s tick before it is jitted, and the positions of
+        the operands it donates: what ``_lay_out_weights`` compiles with
+        the weights' layouts left open."""
+        jnp, llama, cfg = self._jnp, self._llama, self.cfg
+        from jax import lax
+
+        from gofr_tpu.ops.sampling import sample_batch
+        names = self._tick_operands(sampled, biased)
+        counted = self.paged and bool(self._step_counters)
+        if self.paged:
+            step_kw = {"ragged": True} if self._ragged else {}
+            if counted:
+                step_kw["counters"] = True
+
+            def step(params, token, pool, table, cache_len, active):
+                """(logits, pool, new_len, what the step counted: the
+                per-step sums the scan stacks, () for a module that
+                declares no STEP_COUNTERS)."""
+                out = llama.decode_step_paged(
+                    params, cfg, token, pool, table, cache_len, active,
+                    **step_kw)
+                return out if counted else out + ((),)
+        else:
+            def step(params, token, cache, table, cache_len, active):
+                return llama.decode_step(
+                    params, cfg, token, cache, cache_len,
+                    window=width) + ((),)
+
+        def tick(*operands):
+            o = dict(zip(names, operands))
+            params, table = o["params"], o.get("table")
+            active = o["active"].astype(bool) if biased else o["active"]
+
+            def one(carry, _):
+                token, cache, cache_len, *keys = carry
+                logits, cache, new_len, counts = step(
+                    params, token, cache, table, cache_len, active)
+                if biased:
+                    logits = logits + o["bias"]
+                if sampled:
+                    next_token, new_keys = sample_batch(
+                        logits, o["temps"], o["top_ks"], o["top_ps"],
+                        keys[0])
+                else:
+                    next_token = logits.argmax(axis=-1)
+                next_token = next_token.astype(token.dtype)
+                # freeze inactive slots: cache_len stays put and the
+                # carried token is unchanged (ADVICE r1: no unbounded
+                # cache_len growth on idle slots)
+                new_len = jnp.where(active, new_len, cache_len)
+                next_token = jnp.where(active, next_token, token)
+                if sampled:
+                    # inactive rows keep their key: emitted-token index
+                    # == number of participating steps, so sequences
+                    # are seed-deterministic under any tick batching
+                    keys = [jnp.where(active[:, None], new_keys,
+                                      keys[0])]
+                return (next_token, cache, new_len, *keys), (next_token,
+                                                             counts)
+
+            carry = (o["token"], o["cache"], o["cache_len"]) + (
+                (o["keys"],) if sampled else ())
+            (_, *state), (tokens, counts) = lax.scan(
+                one, carry, None, length=k_steps)
+            outs = (tokens, *state)
+            return outs + (counts.sum(axis=0),) if counted else outs
+
+        # the benchmark's trace readers find the decode executables
+        # by this name (jit_decode_k...)
+        tick.__name__ = "decode_k_sampled" if sampled else "decode_k"
+        donated = ("cache", "cache_len") + (("keys",) if sampled else ())
+        return tick, tuple(names.index(n) for n in donated)
 
     def _run_tick(self, k: int, sampled: bool, width: Optional[int], active,
                   bias=None, state=None):
@@ -1273,7 +1436,6 @@ class GenerationEngine:
         readers (suffix prefills) before the aliased write."""
         fn = self._insert_paged_fns.get((nb, lb, plen))
         if fn is None:
-            jax = self._jax
             page = self.kv_page
             n_pages = lb // page
 
@@ -1299,7 +1461,7 @@ class GenerationEngine:
                 return (pool, cache_len, last_token, temps,
                         top_ks, top_ps, sample_keys)
 
-            fn = jax.jit(insert, donate_argnums=(0, 6, 7, 8, 9, 10, 11))
+            fn = self._jit(insert, donate_argnums=(0, 6, 7, 8, 9, 10, 11))
             self._insert_paged_fns[(nb, lb, plen)] = fn
             self._note_compile("insert_paged", (nb, lb, plen))
         return fn
@@ -1314,7 +1476,6 @@ class GenerationEngine:
         ``prefill_bucket_tokens`` at zero for migrated requests."""
         fn = self._adopt_fns.get(n_pages)
         if fn is None:
-            jax = self._jax
 
             def adopt(pool, pages, ids, slot, length, first, cache_len,
                       last_token, temps, top_ks, top_ps, sample_keys,
@@ -1330,7 +1491,7 @@ class GenerationEngine:
                 return (pool, cache_len, last_token, temps, top_ks,
                         top_ps, sample_keys)
 
-            fn = jax.jit(adopt, donate_argnums=(0, 6, 7, 8, 9, 10, 11))
+            fn = self._jit(adopt, donate_argnums=(0, 6, 7, 8, 9, 10, 11))
             self._adopt_fns[n_pages] = fn
             self._note_compile("adopt", n_pages)
         return fn
@@ -1344,7 +1505,7 @@ class GenerationEngine:
         draft's covered length either way."""
         fn = self._draft_prefill_fns.get((nb, lb))
         if fn is None:
-            jax, llama, dcfg = self._jax, self._llama, self.draft_cfg
+            llama, dcfg = self._llama, self.draft_cfg
 
             def draft_prefill(dparams, tokens, lengths):
                 small = llama.init_cache(dcfg, nb, lb)
@@ -1352,7 +1513,7 @@ class GenerationEngine:
                                             lengths=lengths)
                 return small
 
-            fn = jax.jit(draft_prefill)
+            fn = self._jit(draft_prefill)
             self._draft_prefill_fns[(nb, lb)] = fn
             self._note_compile("draft_prefill", (nb, lb))
         return fn
@@ -1363,13 +1524,12 @@ class GenerationEngine:
         owned by the target insert."""
         fn = self._draft_insert_fns.get((nb, lb))
         if fn is None:
-            jax = self._jax
 
             def insert(dcache, small, slots):
                 return {name: dcache[name].at[:, slots, :lb].set(
                     small[name], mode="drop") for name in dcache}
 
-            fn = jax.jit(insert, donate_argnums=(0,))
+            fn = self._jit(insert, donate_argnums=(0,))
             self._draft_insert_fns[(nb, lb)] = fn
             self._note_compile("draft_insert", (nb, lb))
         return fn
@@ -1475,7 +1635,7 @@ class GenerationEngine:
                         new_keys)
 
             # cache, dcache, cache_len, keys: the table shifts the last two
-            self._spec_fns[(g, width)] = jax.jit(
+            self._spec_fns[(g, width)] = self._jit(
                 spec_tick, donate_argnums=(3, 4, 5 + paged, 10 + paged))
             self._note_compile("spec_paged" if paged else "spec",
                                (g, width))
@@ -1712,8 +1872,11 @@ class GenerationEngine:
                     if nb > self._group_rows(lb):
                         continue
                     dev = self._padding_group(nb, lb)
-                    first, small, keys = self._run_prefill(nb, lb, dev)
-                    self._run_insert(nb, lb, 0, dev, first, small, keys)
+                    # no name holds the group's prefill once it is
+                    # inserted: the next rung's program loads beside
+                    # nothing of this one (2 GB at 16 x 1024 of a 7B)
+                    self._run_insert(nb, lb, 0, dev,
+                                     *self._run_prefill(nb, lb, dev))
                     if self.spec:
                         dsmall = self._draft_prefill_fn(nb, lb)(
                             self.draft_params, dev["padded"], dev["lengths"])
@@ -2875,6 +3038,9 @@ class GenerationEngine:
         # engine-side compile ledger (ISSUE 19): serving-class compiles
         # are the recompile-storm signal the auto-tuner guard reads
         out["compiles"] = dict(self._compiles_by_class)
+        # what _lay_out_weights moved at start, and the steady tick's
+        # temporaries
+        out["weights"] = dict(self._weights)
         if self._constrained_requests or len(self.grammar_cache):
             out["constrained"] = {
                 "requests": self._constrained_requests,

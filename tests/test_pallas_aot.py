@@ -20,7 +20,12 @@ read the optimised HLO: the pool must reach the kernel as the layer
 scan's carry itself, with no per-layer plane and no copy of the pool
 materialised (PR 26: two such planes cost 8 ms of a 35 ms decode step),
 and the layer body must hold the kernel exactly once (the benchmark
-counts decode steps as kernel calls over layers).
+counts decode steps as kernel calls over layers). The same tick at
+``mistral7b.batch``'s widths, given the weights in the layouts the
+engine puts them into at start (``generate.weight_formats``), must not
+re-lay a stacked weight when it is called (PR 30: three whole stacks
+were transposed once a tick, 0.74 ms of a 13.3 ms step), and the cell's
+largest prefill must take the same leaves as they are.
 """
 
 import functools
@@ -36,6 +41,7 @@ from gofr_tpu.ops.pallas import (flash_attention, flash_tileable,
                                  ragged_paged_verify_attention,
                                  ragged_tileable)
 from gofr_tpu.ops.pallas.ragged_paged_attention import walk_sizes
+from gofr_tpu.tpu.generate import weight_formats
 
 HEAD_DIM, PAGE, SLOTS, PAGES_PER_SLOT, NUM_PAGES = 128, 32, 2, 2, 8
 GEOMETRIES = [(32, 32), (32, 8)]
@@ -260,3 +266,88 @@ def test_ragged_step_reads_the_stacked_pool_in_place(v5e, case):
     assert not planes, planes[:2]
     copies = results(pool_shape, r"copy\(")
     assert not copies, copies[:2]
+
+
+# -- the weights' layouts (GenerationEngine._lay_out_weights) ---------------------
+
+@functools.lru_cache(maxsize=None)
+def _cell_engine(sharding):
+    """``mistral7b.batch``'s engine on shapes alone: Mistral-7B widths,
+    all 32 layers, int8 weights, 16 slots, a table of 64 columns over a
+    pool of about a thousand pages. Returns (cfg, the weights, the
+    steady tick's other operands, the formats ``weight_formats`` picks
+    for the weights, the deciding compile's temporaries)."""
+    cfg = llama.config("llama3-8b", vocab_size=32768, max_seq_len=2048,
+                       rope_theta=1e6)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sharding), tree)
+
+    kv = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 1100, PAGE, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    params = on_chip(jax.eval_shape(lambda: llama.init_int8(cfg)))
+    rest = on_chip((
+        jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.int32), {"k": kv, "v": kv},
+        jax.ShapeDtypeStruct((CELL_SLOTS, CELL_COLUMNS), jnp.int32),
+        jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.int32),
+        jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.bool_)))
+    formats, temp_bytes, _ = weight_formats(
+        lambda params, *rest: _decode_tick(params, cfg, *rest),
+        (params,) + rest, donate_argnums=(2, 4))
+    return cfg, params, rest, formats, temp_bytes
+
+
+def _copied_stacks(hlo, cfg):
+    """``copy`` instructions whose result is a whole stacked int8
+    weight: ``s8[n_layers, ., .]``."""
+    return re.findall(rf"^.*= s8\[{cfg.n_layers},\d+,\d+\]\S* copy\(.*$",
+                      hlo, re.M)
+
+
+def test_steady_tick_reads_the_stacked_weights_where_they_lie(v5e):
+    """An executable's parameter layouts are fixed when it is compiled,
+    and the layer loop wants ``wq`` / ``wk`` / ``wv`` with the input
+    dimension minor: handed the default layouts it transposes all 32
+    layers of all three at every call (805 MB in, 805 MB out, 811 MB of
+    temporaries). Handed the formats the engine's helper picks, the
+    steady tick holds no copy of a whole stacked weight and a few MB of
+    temporaries."""
+    cfg, params, rest, formats, temp_bytes = _cell_engine(v5e)
+    assert temp_bytes < 64 << 20
+    moved = [fmt for leaf, fmt in zip(
+        jax.tree.leaves(params),
+        jax.tree.structure(params).flatten_up_to(formats))
+        if fmt.layout.major_to_minor != tuple(range(len(leaf.shape)))]
+    assert moved, "the compiler asks for the default layouts"
+    compiled = jax.jit(
+        lambda params, *rest: _decode_tick(params, cfg, *rest),
+        in_shardings=(formats,) + (None,) * len(rest),
+        donate_argnums=(2, 4)).lower(params, *rest).compile()
+    copies = _copied_stacks(compiled.as_text(), cfg)
+    assert not copies, copies[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_prefill_takes_the_ticks_weight_layouts_as_they_are(v5e):
+    """The steady tick decides the layouts alone and every other
+    program takes what it is given: the cell's largest admission group
+    (16 x 1024), compiled for the same leaves, copies no int8 tensor of
+    any size."""
+    cfg, params, _, formats, _ = _cell_engine(v5e)
+    rows, bucket = CELL_SLOTS, 1024
+
+    def prefill(params, tokens, lengths):
+        logits, small, _ = llama.prefill(
+            params, cfg, tokens, llama.init_cache(cfg, rows, bucket),
+            lengths=lengths)
+        return logits.argmax(axis=-1), small
+
+    compiled = jax.jit(prefill, in_shardings=(formats, None, None)).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, bucket), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e)).compile()
+    copies = re.findall(r"^.*= s8\[[\d,]+\]\S* copy\(.*$",
+                        compiled.as_text(), re.M)
+    assert not copies, copies[:3]
