@@ -1,0 +1,154 @@
+"""The sparse expert layer, shared by the model families that route
+(``models/mla_moe.py``, ``models/swa_moe.py``): router, this chip's share
+of the routed experts in two forms, the shared experts, and the step
+counters. A family's config object is read for ``scoring`` (``sigmoid``
+or ``softmax``), ``top_k``, ``norm_topk_prob``, ``routed_scale``,
+``n_routed_experts`` (the router's width), ``n_held_experts`` and
+``expert_rank`` (which share lives on this chip), and ``shared_scale``
+(what the shared experts' sum is multiplied by: 1, or ``1 /
+n_shared_experts`` where a family averages them).
+
+``s = score(W_g h)`` over all ``n_routed_experts``, in float32; ``T`` =
+the ``top_k`` largest; ``w_e = routed_scale * s_e / sum_{j in T} s_j``;
+``y = shared_scale * Shared(h) + sum_{e in T and held} w_e Expert_e(h)``.
+The shared experts are held as one SwiGLU of ``n_shared_experts`` times
+the expert width: a sum of SwiGLUs over disjoint columns is one SwiGLU
+over all of them. This chip holds experts ``expert_rank *
+n_held_experts ..`` (expert parallelism: one chip's share of a layer).
+What absent experts would add is left out; nothing here stands in for
+the other chips or for their exchange. No capacity, no dropped token.
+A prefill sorts the held (token, expert) pairs by expert and runs
+blocks of one expert's rows, so its work follows the pairs; a decode
+step has one token a slot, is bound by the experts' bytes and not by
+rows, and runs every held expert over all rows in one batched product
+(rows an expert was not chosen for weigh zero).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# what a decode step's expert layers count, summed by the engine over a
+# tick's steps and its active rows (stats()["moe"][...],
+# app_tpu_step_counter_total)
+STEP_COUNTERS = ("moe.routed_pairs", "moe.held_pairs", "moe.experts_hit",
+                 "moe.hot_expert_pairs", "moe.layer_steps")
+
+
+def _swiglu(w, x):
+    gate = jax.nn.silu((x @ w["w_gate"]).astype(jnp.float32))
+    up = (x @ w["w_up"]).astype(jnp.float32)
+    return (gate * up).astype(x.dtype) @ w["w_down"]
+
+
+def route(cfg, router, h):
+    """h (T, D) -> (ids (T, top_k) int32 over all routed experts, weights
+    (T, top_k) float32). Scores in float32 whatever h's dtype."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    top, ids = lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top = top / top.sum(axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), top * cfg.routed_scale
+
+
+def _held(cfg, ids, valid):
+    """Global expert ids (T, K) -> (index among the held experts, is the
+    pair this chip's). ``valid`` (T,) bool drops rows that carry no
+    token (padding, frozen slots)."""
+    local = ids - cfg.expert_rank * cfg.n_held_experts
+    held = (local >= 0) & (local < cfg.n_held_experts)
+    if valid is not None:
+        held = held & valid[:, None]
+    return local, held
+
+
+def experts_batched(cfg, experts, h, ids, weights, valid=None):
+    """Every held expert over every row in one batched product; a row
+    weighs zero for an expert it did not choose. For a decode step: few
+    rows, and the cost is the experts' bytes. Returns (y (T, D) float32,
+    pairs routed to each held expert (E,) int32)."""
+    local, held = _held(cfg, ids, valid)
+    onehot = (local[..., None] == jnp.arange(cfg.n_held_experts)) \
+        & held[..., None]                                   # (T, K, E)
+    combine = (onehot * weights[..., None]).sum(axis=1)     # (T, E) f32
+    gate = jax.nn.silu(jnp.einsum(
+        "td,edf->etf", h, experts["w_gate"]).astype(jnp.float32))
+    up = jnp.einsum("td,edf->etf", h, experts["w_up"]).astype(jnp.float32)
+    out = jnp.einsum("etf,efd->etd", (gate * up).astype(h.dtype),
+                     experts["w_down"], preferred_element_type=jnp.float32)
+    y = jnp.einsum("te,etd->td", combine, out)
+    return y, onehot.sum(axis=(0, 1)).astype(jnp.int32)
+
+
+def _block_rows(cfg, tokens: int) -> int:
+    """Rows a block of the grouped product holds: the pairs one expert
+    expects under even routing, as a power of two in 8..256; or what
+    the family says (``cfg.expert_block_rows(tokens)``), where it has
+    been measured."""
+    own = getattr(cfg, "expert_block_rows", None)
+    if own is not None:
+        return own(tokens)
+    expect = max(1, tokens * cfg.top_k // cfg.n_routed_experts)
+    return min(256, max(8, 1 << (expect - 1).bit_length()))
+
+
+def experts_grouped(cfg, experts, h, ids, weights, valid=None):
+    """The held experts' part with work that follows the routed pairs.
+    The held (token, expert) pairs are sorted by expert; each expert's
+    run is cut into blocks of ``_block_rows`` rows, and a loop over the
+    blocks *that exist* gathers a block's rows, runs its one expert and
+    adds the weighted result to its tokens. Nothing is sized by a
+    capacity: however the router spreads the tokens, each pair is
+    computed. Returns (y (T, D) float32, pairs per held expert (E,))."""
+    tokens, k = ids.shape
+    n_held, block = cfg.n_held_experts, _block_rows(cfg, tokens)
+    local, held = _held(cfg, ids, valid)
+    flat = jnp.where(held, local, n_held).reshape(-1)       # absent: last
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    counts = (flat[:, None] == jnp.arange(n_held)).sum(0).astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts                    # in sorted order
+    blocks = -(-counts // block)
+    block_ends = jnp.cumsum(blocks)
+    flat_w = weights.reshape(-1)
+
+    def one_block(i, y):
+        e = jnp.searchsorted(block_ends, i, side="right").astype(jnp.int32)
+        first = starts[e] + (i - (block_ends[e] - blocks[e])) * block
+        rows = first + jnp.arange(block, dtype=jnp.int32)
+        live = rows < starts[e] + counts[e]
+        pair = order[jnp.minimum(rows, tokens * k - 1)]
+        token = pair // k
+        w = {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
+             for name, leaf in experts.items()}
+        out = _swiglu(w, h[token]).astype(jnp.float32)
+        scale = jnp.where(live, flat_w[pair], 0.0)
+        return y.at[token].add(out * scale[:, None])
+
+    y = lax.fori_loop(0, block_ends[-1], one_block,
+                      jnp.zeros((tokens, h.shape[-1]), jnp.float32))
+    return y, counts
+
+
+def moe_ffn(cfg, layer, h, valid=None, grouped=False):
+    """The expert layer on h (T, D): shared experts + this chip's routed
+    part. Returns (y (T, D) in h's dtype, the step counters (5,) int32
+    in ``STEP_COUNTERS`` order, over the ``valid`` rows, the experts
+    chosen (T, top_k))."""
+    ids, weights = route(cfg, layer["router"], h)
+    run = experts_grouped if grouped else experts_batched
+    y, per_expert = run(cfg, layer["experts"], h, ids, weights, valid)
+    if "shared" in layer:
+        shared = _swiglu(layer["shared"], h).astype(jnp.float32)
+        scale = getattr(cfg, "shared_scale", 1.0)
+        y = y + (shared if scale == 1.0 else shared * scale)
+    rows = (jnp.int32(h.shape[0]) if valid is None
+            else valid.sum().astype(jnp.int32))
+    counters = jnp.stack([rows * cfg.top_k, per_expert.sum(),
+                          (per_expert > 0).sum().astype(jnp.int32),
+                          per_expert.max(), jnp.int32(1)])
+    return y.astype(h.dtype), counters, ids

@@ -7,6 +7,7 @@ north star (BASELINE.json); the Go reference has no compute ops at all
 
 from gofr_tpu.ops.attention import (
     attention,
+    banded_attention,
     causal_mask,
     decode_attention,
     decode_attention_cached,
@@ -21,7 +22,7 @@ from gofr_tpu.ops.norms import layer_norm, rms_norm
 from gofr_tpu.ops.rotary import apply_rope, rope_table
 
 __all__ = [
-    "attention", "causal_mask", "decode_attention", "prefill_attention",
+    "attention", "banded_attention", "causal_mask", "decode_attention", "prefill_attention",
     "prefix_prefill_attention", "gather_kv_pages", "paged_decode_attention",
     "verify_attention", "paged_verify_attention",
     "layer_norm", "rms_norm", "apply_rope", "rope_table",

@@ -74,6 +74,84 @@ def prefill_attention(q, k, v) -> jnp.ndarray:
     return attention(q, k, v, mask)
 
 
+def banded_attention(q, k, v, window=None, block: int = 512) -> jnp.ndarray:
+    """Causal self-attention over whole prompts in blocks, with an
+    optional sliding window: query ``t`` attends keys ``s`` with ``t -
+    window < s <= t`` (``window`` None: every ``s <= t``).
+
+    q: (B, S, Hq, D); k, v: (B, S, Hkv, D). The (S, S) scores never
+    exist: a prompt goes one block of ``block`` query rows after the
+    other, each against the key blocks its band touches and no others
+    (a loop whose bounds follow the band), with the softmax's maximum
+    and sum carried in float32 and rescaled as blocks arrive. So the
+    work is the band's, and the temporaries are one ``(Hq, block,
+    block)`` float32 tile whatever S: 128 heads over 8192 positions are
+    a 34 GB score tensor if materialised. Prompts go one after the
+    other too (``lax.map``): a tile a prompt at a time. Plain XLA; S
+    must be a whole number of blocks (``block`` clamps to S)."""
+    from jax import lax as _lax
+
+    batch, s_len, q_heads, head_dim = q.shape
+    kv_heads = k.shape[2]
+    group = q_heads // kv_heads
+    blk = min(block, s_len)
+    if s_len % blk:
+        raise ValueError(f"banded_attention: S={s_len} does not split "
+                         f"into {blk}-row blocks")
+    n_blocks = s_len // blk
+    scale = head_dim ** -0.5
+    # (B, blocks, Hkv, G, blk, D) and (B, Hkv, S, D)
+    qh = q.reshape(batch, n_blocks, blk, kv_heads, group, head_dim) \
+        .transpose(0, 1, 3, 4, 2, 5)
+    kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    rows = jnp.arange(blk)
+
+    def one_prompt(args):
+        q_row, k_row, v_row = args
+
+        def one_block(i, q_blk):
+            q_pos = i * blk + rows[:, None]
+
+            def fold(j, carry):
+                m, l, acc = carry
+                k_blk = _lax.dynamic_slice_in_dim(k_row, j * blk, blk, 1)
+                v_blk = _lax.dynamic_slice_in_dim(v_row, j * blk, blk, 1)
+                scores = jnp.einsum(
+                    "kgqd,ktd->kgqt", q_blk, k_blk,
+                    preferred_element_type=jnp.float32) * scale
+                k_pos = j * blk + rows[None, :]
+                ok = k_pos <= q_pos
+                if window is not None:
+                    ok = ok & (k_pos > q_pos - window)
+                scores = jnp.where(ok, scores, _NEG_INF)
+                # a row with no key yet carries m = _NEG_INF and sums
+                # garbage; the first real key rescales it by exp(-1e30)
+                # = 0, and the diagonal block gives every row one
+                m_new = jnp.maximum(m, scores.max(axis=-1))
+                p = jnp.exp(scores - m_new[..., None])
+                corr = jnp.exp(m - m_new)
+                acc = acc * corr[..., None] + jnp.einsum(
+                    "kgqt,ktd->kgqd", p.astype(v.dtype), v_blk,
+                    preferred_element_type=jnp.float32)
+                return m_new, l * corr + p.sum(axis=-1), acc
+
+            first = 0 if window is None else \
+                jnp.maximum(i * blk - window + 1, 0) // blk
+            stat = (kv_heads, group, blk)
+            m, l, acc = _lax.fori_loop(
+                first, i + 1, fold,
+                (jnp.full(stat, _NEG_INF, jnp.float32),
+                 jnp.zeros(stat, jnp.float32),
+                 jnp.zeros(stat + (head_dim,), jnp.float32)))
+            return (acc / l[..., None]).astype(q.dtype)
+
+        return _lax.map(lambda xs: one_block(*xs),
+                        (jnp.arange(n_blocks), q_row))
+
+    out = _lax.map(one_prompt, (qh, kh, vh))   # (B, blocks, Hkv, G, blk, D)
+    return out.transpose(0, 1, 4, 2, 3, 5).reshape(q.shape)
+
+
 def prefix_prefill_attention(q, k, v, prefix_len: int) -> jnp.ndarray:
     """Causal attention for a suffix prefill over cached-prefix + suffix
     K/V (the prefix-KV-reuse path, tpu/prefix_cache).
@@ -171,7 +249,7 @@ def check_sentinel_masked(page_table, cache_len, page: int, sentinel: int,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, k_new, v_new,
                            cache_len, k_scale_pages=None,
-                           v_scale_pages=None) -> jnp.ndarray:
+                           v_scale_pages=None, start=None) -> jnp.ndarray:
     """Ragged paged decode attention (pure-jnp gather formulation).
 
     The unified-paged-KV decode op (ISSUE 6, after "Ragged Paged
@@ -190,6 +268,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, k_new, v_new,
     as on the dense path (the caller scatters into the pool after);
     cache_len: (B,) valid tokens excluding the current one. int8 pools
     pass ``k_scale_pages``/``v_scale_pages`` (num_pages, page, Hkv).
+    ``start`` (B,), a sliding-window layer's lower bound: positions
+    before it are masked (their table columns may hold the sentinel:
+    the pages went back to the pool).
 
     A fused Pallas variant (gather + flash inside one kernel, no
     materialized (B, P*page) view) is the known next step; this
@@ -203,7 +284,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, k_new, v_new,
                if v_scale_pages is not None else None)
     return decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
                                    cache_len, k_scale=k_scale,
-                                   v_scale=v_scale)
+                                   v_scale=v_scale, start=start)
 
 
 def verify_attention(q, k_cache, v_cache, k_new, v_new,
@@ -291,7 +372,7 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, k_new, v_new,
 
 def decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
                             cache_len, k_scale=None,
-                            v_scale=None) -> jnp.ndarray:
+                            v_scale=None, start=None) -> jnp.ndarray:
     """Decode attention over (prior cache entries + the current token's
     K/V), *without* requiring the scatter first.
 
@@ -304,6 +385,9 @@ def decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
 
     q: (B, 1, Hq, D); caches: (B, Tmax, Hkv, D); k_new/v_new: (B, Hkv, D);
     cache_len: (B,) — valid entries *excluding* the current token.
+    ``start`` (B,) or None: the first position attended (a sliding
+    window's lower bound, ``cache_len - window + 1`` clipped at 0);
+    entries before it are masked like those past ``cache_len``.
     Returns (B, 1, Hq, D).
 
     int8 KV cache (ops/quant.quantize_kv): pass ``k_cache``/``v_cache`` as
@@ -334,8 +418,10 @@ def decode_attention_cached(q, k_cache, v_cache, k_new, v_new,
                    q.dtype) * scale
     if k_scale is not None:
         scores = scores * k_scale.transpose(0, 2, 1)[:, :, None, :]
-    valid = jnp.arange(k_cache.shape[1])[None, None, None, :] \
-        < cache_len[:, None, None, None]
+    at = jnp.arange(k_cache.shape[1])[None, None, None, :]
+    valid = at < cache_len[:, None, None, None]
+    if start is not None:
+        valid = valid & (at >= start[:, None, None, None])
     scores = jnp.where(valid, scores, _NEG_INF)
     score_new = _snap(jnp.einsum("bkgd,bkd->bkg", qg,
                                  k_new.astype(jnp.float32)),

@@ -10,6 +10,8 @@ elementwise/matmul ops.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 
@@ -22,13 +24,17 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     return (normed * weight.astype(jnp.float32)).astype(dtype)
 
 
-def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray,
+               bias: Optional[jnp.ndarray] = None,
                eps: float = 1e-12) -> jnp.ndarray:
-    """LayerNorm (BERT-family). fp32 accumulation, cast back to x.dtype."""
+    """LayerNorm (BERT-family; without ``bias`` Cohere's). fp32
+    accumulation, cast back to x.dtype."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
     normed = (x32 - mean) * (1.0 / jnp.sqrt(var + eps))
-    out = normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)
+    out = normed * weight.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return out.astype(dtype)

@@ -176,11 +176,18 @@ def walk_sizes(page: int, kv_heads: int, head_dim: int, rows_all: int,
     return block_pages, ring_blocks, keep
 
 
-def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   kn_ref, vn_ref, lim_ref, new_ok_ref, *rest,
+def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
                    block_pages: int, ring_blocks: int, keep_scores: bool,
-                   int8: bool, sm_scale: float):
+                   int8: bool, sm_scale: float, bounded: bool):
     """One slot's program: walk its live pages, and nothing else.
+
+    ``bounded`` (a sliding-window layer): a fourth scalar-prefetch
+    operand gives each slot's first attended position. The walk starts
+    at the block that holds it, pages wholly before it are not copied
+    (their table entries may be the sentinel: the pages went back to the
+    pool), and positions before it inside its page are masked, by the
+    same comparison with ``lim_ref`` that masks the positions past the
+    length. Without it the program is the one it always was.
 
     ``k_hbm`` / ``v_hbm`` are the stacked pool leaves, left in HBM; the
     body copies the live pages of this layer into a VMEM ring itself,
@@ -209,6 +216,9 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if bounded:
+        start_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, kn_ref, vn_ref, lim_ref, new_ok_ref, *rest = rest
     if int8:
         ks_hbm, vs_hbm, *rest = rest
     o_ref, ring, sems, *rest = rest
@@ -234,14 +244,24 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
         return _round_to(x, cdt)
 
     def walk(slot_b):
+        """(pages up to the length, first live page, first live block,
+        live blocks, items) of a slot's walk."""
         pages = jnp.minimum(lax.div(len_ref[slot_b] + page - 1, page),
                             table_width)
         blocks = lax.div(pages + block_pages - 1, block_pages)
-        return pages, blocks, blocks * (2 if keep_scores else 3)
+        if not bounded:
+            return pages, 0, 0, blocks, blocks * (2 if keep_scores else 3)
+        first_page = lax.div(
+            jnp.minimum(start_ref[slot_b], len_ref[slot_b]), page)
+        first_block = lax.div(first_page, block_pages)
+        live = blocks - first_block
+        return (pages, first_page, first_block, live,
+                live * (2 if keep_scores else 3))
 
-    def for_copies(slot_b, item, pages, blocks, do):
+    def for_copies(slot_b, item, walked, do):
         """``do`` each page copy of ``item`` of slot ``slot_b``: live
         pages only, so a sentinel id is never read, let alone fetched."""
+        pages, first_page, first_block, blocks, _ = walked
         in_phase0 = item < blocks
         if keep_scores:
             is_k, j = in_phase0, jnp.where(in_phase0, item, item - blocks)
@@ -250,7 +270,7 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
             is_k = jnp.logical_or(in_phase0, lax.rem(turn, 2) == 0)
             j = jnp.where(in_phase0, item, lax.div(turn, 2))
         at = lax.rem(item, ring_blocks)
-        first = j * block_pages
+        first = (first_block + j if bounded else j) * block_pages
         sources = [(k_hbm, ks_hbm if int8 else None),
                    (v_hbm, vs_hbm if int8 else None)]
         for wants_k, (pages_hbm, scales_hbm) in zip((True, False), sources):
@@ -268,18 +288,18 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
                             sems.at[at]))
                     return carry
 
-                lax.fori_loop(0, jnp.minimum(pages - first, block_pages),
-                              one, 0)
+                lax.fori_loop(
+                    jnp.maximum(first_page - first, 0) if bounded else 0,
+                    jnp.minimum(pages - first, block_pages), one, 0)
 
     def start_first_items(slot_b):
-        pages, blocks, items = walk(slot_b)
+        walked = walk(slot_b)
 
         def one(item, carry):
-            for_copies(slot_b, item, pages, blocks,
-                       lambda copy: copy.start())
+            for_copies(slot_b, item, walked, lambda copy: copy.start())
             return carry
 
-        lax.fori_loop(0, jnp.minimum(items, ring_blocks - 1), one, 0)
+        lax.fori_loop(0, jnp.minimum(walked[-1], ring_blocks - 1), one, 0)
 
     @pl.when(b == 0)
     def _first_program():
@@ -292,7 +312,8 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
             scale_ring[...] = jnp.zeros_like(scale_ring)
         start_first_items(b)
 
-    pages, blocks, items = walk(b)
+    walked = walk(b)
+    _, _, first_block, blocks, items = walked
 
     def arrive(item):
         """Keep the ring full, then wait for ``item``; its ring place."""
@@ -300,10 +321,14 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when(ahead < items)
         def _():
-            for_copies(b, ahead, pages, blocks, lambda copy: copy.start())
+            for_copies(b, ahead, walked, lambda copy: copy.start())
 
-        for_copies(b, item, pages, blocks, lambda copy: copy.wait())
+        for_copies(b, item, walked, lambda copy: copy.wait())
         return lax.rem(item, ring_blocks)
+
+    def table_block(j):
+        """The table's block of the walk's ``j``-th live block."""
+        return first_block + j if bounded else j
 
     def flat(at, dtype):
         # (block_pages, page, Hkv, D) -> (width, D): free in float32,
@@ -333,8 +358,11 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
             # exact through the rounded dot, and the per-vector scale
             # folds into f32 AFTER — never a converted cache copy
             s = s * scale_row(at)
-        return jnp.where(lim_ref[...] < length - j * block_tokens, s,
-                         _NEG_INF)
+        ok = lim_ref[...] < length - j * block_tokens
+        if bounded:
+            ok = jnp.logical_and(
+                ok, lim_ref[...] >= start_ref[b] - j * block_tokens)
+        return jnp.where(ok, s, _NEG_INF)
 
     def fold_stats(stats, scores):
         m_prev, l_prev = stats
@@ -347,9 +375,9 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
     # -- phase 0: softmax statistics over the live pages ------------------
     def stats_step(j, stats):
-        s = block_scores(arrive(j), j)
+        s = block_scores(arrive(j), table_block(j))
         if keep_scores:
-            score_ref[:, score_cols(j)] = s
+            score_ref[:, score_cols(table_block(j))] = s
         return fold_stats(stats, s)
 
     stats = lax.fori_loop(
@@ -366,10 +394,10 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
     # -- phase 1: oracle-identical probabilities, P.V accumulation --------
     def value_step(j, acc):
         if keep_scores:
-            s = score_ref[:, score_cols(j)]
+            s = score_ref[:, score_cols(table_block(j))]
             at = arrive(blocks + j)
         else:
-            s = block_scores(arrive(blocks + 2 * j), j)
+            s = block_scores(arrive(blocks + 2 * j), table_block(j))
             at = arrive(blocks + 2 * j + 1)
         p = jnp.exp(s - m_fin) / l_fin
         if int8:
@@ -396,7 +424,8 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
 
 def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
-                   cache_len, layer, *scale_pages, interpret: bool):
+                   cache_len, layer, *scale_pages, interpret: bool,
+                   start=None):
     """``k_pages`` / ``v_pages`` are the STACKED pool leaves
     ``(L, num_pages, page, Hkv, D)`` and ``layer`` (int32, shape (1,),
     traced) says which layer to read; ``scale_pages`` is
@@ -454,9 +483,13 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     def whole(b, *_):
         return (0, 0)
 
+    bounded = start is not None
     kernel = functools.partial(
         _ragged_kernel, block_pages=block_pages, ring_blocks=ring_blocks,
-        keep_scores=keep_scores, int8=int8, sm_scale=head_dim ** -0.5)
+        keep_scores=keep_scores, int8=int8, sm_scale=head_dim ** -0.5,
+        bounded=bounded)
+    prefetch = (table, lens, layer) + (
+        (start.astype(jnp.int32),) if bounded else ())
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
         pl.BlockSpec((1, rows_all, head_dim), slot_block),
@@ -484,7 +517,7 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         scratch.append(pltpu.VMEM((ring_blocks, block_pages, 1, cols),
                                   jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(batch,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows_all, head_dim), slot_block),
@@ -502,7 +535,7 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         out_shape=jax.ShapeDtypeStruct(q_hm.shape, q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(table, lens, layer, *operands)
+    )(*prefetch, *operands)
     return out.reshape(batch, kv_heads, g_len, group, head_dim) \
         .transpose(0, 2, 1, 3, 4).reshape(q.shape)
 
@@ -520,8 +553,8 @@ def _layer(layer):
 def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
                                   v_new, cache_len, layer,
                                   k_scale_pages=None, v_scale_pages=None,
-                                  interpret: Optional[bool] = None
-                                  ) -> jnp.ndarray:
+                                  interpret: Optional[bool] = None,
+                                  start=None) -> jnp.ndarray:
     """Kernel counterpart of ops.attention.paged_decode_attention, over
     the pool as the engine holds it. q (B,1,Hq,D); k_pages/v_pages the
     stacked pool leaves (L,num_pages,page,Hkv,D) and ``layer`` the int32
@@ -530,12 +563,22 @@ def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
     page_table (B,P) int32 with ``num_pages`` the unallocated sentinel;
     k_new/v_new (B,Hkv,D); cache_len (B,) valid tokens excluding the
     current one; int8 pools pass the (L,num_pages,page,Hkv) scale
-    planes. ``interpret=None`` follows the lowering target
+    planes. ``start`` (B,) int32 or None: a sliding-window layer's first
+    attended position (``max(cache_len - window + 1, 0)``); the walk
+    begins at its page and masks what lies before it in that page.
+    ``interpret=None`` follows the lowering target
     (ops/pallas/select). Returns (B,1,Hq,D)."""
-    return lower_for_target(
-        _pallas_ragged, interpret, q, k_pages, v_pages, page_table,
-        k_new[:, None], v_new[:, None], cache_len, _layer(layer),
-        *_scales(k_scale_pages, v_scale_pages))
+    kernel = _pallas_ragged
+    operands = (q, k_pages, v_pages, page_table, k_new[:, None],
+                v_new[:, None], cache_len, _layer(layer),
+                *_scales(k_scale_pages, v_scale_pages))
+    if start is not None:
+        # the bound rides as the last operand through platform_dependent
+        def kernel(*ops, interpret):
+            return _pallas_ragged(*ops[:-1], interpret=interpret,
+                                  start=ops[-1])
+        operands += (start,)
+    return lower_for_target(kernel, interpret, *operands)
 
 
 def ragged_paged_verify_attention(q, k_pages, v_pages, page_table, k_new,

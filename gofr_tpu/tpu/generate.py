@@ -65,6 +65,7 @@ from gofr_tpu.tpu.compile_ledger import (ExecutableLedger, ShapeStats,
                                          charge_device_time, suggest_ladder)
 from gofr_tpu.tpu.constrain import GrammarWalker
 from gofr_tpu.tpu.flightrecorder import FlightRecorder, RequestRecord
+from gofr_tpu.tpu.page_pool import ONE_KIND, by_kind, cache_kinds
 from gofr_tpu.tpu.sched import (ClassQueues, DEFAULT_CLASS_WEIGHTS,
                                 brownout_shed_classes, deadline_class)
 from gofr_tpu.trace import Span, current_span, extract_traceparent
@@ -293,13 +294,18 @@ class _Flight:
 class _Slot:
     __slots__ = ("future", "remaining", "eos_id", "tokens", "active", "gen",
                  "inflight", "queue", "temperature", "fill", "submitted_at",
-                 "deadline", "record", "req_span", "phase_span", "pages",
+                 "deadline", "record", "req_span", "phase_span", "chains",
                  "nodes", "cls", "spec_proposed", "spec_accepted", "grammar",
                  "migrating")
 
     def __init__(self):
         self.migrating = False  # quiescing for export: joins no new tick
-        self.pages: List[int] = []   # paged KV: pool pages this slot owns
+        # paged KV: the pool pages this slot owns, a cache kind: kind ->
+        # [first table column they fill, the pages from there on]. The
+        # columns before it hold pinned prefix nodes' pages, or nothing
+        # any more: a window kind's first column moves up as the slot
+        # decodes past its pages
+        self.chains: Dict[str, List[Any]] = {}
         self.nodes: List[Any] = []   # paged KV: pinned prefix-trie nodes
         self.cls = "batch"           # SLO class (tpu.sched.deadline_class)
         self.grammar = None          # constrained decoding: GrammarWalker
@@ -322,6 +328,18 @@ class _Slot:
         self.record: Optional[RequestRecord] = None  # flight recorder entry
         self.req_span: Optional[Span] = None   # request span (link target)
         self.phase_span: Optional[Span] = None  # open prefill/decode span
+
+    def held_pages(self) -> int:
+        """Pool pages this slot holds, of every kind, pinned prefix
+        nodes included."""
+        return len(self.nodes) + sum(
+            len(pages) for _, pages in self.chains.values())
+
+
+def _kind_label(kind: str) -> str:
+    """A cache kind in an error's text: the one kind of a k/v pool reads
+    "KV"."""
+    return "KV" if kind == ONE_KIND else kind
 
 
 class _Fetch:
@@ -511,9 +529,23 @@ class GenerationEngine:
         # adoption, session migration): a module whose cache leaves are
         # of another form cannot use it
         cache_leaves = getattr(self._llama, "cache_leaves", None)
-        self._leaf_specs: Optional[Dict[str, tuple]] = (
+        self._leaf_specs: Optional[Dict[str, Any]] = (
             cache_leaves(cfg) if cache_leaves else None)
+        # a module whose layers keep caches of different kinds answers by
+        # kind (page_pool.cache_kinds): the pool keeps pages and a slot a
+        # page table a kind, and a window kind's pages go back to the pool
+        # as the slot decodes past them
+        self._by_kind = by_kind(self._leaf_specs)
+        if self._by_kind and not paged_kv:
+            raise ValueError(
+                f"model_module {named}: cache leaves by layer kind "
+                f"{sorted(self._leaf_specs)} are served from the page "
+                f"pool (paged_kv=True); the dense cache has one kind")
         self._kv_wire_refusal: Optional[str] = (
+            f"model_module {named}: its cache has a page table a layer "
+            f"kind {sorted(self._leaf_specs)}, kv_wire (prefill export, "
+            f"adoption, session migration) ships one table's k/v pages"
+            if self._by_kind else
             None if self._leaf_specs is None
             or {"k", "v"} <= set(self._leaf_specs) else
             f"model_module {named}: its cache leaves "
@@ -658,7 +690,7 @@ class GenerationEngine:
                 self._placement = held
         self.cache = None
         self._pool = None
-        self._table = None
+        self._tables: Dict[str, Any] = {}
         if self.paged:
             from gofr_tpu.tpu.page_pool import PagePool, kv_leaf_specs
             self.pages_per_slot = self.max_len // self.kv_page
@@ -681,6 +713,30 @@ class GenerationEngine:
                         f"dtypes) must agree; heterogeneous models need "
                         f"their own pools carved from an HBMBudget")
                 self._pool = page_pool
+            elif self._by_kind:
+                # a number of pages a kind: as given (kv_pages by kind),
+                # or what every slot needs at max_len (a window kind:
+                # its window and a page at each end), scaled to the
+                # byte budget where one is given
+                kinds = cache_kinds(cfg, self._leaf_specs)
+                pages = {k.name: max_slots * self._slot_pages(k.window)
+                         for k in kinds}
+                if isinstance(kv_pages, dict):
+                    pages = {k.name: int(kv_pages[k.name]) for k in kinds}
+                elif kv_pages is not None:
+                    raise ValueError(
+                        f"model_module {named}: kv_pages is a number of "
+                        f"pages a cache kind, {sorted(pages)}")
+                elif kv_pool_bytes is not None:
+                    full = sum(pages[k.name] * PagePool._kind_page_bytes(
+                        k, self.kv_page) for k in kinds)
+                    share = min(1.0, int(kv_pool_bytes) / full)
+                    pages = {name: max(1, int(n * share))
+                             for name, n in pages.items()}
+                self._pool = PagePool(cfg, page=self.kv_page,
+                                      num_pages=pages, mesh=mesh,
+                                      metrics=metrics,
+                                      leaf_specs=self._leaf_specs)
             elif kv_pages is not None:
                 self._pool = PagePool(cfg, page=self.kv_page,
                                       num_pages=int(kv_pages), mesh=mesh,
@@ -700,15 +756,17 @@ class GenerationEngine:
                     num_pages=max_slots * self.pages_per_slot, mesh=mesh,
                     metrics=metrics, leaf_specs=self._leaf_specs)
             # reserve watermark: pages admission must leave free for
-            # in-flight decode growth of already-admitted slots
-            self._kv_reserve = (int(kv_page_reserve)
-                                if kv_page_reserve is not None
-                                else min(max_slots,
-                                         self._pool.num_pages // 8))
-            # per-slot page table (host master copy; device uploads are
-            # cached per gather-width and invalidated by version bumps)
-            self._table = np.full((max_slots, self.pages_per_slot),
-                                  self._pool.sentinel, np.int32)
+            # in-flight decode growth of already-admitted slots, of each
+            # cache kind (by default an eighth of the smallest kind)
+            self._kv_reserve = (
+                int(kv_page_reserve) if kv_page_reserve is not None
+                else min(max_slots, min(
+                    kind.num_pages
+                    for kind in self._pool.kinds.values()) // 8))
+            # per-slot page tables, one a cache kind (host master copies;
+            # device uploads are cached per gather-width and invalidated
+            # by version bumps)
+            self._fresh_tables()
             self._table_version = 0
             self._table_cache: Dict[int, Tuple[int, Any]] = {}
             self._page_stalls = 0
@@ -909,6 +967,7 @@ class GenerationEngine:
         # workload capture (ISSUE 17): a TrafficRecorder attached via
         # attach_workload; None keeps admission byte-identical
         self.workload = None
+        self._prefill_rows = 0            # rows of the admission groups
         self._prefill_bucket_tokens = 0   # bucket rows*cols dispatched to
         self._prefill_real_tokens = 0     # prefill vs real prompt tokens
         self._prefix = None
@@ -1021,8 +1080,10 @@ class GenerationEngine:
                      top_ps=jnp.ones((nb,), jnp.float32),
                      seeds=jnp.zeros((nb,), jnp.uint32))
         if self.paged:
-            group["flat_ids"] = jnp.full((nb * (lb // self.kv_page),),
-                                         self._pool.sentinel, jnp.int32)
+            ids = (nb * (lb // self.kv_page),)
+            group["flat_ids"] = self._pool.as_leaves(
+                {name: jnp.full(ids, kind.num_pages, jnp.int32)
+                 for name, kind in self._pool.kinds.items()})
         return group
 
     def _run_prefill(self, nb: int, lb: int, dev: Dict[str, Any]):
@@ -1274,7 +1335,9 @@ class GenerationEngine:
             params=jax.tree.map(abstract, self.params),
             token=jax.ShapeDtypeStruct(row, jnp.int32),
             cache=jax.tree.map(abstract, self._kv),
-            table=jax.ShapeDtypeStruct(row + (width,), jnp.int32),
+            table=(jax.tree.map(
+                lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype),
+                self._empty_table(width)) if self.paged else None),
             cache_len=jax.ShapeDtypeStruct(row, jnp.int32),
             active=jax.ShapeDtypeStruct(row, jnp.bool_))
         formats, temp_bytes, compiled = weight_formats(
@@ -1405,9 +1468,8 @@ class GenerationEngine:
         with self._pool.lock if own and self.paged else nullcontext():
             table = None
             if self.paged:
-                table = (self._table_dev(width) if own else self._jnp.full(
-                    (self.max_slots, width), self._pool.sentinel,
-                    self._jnp.int32))
+                table = (self._table_dev(width) if own
+                         else self._empty_table(width))
             operands = dict(
                 params=self.params, token=s.last_token, cache=s._kv,
                 table=table, cache_len=s.cache_len, active=active, bias=bias,
@@ -1444,12 +1506,19 @@ class GenerationEngine:
                        sample_keys, new_t, new_k, new_p, new_keys):
                 # small leaves: (L, nb, lb, ...) -> (L, nb*n_pages, page,
                 # ...); pool leaves: (L, N, page, ...). One scatter per
-                # leaf publishes the whole group's KV into its pages.
-                pool = {name: pool[name].at[:, flat_ids].set(
-                    small[name].reshape(
-                        small[name].shape[0], nb * n_pages, page,
-                        *small[name].shape[3:]),
-                    mode="drop") for name in pool}
+                # leaf publishes the whole group's KV into its pages,
+                # a kind at a time; a window kind's ids hold the sentinel
+                # for the columns behind its window: nothing older is
+                # written
+                def scatter(leaves, small, ids):
+                    return {name: leaves[name].at[:, ids].set(
+                        small[name].reshape(
+                            small[name].shape[0], nb * n_pages, page,
+                            *small[name].shape[3:]),
+                        mode="drop") for name in leaves}
+
+                pool = self._pool.map_kinds(scatter, pool, small,
+                                            flat_ids)
                 cache_len = cache_len.at[slots].set(plen + lengths,
                                                     mode="drop")
                 last_token = last_token.at[slots].set(first, mode="drop")
@@ -1656,16 +1725,43 @@ class GenerationEngine:
                 self.temps, self.top_ks, self.top_ps, self.sample_keys)
         return toks_dev, accepts_dev
 
+    def _slot_pages(self, window: Optional[int]) -> int:
+        """Pages a slot can hold of a cache kind: every column of its
+        table, or a window kind's window with a partial page at each end
+        and the steps of a tick before the release."""
+        if window is None:
+            return self.pages_per_slot
+        return min(self.pages_per_slot, window // self.kv_page + 2)
+
+    def _fresh_tables(self) -> None:
+        """All-sentinel host page tables, one a cache kind (each filled
+        with its kind's sentinel)."""
+        shape = (self.max_slots, self.pages_per_slot)
+        self._tables = {name: np.full(shape, kind.num_pages, np.int32)
+                        for name, kind in self._pool.kinds.items()}
+
+    def _empty_table(self, width: int):
+        """A device table of ``width`` columns that holds no page: what
+        the warm-ups and the deciding compile run a tick with."""
+        jnp = self._jnp
+        shape = (self.max_slots, width)
+        return self._pool.as_leaves(
+            {name: jnp.full(shape, kind.num_pages, jnp.int32)
+             for name, kind in self._pool.kinds.items()})
+
     def _table_dev(self, pw: int):
-        """Device copy of the first ``pw`` page-table columns, cached per
-        gather width and invalidated by host-table version bumps. ``pw``
-        is always ladder-derived (window rung // kv_page, or the full
-        pages_per_slot) — never a live page count — so the executable set
-        stays bounded (graftcheck GT003 page-width rule)."""
+        """Device copy of the first ``pw`` page-table columns (of each
+        kind's table), cached per gather width and invalidated by
+        host-table version bumps. ``pw`` is always ladder-derived (window
+        rung // kv_page, or the full pages_per_slot) — never a live page
+        count — so the executable set stays bounded (graftcheck GT003
+        page-width rule)."""
         cached = self._table_cache.get(pw)
         if cached is not None and cached[0] == self._table_version:
             return cached[1]
-        dev = self._jnp.asarray(self._table[:, :pw])
+        dev = self._pool.as_leaves(
+            {name: self._jnp.asarray(table[:, :pw])
+             for name, table in self._tables.items()})
         self._table_cache[pw] = (self._table_version, dev)
         return dev
 
@@ -2168,6 +2264,7 @@ class GenerationEngine:
         first, host, key = await loop.run_in_executor(None, export)
         self._prefills += 1
         self._prefill_bucket_tokens += bucket
+        self._prefill_rows += 1
         self._prefill_real_tokens += len(prompt)
         self._kv_exports += 1
         record.first_token()
@@ -2340,12 +2437,12 @@ class GenerationEngine:
         slot.spec_accepted = 0
         slot.fill = payload.tokens
         slot.nodes = []
-        slot.pages = list(ids)
+        # kv_wire ships the one kind's pages (_kv_wire_refusal)
+        slot.chains = {ONE_KIND: [0, list(ids)]}
         slot.record = record
         slot.req_span = span
         slot.phase_span = None     # decode span opens at the first push
-        for j, pid in enumerate(ids):
-            self._table[slot_idx, j] = pid
+        self._tables[ONE_KIND][slot_idx, :len(ids)] = ids
         self._table_version += 1
 
         fn = self._adopt_fn(need)
@@ -2495,7 +2592,8 @@ class GenerationEngine:
             fill = slot.fill
             page = self.kv_page
             n_pages = -(-fill // page)
-            ids = [int(self._table[slot_idx, j]) for j in range(n_pages)]
+            ids = [int(pid)
+                   for pid in self._tables[ONE_KIND][slot_idx, :n_pages]]
             if any(pid == self._pool.sentinel for pid in ids):
                 raise RuntimeError(
                     f"slot {slot_idx} table row holds a sentinel inside "
@@ -2617,7 +2715,8 @@ class GenerationEngine:
         runs out rather than piling deferred requests into overflow."""
         if not self.paged:
             return None
-        return self._pool.free_pages - self._kv_reserve
+        return min(self._pool.free_pages_of(name)
+                   for name in self._pool.kinds) - self._kv_reserve
 
     def attach_telemetry(self, store, every: int = 64) -> None:
         """Wire the continuous telemetry plane (ISSUE 16): ``store`` gets
@@ -2980,6 +3079,9 @@ class GenerationEngine:
                # former for the same admitted traffic
                "prefill_bucket_tokens": self._prefill_bucket_tokens,
                "prefill_real_tokens": self._prefill_real_tokens,
+               # rows of those groups, padding rows included: bucket
+               # tokens over rows is the mean bucket a prompt ran in
+               "prefill_rows": self._prefill_rows,
                # disaggregated handoff accounting: exports are prompt
                # forwards shipped out, adoptions are migrated prompts
                # admitted with ZERO local prefill dispatches
@@ -3104,7 +3206,7 @@ class GenerationEngine:
             for slot in self._slots:
                 if not slot.active:
                     continue
-                held = len(slot.pages)
+                held = slot.held_pages() - len(slot.nodes)
                 if slot.cls == CLASS_MIGRATED:
                     migrated_pages += held
                 else:
@@ -3177,7 +3279,7 @@ class GenerationEngine:
                 "spec_accepted": slot.spec_accepted if slot.active else 0,
                 "spec_proposed": slot.spec_proposed if slot.active else 0,
                 "streaming": slot.queue is not None,
-                "pages_held": (len(slot.pages) + len(slot.nodes)
+                "pages_held": (slot.held_pages()
                                if slot.active else 0),
                 "trace_id": (slot.record.trace_id
                              if slot.record is not None else None),
@@ -3187,7 +3289,7 @@ class GenerationEngine:
             # occupancy against the POOL, not max_slots x max_len — paged
             # HBM is the pool, and live tokens ride actual pages
             capacity = self._pool.num_pages * self.kv_page
-            pages_held = sum(len(s.pages) + len(s.nodes)
+            pages_held = sum(s.held_pages()
                              for s in self._slots if s.active)
             kv_cache = {
                 "paged": True,
@@ -3390,13 +3492,11 @@ class GenerationEngine:
                     self._pool.reset()
             finally:
                 self._in_pool_reset = False
-            self._table = np.full(
-                (self.max_slots, self.pages_per_slot),
-                self._pool.sentinel, np.int32)
+            self._fresh_tables()
             self._table_version += 1
             self._table_cache.clear()
             for slot in self._slots:
-                slot.pages = []
+                slot.chains = {}
                 slot.nodes = []
         elif self.mesh is not None:
             from gofr_tpu.parallel.sharding import (
@@ -3711,7 +3811,8 @@ class GenerationEngine:
                             Optional[Span], str]] = []
         by_group: Dict[Tuple[int, int, bool], List[Tuple]] = {}
         leases: List[Any] = []
-        committed = 0      # pages promised to requests admitted this pass
+        # pages promised to requests admitted this pass, a cache kind
+        promised: Dict[str, int] = {}
         for ri, request in enumerate(requests):
             prompt, bucket, budget, eos_id, sampling, future, queue, \
                 submitted_at, flight, cls, grammar = request
@@ -3748,16 +3849,22 @@ class GenerationEngine:
                         (time.monotonic() - flight.deadline) * 1000.0)
                 continue
             if self.paged:
-                # admission is page-gated, BEFORE the prefix lookup so a
-                # deferred request doesn't double-count hit/save metrics
-                # when it retries. Worst case: the whole prompt needs
-                # fresh pages; the reserve keeps headroom for decode
-                # growth of slots already running.
-                need_max = -(-len(prompt) // self.kv_page)
-                if need_max + self._kv_reserve > self._pool.num_pages:
+                # admission is page-gated a cache kind, BEFORE the prefix
+                # lookup so a deferred request doesn't double-count
+                # hit/save metrics when it retries. Worst case: every
+                # kind needs fresh pages for the whole prompt (a window
+                # kind: for its last window); the reserve keeps headroom
+                # for decode growth of slots already running.
+                need = {name: n for name, (_, n)
+                        in self._kind_pages(len(prompt)).items()}
+                never = [f"{n} {_kind_label(name)} pages but the pool "
+                         f"holds {self._pool.kinds[name].num_pages}"
+                         for name, n in need.items()
+                         if n + self._kv_reserve
+                         > self._pool.kinds[name].num_pages]
+                if never:
                     exc = RuntimeError(
-                        f"prompt needs {need_max} KV pages but the pool "
-                        f"holds {self._pool.num_pages} (reserve "
+                        f"prompt needs {' and '.join(never)} (reserve "
                         f"{self._kv_reserve}); it can never be admitted")
                     if not future.done():
                         future.set_exception(exc)
@@ -3768,13 +3875,17 @@ class GenerationEngine:
                         flight.qspan.finish()
                     self.recorder.finish(flight.record, "error")
                     continue
-                while (self._pool.free_pages - committed
-                        < need_max + self._kv_reserve
-                        and self._prefix is not None
+
+                def short():
+                    return any(self._pool.free_pages_of(name)
+                               - promised.get(name, 0)
+                               < n + self._kv_reserve
+                               for name, n in need.items())
+
+                while (short() and self._prefix is not None
                         and self._prefix.evict_one()):
                     pass
-                if (self._pool.free_pages - committed
-                        < need_max + self._kv_reserve):
+                if short():
                     # head-of-line FIFO: defer this and everything popped
                     # after it (admitting a shorter later request first
                     # would starve long prompts under pressure); past the
@@ -3782,7 +3893,8 @@ class GenerationEngine:
                     self._overflow.extend(requests[ri:])
                     self._shed_overflow()
                     break
-                committed += need_max
+                for name, n in need.items():
+                    promised[name] = promised.get(name, 0) + n
             # constrained requests always run a FULL prefill (p_rung 0):
             # the biased executable family is keyed (nb, bucket) only, so
             # the suffix-prefill ladder never multiplies by grammar state
@@ -3835,8 +3947,9 @@ class GenerationEngine:
             # sentinel where the row has no page (padding rows / short
             # suffixes) — the insert scatter drops those
             npg = bucket // self.kv_page if self.paged else 0
-            flat_ids = (np.full((nb * npg,), self._pool.sentinel, np.int32)
-                        if self.paged else None)
+            flat_ids = {name: np.full((nb * npg,), kind.num_pages, np.int32)
+                        for name, kind in self._pool.kinds.items()} \
+                if self.paged else None
             db = 0
             draft_padded = draft_lengths = None
             if self.spec:
@@ -3908,29 +4021,32 @@ class GenerationEngine:
                 if self.paged:
                     # prefix hit = table entries, zero KV copies: the
                     # pinned trie nodes' pages map straight into columns
-                    # [0, p_rung); fresh suffix pages follow. The reserve
-                    # gating above guarantees the alloc (reclaim backstop
-                    # evicts cold prefixes if it somehow doesn't).
+                    # [0, p_rung); fresh pages follow, of each kind for
+                    # the columns its layers will read: all of the
+                    # suffix's, or a window kind's last window's (the
+                    # insert drops what lies before). The reserve gating
+                    # above guarantees the alloc (reclaim backstop evicts
+                    # cold prefixes if it somehow doesn't).
                     slot.nodes = list(nodes)
                     for j, node in enumerate(nodes):
-                        self._table[slot_idx, j] = node.page_id
-                    n_fresh = -(-len(suffix) // self.kv_page)
-                    ids = self._pool.alloc(
-                        n_fresh,
-                        reclaim=(self._prefix.evict_one
-                                 if self._prefix is not None else None))
-                    if ids is None:
-                        raise RuntimeError(
-                            f"kv page pool exhausted at admission: "
-                            f"{n_fresh} pages wanted, "
-                            f"{self._pool.free_pages} free")
-                    slot.pages = list(ids)
-                    for j, pid in enumerate(ids):
-                        self._table[slot_idx, p_rung + j] = pid
+                        self._tables[ONE_KIND][slot_idx, j] = node.page_id
+                    for name, (first, n) in self._kind_pages(
+                            len(prompt), p_rung).items():
+                        ids = self._pool.alloc(
+                            n, kind=name,
+                            reclaim=(self._prefix.evict_one
+                                     if self._prefix is not None else None))
+                        if ids is None:
+                            raise RuntimeError(
+                                f"kv page pool exhausted at admission: {n} "
+                                f"{_kind_label(name)} pages wanted, "
+                                f"{self._pool.free_pages_of(name)} free")
+                        slot.chains[name] = [first, list(ids)]
+                        self._tables[name][slot_idx, first:first + n] = ids
+                        at = row * npg + first - p_rung
+                        flat_ids[name][at:at + n] = ids
                     self._table_version += 1
-                    flight.record.pages_held = p_rung + n_fresh
-                    for j in range(n_fresh):
-                        flat_ids[row * npg + j] = ids[j]
+                    flight.record.pages_held = slot.held_pages()
                     if p_rung == 0 and self._prefix is not None:
                         # zero-copy publish: fully-valid prompt pages are
                         # adopted by the trie (one retain per new page);
@@ -3938,7 +4054,8 @@ class GenerationEngine:
                         want = min(len(prompt) // self.kv_page,
                                    self._prefix.max_pages)
                         if want > 0:
-                            self._prefix.register(prompt, ids[:want])
+                            self._prefix.register(
+                                prompt, slot.chains[ONE_KIND][1][:want])
                 slots[row] = slot_idx
                 temps[row] = max(sampling.temperature, 0.0)
                 top_ks[row] = sampling.top_k
@@ -3981,13 +4098,19 @@ class GenerationEngine:
                     # rows (float32) ride the same frame
                     group = dict(padded=padded, lengths=lengths,
                                  slots=slots, temps=temps, top_ks=top_ks,
-                                 top_ps=top_ps, seeds=seeds,
-                                 flat_ids=flat_ids)
+                                 top_ps=top_ps, seeds=seeds)
+                    # a kind's ids under a name of their own: the upload
+                    # takes a flat group of arrays
+                    group.update((f"flat_ids.{name}", ids)
+                                 for name, ids in flat_ids.items())
                     if p:
                         group["page_mat"] = page_mat
                     if bias_rows is not None:
                         group["bias"] = bias_rows
                     dev = self._upload_group(group)
+                    dev["flat_ids"] = self._pool.as_leaves(
+                        {name: dev.pop(f"flat_ids.{name}")
+                         for name in flat_ids})
                     # pool lock: a co-resident engine's donating dispatch
                     # must not interleave between our read of the leaves
                     # handle and the write-back below (tenancy safety)
@@ -4008,8 +4131,9 @@ class GenerationEngine:
                                 dev["seeds"])
                         self._run_insert(nb, bucket, plen, dev, first, small,
                                          keys)
-                    self._pool.note_writes(
-                        int((flat_ids != self._pool.sentinel).sum()))
+                    self._pool.note_writes(sum(
+                        int((ids != self._pool.sentinel_of(name)).sum())
+                        for name, ids in flat_ids.items()))
                     return first
 
                 warm = ((nb, bucket, plen) in self._insert_paged_fns
@@ -4117,6 +4241,7 @@ class GenerationEngine:
                     first_dev = await self._off_loop(loop, cold)
                 self._prefills += 1
                 self._prefill_bucket_tokens += nb * bucket
+                self._prefill_rows += nb
                 family = (f"suffix_prefill[nb={nb},p={p_rung},b={bucket}]"
                           if p_rung else f"prefill[nb={nb},b={bucket}]")
                 fetches.append((first_dev, claimed, step_span, family))
@@ -4323,8 +4448,7 @@ class GenerationEngine:
                 "app_tpu_attn_kernel_total", model=self.model_name,
                 path=self.attn_path)
             if self.paged:
-                held = sum(len(s.nodes) + len(s.pages)
-                           for _, s in eligible)
+                held = sum(s.held_pages() for _, s in eligible)
                 filled = sum(s.fill for _, s in eligible)
                 if held:
                     self.metrics.set_gauge(
@@ -4403,30 +4527,73 @@ class GenerationEngine:
                   else f"spec[g={g},w={window or self.max_len}]")
         return "spec", fetch, (snapshot, g), step_span, family
 
+    def _kind_pages(self, tokens: int, pinned: int = 0
+                    ) -> Dict[str, Tuple[int, int]]:
+        """A kind's table columns a context of ``tokens`` holds pages of
+        the slot's own for, as (first column, how many): all of them
+        after the ``pinned`` columns of a reused prefix, or from the page
+        a window kind's first attended position (that of the next
+        token's query) lies in."""
+        out = {}
+        for name, kind in self._pool.kinds.items():
+            first = (pinned if kind.window is None
+                     else self._window_column(tokens, kind.window))
+            out[name] = (first, -(-tokens // self.kv_page) - first)
+        return out
+
+    def _window_column(self, tokens: int, window: int) -> int:
+        """The table column of the first position a query after
+        ``tokens`` tokens attends in a layer that looks ``window`` back."""
+        return max(tokens - window + 1, 0) // self.kv_page
+
     def _cover_pages(self, eligible, k: int):
-        """Grow each participating slot's page chain to cover its fill + k
-        tokens, reclaiming cold prefix pages when the free list runs
-        short. Slots that cannot be covered sit this tick out (admission
+        """Give back a window kind's pages that lie wholly behind the
+        first position this tick attends, then grow each participating
+        slot's page chains, a kind, to cover its fill + k tokens,
+        reclaiming cold prefix pages when the free list runs short.
+        Slots that cannot be covered sit this tick out (admission
         backpressure, not an error): their pages come back when other
-        slots complete."""
+        slots complete. Ticks already dispatched read the pages given
+        back before any later program writes them: the device runs the
+        programs in order, each with the table it was dispatched with."""
+        reclaim = (self._prefix.evict_one
+                   if self._prefix is not None else None)
+        windows = [(name, kind.window)
+                   for name, kind in self._pool.kinds.items()
+                   if kind.window is not None]
         covered = []
         for slot_idx, slot in eligible:
-            need = -(-(slot.fill + k) // self.kv_page)
-            held = len(slot.nodes) + len(slot.pages)
-            short = need - held
-            if short > 0:
-                ids = self._pool.alloc(
-                    short, reclaim=(self._prefix.evict_one
-                                    if self._prefix is not None else None))
-                if ids is None:
-                    self._page_stalls += 1
+            for name, window in windows:
+                chain = slot.chains[name]
+                keep = self._window_column(slot.fill, window)
+                behind = keep - chain[0]
+                if behind > 0:
+                    self._pool.release_behind(chain[1][:behind], name)
+                    self._tables[name][slot_idx, chain[0]:keep] = \
+                        self._pool.sentinel_of(name)
+                    chain[0], chain[1] = keep, chain[1][behind:]
+                    self._table_version += 1
+            columns = -(-(slot.fill + k) // self.kv_page)
+            grown = short = False
+            for name, (first, pages) in slot.chains.items():
+                want = columns - first - len(pages)
+                if want <= 0:
                     continue
-                for j, pid in enumerate(ids):
-                    self._table[slot_idx, held + j] = pid
-                slot.pages.extend(ids)
+                ids = self._pool.alloc(want, kind=name, reclaim=reclaim)
+                if ids is None:
+                    short = True
+                    break
+                at = first + len(pages)
+                self._tables[name][slot_idx, at:at + want] = ids
+                pages.extend(ids)
+                grown = True
+            if grown:
                 self._table_version += 1
-                if slot.record is not None:
-                    slot.record.pages_held = need
+            if short:
+                self._page_stalls += 1
+                continue
+            if grown and slot.record is not None:
+                slot.record.pages_held = slot.held_pages()
             covered.append((slot_idx, slot))
         return covered
 
@@ -4442,7 +4609,7 @@ class GenerationEngine:
         if self.logger is not None:
             self.logger.error(
                 "engine: %s (slot %d, %d pages back to the pool)",
-                exc, slot_idx, len(slot.pages) + len(slot.nodes))
+                exc, slot_idx, slot.held_pages())
         slot.active = False
         slot.gen += 1
         slot.inflight = 0
@@ -4526,8 +4693,7 @@ class GenerationEngine:
             return
         self._fail_outstanding(RuntimeError(
             "shared kv page pool was reset by a co-resident engine"))
-        self._table = np.full((self.max_slots, self.pages_per_slot),
-                              self._pool.sentinel, np.int32)
+        self._fresh_tables()
         self._table_version += 1
         self._table_cache.clear()
         if self._prefix is not None:
@@ -4728,13 +4894,14 @@ class GenerationEngine:
             if self._prefix is not None:
                 self._prefix.release(slot.nodes)
             slot.nodes = []
-        if slot.pages:
-            self._pool.release(slot.pages)
-            slot.pages = []
-        row = self._table[slot_idx]
-        if (row != self._pool.sentinel).any():
-            row.fill(self._pool.sentinel)
-            self._table_version += 1
+        for kind, (_, pages) in slot.chains.items():
+            self._pool.release(pages, kind)
+        slot.chains = {}
+        for kind, table in self._tables.items():
+            row, sentinel = table[slot_idx], self._pool.sentinel_of(kind)
+            if (row != sentinel).any():
+                row.fill(sentinel)
+                self._table_version += 1
 
     def _finish_slot(self, slot: _Slot, status: str) -> None:
         """Close a slot's observability state: finish the open phase span

@@ -15,7 +15,17 @@ PAPERS.md: "Ragged Paged Attention", arxiv 2604.15464; sizing by real
 footprint instead of static worst case follows the batch-size/latency
 study, arxiv 1812.11731).
 
-Host-side state is a free list plus a per-page refcount:
+A module whose layers keep caches of different kinds (sliding-window
+layers beside global ones) answers ``cache_leaves`` **by layer kind**:
+``{kind: {"layers": n, "window": tokens or None, "leaves": {name: spec}}}``.
+The pool then keeps, a kind, the stacked leaves ``(layers of that kind,
+that kind's num_pages, page, ...)``, a free list, refcounts and the
+counts; ``leaves`` is ``{kind: {name: array}}`` and a slot has a page
+table a kind, because a window kind lets go of the pages that fall
+behind its window (``release_behind``) while a global kind keeps the
+whole context. One kind is the case above: same leaves, same calls.
+
+Host-side state is a free list plus a per-page refcount, a kind:
 
 - ``alloc`` hands out pages at refcount 1 (the allocating owner — an
   engine slot or a prefix-trie node).
@@ -40,9 +50,14 @@ donated buffer is reused.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Sequence
+import dataclasses
+import math
+from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
-__all__ = ["PagePool", "HBMBudget"]
+__all__ = ["PagePool", "HBMBudget", "CacheKind", "cache_kinds"]
+
+# the one kind of a pool whose module answers ``cache_leaves`` flat
+ONE_KIND = "kv"
 
 
 def kv_leaf_specs(cfg) -> Dict[str, tuple]:
@@ -58,16 +73,62 @@ def kv_leaf_specs(cfg) -> Dict[str, tuple]:
     return {"k": (tail, cfg.dtype), "v": (tail, cfg.dtype)}
 
 
+@dataclasses.dataclass
+class CacheKind:
+    """One kind of layer cache: how many layers keep it, what a token
+    leaves in one of them, and how far back they attend (``window``
+    tokens; None: the whole context). The host-side ownership of the
+    kind's pages lives here too (``PagePool`` fills it)."""
+    name: str
+    layers: int
+    leaves: Dict[str, tuple]
+    window: Optional[int] = None
+    num_pages: int = 0
+    page_bytes: int = 0
+    free: List[int] = dataclasses.field(default_factory=list)
+    refs: Any = None
+    allocs: int = 0            # cumulative, as the pool's
+    stalls: int = 0
+    freed_behind: int = 0      # pages let go because the window passed them
+
+    @property
+    def used_pages(self) -> int:
+        return self.num_pages - len(self.free)
+
+
+def cache_kinds(cfg, leaf_specs: Optional[Dict[str, Any]] = None
+                ) -> List[CacheKind]:
+    """A module's ``cache_leaves(cfg)`` answer as a list of kinds. A
+    flat answer (name -> spec tuple; None: the pool's own k/v form) is
+    one kind over all ``cfg.n_layers``; an answer by kind is taken as it
+    is, in its own order."""
+    specs = dict(leaf_specs or kv_leaf_specs(cfg))
+    if not by_kind(specs):
+        return [CacheKind(ONE_KIND, cfg.n_layers, specs)]
+    return [CacheKind(name, int(kind["layers"]), dict(kind["leaves"]),
+                      kind.get("window"))
+            for name, kind in specs.items()]
+
+
+def by_kind(leaf_specs: Optional[Dict[str, Any]]) -> bool:
+    """Is this ``cache_leaves`` answer one by layer kind?"""
+    return bool(leaf_specs) and all(
+        isinstance(spec, dict) for spec in leaf_specs.values())
+
+
 class PagePool:
     """Refcounted device page pool shared by prefill, prefix cache, and
-    decode. ``num_pages`` may be given directly or derived from
-    ``budget_bytes`` (HBM cap across every leaf)."""
+    decode. ``num_pages`` may be given directly (a number, or a number a
+    kind) or derived from ``budget_bytes`` (HBM cap across every leaf;
+    one kind only: a pool of several kinds is told how its owner splits
+    the bytes). Every ownership call takes ``kind``; left out it means
+    the pool's first kind, which is the only one of a flat pool."""
 
     def __init__(self, cfg, page: int = 32,
-                 num_pages: Optional[int] = None,
+                 num_pages: Union[None, int, Dict[str, int]] = None,
                  budget_bytes: Optional[int] = None,
                  mesh=None, metrics=None,
-                 leaf_specs: Optional[Dict[str, tuple]] = None):
+                 leaf_specs: Optional[Dict[str, Any]] = None):
         import threading
 
         import jax
@@ -90,56 +151,121 @@ class PagePool:
         self.metrics = metrics
         self.page = int(page)
         self.leaf_specs = dict(leaf_specs or kv_leaf_specs(cfg))
-        if mesh is not None and set(self.leaf_specs) - {"k", "v", "ks",
-                                                        "vs"}:
-            raise ValueError(
-                f"PagePool: a mesh shards k/v leaves by kv-head; leaves "
-                f"{sorted(self.leaf_specs)} have no sharding rule")
-        self.page_bytes = self._page_bytes(cfg, self.page, self.leaf_specs)
-        if num_pages is not None:
-            self.num_pages = int(num_pages)
-        elif budget_bytes is not None:
-            self.num_pages = max(1, int(budget_bytes) // self.page_bytes)
+        # leaves nest by kind exactly when the module answered by kind
+        self.by_kind = by_kind(self.leaf_specs)
+        self.kinds: Dict[str, CacheKind] = {
+            kind.name: kind for kind in cache_kinds(cfg, self.leaf_specs)}
+        for kind in self.kinds.values():
+            if mesh is not None and (self.by_kind or set(kind.leaves)
+                                     - {"k", "v", "ks", "vs"}):
+                raise ValueError(
+                    f"PagePool: a mesh shards one kind of k/v leaves by "
+                    f"kv-head; kind {kind.name!r} with leaves "
+                    f"{sorted(kind.leaves)} has no sharding rule")
+            kind.page_bytes = self._kind_page_bytes(kind, self.page)
+        # one page of every kind: what a token position costs the pool
+        self.page_bytes = sum(k.page_bytes for k in self.kinds.values())
+        if isinstance(num_pages, dict):
+            for name, kind in self.kinds.items():
+                kind.num_pages = int(num_pages[name])
+        elif num_pages is not None:
+            for kind in self.kinds.values():
+                kind.num_pages = int(num_pages)
+        elif budget_bytes is not None and len(self.kinds) == 1:
+            self._first.num_pages = max(
+                1, int(budget_bytes) // self.page_bytes)
         else:
-            raise ValueError("PagePool needs num_pages or budget_bytes")
+            raise ValueError(
+                "PagePool needs num_pages or budget_bytes (several kinds: "
+                "num_pages, a number a kind)")
         # cumulative counters (survive reset: pool history, not contents)
         self.writes = 0        # page-rows scattered into the pool
-        self.stalls = 0        # failed allocations (free list exhausted)
-        self.allocs = 0
         self.leaves: Dict[str, Any] = {}
-        self._free: List[int] = []
-        self._refs = np.zeros((self.num_pages,), np.int32)
         self._reset_subscribers: List[Callable[[], None]] = []
         self.reset()
 
     @property
+    def _first(self) -> CacheKind:
+        return next(iter(self.kinds.values()))
+
+    def as_leaves(self, per_kind: Dict[str, Any]):
+        """A value a kind in the form ``leaves`` has, which is the form
+        the module's programs take their page tables and page ids in:
+        nested by kind where the module answered by kind, else the one
+        kind's value itself."""
+        return per_kind if self.by_kind else per_kind[self._first.name]
+
+    def map_kinds(self, fn: Callable[..., Any], *trees):
+        """``fn`` over each kind's part of trees in ``leaves``' form."""
+        if not self.by_kind:
+            return fn(*trees)
+        return {name: fn(*(tree[name] for tree in trees))
+                for name in self.kinds}
+
+    def _kind(self, kind: Optional[str]) -> CacheKind:
+        return self._first if kind is None else self.kinds[kind]
+
+    @property
+    def num_pages(self) -> int:
+        """Pages of every kind together (one kind: its pages)."""
+        return sum(k.num_pages for k in self.kinds.values())
+
+    @num_pages.setter
+    def num_pages(self, n: int) -> None:
+        # tests shrink a one-kind pool to force eviction, then reset()
+        if len(self.kinds) != 1:
+            raise ValueError("set kinds[name].num_pages on a pool of "
+                             "several kinds")
+        self._first.num_pages = int(n)
+
+    @property
     def sentinel(self) -> int:
         """Out-of-bounds page id: dropped by scatters, clamped (and then
-        length-masked) by gathers."""
-        return self.num_pages
+        length-masked) by gathers. A kind's own is ``sentinel_of``."""
+        return self._first.num_pages
+
+    def sentinel_of(self, kind: Optional[str] = None) -> int:
+        return self._kind(kind).num_pages
+
+    @property
+    def allocs(self) -> int:
+        return sum(k.allocs for k in self.kinds.values())
+
+    @property
+    def stalls(self) -> int:
+        return sum(k.stalls for k in self.kinds.values())
 
     @staticmethod
-    def _page_bytes(cfg, page: int,
-                    leaf_specs: Optional[Dict[str, tuple]] = None) -> int:
-        """HBM bytes one page occupies across every cache leaf."""
-        import math
-
+    def _kind_page_bytes(kind: CacheKind, page: int) -> int:
         import jax.numpy as jnp
 
         per_token = sum(
             math.prod(spec[0]) * jnp.dtype(spec[1]).itemsize
-            for spec in (leaf_specs or kv_leaf_specs(cfg)).values())
-        return cfg.n_layers * page * per_token
+            for spec in kind.leaves.values())
+        return kind.layers * page * per_token
+
+    @staticmethod
+    def _page_bytes(cfg, page: int,
+                    leaf_specs: Optional[Dict[str, Any]] = None) -> int:
+        """HBM bytes one page occupies across every cache leaf (of every
+        kind: one page of each)."""
+        return sum(PagePool._kind_page_bytes(kind, page)
+                   for kind in cache_kinds(cfg, leaf_specs))
 
     def _init_leaves(self) -> None:
         import jax.numpy as jnp
 
-        lead = (self.cfg.n_layers, self.num_pages, self.page)
-
-        def fresh():
+        def fresh_kind(kind: CacheKind):
+            lead = (kind.layers, kind.num_pages, self.page)
             return {name: jnp.full(lead + tuple(spec[0]),
                                    spec[2] if len(spec) > 2 else 0, spec[1])
-                    for name, spec in self.leaf_specs.items()}
+                    for name, spec in kind.leaves.items()}
+
+        def fresh():
+            if not self.by_kind:
+                return fresh_kind(self._first)
+            return {name: fresh_kind(kind)
+                    for name, kind in self.kinds.items()}
 
         if self.mesh is None:
             self.leaves = fresh()
@@ -165,8 +291,10 @@ class PagePool:
         subscriber is notified so co-resident owners can drop their now
         dangling page ids and device handles."""
         with self.lock:
-            self._free = list(range(self.num_pages))
-            self._refs = self._np.zeros((self.num_pages,), self._np.int32)
+            for kind in self.kinds.values():
+                kind.free = list(range(kind.num_pages))
+                kind.refs = self._np.zeros((kind.num_pages,),
+                                           self._np.int32)
             self._init_leaves()
             self._set_gauges()
             callbacks = list(self._reset_subscribers)
@@ -182,12 +310,13 @@ class PagePool:
 
     # -- ownership ----------------------------------------------------------
     def alloc(self, n: int = 1,
-              reclaim: Optional[Callable[[], bool]] = None
-              ) -> Optional[List[int]]:
-        """Allocate ``n`` pages at refcount 1, all-or-nothing. While the
-        free list is short, ``reclaim()`` (if given) is called to release
-        evictable pages; it returns False when it has nothing left. On
-        failure returns None and counts a stall — never blocks.
+              reclaim: Optional[Callable[[], bool]] = None,
+              kind: Optional[str] = None) -> Optional[List[int]]:
+        """Allocate ``n`` pages of ``kind`` at refcount 1,
+        all-or-nothing. While the free list is short, ``reclaim()`` (if
+        given) is called to release evictable pages; it returns False
+        when it has nothing left. On failure returns None and counts a
+        stall — never blocks.
 
         Self-serializing: the free list and refcounts mutate under the
         pool's own (reentrant) lock, so loop-thread allocation cannot
@@ -195,37 +324,51 @@ class PagePool:
         pool but not one thread. ``reclaim`` runs under the lock too;
         eviction callbacks re-enter ``release`` harmlessly (RLock)."""
         with self.lock:
-            while len(self._free) < n and reclaim is not None \
+            k = self._kind(kind)
+            while len(k.free) < n and reclaim is not None \
                     and reclaim():
                 pass
-            if len(self._free) < n:
-                self.stalls += 1
+            if len(k.free) < n:
+                k.stalls += 1
                 if self.metrics is not None:
                     self.metrics.increment_counter(
                         "app_tpu_kv_pages_stalled_total")
                 return None
-            ids = [self._free.pop() for _ in range(n)]
+            ids = [k.free.pop() for _ in range(n)]
             for pid in ids:
-                self._refs[pid] = 1
-            self.allocs += n
+                k.refs[pid] = 1
+            k.allocs += n
             self._set_gauges()
             return ids
 
-    def retain(self, page_ids: Sequence[int]) -> None:
+    def retain(self, page_ids: Sequence[int],
+               kind: Optional[str] = None) -> None:
         with self.lock:
+            refs = self._kind(kind).refs
             for pid in page_ids:
-                self._refs[pid] += 1
+                refs[pid] += 1
 
-    def release(self, page_ids: Sequence[int]) -> None:
-        """Drop one ref per page; refcount 0 returns the page to the free
-        list. Releasing an already-free page is a no-op (reset guards)."""
+    def release(self, page_ids: Sequence[int],
+                kind: Optional[str] = None) -> None:
+        """Drop one ref per page; refcount 0 returns the page to its
+        kind's free list. Releasing an already-free page is a no-op
+        (reset guards)."""
         with self.lock:
+            k = self._kind(kind)
             for pid in page_ids:
-                if self._refs[pid] > 0:
-                    self._refs[pid] -= 1
-                    if self._refs[pid] == 0:
-                        self._free.append(pid)
+                if k.refs[pid] > 0:
+                    k.refs[pid] -= 1
+                    if k.refs[pid] == 0:
+                        k.free.append(pid)
             self._set_gauges()
+
+    def release_behind(self, page_ids: Sequence[int], kind: str) -> None:
+        """``release`` for pages a window kind's slot has decoded past:
+        counted apart (``kinds.<kind>.freed_behind``), so a run can say
+        whether its traffic ever passed the window."""
+        with self.lock:
+            self.release(page_ids, kind)
+            self.kinds[kind].freed_behind += len(page_ids)
 
     @staticmethod
     def pad_table(table, block: int, sentinel: int):
@@ -257,29 +400,38 @@ class PagePool:
                 "app_tpu_kv_pages_written_total", float(pages))
 
     # -- introspection ------------------------------------------------------
+    def free_pages_of(self, kind: Optional[str] = None) -> int:
+        return len(self._kind(kind).free)
+
     @property
     def free_pages(self) -> int:
-        return len(self._free)
+        return sum(len(k.free) for k in self.kinds.values())
 
     @property
     def used_pages(self) -> int:
-        return self.num_pages - len(self._free)
+        return self.num_pages - self.free_pages
 
     @property
     def pool_bytes(self) -> int:
-        return self.num_pages * self.page_bytes
+        return sum(k.num_pages * k.page_bytes for k in self.kinds.values())
 
-    def refs(self, pid: int) -> int:
-        return int(self._refs[pid])
+    def refs(self, pid: int, kind: Optional[str] = None) -> int:
+        return int(self._kind(kind).refs[pid])
 
     def _set_gauges(self) -> None:
-        if self.metrics is not None:
+        if self.metrics is None:
+            return
+        for kind in self.kinds.values():
+            # a pool of several kinds labels its gauges by kind
+            labels = {"kind": kind.name} if self.by_kind else {}
             self.metrics.set_gauge("app_tpu_kv_pages_used",
-                                   float(self.used_pages))
+                                   float(kind.used_pages), **labels)
             self.metrics.set_gauge("app_tpu_kv_pages_capacity",
-                                   float(self.num_pages))
+                                   float(kind.num_pages), **labels)
 
     def stats(self) -> Dict[str, Any]:
+        """The totals over every kind under the names they always had,
+        and each kind's own under ``kinds.<kind>``."""
         return {
             "page_tokens": self.page,
             "num_pages": self.num_pages,
@@ -293,6 +445,13 @@ class PagePool:
             "allocs": self.allocs,
             "writes": self.writes,
             "stalls": self.stalls,
+            "kinds": {name: {"layers": k.layers, "window": k.window,
+                             "num_pages": k.num_pages,
+                             "used_pages": k.used_pages,
+                             "page_bytes": k.page_bytes,
+                             "allocs": k.allocs, "stalls": k.stalls,
+                             "freed_behind": k.freed_behind}
+                      for name, k in self.kinds.items()},
         }
 
 

@@ -295,7 +295,7 @@ def test_engine_failure_resets_pool_and_table(setup):
             engine._fail_outstanding(RuntimeError("boom"))
             engine._reset_device_state()
             assert engine._pool.free_pages == engine._pool.num_pages
-            assert (engine._table == engine._pool.sentinel).all()
+            assert (engine._tables["kv"] == engine._pool.sentinel).all()
             after = await asyncio.wait_for(
                 engine.generate([1, 2, 3, 4, 5], max_new_tokens=4), 60.0)
             return before, after

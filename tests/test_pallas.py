@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from gofr_tpu.models import llama
-from gofr_tpu.ops import attention, prefill_attention
+from gofr_tpu.ops import attention, banded_attention, prefill_attention
 from gofr_tpu.ops.pallas import flash_attention
 
 
@@ -50,6 +50,43 @@ def test_flash_mha_no_gqa():
     ref = prefill_attention(q, k, v)
     out = flash_attention(q, k, v, interpret=True, block_q=128, block_k=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [64, 128, 200, 384, 1000])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 256)])
+def test_flash_with_a_window_equals_the_banded_oracle(window, block_q,
+                                                      block_k):
+    """Query t attends t - window < s <= t: K blocks wholly behind the
+    window are skipped like those after the diagonal, the block at each
+    edge is masked, the blocks wholly inside are not. GQA 4:1, windows
+    narrower than a block, of a whole number of blocks, across blocks
+    and wider than the prompt."""
+    q, k, v = _qkv(512, q_heads=8, kv_heads=2)
+    ref = banded_attention(q, k, v, window, block=128)
+    out = flash_attention(q, k, v, window=window, interpret=True,
+                          block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_flash_in_bfloat16_runs_its_products_in_bfloat16():
+    """bfloat16 operands reach the two products as they are (the MXU's
+    own dtype; float32 sums), the softmax weights rounded to V's dtype
+    for the second: what ops.banded_attention does, so the two agree to
+    bfloat16's last bits at 16 query heads a KV head."""
+    q, k, v = (x.astype(jnp.bfloat16)
+               for x in _qkv(512, q_heads=16, kv_heads=1, batch=1))
+    ref = banded_attention(q, k, v, 192, block=128).astype(jnp.float32)
+    out = flash_attention(q, k, v, window=192, interpret=True, block_q=128,
+                          block_k=128).astype(jnp.float32)
+    assert float(jnp.abs(out - ref).max()) <= 2.0 ** -7 * float(
+        jnp.abs(ref).max())
+
+
+def test_flash_window_needs_causal():
+    q, k, v = _qkv(128)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=64)
 
 
 def test_flash_small_shapes_run_the_kernel():

@@ -351,3 +351,98 @@ def test_prefill_takes_the_ticks_weight_layouts_as_they_are(v5e):
     copies = re.findall(r"^.*= s8\[[\d,]+\]\S* copy\(.*$",
                         compiled.as_text(), re.M)
     assert not copies, copies[:3]
+
+
+# -- the window kind's lower bound, GQA 128:8 (ISSUE 31) ----------------------
+
+# the benchmark's command-a-plus-ep8.mixed call: 32 slots, 192 table
+# columns of page 32 (max_len 6144), 128 query heads over 8 KV heads
+CMDA_SLOTS, CMDA_COLUMNS, CMDA_HEADS, CMDA_KV = 32, 192, 128, 8
+
+
+@pytest.mark.parametrize("bounded", [True, False],
+                         ids=["window-kind", "full-kind"])
+def test_ragged_compiles_at_128_query_rows_with_and_without_a_bound(
+        v5e, bounded):
+    """Both kinds' calls of the window/global family at their real
+    sizes: at 128 query rows a block is 2 pages, the kept scores of a
+    192-column table pass ``_KEEP_SCORE_BYTES`` and K is streamed
+    twice; the bound is a fourth scalar-prefetch operand."""
+    assert ragged_tileable(HEAD_DIM, CMDA_HEADS, CMDA_KV, PAGE)
+    block_pages, ring_blocks, keep = walk_sizes(
+        PAGE, CMDA_KV, HEAD_DIM, CMDA_HEADS, 2, CMDA_COLUMNS)
+    assert (block_pages, keep) == (2, False) and ring_blocks >= 2
+    shapes = _paged_shapes(CMDA_HEADS, CMDA_KV, 1, False, slots=CMDA_SLOTS,
+                           pages_per_slot=CMDA_COLUMNS, num_pages=4224,
+                           layers=3)
+
+    def call(*ops):
+        return ragged_paged_decode_attention(
+            *ops[:8], interpret=False, start=ops[8] if bounded else None)
+
+    _compile(call, v5e, *shapes, ((CMDA_SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("window", [4096, None],
+                         ids=["window-kind", "full-kind"])
+def test_flash_with_a_window_compiles_at_128_query_heads_for_v5e(v5e,
+                                                                 window):
+    """The prefill attention of the window/global family at the cell's
+    longest served bucket: 128 query heads over 8 KV heads as column
+    blocks of (B, S, H x D), the band's edge in the K/V index maps."""
+    assert flash_tileable(6144, HEAD_DIM)
+    bf16 = jnp.bfloat16
+    _compile(functools.partial(flash_attention, window=window,
+                               interpret=False), v5e,
+             ((1, 6144, CMDA_HEADS, HEAD_DIM), bf16),
+             ((1, 6144, CMDA_KV, HEAD_DIM), bf16),
+             ((1, 6144, CMDA_KV, HEAD_DIM), bf16))
+
+
+def test_window_and_global_kinds_decode_in_one_tick_for_v5e(v5e):
+    """The steady tick of ``models/swa_moe.py`` at the published widths,
+    one period: a pool and a page table a kind, both through the ragged
+    kernel, the window kind's with its bound. The layer scan holds the
+    kernel once a layer of the period, and no plane of either pool is
+    copied."""
+    from gofr_tpu.models import swa_moe
+
+    cfg = swa_moe.config(
+        "command-a-plus", n_layers=4, layer_types=swa_moe.SwaMoeConfig()
+        .layer_types[:4], n_held_experts=16, vocab_size=32768,
+        max_seq_len=6144)
+    pages = {"window": 4224, "full": 6144}
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=v5e), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: swa_moe.init(cfg, jax.random.key(0))))
+    pool = {kind: {name: jax.ShapeDtypeStruct(
+        (spec["layers"], pages[kind], PAGE, *tail), dtype)
+        for name, (tail, dtype) in spec["leaves"].items()}
+        for kind, spec in swa_moe.cache_leaves(cfg).items()}
+    rest = on_chip((
+        jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.int32), pool,
+        {kind: jax.ShapeDtypeStruct((CMDA_SLOTS, CMDA_COLUMNS), jnp.int32)
+         for kind in pool},
+        jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.int32),
+        jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.bool_)))
+
+    def tick(params, token, pool, table, cache_len, active):
+        logits, pool, cache_len, counted = swa_moe.decode_step_paged(
+            params, cfg, token, pool, table, cache_len, active,
+            ragged=True, counters=True)
+        return logits.argmax(-1), pool, cache_len, counted
+
+    compiled = jax.jit(tick, donate_argnums=(2, 4)).lower(
+        params, *rest).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          hlo)) == 4
+    for kind, layers in (("window", 3), ("full", 1)):
+        plane = rf"bf16\[{layers},{pages[kind]},{PAGE},8,128\]\S* copy\("
+        assert not re.findall(plane, hlo), kind
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -72,7 +72,7 @@ def admit_two(engine, temperature):
                seeds=jnp.asarray([11, 12], jnp.uint32))
     if engine.paged:
         ids = np.asarray(engine._pool.alloc(6), np.int32).reshape(2, 3)
-        engine._table[:2, :3] = ids
+        engine._tables["kv"][:2, :3] = ids
         engine._table_version += 1
         dev["flat_ids"] = jnp.asarray(ids[:, :2].reshape(-1))
     first, small, keys = engine._run_prefill(nb, lb, dev)
