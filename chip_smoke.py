@@ -66,6 +66,10 @@ SIZES: Dict[str, Dict[str, Any]] = {
         # MHA and Llama-3-8B GQA
         "heads": ((32, 32), (32, 8)), "head_dim": 128, "flash_seq": 1024,
         "kernel_slots": 8,
+        # the window kind of command-a-plus-ep8.mixed: GQA 128:8 (16 query
+        # rows a KV head), 32 slots, a table longer than the window
+        "window_case": {"heads": (128, 8), "slots": 32, "columns": 192,
+                        "window": 4096},
     },
     # CPU rehearsal and the tier-1 test: same code, toy widths
     "tiny": {
@@ -77,6 +81,8 @@ SIZES: Dict[str, Dict[str, Any]] = {
         "max_new_tokens": 6,
         "heads": ((4, 2),), "head_dim": 16, "flash_seq": 32,
         "kernel_slots": 4,
+        "window_case": {"heads": (32, 2), "slots": 4, "columns": 12,
+                        "window": 40},
     },
 }
 
@@ -178,22 +184,28 @@ def _bf16(rng, *shape):
 
 
 def _paged_case(rng, slots, pages_per_slot, page, kv_heads, q_heads,
-                head_dim, g_len, int8):
+                head_dim, g_len, int8, window=None):
     """A pool, a shuffled page table covering mixed fills (empty, one
-    token, page boundaries either side, full) and the new tokens."""
+    token, page boundaries either side, full) and the new tokens. With a
+    ``window`` the fills lie either side of it, a slot holds no page
+    wholly before its first attended position (the engine gave those
+    back), and the positions come back as ``start`` among the scales."""
     import jax.numpy as jnp
 
     full = pages_per_slot * page
     fills = [0, full, page + 1, full // 2 + 3, 1, page - 1, page, full - 1]
+    if window:
+        fills += [window - 1, window, window + 1, window + page + 5]
     fills = (fills * (slots // len(fills) + 1))[:slots]
+    starts = [max(fill - window + 1, 0) if window else 0 for fill in fills]
     num_pages = slots * pages_per_slot + 1
     order = rng.permutation(num_pages - 1)        # last page: never used
     table = np.full((slots, pages_per_slot), num_pages, np.int32)
     taken = 0
-    for slot, fill in enumerate(fills):
-        need = -(-fill // page)
-        table[slot, :need] = order[taken:taken + need]
-        taken += need
+    for slot, (fill, first) in enumerate(zip(fills, starts)):
+        first, need = first // page, -(-fill // page)
+        table[slot, first:need] = order[taken:taken + need - first]
+        taken += need - first
 
     shape = (num_pages, page, kv_heads, head_dim)
     scales = {}
@@ -211,6 +223,8 @@ def _paged_case(rng, slots, pages_per_slot, page, kv_heads, q_heads,
     args = (_bf16(rng, slots, g_len, q_heads, head_dim), k_pages, v_pages,
             jnp.asarray(table), _bf16(rng, *new_shape),
             _bf16(rng, *new_shape), jnp.asarray(fills, jnp.int32))
+    if window:
+        scales["start"] = jnp.asarray(starts, jnp.int32)
     return args, scales
 
 
@@ -245,9 +259,11 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
     def one_plane(kernel):
         # the ragged kernels read the stacked (L, ...) pool and take the
         # layer; a case holding one plane is layer 0 of plane[None]
-        def call(q, k_pages, v_pages, *rest, interpret, **scales):
+        def call(q, k_pages, v_pages, *rest, interpret, start=None,
+                 **scales):
+            bound = {} if start is None else {"start": start}
             return kernel(q, k_pages[None], v_pages[None], *rest, 0,
-                          interpret=interpret,
+                          interpret=interpret, **bound,
                           **{name: plane[None]
                              for name, plane in scales.items()})
         return call
@@ -270,6 +286,15 @@ def phase_kernels(run: Run, size: str, interpret: bool = False) -> None:
         compare(f"ragged_verify {tag} g=5",
                 one_plane(ragged_paged_verify_attention),
                 paged_verify_attention, args)
+    case = spec["window_case"]
+    q_heads, kv_heads = case["heads"]
+    args, bound = _paged_case(rng, case["slots"], case["columns"], page,
+                              kv_heads, q_heads, head_dim, 1, False,
+                              window=case["window"])
+    compare(f"ragged_decode {q_heads}:{kv_heads} bf16 "
+            f"window {case['window']}",
+            one_plane(ragged_paged_decode_attention),
+            paged_decode_attention, args, bound)
     run.emit("kernels", setup_s=time.perf_counter() - started,
              interpret=interpret, kernels=results)
 

@@ -33,18 +33,32 @@ could walk in place. This kernel walks them in place:
   Mistral-7B decode step on a v5e (PERF.md, PR 26). Picking the layer
   inside the kernel is what makes "in place" true on the chip;
   tests/test_pallas_aot.py holds the compiled engine tick to it.
-- ALL KV HEADS IN ONE PRODUCT. A page ``(page, Hkv, D)`` is read as
-  ``page * Hkv`` rows of ``D``, and every query row of every head is
-  multiplied with all of them: of the ``Hkv`` columns a token gets, a
-  row keeps its own head's and masks the rest. Picking one head out of
-  the sublane dimension costs a shuffle a token a head, and a DMA cannot
-  pick it either (bf16 heads are packed in pairs in a 32-bit sublane);
-  the MXU, at a few percent, has the room for the other heads' products,
-  P.V needs no picking (a masked probability is exactly 0), and a block
-  of pages is one ``(rows, D) x (D, block * page * Hkv)`` product. The
-  MXU takes its operands in the cache dtype: their products are exact
-  in float32, so only the order of the float32 sums differs from
-  float32 dots.
+- A QUERY ROW MEETS ITS OWN KV HEAD ONLY. A block of pages lies in the
+  ring token-major, ``(tokens, Hkv, D)``, and a head's ``(tokens, D)``
+  rows come out of it by a strided load of 32-bit words down the
+  sublanes (every ``Hkv / 2``-th word of a bfloat16 pool is one pair of
+  heads of every token) and a shift or a mask (a bfloat16 is the top
+  half of a float32; an int8 a byte, shifted down with its sign): two
+  vector ops a vreg, after jax's own ragged kernel
+  (``strided_load_kv``). A head's ``rows = G x group`` query rows are
+  multiplied with that head's rows alone, so a block's scores are
+  ``(rows_all, tokens)`` and no column is another head's. Until PR 32
+  a page was read as ``page * Hkv`` rows and every query row scored
+  all of them, its own head's column kept and the others masked,
+  because picking a head out of the sublanes was reckoned a shuffle a
+  token a head: the MXU had the room, but the rounding, the masks, the
+  exponential and the sums then ran over ``Hkv`` times the scores the
+  attention has, and at 128 query rows (GQA 128:8) the kernel was bound
+  by that vector work at 22 % of its HBM roofline (PERF.md, PR 32: 1.56
+  -> 0.50 ms a call of 32 slots x ~2500 rows). Where a head's rows are
+  no whole sublane tile (4 at GQA 32:8, 1 at MHA) the fewest heads
+  whose rows are share a product (:func:`walk_sizes`,
+  ``product_heads``): their query rows lie block-diagonally over the
+  heads' lanes against the heads' rows side by side, the zeros do the
+  picking inside the MXU, and the K tiles the MXU loads are the same
+  count. The MXU takes its operands in the cache dtype: their products
+  are exact in float32, so only the order of the float32 sums differs
+  from float32 dots.
 - TWO-PHASE page walk for token identity. Phase 0 streams K and
   finishes the softmax statistics (max and normalizer); phase 1 forms
   the *final* per-position probabilities and accumulates P·V. A
@@ -61,12 +75,14 @@ could walk in place. This kernel walks them in place:
   heads; over 32 query heads or hundreds of tokens an output's last bit
   moves now and then, under this walk as under the grid kernel before
   it: tests/test_ragged_attention.py ``_assert_identity``). **K
-  leaves HBM once**: phase 0 keeps its masked float32 scores in VMEM
-  (2 MiB at 16 slots x 2048 positions, GQA 32:8) and phase 1 reads V
-  alone. Where a table's scores pass ``_KEEP_SCORE_BYTES`` (the verify
-  variant at that table, any decode table much longer) phase 1 streams
-  K again and re-derives them: the same body, chosen by the shapes
-  (:func:`walk_sizes`), not by a flag.
+  leaves HBM once**: phase 0 keeps its masked float32 scores in VMEM,
+  a row a token (0.25 MiB at 2048 positions and GQA 32:8, 4.7 MB at
+  9216 positions and GQA 128:8, where a column a token a KV head was
+  38 MB and K was streamed twice), and phase 1 reads V alone. Where a
+  table's scores pass ``_KEEP_SCORE_BYTES`` (128 query rows over some
+  16000 positions and more) phase 1 streams K again and re-derives
+  them: the same body, chosen by the shapes (:func:`walk_sizes`), not
+  by a flag.
 - The copies of one slot are ONE sequence, K blocks then V blocks, so
   V's first blocks are in flight while phase 0 ends, and a program
   starts the first copies of the next slot before it returns: the ring
@@ -79,19 +95,21 @@ could walk in place. This kernel walks them in place:
   NaN, table entries past the prefix inside a partly live block
   included.
 - int8 pools dequantize **in-kernel** from the scale planes that live
-  beside the pages: a page's scales ride the ring as one lane row, in
-  the order of its score columns (the same math as the gather path's
-  post-einsum score folding and pre-einsum value folding, without ever
-  materializing a converted cache copy).
+  beside the pages: a page's scales ride the ring head-major in whole
+  lane rows, ``page`` scales a head, in the order of the head's score
+  columns (the same math as the gather path's post-einsum score folding
+  and pre-einsum value folding, without ever materializing a converted
+  cache copy).
 - The γ+1-token query variant (:func:`ragged_paged_verify_attention`)
   backs speculative verify: G queries at positions ``cache_len + g``
   attend the paged cache plus each other causally, so verify stops
   paying prefill-shaped attention.
 
-Measured on the chip through the engine: bf16 pools at GQA 32:8, decode
-(the benchmark's ``mistral7b.batch``). int8 pools, the verify variant
-and MHA 32:32 have been timed alone and compared with the oracle there,
-not served (PERF.md, PR 28).
+Measured on the chip through the engine: bf16 pools at GQA 32:8 (the
+benchmark's ``mistral7b.batch``) and at GQA 128:8 with and without a
+window's bound (``command-a-plus-ep8.mixed``), decode. int8 pools, the
+verify variant and MHA 32:32 are compared with the oracle there by
+``chip_smoke.py``, not served (PERF.md, PR 28 and PR 32).
 
 Post-mortem context: a dense flash-decode kernel over the per-slot cache
 (deleted in PR 29) lost 5x *inside* the per-layer scan, 640 vs 131 ms a
@@ -109,7 +127,7 @@ caller's decision (ops/pallas/select), never taken in here.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -147,38 +165,73 @@ def _round_to(x, dtype):
     return lax.bitcast_convert_type(bits, jnp.float32)
 
 
-# the walk's sizes come from these three and the shapes the call sees
+# the walk's sizes come from these and the shapes the call sees
 _BLOCK_SCORE_BYTES = 256 * 1024   # float32 scores of one block: 64 vregs
 _RING_BYTES = 2 << 20             # page copies in flight, K or V
 _KEEP_SCORE_BYTES = 8 << 20       # a slot's masked scores kept for phase 1
-_NEVER = 1 << 30                  # ``lim`` of a column of another head
+_ROW_TILE = 16                    # sublanes of a bfloat16 tile
+_LANES = 128
+
+
+def _score_columns(block_pages: int, page: int) -> int:
+    """Columns a block takes among the kept scores: its tokens, in whole
+    lane tiles, so that every block's scores start at one."""
+    return -(-block_pages * page // _LANES) * _LANES
+
+
+class Walk(NamedTuple):
+    """What :func:`walk_sizes` answers for a call's shapes."""
+    block_pages: int
+    ring_blocks: int
+    keep_scores: bool
+    product_heads: int
 
 
 def walk_sizes(page: int, kv_heads: int, head_dim: int, rows_all: int,
-               itemsize: int, table_width: int):
-    """``(block_pages, ring_blocks, keep_scores)`` of the page walk.
+               itemsize: int, table_width: int) -> Walk:
+    """``(block_pages, ring_blocks, keep_scores, product_heads)`` of the
+    page walk.
 
-    A block is ``block_pages`` pages scored in one product,
-    ``(rows_all, D) x (D, block_pages * page * kv_heads)``: as many as
-    keep the block's float32 scores within ``_BLOCK_SCORE_BYTES``.
-    The ring holds ``ring_blocks`` blocks of page copies, about
-    ``_RING_BYTES`` of them and at least two. ``keep_scores`` says
-    whether a slot's masked scores over the whole table fit in
-    ``_KEEP_SCORE_BYTES`` of VMEM, so that phase 1 reads V alone; where
-    they do not, phase 1 streams K a second time."""
-    cols = page * kv_heads
+    A block is ``block_pages`` pages, scored a KV head at a time,
+    ``(rows, D) x (D, block_pages * page)``: as many pages as keep the
+    block's float32 scores ``(rows_all, block_pages * page)`` within
+    ``_BLOCK_SCORE_BYTES`` and the block's page copies within a quarter
+    of ``_RING_BYTES``. The ring holds ``ring_blocks`` blocks, about
+    ``_RING_BYTES`` of them: four blocks of 8 pages in flight were as
+    fast as two or three of 16 at GQA 128:8 (0.408 against 0.416 and
+    0.396 ms a call of 32 slots) and faster at GQA 32:8, where a slot
+    holds some 18 pages and a long block is mostly dead columns (0.0742
+    against 0.0807 ms; PERF.md, PR 32).
+
+    ``keep_scores`` says whether a slot's masked scores over the whole
+    table fit in ``_KEEP_SCORE_BYTES`` of VMEM, so that phase 1 reads V
+    alone; where they do not, phase 1 streams K a second time.
+
+    ``product_heads`` is how many KV heads share one product: one where
+    a head's query rows are whole ``_ROW_TILE`` tiles (GQA 128:8: 16
+    rows), else the fewest whose rows together are (GQA 32:8: 4 heads
+    of 4 rows; all of them where none does), their query rows laid
+    block-diagonally over the heads' lanes so that a row still meets
+    its own head only."""
+    page_bytes = page * kv_heads * head_dim * itemsize
     block_pages = max(1, min(table_width,
-                             _BLOCK_SCORE_BYTES // (rows_all * cols * 4)))
-    block_bytes = block_pages * cols * head_dim * itemsize
-    ring_blocks = max(2, min(8, _RING_BYTES // block_bytes))
+                             _BLOCK_SCORE_BYTES // (rows_all * page * 4),
+                             _RING_BYTES // (4 * page_bytes)))
+    ring_blocks = max(2, min(8, _RING_BYTES // (block_pages * page_bytes)))
     blocks = -(-table_width // block_pages)
-    keep = rows_all * blocks * block_pages * cols * 4 <= _KEEP_SCORE_BYTES
-    return block_pages, ring_blocks, keep
+    keep = (rows_all * blocks * _score_columns(block_pages, page) * 4
+            <= _KEEP_SCORE_BYTES)
+    rows = rows_all // kv_heads
+    product_heads = next(
+        (n for n in range(1, kv_heads) if kv_heads % n == 0
+         and n * rows % _ROW_TILE == 0), kv_heads)
+    return Walk(block_pages, ring_blocks, keep, product_heads)
 
 
 def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
                    block_pages: int, ring_blocks: int, keep_scores: bool,
-                   int8: bool, sm_scale: float, bounded: bool):
+                   product_heads: int, int8: bool, sm_scale: float,
+                   bounded: bool):
     """One slot's program: walk its live pages, and nothing else.
 
     ``bounded`` (a sliding-window layer): a fourth scalar-prefetch
@@ -186,8 +239,8 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
     at the block that holds it, pages wholly before it are not copied
     (their table entries may be the sentinel: the pages went back to the
     pool), and positions before it inside its page are masked, by the
-    same comparison with ``lim_ref`` that masks the positions past the
-    length. Without it the program is the one it always was.
+    same comparison with the token's offset that masks the positions
+    past the length. Without it the program is the one it always was.
 
     ``k_hbm`` / ``v_hbm`` are the stacked pool leaves, left in HBM; the
     body copies the live pages of this layer into a VMEM ring itself,
@@ -199,16 +252,17 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
     before this program returns (the grid runs in order on one core).
     Where the scores are not kept, phase 1's items alternate K and V.
 
-    Every kv-head is scored at once: a page ``(page, Hkv, D)`` is read
-    as ``(page * Hkv, D)`` rows, token-major, so ``q (R, D)`` (all
-    query rows of all heads) times its transpose gives ``(R, page *
-    Hkv)`` scores of which a row's own head holds one column in
-    ``Hkv``; the others are masked, like the positions past the slot's
-    length, by one comparison with ``lim_ref`` (column's token offset in
-    the block, or ``_NEVER`` for another head's column). Picking a head
-    out of the sublane dimension costs a shuffle a token a head; the
-    MXU has the room for the other heads' products and P.V needs no
-    picking either, since a masked probability is exactly 0.
+    A query row is scored against its own KV head only. A block lies in
+    the ring token-major, ``(tokens, Hkv, D)``; ``heads_of`` takes each
+    head's ``(tokens, D)`` rows out of it with a strided load of 32-bit
+    words and a shift or a mask (module docstring), and a head's
+    ``rows`` query rows meet those alone, so a block's scores are
+    ``(rows_all, tokens)`` and no column is another head's. Where a
+    head's rows are no whole sublane tile, ``product_heads`` heads share
+    a product: their query rows sit block-diagonally over the heads'
+    lanes, ``(product_heads * rows, product_heads * D)``, against the
+    heads' rows side by side, so the zeros do the picking inside the
+    MXU; P.V comes out a head a lane block, and a row keeps its own.
 
     Rounding points are the oracle's (module docstring): the MXU takes
     its operands in the cache dtype, whose products are exact in
@@ -218,7 +272,7 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
 
     if bounded:
         start_ref, *rest = rest
-    q_ref, k_hbm, v_hbm, kn_ref, vn_ref, lim_ref, new_ok_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, kn_ref, vn_ref, new_ok_ref, *rest = rest
     if int8:
         ks_hbm, vs_hbm, *rest = rest
     o_ref, ring, sems, *rest = rest
@@ -232,8 +286,8 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
     table_width = table_ref.shape[1]
     num_pages, page, kv_heads, head_dim = k_hbm.shape[1:]
     rows_all = q_ref.shape[1]
-    cols = page * kv_heads
-    width = block_pages * cols                 # score columns of a block
+    rows = rows_all // kv_heads                # query rows of a KV head
+    product_rows = product_heads * rows
     block_tokens = block_pages * page
     layer = layer_ref[0]
     # valid tokens, excl. new; the table holds no more than its columns
@@ -330,39 +384,117 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
         """The table's block of the walk's ``j``-th live block."""
         return first_block + j if bounded else j
 
-    def flat(at, dtype):
-        # (block_pages, page, Hkv, D) -> (width, D): free in float32,
-        # where a token's (Hkv, D) is whole tiles
-        return ring[at].astype(jnp.float32) \
-            .reshape(width, head_dim).astype(dtype)
+    def heads_of(at, dtype):
+        """The block at ring place ``at`` a KV head: ``kv_heads`` arrays
+        ``(block_tokens, D)`` in ``dtype``. The ring's rows are (token,
+        head), and a 32-bit word down the sublanes holds ``pack`` heads
+        of one token: a load of every ``kv_heads / pack``-th word is
+        ``pack`` heads' rows, and a shift or a mask is one of them (a
+        bfloat16 is the top half of a float32; an int8 its top byte,
+        shifted down with its sign)."""
+        tokens = ring.at[at].reshape(block_tokens * kv_heads, head_dim)
+        pack = 4 // ring.dtype.itemsize
+        if pack == 1 or kv_heads % pack:
+            # a float32 pool's rows are words; heads that fill no whole
+            # word are a geometry ``ragged_tileable`` keeps off the
+            # chip, the interpreter's
+            return [tokens[pl.ds(h, block_tokens, stride=kv_heads), :]
+                    .astype(dtype) for h in range(kv_heads)]
+        words = tokens.bitcast(jnp.int32)
+        heads = []
+        for w in range(kv_heads // pack):
+            word = words[pl.ds(w, block_tokens, stride=kv_heads // pack), :]
+            for i in range(pack):
+                if pack == 2:
+                    head = lax.bitcast_convert_type(
+                        word << 16 if i == 0 else word & -65536,
+                        jnp.float32)
+                else:
+                    head = lax.shift_right_arithmetic(
+                        word << (24 - 8 * i), 24).astype(jnp.float32)
+                heads.append(head.astype(dtype))
+        return heads
 
-    def scale_row(at):
-        return jnp.concatenate(
-            [scale_ring[at, c] for c in range(block_pages)], axis=-1)
+    # a product is ``product_heads`` KV heads and their query rows
+    firsts = range(0, kv_heads, product_heads)
+    row = lax.broadcasted_iota(jnp.int32, (product_rows, 1), 0)
+
+    def rows_of(h):
+        return slice(h * rows, h * rows + product_rows)
+
+    def own(parts):
+        """One ``(product_rows, n)`` of a part a head of a product: each
+        row takes the part of its own head."""
+        out = parts[0]
+        for i, part in enumerate(parts[1:], 1):
+            out = jnp.where(row >= i * rows, part, out)
+        return out
+
+    def products(fn):
+        """``fn(first head)`` of each product, one under the other."""
+        return jnp.concatenate([fn(h) for h in firsts], axis=0)
+
+    def side_by_side(heads, h):
+        return jnp.concatenate(heads[h:h + product_heads], axis=1)
+
+    def scale_of(at, h):
+        """The scales of a product's score columns, ``(product_rows,
+        block_tokens)``. A page's scales ride the ring head-major in
+        whole lane rows, so a head's are ``page`` lanes of a row (of
+        several rows where a page is longer than one)."""
+        def of_head(head):
+            pieces = []
+            for c in range(block_pages):
+                lo, hi = head * page, (head + 1) * page
+                while lo < hi:
+                    r, lane = divmod(lo, _LANES)
+                    n = min(_LANES - lane, hi - lo)
+                    pieces.append(scale_ring[at, c, r:r + 1, lane:lane + n])
+                    lo += n
+            return jnp.concatenate(pieces, axis=1)
+
+        return own([of_head(h + i) for i in range(product_heads)])
 
     q_all = q_ref[0]                                        # (R, D)
 
-    def scores_of(keys):
+    def diagonal(q):
+        """A product's query rows, block-diagonal over its heads' lanes:
+        a row is zero in the lanes of every head but its own."""
+        if product_heads == 1:
+            return q
+        wide = q.astype(jnp.float32)
+        return jnp.concatenate(
+            [jnp.where(jnp.logical_and(row >= i * rows,
+                                       row < (i + 1) * rows), wide, 0.0)
+             for i in range(product_heads)], axis=1).astype(q.dtype)
+
+    q_own = {h: diagonal(q_all[rows_of(h)]) for h in firsts}
+    offset = lax.broadcasted_iota(jnp.int32, (1, block_tokens), 1)
+
+    def scores_of(q, keys):
         # rounding order matches the oracle exactly: dot -> cache-dtype
         # round -> * sm_scale -> (* k_scale on int8) -> mask. q stays
         # UNSCALED: the oracle applies sm_scale after the (rounded)
         # score einsum.
         return _round(lax.dot_general(
-            q_all, keys, (((1,), (1,)), ((), ())),
+            q, keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)) * sm_scale
 
     def block_scores(at, j):
-        s = scores_of(flat(at, q_all.dtype))
-        if int8:
+        heads = heads_of(at, q_all.dtype)
+
+        def one(h):
+            s = scores_of(q_own[h], side_by_side(heads, h))
             # fused dequant, oracle formulation: the int8 scores are
             # exact through the rounded dot, and the per-vector scale
             # folds into f32 AFTER — never a converted cache copy
-            s = s * scale_row(at)
-        ok = lim_ref[...] < length - j * block_tokens
+            return s * scale_of(at, h) if int8 else s
+
+        ok = offset < length - j * block_tokens
         if bounded:
             ok = jnp.logical_and(
-                ok, lim_ref[...] >= start_ref[b] - j * block_tokens)
-        return jnp.where(ok, s, _NEG_INF)
+                ok, offset >= start_ref[b] - j * block_tokens)
+        return jnp.where(ok, products(one), _NEG_INF)
 
     def fold_stats(stats, scores):
         m_prev, l_prev = stats
@@ -371,7 +503,8 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
                        + jnp.exp(scores - m_new).sum(axis=-1, keepdims=True))
 
     def score_cols(j):
-        return pl.ds(pl.multiple_of(j * width, width), width)
+        stride = _score_columns(block_pages, page)
+        return pl.ds(pl.multiple_of(j * stride, stride), block_tokens)
 
     # -- phase 0: softmax statistics over the live pages ------------------
     def stats_step(j, stats):
@@ -385,10 +518,13 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
         (jnp.full((rows_all, 1), _NEG_INF, jnp.float32),
          jnp.zeros((rows_all, 1), jnp.float32)))
     # the G new tokens (positions length..length+G-1, causal among
-    # themselves); their K arrives unquantized even on int8 pools (oracle
-    # contract). Folding them makes m/l FINAL (the causal diagonal
-    # guarantees l >= 1, so phase 1 never divides by zero)
-    s_new = jnp.where(new_ok_ref[...] > 0, scores_of(kn_ref[0]), _NEG_INF)
+    # themselves), once a slot and a few columns, so every row meets
+    # every head's and ``new_ok`` masks the others'; their K arrives
+    # unquantized even on int8 pools (oracle contract). Folding them
+    # makes m/l FINAL (the causal diagonal guarantees l >= 1, so phase 1
+    # never divides by zero)
+    s_new = jnp.where(new_ok_ref[...] > 0, scores_of(q_all, kn_ref[0]),
+                      _NEG_INF)
     m_fin, l_fin = fold_stats(stats, s_new)
 
     # -- phase 1: oracle-identical probabilities, P.V accumulation --------
@@ -400,14 +536,23 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
             s = block_scores(arrive(blocks + 2 * j), table_block(j))
             at = arrive(blocks + 2 * j + 1)
         p = jnp.exp(s - m_fin) / l_fin
-        if int8:
-            # oracle int8 V path: normalized probs stay f32 and the
-            # per-vector scale folds in pre-einsum (precision over
-            # bandwidth — see decode_attention_cached)
-            p, v = p * scale_row(at), flat(at, jnp.float32)
-        else:
-            p, v = _round(p).astype(cdt), flat(at, cdt)  # probs.astype
-        return acc + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        # oracle int8 V path: normalized probs stay f32 and the
+        # per-vector scale folds in pre-einsum (precision over
+        # bandwidth — see decode_attention_cached); else probs.astype
+        heads = heads_of(at, jnp.float32 if int8 else cdt)
+        if not int8:
+            p = _round(p).astype(cdt)
+
+        def one(h):
+            p_own = p[rows_of(h)]
+            wide = jnp.dot(p_own * scale_of(at, h) if int8 else p_own,
+                           side_by_side(heads, h),
+                           preferred_element_type=jnp.float32)
+            # a head a lane block: a row keeps its own head's
+            return own([wide[:, i * head_dim:(i + 1) * head_dim]
+                        for i in range(product_heads)])
+
+        return acc + products(one)
 
     acc = lax.fori_loop(0, blocks, value_step,
                         jnp.zeros((rows_all, head_dim), jnp.float32))
@@ -438,8 +583,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     re-lay the WHOLE leaf out, padded sixteenfold, every layer (AOT at
     Mistral-7B sizes: two 452 MB copies a layer). One layer's scale
     plane is 1/128 of its K plane, so slicing it costs what it always
-    did; the slice comes out as one lane row a page, token-major like
-    the scores it scales."""
+    did; the slice comes out head-major in whole lane rows, ``page``
+    scales a head a page, in the order of the scores they scale."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -448,12 +593,9 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     group = q_heads // kv_heads
     rows = g_len * group
     rows_all = kv_heads * rows
-    cols = page * kv_heads
     int8 = bool(scale_pages)
-    block_pages, ring_blocks, keep_scores = walk_sizes(
-        page, kv_heads, head_dim, rows_all, k_pages.dtype.itemsize,
-        page_table.shape[1])
-    width = block_pages * cols
+    sizes = walk_sizes(page, kv_heads, head_dim, rows_all,
+                       k_pages.dtype.itemsize, page_table.shape[1])
     table = page_table.astype(jnp.int32)
     lens = cache_len.astype(jnp.int32)
     # head-major rows (see _ragged_kernel): q-head kv*group + j of query
@@ -467,10 +609,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         batch, kv_heads * g_len, head_dim)
     vn_hm = v_new.transpose(0, 2, 1, 3).reshape(
         batch, kv_heads * g_len, head_dim)
-    # the masks' shape-only halves, built here so the body divides nothing
+    # the new tokens' mask, built here so the body divides nothing
     row_head = np.arange(rows_all)[:, None] // rows
-    col = np.arange(width)[None, :]
-    lim = np.where(col % kv_heads == row_head, col // kv_heads, _NEVER)
     new_col = np.arange(kv_heads * g_len)[None, :]
     new_ok = np.logical_and(
         new_col // g_len == row_head,
@@ -485,9 +625,10 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
 
     bounded = start is not None
     kernel = functools.partial(
-        _ragged_kernel, block_pages=block_pages, ring_blocks=ring_blocks,
-        keep_scores=keep_scores, int8=int8, sm_scale=head_dim ** -0.5,
-        bounded=bounded)
+        _ragged_kernel, block_pages=sizes.block_pages,
+        ring_blocks=sizes.ring_blocks, keep_scores=sizes.keep_scores,
+        product_heads=sizes.product_heads, int8=int8,
+        sm_scale=head_dim ** -0.5, bounded=bounded)
     prefetch = (table, lens, layer) + (
         (start.astype(jnp.int32),) if bounded else ())
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -496,26 +637,31 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         in_hbm, in_hbm,
         pl.BlockSpec((1, kv_heads * g_len, head_dim), slot_block),
         pl.BlockSpec((1, kv_heads * g_len, head_dim), slot_block),
-        pl.BlockSpec((rows_all, width), whole),
         pl.BlockSpec((rows_all, kv_heads * g_len), whole),
     ]
     operands = [q_hm, k_pages, v_pages, kn_hm, vn_hm,
-                jnp.asarray(lim, jnp.int32), jnp.asarray(new_ok, jnp.int32)]
+                jnp.asarray(new_ok, jnp.int32)]
     scratch = [
-        pltpu.VMEM((ring_blocks, block_pages, page, kv_heads, head_dim),
-                   k_pages.dtype),
-        pltpu.SemaphoreType.DMA((ring_blocks,)),
+        pltpu.VMEM((sizes.ring_blocks, sizes.block_pages, page, kv_heads,
+                    head_dim), k_pages.dtype),
+        pltpu.SemaphoreType.DMA((sizes.ring_blocks,)),
     ]
-    if keep_scores:
-        blocks = -(-page_table.shape[1] // block_pages)
-        scratch.append(pltpu.VMEM((rows_all, blocks * width), jnp.float32))
+    if sizes.keep_scores:
+        blocks = -(-page_table.shape[1] // sizes.block_pages)
+        scratch.append(pltpu.VMEM(
+            (rows_all, blocks * _score_columns(sizes.block_pages, page)),
+            jnp.float32))
     if int8:
         in_specs += [in_hbm, in_hbm]
-        operands += [
+        scale_rows = -(-kv_heads * page // _LANES)
+        operands += [jnp.pad(
             lax.dynamic_index_in_dim(s, layer[0], 0, keepdims=False)
-            .reshape(num_pages, 1, cols) for s in scale_pages]
-        scratch.append(pltpu.VMEM((ring_blocks, block_pages, 1, cols),
-                                  jnp.float32))
+            .transpose(0, 2, 1).reshape(num_pages, kv_heads * page),
+            ((0, 0), (0, scale_rows * _LANES - kv_heads * page)))
+            .reshape(num_pages, scale_rows, _LANES) for s in scale_pages]
+        scratch.append(pltpu.VMEM(
+            (sizes.ring_blocks, sizes.block_pages, scale_rows, _LANES),
+            jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(batch,),

@@ -37,13 +37,18 @@ def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
 
 
 def ragged_tileable(head_dim: int, q_heads: int, kv_heads: int,
-                    page: int) -> bool:
+                    page: int, kv_itemsize: int = 2) -> bool:
     """Ragged-paged-attention tiling predicate: a 128-lane head_dim, a
-    sublane-filling q-head count, and a page deep enough to tile the KV
-    block (AOT-compiled for v5e at MHA 32:32 and GQA 32:8 by
+    sublane-filling q-head count, a page deep enough to tile the KV
+    block, and KV heads that fill whole 32-bit words down a page's
+    sublanes (two bfloat16 heads, four int8: the kernel takes a head's
+    rows out of a block by words; Mosaic has no strided load of
+    narrower rows, and refused a single KV head before that) (AOT-
+    compiled for v5e at MHA 32:32, GQA 32:8 and GQA 128:8 by
     tests/test_pallas_aot.py)."""
     return (q_heads % kv_heads == 0 and head_dim % 128 == 0
-            and q_heads % 8 == 0 and page % 16 == 0)
+            and q_heads % 8 == 0 and page % 16 == 0
+            and kv_heads % (4 // kv_itemsize) == 0)
 
 
 def lower_for_target(kernel: Callable, interpret: Optional[bool],

@@ -802,6 +802,7 @@ class GenerationEngine:
             raise ValueError("ragged_attn='on' requires paged_kv=True "
                              "(the kernel walks the page pool)")
         self._ragged, self.attn_reason = self._resolve_ragged()
+        self._walk = self._ragged_walk() if self._ragged else None
         if logger is not None:
             logger.info("engine %s attention: %s", self.model_name,
                         self.attention_paths())
@@ -1806,12 +1807,15 @@ class GenerationEngine:
         if self.mesh is not None:
             return False, ("auto: pallas_call has no partitioning rule, "
                            "a mesh would replicate the pool into it")
+        kv_itemsize = min(self._jnp.dtype(kind.leaves["k"][1]).itemsize
+                          for kind in self._pool.kinds.values())
         if not ragged_tileable(cfg.head_dim, cfg.n_heads, cfg.n_kv_heads,
-                               self.kv_page):
+                               self.kv_page, kv_itemsize):
             return False, (
                 f"auto: head_dim {cfg.head_dim}, heads {cfg.n_heads}:"
                 f"{cfg.n_kv_heads}, page {self.kv_page} do not tile "
-                f"(head_dim % 128, heads % 8, page % 16)")
+                f"(head_dim % 128, heads % 8, page % 16, kv heads % "
+                f"{4 // kv_itemsize})")
         return True, "auto: tpu devices and the geometry tiles"
 
     def attention_paths(self) -> Dict[str, Any]:
@@ -1822,10 +1826,29 @@ class GenerationEngine:
         flash = [b for b in self.prompt_buckets
                  if getattr(cfg, "use_flash", False)
                  and flash_tileable(b, cfg.head_dim)]
-        return {"decode": self.attn_path, "why": self.attn_reason,
-                "prefill_flash_buckets": flash,
-                "prefill_dense_buckets": [b for b in self.prompt_buckets
-                                          if b not in flash]}
+        paths = {"decode": self.attn_path, "why": self.attn_reason,
+                 "prefill_flash_buckets": flash,
+                 "prefill_dense_buckets": [b for b in self.prompt_buckets
+                                           if b not in flash]}
+        if self._walk:
+            paths["ragged_walk"] = self._walk
+        return paths
+
+    def _ragged_walk(self) -> Dict[str, Dict[str, Any]]:
+        """What the ragged kernel's page walk is at the decode call's
+        shape, a cache kind: the kernel's form follows from the shapes
+        (``walk_sizes``: pages a block, blocks in flight, whether a
+        slot's scores are kept so that K leaves HBM once, KV heads a
+        product), so this is where it shows."""
+        from gofr_tpu.ops.pallas.ragged_paged_attention import walk_sizes
+        walks = {}
+        for name, kind in self._pool.kinds.items():
+            (kv_heads, head_dim), dtype = kind.leaves["k"][:2]
+            walks[name] = walk_sizes(
+                self.kv_page, kv_heads, head_dim, self.cfg.n_heads,
+                self._jnp.dtype(dtype).itemsize,
+                self.pages_per_slot)._asdict()
+        return walks
 
     @property
     def attn_path(self) -> str:
@@ -3113,6 +3136,8 @@ class GenerationEngine:
             pool["deferred_requests"] = len(self._overflow)
             pool["attn_path"] = self.attn_path
             pool["ragged_attn"] = self.ragged_attn
+            if self._walk:
+                pool["ragged_walk"] = self._walk
             out["kv_pool"] = pool
         if self.spec:
             rate = (self._spec_accepted / self._spec_proposed

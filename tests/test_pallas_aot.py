@@ -124,21 +124,22 @@ def test_ragged_compiles_at_the_cells_call_shape(v5e, case):
     """The page walk at its real sizes. Mosaic refuses a kernel whose
     scratch passes the scoped VMEM limit, so the compile is the check;
     the arithmetic says what the scratch is: the ring of page copies,
-    the masked scores where they are kept (where they are not, phase 1
-    streams K again: both verify cases), the scale rows of an int8
-    pool, and the two constant masks the pipeline double-buffers."""
+    the masked scores a row a token (kept in every case here, so K
+    leaves HBM once: a column is a token, not a token a KV head), the
+    scale rows of an int8 pool, and the new tokens' constant mask the
+    pipeline double-buffers."""
     kernel, q_heads, kv_heads, g_len, int8 = CELL_CALLS[case]
     rows_all, cols = q_heads * g_len, PAGE * kv_heads
-    block_pages, ring_blocks, keep = walk_sizes(
-        PAGE, kv_heads, HEAD_DIM, rows_all, 1 if int8 else 2, CELL_COLUMNS)
-    width = block_pages * cols
-    ring = ring_blocks * width * HEAD_DIM * (1 if int8 else 2)
-    scores = rows_all * -(-CELL_COLUMNS // block_pages) * width * 4
-    scratch = (ring + (scores if keep else 0)
-               + (ring_blocks * width * 4 if int8 else 0)
-               + 2 * rows_all * (width + kv_heads * g_len) * 4)
-    assert 2 <= ring_blocks
-    assert keep == (g_len == 1), (case, scores)
+    sizes = walk_sizes(PAGE, kv_heads, HEAD_DIM, rows_all,
+                       1 if int8 else 2, CELL_COLUMNS)
+    ring_pages = sizes.ring_blocks * sizes.block_pages
+    ring = ring_pages * cols * HEAD_DIM * (1 if int8 else 2)
+    blocks = -(-CELL_COLUMNS // sizes.block_pages)
+    scores = rows_all * blocks * max(sizes.block_pages * PAGE, 128) * 4
+    scratch = (ring + scores + (ring_pages * cols * 4 if int8 else 0)
+               + 2 * rows_all * kv_heads * g_len * 4)
+    assert 2 <= sizes.ring_blocks
+    assert sizes.keep_scores, (case, scores)
     assert scratch < SCOPED_VMEM, (case, scratch)
     _compile(functools.partial(kernel, interpret=False), v5e,
              *_paged_shapes(q_heads, kv_heads, g_len, int8,
@@ -355,9 +356,12 @@ def test_prefill_takes_the_ticks_weight_layouts_as_they_are(v5e):
 
 # -- the window kind's lower bound, GQA 128:8 (ISSUE 31) ----------------------
 
-# the benchmark's command-a-plus-ep8.mixed call: 32 slots, 192 table
-# columns of page 32 (max_len 6144), 128 query heads over 8 KV heads
-CMDA_SLOTS, CMDA_COLUMNS, CMDA_HEADS, CMDA_KV = 32, 192, 128, 8
+# the benchmark's command-a-plus-ep8.mixed call: 32 slots, 288 table
+# columns of page 32 (max_len 9216), 128 query heads over 8 KV heads
+CMDA_SLOTS, CMDA_COLUMNS, CMDA_HEADS, CMDA_KV = 32, 288, 128, 8
+# what the cell's kernel metrics find the call by
+# (benchmark/layer_metrics/*.cmda.json)
+CMDA_RESULT = r"bf16\[32,128,128\]\S* custom-call\("
 
 
 @pytest.mark.parametrize("bounded", [True, False],
@@ -365,13 +369,14 @@ CMDA_SLOTS, CMDA_COLUMNS, CMDA_HEADS, CMDA_KV = 32, 192, 128, 8
 def test_ragged_compiles_at_128_query_rows_with_and_without_a_bound(
         v5e, bounded):
     """Both kinds' calls of the window/global family at their real
-    sizes: at 128 query rows a block is 2 pages, the kept scores of a
-    192-column table pass ``_KEEP_SCORE_BYTES`` and K is streamed
-    twice; the bound is a fourth scalar-prefetch operand."""
+    sizes: 16 query rows a KV head, so a product a head; the scores of
+    all 288 columns are kept (4.7 MB) and K leaves HBM once; the bound
+    is a fourth scalar-prefetch operand; the call's result is the type
+    the cell's metrics find it by."""
     assert ragged_tileable(HEAD_DIM, CMDA_HEADS, CMDA_KV, PAGE)
-    block_pages, ring_blocks, keep = walk_sizes(
-        PAGE, CMDA_KV, HEAD_DIM, CMDA_HEADS, 2, CMDA_COLUMNS)
-    assert (block_pages, keep) == (2, False) and ring_blocks >= 2
+    sizes = walk_sizes(PAGE, CMDA_KV, HEAD_DIM, CMDA_HEADS, 2, CMDA_COLUMNS)
+    assert sizes.keep_scores and sizes.product_heads == 1
+    assert sizes.ring_blocks >= 2
     shapes = _paged_shapes(CMDA_HEADS, CMDA_KV, 1, False, slots=CMDA_SLOTS,
                            pages_per_slot=CMDA_COLUMNS, num_pages=4224,
                            layers=3)
@@ -380,7 +385,9 @@ def test_ragged_compiles_at_128_query_rows_with_and_without_a_bound(
         return ragged_paged_decode_attention(
             *ops[:8], interpret=False, start=ops[8] if bounded else None)
 
-    _compile(call, v5e, *shapes, ((CMDA_SLOTS,), jnp.int32))
+    hlo = _compile(call, v5e, *shapes, ((CMDA_SLOTS,), jnp.int32)).as_text()
+    assert len(re.findall(CMDA_RESULT, hlo)) == 1
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("window", [4096, None],
@@ -410,8 +417,8 @@ def test_window_and_global_kinds_decode_in_one_tick_for_v5e(v5e):
     cfg = swa_moe.config(
         "command-a-plus", n_layers=4, layer_types=swa_moe.SwaMoeConfig()
         .layer_types[:4], n_held_experts=16, vocab_size=32768,
-        max_seq_len=6144)
-    pages = {"window": 4224, "full": 6144}
+        max_seq_len=9216)
+    pages = {"window": 4224, "full": 9216}
 
     def on_chip(tree):
         return jax.tree.map(
@@ -442,6 +449,7 @@ def test_window_and_global_kinds_decode_in_one_tick_for_v5e(v5e):
     hlo = compiled.as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           hlo)) == 4
+    assert len(re.findall(CMDA_RESULT, hlo)) == 4
     for kind, layers in (("window", 3), ("full", 1)):
         plane = rf"bf16\[{layers},{pages[kind]},{PAGE},8,128\]\S* copy\("
         assert not re.findall(plane, hlo), kind

@@ -75,13 +75,13 @@ def _operands(seed, B, g_len, int8, num_pages, page, hkv, hq, head_dim):
     return q, k_pages, v_pages, k_new, v_new, scales
 
 
-def _scenario(cache_lens, g_len=1, int8=False, head_dim=D, seed=0):
+def _scenario(cache_lens, g_len=1, int8=False, head_dim=D, seed=0, hq=HQ):
     """Pool leaves + a page table covering each slot's cache_len (pages
     allocated bottom-up, page NUM_PAGES-1 deliberately never used — it
     is the kernel's clamp target for sentinel entries)."""
     B = len(cache_lens)
     q, k_pages, v_pages, k_new, v_new, scales = _operands(
-        seed, B, g_len, int8, NUM_PAGES, PAGE, HKV, HQ, head_dim)
+        seed, B, g_len, int8, NUM_PAGES, PAGE, HKV, hq, head_dim)
     table = np.full((B, P), SENTINEL, np.int32)
     nxt = 0
     for b, n in enumerate(cache_lens):
@@ -116,8 +116,17 @@ FILLS = [0, 5, P * PAGE, 17]            # empty / one partial / max / mixed
 
 # -- tentpole: bit-identity with the gather oracle ---------------------------
 
-def test_decode_identity_vs_gather_fill_patterns():
-    args, _, _ = _scenario(FILLS)
+# query heads over the 2 KV heads: 2 rows a KV head (both heads share a
+# product, block-diagonally) and 16 (a product a head)
+ROWS_A_HEAD = pytest.mark.parametrize("hq", [HQ, 16 * HKV],
+                                      ids=["2-rows", "16-rows"])
+
+
+@ROWS_A_HEAD
+def test_decode_identity_vs_gather_fill_patterns(hq):
+    assert walk_sizes(PAGE, HKV, D, hq, 2, P).product_heads == (
+        HKV if hq == HQ else 1)
+    args, _, _ = _scenario(FILLS, hq=hq)
     oracle = paged_decode_attention(*args)
     out = ragged_paged_decode_attention(*_stacked(args)[0])
     assert out.dtype == oracle.dtype
@@ -135,25 +144,28 @@ def test_decode_identity_under_jit():
     assert bool((jitted == ragged).all())
 
 
-def test_decode_identity_int8_fused_dequant():
-    args, scales, _ = _scenario([5, 33, 64], int8=True)
+@ROWS_A_HEAD
+def test_decode_identity_int8_fused_dequant(hq):
+    args, scales, _ = _scenario([5, 33, 64], int8=True, hq=hq)
     oracle = paged_decode_attention(*args, **scales)
     args, scales = _stacked(args, scales)
     out = ragged_paged_decode_attention(*args, **scales)
     assert bool((out == oracle).all())
 
 
-def test_verify_identity_gamma_plus_one():
+@ROWS_A_HEAD
+def test_verify_identity_gamma_plus_one(hq):
     """γ+1-token verify variant: causal among the new tokens, same
     rounding schedule — bit-equal to paged_verify_attention."""
-    args, _, _ = _scenario([0, 7, 40], g_len=3)
+    args, _, _ = _scenario([0, 7, 40], g_len=3, hq=hq)
     oracle = paged_verify_attention(*args)
     out = ragged_paged_verify_attention(*_stacked(args)[0])
     assert bool((out == oracle).all())
 
 
-def test_verify_identity_int8():
-    args, scales, _ = _scenario([9, 21], g_len=2, int8=True)
+@ROWS_A_HEAD
+def test_verify_identity_int8(hq):
+    args, scales, _ = _scenario([9, 21], g_len=2, int8=True, hq=hq)
     oracle = paged_verify_attention(*args, **scales)
     args, scales = _stacked(args, scales)
     out = ragged_paged_verify_attention(*args, **scales)
@@ -189,11 +201,15 @@ def test_layer_picked_in_kernel_from_stacked_pool(case, layer):
 
 
 # -- the page walk's edges ---------------------------------------------------
-# A geometry at which the walk has something to walk: 256 score columns a
-# page, so a block is a few pages (walk_sizes), a table of 72 columns is
-# several blocks and longer than the ring of page copies.
+# A geometry at which the walk has something to walk: the published page
+# and head widths, so a block is 8 pages (walk_sizes), a table of 76
+# columns is several blocks and a partly live one, and longer than the
+# ring of page copies. Two query-head counts over the 8 KV heads: 4 rows a
+# KV head (GQA 32:8: four heads share a product) and 16 (GQA 128:8: a
+# product a head).
 
-W_PAGE, W_HKV, W_HQ, W_D, W_P = 32, 8, 32, 16, 72
+W_PAGE, W_HKV, W_D, W_P = 32, 8, 128, 76
+W_HEADS = {"4-rows": 32, "16-rows": 128}
 WALKS = {
     "decode-bf16": (ragged_paged_decode_attention, paged_decode_attention,
                     dict()),
@@ -204,57 +220,75 @@ WALKS = {
 }
 EDGES = ["empty", "one", "page-1", "page", "page+1", "block-1", "block",
          "block+1", "table"]
+walks_by_rows = pytest.mark.parametrize("rows", W_HEADS)
 
 
-def _walk_sizes(g_len=1, int8=False):
-    return walk_sizes(W_PAGE, W_HKV, W_D, W_HQ * g_len, 1 if int8 else 2, W_P)
+def _walk_sizes(rows, g_len=1, int8=False, table_width=W_P):
+    return walk_sizes(W_PAGE, W_HKV, W_D, W_HEADS[rows] * g_len,
+                      1 if int8 else 2, table_width)
 
 
-def _edge_lengths(**variant):
-    block_pages, ring_blocks, _ = _walk_sizes(**variant)
-    assert 1 < block_pages * ring_blocks < W_P     # longer than the ring
-    block = block_pages * W_PAGE
+def _edge_lengths(rows, **variant):
+    sizes = _walk_sizes(rows, **variant)
+    assert 1 < sizes.block_pages * sizes.ring_blocks < W_P   # > the ring
+    assert W_P % sizes.block_pages                 # a partly live block
+    block = sizes.block_pages * W_PAGE
     return [0, 1, W_PAGE - 1, W_PAGE, W_PAGE + 1, block - 1, block,
             block + 1, W_P * W_PAGE]
 
 
-def _walk_scenario(cache_lens, g_len=1, int8=False, full_table=False,
-                   seed=0, table_width=W_P):
+def _walk_scenario(cache_lens, rows, g_len=1, int8=False, full_table=False,
+                   seed=0, table_width=W_P, starts=None):
     """``_scenario`` at the walk's geometry. Pages are handed out in a
     shuffled order; ``full_table`` fills the columns past a slot's live
     prefix with real page ids too (what a table looks like when a slot
-    holds more pages than it has filled). Returns (args, scales, ids of
-    the pages inside a live prefix)."""
+    holds more pages than it has filled); with ``starts`` a slot holds
+    no page wholly before its first attended position. Returns (args,
+    scales, ids of the live pages)."""
     rng = np.random.default_rng(seed)
     B = len(cache_lens)
     num_pages = B * table_width + 1
     q, k_pages, v_pages, k_new, v_new, scales = _operands(
-        seed, B, g_len, int8, num_pages, W_PAGE, W_HKV, W_HQ, W_D)
+        seed, B, g_len, int8, num_pages, W_PAGE, W_HKV, W_HEADS[rows], W_D)
     ids = rng.permutation(num_pages - 1).reshape(B, table_width)
     table = np.full((B, table_width), num_pages, np.int32)
     live = set()
     for b, n in enumerate(cache_lens):
+        first = 0 if starts is None else starts[b] // W_PAGE
         held = min(-(-n // W_PAGE), table_width)
-        table[b, :table_width if full_table else held] = \
-            ids[b, :table_width if full_table else held]
-        live.update(ids[b, :held].tolist())
+        last = table_width if full_table else held
+        table[b, first:last] = ids[b, first:last]
+        live.update(ids[b, first:held].tolist())
     return (q, k_pages, v_pages, jnp.asarray(table), k_new, v_new,
             jnp.asarray(cache_lens, jnp.int32)), scales, live
+
+
+def _poisoned(args, scales, live):
+    """Every page outside ``live`` made poison: NaN, or 127 with NaN
+    scales on an int8 pool."""
+    q, k_pages, v_pages, *rest = args
+    dead = np.array([p for p in range(k_pages.shape[0]) if p not in live])
+
+    def poison(plane):
+        bad = jnp.nan if jnp.issubdtype(plane.dtype, jnp.floating) else 127
+        return plane.at[dead].set(bad)
+
+    return ((q, poison(k_pages), poison(v_pages), *rest),
+            {name: poison(plane) for name, plane in scales.items()}, dead)
 
 
 def _assert_identity(out, oracle):
     """Identity with the oracle as far as it is the kernel's to give, a
     slot at a time: equal in 99 % of its outputs, the rest within a
-    bf16 step of the slot's largest. At this geometry (96-1536 outputs
-    a slot, contexts up to 2304 tokens) the order of the float32
+    bf16 step of the slot's largest. At this geometry (4096-49152
+    outputs a slot, contexts up to 2304 tokens) the order of the float32
     partial sums shows in the last bit of an output now and then,
-    whichever kernel walks the pages: the grid kernel this one replaced
-    differs from the oracle in the same 8 of 1536 outputs of the
-    32-token verify slot, and in 5 of 12288 over six seeds of 600-2304
-    tokens where this one differs in 4. The cases above, at tens of
-    tokens and 4 query heads, stay bit-equal. One token skipped in 256
-    moves most outputs by a step; a page skipped, doubled or taken from
-    another slot moves every output by far more."""
+    whichever kernel walks the pages: the grid kernel and the all-heads
+    product this one replaced differed from the oracle in the same few
+    outputs in a thousand of the longest slots. The cases above, at tens
+    of tokens, stay bit-equal. One token skipped in 256 moves most
+    outputs by a step; a page skipped, doubled or taken from another
+    slot moves every output by far more."""
     out = np.asarray(out, np.float32)
     oracle = np.asarray(oracle, np.float32)
     assert np.isfinite(out).all()
@@ -268,38 +302,41 @@ def _assert_identity(out, oracle):
 _EDGE_RUNS = {}
 
 
-def _edge_run(walk):
+def _edge_run(walk, rows):
     """One call a variant, a slot an edge: the kernel's and the oracle's
     outputs, kept for the cases that each look at their own slot."""
-    if walk not in _EDGE_RUNS:
+    if (walk, rows) not in _EDGE_RUNS:
         kernel, oracle_fn, variant = WALKS[walk]
-        args, scales, _ = _walk_scenario(_edge_lengths(**variant), **variant)
+        args, scales, _ = _walk_scenario(
+            _edge_lengths(rows, **variant), rows, **variant)
         oracle = oracle_fn(*args, **scales)
         args, scales = _stacked(args, scales)
-        _EDGE_RUNS[walk] = (jax.jit(kernel)(*args, **scales), oracle)
-    return _EDGE_RUNS[walk]
+        _EDGE_RUNS[walk, rows] = (jax.jit(kernel)(*args, **scales), oracle)
+    return _EDGE_RUNS[walk, rows]
 
 
 @pytest.mark.parametrize("edge", EDGES)
 @pytest.mark.parametrize("walk", WALKS)
-def test_identity_at_the_walks_edges(walk, edge):
+@walks_by_rows
+def test_identity_at_the_walks_edges(rows, walk, edge):
     """Identity with the gather oracle where the walk changes shape:
     no page, one token, a page less one / exact / plus one, a block of
     pages less one token / exact / plus one, the whole table (more
-    blocks than the ring holds)."""
-    out, oracle = _edge_run(walk)
+    blocks than the ring holds, the last one partly live)."""
+    out, oracle = _edge_run(walk, rows)
     slot = EDGES.index(edge)
     _assert_identity(out[slot:slot + 1], oracle[slot:slot + 1])
 
 
 @pytest.mark.parametrize("walk", WALKS)
-def test_identity_mixed_batch_with_inactive_slots(walk):
+@walks_by_rows
+def test_identity_mixed_batch_with_inactive_slots(rows, walk):
     """Inactive slots (length 0, a row of sentinels) between live ones:
     they fold their new tokens alone, and the copies a slot's program
     starts for the next slot must not leak into a slot without any."""
     kernel, oracle_fn, variant = WALKS[walk]
     lens = [0, 300, 0, 0, 77, 1025, 0]
-    args, scales, _ = _walk_scenario(lens, **variant)
+    args, scales, _ = _walk_scenario(lens, rows, **variant)
     oracle = oracle_fn(*args, **scales)
     args, scales = _stacked(args, scales)
     out = jax.jit(kernel)(*args, **scales)
@@ -334,7 +371,8 @@ def test_sentinel_pages_never_dereferenced():
 
 
 @pytest.mark.parametrize("walk", WALKS)
-def test_walk_copies_live_pages_only(walk):
+@walks_by_rows
+def test_walk_copies_live_pages_only(rows, walk):
     """The poison test at the walk's geometry: the table is FULL (the
     columns past a slot's live prefix name real pages, as when a slot
     holds more than it has filled), longer than the ring, and every
@@ -343,38 +381,81 @@ def test_walk_copies_live_pages_only(walk):
     in partly live blocks beside live ones. One copied dead page turns
     the output NaN (P.V multiplies its rows by exactly 0)."""
     kernel, oracle_fn, variant = WALKS[walk]
-    block_pages, ring_blocks, _ = _walk_sizes(**variant)
-    block = block_pages * W_PAGE
+    sizes = _walk_sizes(rows, **variant)
+    block = sizes.block_pages * W_PAGE
     lens = [block + 1, 0, W_P * W_PAGE - block - W_PAGE - 3, W_PAGE, 5]
-    args, scales, live = _walk_scenario(lens, full_table=True, **variant)
+    args, scales, live = _walk_scenario(lens, rows, full_table=True,
+                                        **variant)
     oracle = oracle_fn(*args, **scales)
-    q, k_pages, v_pages, *rest = args
-    dead = np.array([p for p in range(k_pages.shape[0]) if p not in live])
-    assert len(dead) > ring_blocks * block_pages
-
-    def poison(plane):
-        bad = jnp.nan if jnp.issubdtype(plane.dtype, jnp.floating) else 127
-        return plane.at[dead].set(bad)
-
-    args, scales = _stacked(
-        (q, poison(k_pages), poison(v_pages), *rest),
-        {name: poison(plane) for name, plane in scales.items()})
+    args, scales, dead = _poisoned(args, scales, live)
+    assert len(dead) > sizes.ring_blocks * sizes.block_pages
+    args, scales = _stacked(args, scales)
     out = jax.jit(kernel)(*args, **scales)
     _assert_identity(out, oracle)
 
 
-def test_length_beyond_the_table_attends_the_table():
+@walks_by_rows
+def test_identity_with_a_start_inside_a_block(rows):
+    """A sliding-window layer's bound at the walk's geometry: first
+    attended positions inside a page inside a block (the first, a
+    middle one, the last, partly live), at a block's first token, past
+    the length's page, and none. The pages wholly before a slot's
+    bound are sentinels in its table, and every page that is not live
+    is poison: the walk starts at the bound's block and copies from the
+    bound's page on."""
+    block = _walk_sizes(rows).block_pages * W_PAGE
+    lens = [3 * block + 70, 2 * block, block + 9, W_P * W_PAGE, 900, 0]
+    starts = [block + 3 * W_PAGE + 5, block, 7, W_P * W_PAGE - 40, 0, 0]
+    args, scales, live = _walk_scenario(lens, rows, starts=starts)
+    start = jnp.asarray(starts, jnp.int32)
+    oracle = paged_decode_attention(*args, start=start)
+    args, _, _ = _poisoned(args, scales, live)
+    out = jax.jit(lambda *ops: ragged_paged_decode_attention(
+        *ops, start=start))(*_stacked(args)[0])
+    _assert_identity(out, oracle)
+
+
+@walks_by_rows
+def test_length_beyond_the_table_attends_the_table(rows):
     """A length past what the table's columns hold attends exactly the
     table, as the oracle's gathered window does — also where the table
-    ends inside a block of pages (12 columns, blocks of 8), whose
+    ends inside a block of pages (20 columns, blocks of 8), whose
     further ring rows were never copied."""
-    block_pages, _, _ = walk_sizes(W_PAGE, W_HKV, W_D, W_HQ, 2, 12)
-    assert 12 % block_pages
-    lens = [12 * W_PAGE + 40, 100]
-    args, scales, _ = _walk_scenario(lens, full_table=True, table_width=12)
+    assert 20 % _walk_sizes(rows, table_width=20).block_pages
+    lens = [20 * W_PAGE + 40, 100]
+    args, scales, _ = _walk_scenario(lens, rows, full_table=True,
+                                     table_width=20)
     oracle = paged_decode_attention(*args)
     out = jax.jit(ragged_paged_decode_attention)(*_stacked(args)[0])
     _assert_identity(out, oracle)
+
+
+# -- the walk's sizes ---------------------------------------------------------
+
+WALK_SIZES = {
+    # command-a-plus-ep8.mixed's decode call (GQA 128:8, max_len 9216):
+    # four blocks of 8 pages in flight, the scores of all 288 columns
+    # kept, K read once
+    "cmda-decode": ((32, 8, 128, 128, 2, 288), (8, 4, True, 1)),
+    # the same heads over the uncut 200000 positions: K streamed twice
+    "cmda-uncut": ((32, 8, 128, 128, 2, 6250), (8, 4, False, 1)),
+    # mistral7b.batch's (GQA 32:8, max_len 2048): four heads a product
+    "mistral7b-decode": ((32, 8, 128, 32, 2, 64), (8, 4, True, 4)),
+    "mistral7b-int8": ((32, 8, 128, 32, 1, 64), (16, 4, True, 4)),
+    "mistral7b-verify": ((32, 8, 128, 160, 2, 64), (8, 4, True, 4)),
+    # the same verify at 128 query heads: the block's scores set its size
+    "cmda-verify": ((32, 8, 128, 640, 2, 288), (3, 8, False, 1)),
+    # MHA 32:32: a row a KV head, sixteen heads a product
+    "mha-decode": ((32, 32, 128, 32, 2, 64), (2, 4, True, 16)),
+}
+
+
+@pytest.mark.parametrize("case", WALK_SIZES)
+def test_walk_sizes_at_the_serving_shapes(case):
+    """``walk_sizes`` is where the kernel's form is decided, from the
+    call's shapes and the module's constants alone."""
+    shapes, want = WALK_SIZES[case]
+    assert tuple(walk_sizes(*shapes)) == want
 
 
 def test_check_sentinel_masked_contract():
@@ -425,6 +506,11 @@ def test_ragged_tileable_predicate():
     assert not ragged_tileable(128, 4, 2, 16)                # hq % 8
     assert not ragged_tileable(128, 8, 2, 8)                 # page % 16
     assert not ragged_tileable(128, 8, 3, 16)                # hq % hkv
+    # a head's rows leave a block by 32-bit words of 2 bf16 / 4 int8 heads
+    assert not ragged_tileable(128, 8, 1, 16)
+    assert not ragged_tileable(128, 24, 3, 16)
+    assert ragged_tileable(128, 32, 8, 32, kv_itemsize=1)
+    assert not ragged_tileable(128, 8, 2, 16, kv_itemsize=1)
     assert not ragged_tileable(D, HQ, HKV, PAGE)             # test shapes
 
 
@@ -479,6 +565,14 @@ def test_engine_greedy_identity_and_ladder_retirement(setup):
     assert ragged == dense
 
     assert g_eng.attn_path == "gather" and r_eng.attn_path == "ragged"
+    # the start-up report says what walk_sizes answered for the decode
+    # call's shape; an engine on the gather path has no walk to report
+    assert r_eng.attention_paths()["ragged_walk"] == {"kv": walk_sizes(
+        4, cfg.n_kv_heads, cfg.head_dim, cfg.n_heads, 2,
+        r_eng.pages_per_slot)._asdict()}
+    assert r_eng.stats()["kv_pool"]["ragged_walk"]["kv"]["keep_scores"]
+    assert "ragged_walk" not in g_eng.attention_paths()
+    assert "ragged_walk" not in g_eng.stats()["kv_pool"]
     widths = r_eng.xlaz()["paged_kv"]["gather_widths"]
     assert widths == [r_eng.pages_per_slot]          # ladder collapsed
     assert len(g_eng.xlaz()["paged_kv"]["gather_widths"]) >= 1

@@ -595,6 +595,14 @@ def test_engine_serves_token_identical_through_kernel_and_gather():
         assert abs(want[ours[at]] - want[theirs[at]]) / want.std() < 0.3
     assert sum(a == b for a, b in zip(kernel, gather)) >= 2
     assert engine.attn_path == "ragged" and engine._by_kind
+    # the kernel's form at the decode call's shape, a kind, at start-up
+    # and in every stats(): no selection is silent
+    walk = stats["kv_pool"]["ragged_walk"]
+    assert walk == engine.attention_paths()["ragged_walk"]
+    assert set(walk) == {"window", "full"}
+    assert all(kind["keep_scores"] and kind["ring_blocks"] >= 2
+               for kind in walk.values())
+    assert "ragged_walk" not in other["kv_pool"]
     assert stats["compiles"]["serving"] == 0
     kinds = stats["kv_pool"]["kinds"]
     # window 16 at page 8: two pages, a partial one at each end, and the
