@@ -19,9 +19,10 @@ n_held_experts ..`` (expert parallelism: one chip's share of a layer).
 What absent experts would add is left out; nothing here stands in for
 the other chips or for their exchange. No capacity, no dropped token.
 A prefill sorts the held (token, expert) pairs by expert and runs
-blocks of one expert's rows, so its work follows the pairs (given the
-whole stack and a layer's index it reads each expert in place and sums
-a token's pairs once, ``experts_grouped``'s ``at``); a decode
+blocks of one expert's rows, so its work follows the pairs
+(``experts_grouped``: given the whole stack and a layer's index it
+reads each expert where it lies; how a token's pairs are summed
+follows from the share of the experts held); a decode
 step has one token a slot, is bound by the experts' bytes and not by
 rows, and runs every held expert over all rows in one batched product
 (rows an expert was not chosen for weigh zero).
@@ -111,21 +112,33 @@ def experts_grouped(cfg, experts, h, ids, weights, valid=None, at=None):
     """The held experts' part with work that follows the routed pairs.
     The held (token, expert) pairs are sorted by expert; each expert's
     run is cut into blocks of ``_block_rows`` rows, and a loop over the
-    blocks *that exist* gathers a block's rows, runs its one expert and
-    adds the weighted result to its tokens. Nothing is sized by a
-    capacity: however the router spreads the tokens, each pair is
-    computed. Returns (y (T, D) float32, pairs per held expert (E,)).
+    blocks *that exist* gathers a block's rows and runs its one expert.
+    Nothing is sized by a capacity: however the router spreads the
+    tokens, each pair is computed. Returns (y (T, D) float32, pairs per
+    held expert (E,)).
 
-    With ``at`` (a layer's index, traced) ``experts`` is the whole
-    stack, (layers, E, ..) a leaf, and a block reads its expert at
-    ``[at, e]`` in place: a layer's slice handed to the block loop is a
-    copy of every held expert (3 x 470 MB a layer a prefill at
-    xing4-29b's widths, PERF.md section 6). In that form a block's
-    result is written at its rows' places in the sorted order, one
-    slab, and the tokens' sums are taken once after the loop: a tail
-    block's dead rows land on the next experts' places and are written
-    over by the blocks that own them (the loop runs in order), the
-    places of pairs not held stay zero or finite and weigh zero."""
+    Where an expert's weights are read: with ``at`` (a layer's index,
+    traced) ``experts`` is the whole stack, (layers, E, ..) a leaf, and
+    a block reads its expert at ``[at, e]`` in place; without it
+    ``experts`` is one layer's, (E, ..) a leaf. A caller whose layers
+    are a scan's steps passes the stack and ``at``: a layer's slice
+    handed to the block loop is a copy of every held expert, because a
+    loop's operand is a buffer (3 x 470 MB a layer a prefill at
+    xing4-29b's widths, 3 x 503 MB at pangu-ultra-moe-ep16's: PERF.md
+    section 6, docs/tpu/model-serving.md).
+
+    How a token's pairs are summed follows from the share held. A
+    chip's share of the experts (``n_held_experts <
+    n_routed_experts``): a block's weighted result is added to its
+    tokens' rows of the (T, D) sum; one sized by every routed pair
+    would be 2 GB at 16 x 512 tokens, 8 of 256, for the 6 % of its
+    places ever written. Every routed expert held: a block's result is
+    written at its rows' places in the sorted order, one (T * top_k +
+    block, D) slab, and the tokens' sums are taken once after the loop:
+    a tail block's dead rows land on the next expert's places and are
+    written over by the blocks that own them (the loop runs in order);
+    the places of padding rows' pairs, the last, stay zero or finite
+    and weigh zero."""
     tokens, k = ids.shape
     n_held, block = cfg.n_held_experts, _block_rows(cfg, tokens)
     local, held = _held(cfg, ids, valid)
@@ -145,30 +158,34 @@ def experts_grouped(cfg, experts, h, ids, weights, valid=None, at=None):
         pair = order[jnp.minimum(rows, tokens * k - 1)]
         return e, first, live, pair
 
-    def one_block(i, y):
-        e, _, live, pair = rows_of(i)
-        token = pair // k
-        w = {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
-             for name, leaf in experts.items()}
-        out = _swiglu(w, h[token]).astype(jnp.float32)
-        scale = jnp.where(live, flat_w[pair], 0.0)
-        return y.at[token].add(out * scale[:, None])
-
-    def one_block_in_place(i, out):
-        e, first, _, pair = rows_of(i)
-        w = {name: lax.dynamic_slice(
+    def expert(e):
+        if at is None:
+            return {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
+                    for name, leaf in experts.items()}
+        return {name: lax.dynamic_slice(
             leaf, (at, e) + (0,) * (leaf.ndim - 2),
             (1, 1) + leaf.shape[2:]).reshape(leaf.shape[2:])
             for name, leaf in experts.items()}
-        return lax.dynamic_update_slice_in_dim(
-            out, _swiglu(w, h[pair // k]).astype(jnp.float32), first, 0)
 
-    if at is None:
-        y = lax.fori_loop(0, block_ends[-1], one_block,
+    def add_block(i, y):
+        e, _, live, pair = rows_of(i)
+        token = pair // k
+        out = _swiglu(expert(e), h[token]).astype(jnp.float32)
+        scale = jnp.where(live, flat_w[pair], 0.0)
+        return y.at[token].add(out * scale[:, None])
+
+    def place_block(i, out):
+        e, first, _, pair = rows_of(i)
+        return lax.dynamic_update_slice_in_dim(
+            out, _swiglu(expert(e), h[pair // k]).astype(jnp.float32),
+            first, 0)
+
+    if n_held < cfg.n_routed_experts:
+        y = lax.fori_loop(0, block_ends[-1], add_block,
                           jnp.zeros((tokens, h.shape[-1]), jnp.float32))
         return y, counts
     out = lax.fori_loop(
-        0, block_ends[-1], one_block_in_place,
+        0, block_ends[-1], place_block,
         jnp.zeros((tokens * k + block, h.shape[-1]), jnp.float32))
     place = jnp.zeros((tokens * k,), jnp.int32).at[order].set(
         jnp.arange(tokens * k, dtype=jnp.int32))

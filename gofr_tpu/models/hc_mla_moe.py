@@ -183,12 +183,9 @@ class Residual:
 def prefill(params: Dict[str, Any], cfg: HcMlaMoeConfig, tokens: jnp.ndarray,
             cache: Dict[str, jnp.ndarray],
             lengths: Optional[jnp.ndarray] = None, routes: bool = False):
-    """``mla_moe.prefill`` around the hyper-connected layer, the routed
-    experts read in place: every prompt of this member's cell is a
-    prefill of its own, and a copy of a layer's 64 experts before each
-    block loop was 21 ms of each (PERF.md section 6)."""
+    """``mla_moe.prefill`` around the hyper-connected layer."""
     return mla_moe.prefill(params, cfg, tokens, cache, lengths, routes,
-                           residual=Residual, experts_in_place=True)
+                           residual=Residual)
 
 
 def decode_step(params: Dict[str, Any], cfg: HcMlaMoeConfig,

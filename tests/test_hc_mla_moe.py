@@ -38,7 +38,8 @@ from jax import lax
 
 from gofr_tpu.container import new_mock_container
 from gofr_tpu.models import hc_mla_moe, hyper_connection, mla_moe
-from gofr_tpu.models.experts import experts_grouped, route
+from gofr_tpu.models.experts import (experts_batched, experts_grouped,
+                                     route)
 from gofr_tpu.ops import prefill_attention, rope_table
 from gofr_tpu.ops.pallas import flash_attention
 from gofr_tpu.tpu.generate import GenerationEngine
@@ -358,14 +359,18 @@ def test_route_with_a_bias_chooses_by_s_plus_b_and_weighs_by_s():
 
 @pytest.mark.parametrize("held,rank,padded,tokens", [
     (16, 0, False, 40), (16, 0, True, 40), (4, 1, False, 40),
-    (4, 3, True, 96), (16, 0, False, 7)])
+    (4, 3, True, 96), (16, 0, False, 7), (4, 1, True, 40),
+    (4, 3, False, 96)])
 def test_experts_read_in_place_equal_a_layers_slice(held, rank, padded,
                                                     tokens):
-    """``experts_grouped`` over the whole stack at a layer's index (each
-    block written at its rows' sorted places, the tokens' sums taken
-    once) against the same call on that layer's slice: every held pair,
-    padding rows and absent experts nowhere, tail blocks' dead rows
-    written over."""
+    """``experts_grouped`` over the whole stack at a layer's index
+    against the same call on that layer's slice: where the weights are
+    read is all that differs, so the results are equal bit for bit,
+    whether every expert is held (each block written at its rows'
+    sorted places, the tokens' sums taken once) or a share (each block
+    added to its tokens' rows). Both forms against the batched product:
+    every held pair, padding rows and absent experts nowhere, tail
+    blocks' dead rows written over."""
     cfg = hc_mla_moe.config("tiny", n_held_experts=held, expert_rank=rank)
     keys = jax.random.split(jax.random.PRNGKey(held + tokens), 6)
     stack = {name: 0.2 * jax.random.normal(key, (3, held, *shape))
@@ -384,7 +389,11 @@ def test_experts_read_in_place_equal_a_layers_slice(held, rank, padded,
         got, counts = jax.jit(lambda *a: experts_grouped(cfg, *a))(
             stack, h, ids, weights, valid, jnp.int32(at))
         np.testing.assert_array_equal(counts, want_counts)
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(got, want)
+        dense, dense_counts = experts_batched(cfg, layer, h, ids, weights,
+                                              valid)
+        np.testing.assert_array_equal(counts, dense_counts)
+        np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-5)
 
 
 # -- (g) the latent prefill kernel ------------------------------------------------

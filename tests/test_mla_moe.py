@@ -258,6 +258,51 @@ def test_all_sixteen_shares_add_up_to_the_uncut_layer(grouped):
     np.testing.assert_allclose(uncut, want, rtol=1e-5, atol=1e-5)
 
 
+# -- (d') a prefill reads its routed experts where they lie ------------------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(inner)
+
+
+def test_prefill_scans_no_expert_leaf_and_blocks_read_the_stack():
+    """A layer scan that carried the routed experts' stack as a scanned
+    operand would hand the grouped product's block loop a slice, and a
+    loop's operand is a buffer: a copy of every held expert a layer a
+    call (3 x 503 MB at pangu-ultra-moe-ep16's widths, PERF.md section
+    6). At the tiny preset with a share held (8 of 32, 2 expert layers;
+    8 so that no other leaf starts like the experts') no scan of
+    ``prefill`` scans a leaf of shape (expert layers, held, ..), and
+    inside the block loop each ``dynamic_slice`` of weights takes the
+    four-dimensional stack."""
+    cfg = mla_moe.config("tiny", n_held_experts=8)
+    params = jax.eval_shape(lambda key: mla_moe.init(cfg, key),
+                            jax.random.key(0))
+    rows, bucket = 2, 16
+    jaxpr = jax.make_jaxpr(lambda p, t, l: mla_moe.prefill(
+        p, cfg, t, mla_moe.init_cache(cfg, rows, bucket), lengths=l))(
+        params, jnp.zeros((rows, bucket), jnp.int32),
+        jnp.full((rows,), bucket, jnp.int32)).jaxpr
+    stack = (cfg.n_moe_layers, cfg.n_held_experts)
+    scans = [eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scan"]
+    assert len(scans) >= 2                      # the two stacks, at least
+    for scan in scans:
+        first = scan.params["num_consts"] + scan.params["num_carry"]
+        scanned = [var.aval.shape for var in scan.invars[first:]]
+        assert not [shape for shape in scanned if shape[:2] == stack], scanned
+    loops = [eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "while"]
+    read = [eqn.invars[0].aval.shape
+            for loop in loops for eqn in _eqns(loop.params["body_jaxpr"].jaxpr)
+            if eqn.primitive.name == "dynamic_slice"
+            and eqn.invars[0].aval.shape[-2:] in (
+                (cfg.dim, cfg.moe_ffn_dim), (cfg.moe_ffn_dim, cfg.dim))]
+    assert len(read) == 3 and all(shape[:2] == stack and len(shape) == 4
+                                  for shape in read), read
+
+
 # -- (e) no token is dropped -----------------------------------------------------
 
 @pytest.mark.parametrize("grouped", [False, True],

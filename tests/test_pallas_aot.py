@@ -474,3 +474,42 @@ def test_window_and_global_kinds_decode_in_one_tick_for_v5e(v5e):
         plane = rf"bf16\[{layers},{pages[kind]},{PAGE},8,128\]\S* copy\("
         assert not re.findall(plane, hlo), kind
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# -- a latent prefill reads its routed experts where they lie (ISSUE 34) ------
+
+def test_latent_prefill_copies_no_layers_experts_for_v5e(v5e):
+    """``mla_moe.prefill`` at pangu-ultra-moe-ep16's widths (16 of 256
+    experts held, hidden 7680, expert width 2048), a 1 x 512 group, one
+    dense and two expert layers. The layer scan once handed the grouped
+    product's block loop a slice of the expert stack, and the compiler
+    made the slice a buffer: three fusions a layer whose result was a
+    layer's leaf, 503 MB each. No operation's result is a layer's leaf
+    now, and the whole stack is met only as it is given: a parameter
+    or an element of a loop's tuple."""
+    from gofr_tpu.models import mla_moe
+
+    cfg = mla_moe.config("pangu-ultra-moe", n_layers=3, n_dense_layers=1,
+                         n_held_experts=16, vocab_size=19200,
+                         max_seq_len=2048)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=v5e),
+        jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.key(0))))
+    rows, bucket = 1, 512
+
+    def prefill(params, tokens, lengths):
+        return mla_moe.prefill(params, cfg, tokens,
+                               mla_moe.init_cache(cfg, rows, bucket),
+                               lengths=lengths)
+
+    hlo = jax.jit(prefill).lower(
+        params,
+        jax.ShapeDtypeStruct((rows, bucket), jnp.int32, sharding=v5e),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e)
+    ).compile().as_text()
+    leaf = r"(?:2048,7680|7680,2048)\]"
+    a_layers = re.findall(rf"^.*= bf16\[(?:1,)?16,{leaf}.*$", hlo, re.M)
+    assert not a_layers, a_layers[:3]
+    whole = re.findall(rf"^.*= bf16\[2,16,{leaf}\S* (\S+?)\(", hlo, re.M)
+    assert whole and set(whole) <= {"parameter", "get-tuple-element"}, whole
