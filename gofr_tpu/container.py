@@ -175,8 +175,6 @@ class Container:
         metrics.new_gauge("app_tpu_hbm_bytes_in_use", "HBM bytes in use per device")
         metrics.new_gauge("app_tpu_device_up", "per-device liveness 0/1")
         metrics.new_counter("app_tpu_requests_total", "TPU predict requests")
-        metrics.new_gauge("app_tpu_attention_window",
-                          "decode attention window rung (fill-bounded)")
         metrics.new_histogram(
             "app_tpu_ttft",
             "time to first generated token (s): admission wait + prefill "
@@ -184,9 +182,11 @@ class Container:
             (0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0))
         metrics.new_histogram(
             "app_tpu_request_phase_seconds",
-            "app_tpu_ttft in its two parts, per request (s): "
+            "a request's phases (s): app_tpu_ttft in its two parts, "
             "phase=queue (submit -> slot claimed) and phase=first_token "
-            "(slot claimed -> first token published)",
+            "(slot claimed -> first token published), and phase=stall "
+            "(of its decode phase, the part the device spent in other "
+            "requests' prefill groups)",
             (0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0))
         # SLO & saturation catalog (ISSUE 2): goodput vs raw throughput,
         # deadline outcome counts, device utilization, health transitions
@@ -299,10 +299,6 @@ class Container:
             "app_tpu_kv_pages_stalled_total",
             "page allocations that failed after reclaim (admission "
             "backpressure / decode-growth stalls)")
-        metrics.new_gauge(
-            "app_tpu_kv_ragged_fill_ratio",
-            "live tokens / (pages held x page size) across decoding "
-            "slots — how ragged the paged KV actually is")
         metrics.new_counter(
             "app_tpu_attn_kernel_total",
             "decode/verify dispatches per attention path "
@@ -382,10 +378,10 @@ class Container:
             "capacity pressure) — each one is a wasted prompt forward")
         metrics.new_updown_counter(
             "app_tpu_device_seconds_total",
-            "dispatch→publish device step wall time attributed per "
-            "(model, SLO class), split evenly across a step's "
-            "participants — attribution, not utilization: pipelined "
-            "ticks overlap")
+            "device time attributed per (model, SLO class): a program's "
+            "interval between landings on the engine's timeline, split "
+            "evenly across its participants — disjoint intervals, so "
+            "the sum over one device is at most wall time")
         metrics.new_gauge(
             "app_tpu_hbm_attributed_bytes",
             "device bytes the serving stack accounts for (params + KV "
@@ -502,9 +498,9 @@ class Container:
             "only, never token content")
         metrics.new_updown_counter(
             "app_tpu_executable_device_seconds_total",
-            "dispatch→publish device step wall time per (model, "
-            "compiled executable family) — the roofline-attribution "
-            "twin of app_tpu_device_seconds_total; their totals match")
+            "device time per (model, compiled executable family): the "
+            "same intervals as app_tpu_device_seconds_total, the "
+            "roofline-attribution twin; their totals match")
         metrics.new_updown_counter("app_http_inflight",
                                    "inbound HTTP requests currently in flight")
         metrics.new_histogram("app_cron_duration", "cron job run time (s)",

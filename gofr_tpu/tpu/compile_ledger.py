@@ -182,7 +182,9 @@ class ExecutableLedger:
 
     def charge(self, model: str, family: str, seconds: float,
                flops: Optional[float] = None) -> None:
-        """One dispatch→publish measurement for ``family``. ``flops`` is
+        """One measurement of device time for ``family`` (the engine: a
+        program's interval between landings; the executor: a step's
+        dispatch → fetch). ``flops`` is
         the executed FLOPs of that dispatch when the caller has a cached
         ``cost_analysis`` (executor buckets); engines whose executables
         ride ``jax.jit`` caches pass None and their rows report a null

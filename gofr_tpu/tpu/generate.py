@@ -52,7 +52,8 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import deque
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
+from functools import partial
 from types import SimpleNamespace
 from typing import Any, Dict, List, Optional, Tuple, Union
 
@@ -348,42 +349,67 @@ class _Fetch:
     "tick" (payload: [(slot, gen)]), or "spec" (payload: ([(slot, gen)],
     gamma); the fetch lands (tokens, accept_counts)). ``span`` is the open
     engine-step span (dispatch → publish), finished when the fetch
-    lands. ``dispatched_at`` anchors device-time attribution: dispatch →
-    publish wall time is charged to the participating requests' {model,
-    slo class} (ISSUE 10). ``anatomy`` carries a sampled tick's share of
-    the loop clock to ``_publish`` (None on unsampled ticks): the pass
-    that dispatched it stores its admit and dispatch laps here, and
-    ``_publish`` adds the device wait and its own lap before handing the
-    dict to telemetry.
-    ``family`` names the compiled-executable family the dispatch hit
-    (ISSUE 17) so the same elapsed window also lands in the
-    per-executable roofline ledger."""
+    lands. ``dispatched_at`` is stamped before the program is handed to
+    the runtime and ``landed_at`` where the fetch lands: on the worker
+    thread, as the last act of ``tpu.fetch.<kind>`` (a host annotation
+    that ends at that instant, so the landing lies on a profiler
+    capture's clock beside the device's own events; the name is outside
+    ``tpu.engine.*`` because it spans the device's work from another
+    thread and would take every idle gap from the loop's phases). The
+    two stamps are all ``_Timeline`` needs. ``work`` is what a tick
+    tells the timeline about itself (``_dispatch_tick``). ``anatomy``
+    carries a sampled tick's share of the loop clock to ``_publish``
+    (None on unsampled ticks): the pass that dispatched it stores its
+    admit and dispatch laps here, and ``_publish`` adds the device wait
+    and its own lap before handing the dict to telemetry. ``family``
+    names the compiled-executable family the dispatch hit (ISSUE 17):
+    the entry's interval also lands in the per-executable roofline
+    ledger under it."""
     __slots__ = ("task", "kind", "payload", "span", "dispatched_at",
-                 "anatomy", "family")
+                 "landed_at", "anatomy", "family", "work", "_get")
 
-    def __init__(self, task, kind: str, payload,
-                 span: Optional[Span] = None, anatomy=None,
-                 family: Optional[str] = None):
-        self.task = task
+    def __init__(self, kind: str, payload, get, dispatched_at: float,
+                 span: Optional[Span] = None, family: Optional[str] = None,
+                 work=None):
+        self.task = None
         self.kind = kind
         self.payload = payload
         self.span = span
-        self.dispatched_at = time.monotonic()
-        self.anatomy = anatomy
+        self.dispatched_at = dispatched_at
+        self.landed_at = dispatched_at
+        self.anatomy = None
         self.family = family
+        self.work = work
+        self._get = get
+
+    def start(self, loop, annotation) -> "_Fetch":
+        """Start the fetch in a worker thread; ``task`` resolves to what
+        ``get`` returned."""
+        self.task = loop.run_in_executor(None, self._land, annotation)
+        return self
+
+    def _land(self, annotation):
+        with annotation("tpu.fetch." + self.kind):
+            host = self._get()
+            self.landed_at = time.monotonic()
+        return host
 
 
 class _LoopClock:
     """The engine loop's one clock: who holds the serving thread.
 
     Every pass of ``_loop_body`` is cut into contiguous phases. ``admit``
-    (admission staging, uploads, prefill / insert dispatch), ``dispatch``
-    (the decode or speculative tick up to its ``_Fetch``) and ``publish``
-    (every ``_publish`` of the pass) *hold* the event loop's thread;
-    ``wait`` (awaiting the oldest fetch, the 1 ms sleep of a pass that
-    dispatched nothing, a first-time compile running off the loop) and
-    ``park`` (no work) yield it to the other coroutines: HTTP parsing,
-    handlers, SSE framing, socket writes.
+    (admission staging, prefill / insert dispatch), ``dispatch`` (the
+    decode or speculative tick up to its ``_Fetch``) and ``publish``
+    (every ``_publish`` of the pass) *hold* the event loop's thread, and
+    so do the two laps cut out of them where the thread can block in the
+    runtime (``lap``): ``enqueue`` (a warm call of a program: a tick, a
+    speculative tick, a prefill group with its insert) and ``upload``
+    (``_upload_group``), so ``admit`` and ``dispatch`` are the rest of
+    their phases. ``wait`` (awaiting the oldest fetch, the 1 ms sleep of
+    a pass that dispatched nothing, a first-time compile running off the
+    loop) and ``park`` (no work) yield the thread to the other
+    coroutines: HTTP parsing, handlers, SSE framing, socket writes.
 
     ``enter`` stamps a boundary with ``time.monotonic()`` and
     ``time.thread_time()`` (CPU of the calling thread, which must be the
@@ -395,17 +421,22 @@ class _LoopClock:
     and ``park`` is everything else on the thread (``yield_cpu_s``), and
     wall less CPU of a holding phase is time the engine kept the thread
     while doing nothing (blocked in the runtime, or waiting for the GIL).
+    A phase's longest single lap is kept with its CPU and when it ended
+    (``longest``): a stall of seconds names the phase it sat in.
     Readers: ``stats()["loop"]``, the profiler's trace, and the sampled
     tick ring of ``/debug/timez`` (a view of the same stamps)."""
 
-    PHASES = ("admit", "dispatch", "publish", "wait", "park")
-    HOLDING = PHASES[:3]
-    __slots__ = ("wall", "cpu", "passes", "phase", "at", "_cpu_at", "_span",
-                 "_annotation")
+    PHASES = ("admit", "dispatch", "publish", "enqueue", "upload", "wait",
+              "park")
+    HOLDING = PHASES[:5]
+    __slots__ = ("wall", "cpu", "longest", "passes", "phase", "at",
+                 "_cpu_at", "_span", "_annotation")
 
     def __init__(self, annotation):
         self.wall = dict.fromkeys(self.PHASES, 0.0)
         self.cpu = dict.fromkeys(self.PHASES, 0.0)
+        # phase -> (wall, cpu, time.time() at its end) of its longest lap
+        self.longest: Dict[str, Tuple[float, float, float]] = {}
         self.passes = 0
         self.phase: Optional[str] = None    # None: the loop is not running
         self.at = 0.0                       # monotonic stamp of the last boundary
@@ -423,6 +454,8 @@ class _LoopClock:
             lap = (now - self.at, cpu - self._cpu_at)
             self.wall[was] += lap[0]
             self.cpu[was] += lap[1]
+            if lap[0] > self.longest.get(was, (-1.0,))[0]:
+                self.longest[was] = (lap[0], lap[1], time.time())
         self.at, self._cpu_at = now, cpu
         if phase != was:
             if self._span is not None:
@@ -434,10 +467,33 @@ class _LoopClock:
             self.phase = phase
         return lap
 
+    def stamp(self) -> Tuple[float, float]:
+        """The last boundary's (monotonic, thread CPU) readings: two
+        stamps' difference is everything the thread did between them,
+        whatever phases and laps that took."""
+        return self.at, self._cpu_at
+
+    @contextmanager
+    def lap(self, phase: str):
+        """Cut ``phase`` out of the open holding phase: enter it, and go
+        back to the phase that was open when the block ends. Under a
+        yielding phase (a first-time compile dispatching from a worker
+        thread while the loop waits, ``_off_loop``) or a stopped clock
+        the block is not the loop thread's to stamp and runs unclocked."""
+        was = self.phase
+        if was not in self.HOLDING:
+            yield
+            return
+        self.enter(phase)
+        try:
+            yield
+        finally:
+            self.enter(was)
+
     def stats(self) -> Dict[str, Any]:
-        """Cumulative seconds, all monotone. The open phase's wall time up
-        to now is folded in, so ``wall_s`` is the time the loop has run;
-        its CPU lands at the next boundary."""
+        """Cumulative seconds, all monotone but ``longest``. The open
+        phase's wall time up to now is folded in, so ``wall_s`` is the
+        time the loop has run; its CPU lands at the next boundary."""
         wall = dict(self.wall)
         if self.phase is not None:
             wall[self.phase] += max(0.0, time.monotonic() - self.at)
@@ -450,6 +506,88 @@ class _LoopClock:
         out["held_s"] = sum(wall[p] for p in self.HOLDING)
         out["held_cpu_s"] = sum(self.cpu[p] for p in self.HOLDING)
         out["yield_cpu_s"] = self.cpu["wait"] + self.cpu["park"]
+        out["longest"] = {
+            phase: {"wall_s": lap[0], "cpu_s": lap[1], "at": lap[2]}
+            for phase, lap in self.longest.items()}
+        return out
+
+
+class _Timeline:
+    """The device's timeline as the engine sees it, always on like
+    ``_LoopClock``. The device runs the engine's programs in dispatch
+    order and every program's token fetch is stamped where it lands
+    (``_Fetch``), so an entry's *interval*, its landing less the later of
+    the previous entry's landing and its own dispatch, is that program's
+    device time on the host's clock wherever the device had work queued,
+    which under load is always. Where it had none the interval starts at
+    the dispatch and holds the enqueue. A prefill entry's fetch lands
+    when the prefill executable ends: its insert runs after and falls
+    into the next entry's interval. Intervals are disjoint, so their sum
+    over one device cannot pass the loop's wall time.
+
+    ``land`` is called once an entry, in dispatch order, from
+    ``_publish``. Everything is cumulative and monotone but ``longest``
+    (a kind's longest interval with its executable family and when it
+    landed); a reader takes deltas over its window.
+
+    ``busy_s`` all intervals; ``tick_s``, ``ticks``, ``tick_steps`` (the
+    K of each tick summed: ``decode_steps`` counts ticks) and
+    ``tick_tokens`` (tokens the ticks published to live requests) the
+    decode ticks; ``prefill_s``, ``prefill_groups``; ``spec_s``;
+    ``ticks_by_width`` a tick's table or window width (``"full"``: the
+    dense cache's whole length) to ticks: which rung the ladder took;
+    ``rows_live`` / ``rows_gathered`` (paged gather path only, a layer):
+    cache rows under the participants' lengths, the new row with them,
+    over the rows ``max_slots x width x kv_page`` a step's page gather
+    brings in; ``stalled_slot_s``: prefill intervals times the decoding
+    slots each stopped (``GenerationEngine._stall``)."""
+
+    COUNTS = ("busy_s", "tick_s", "ticks", "tick_steps", "tick_tokens",
+              "prefill_s", "prefill_groups", "spec_s", "rows_live",
+              "rows_gathered", "stalled_slot_s")
+    __slots__ = COUNTS + ("ticks_by_width", "longest", "_landed_at")
+
+    def __init__(self):
+        self.busy_s = self.tick_s = self.prefill_s = self.spec_s = 0.0
+        self.stalled_slot_s = 0.0
+        self.ticks = self.tick_steps = self.tick_tokens = 0
+        self.prefill_groups = self.rows_live = self.rows_gathered = 0
+        self.ticks_by_width: Dict[str, int] = {}
+        self.longest: Dict[str, Dict[str, Any]] = {}
+        self._landed_at = 0.0
+
+    def land(self, entry: _Fetch) -> float:
+        """Book a landed entry; returns its interval in seconds."""
+        start = max(self._landed_at, entry.dispatched_at)
+        interval = max(0.0, entry.landed_at - start)
+        # two fetches' threads may stamp out of order by microseconds
+        self._landed_at = max(start, entry.landed_at)
+        self.busy_s += interval
+        if entry.kind == "prefill":
+            self.prefill_s += interval
+            self.prefill_groups += 1
+        elif entry.kind == "spec":
+            self.spec_s += interval
+        else:
+            steps, width, live, gathered = entry.work
+            self.tick_s += interval
+            self.ticks += 1
+            self.tick_steps += steps
+            self.ticks_by_width[width] = \
+                self.ticks_by_width.get(width, 0) + 1
+            self.rows_live += live
+            self.rows_gathered += gathered
+        longest = self.longest.get(entry.kind)
+        if longest is None or interval > longest["s"]:
+            self.longest[entry.kind] = {
+                "s": interval, "family": entry.family, "at": time.time()}
+        return interval
+
+    def stats(self) -> Dict[str, Any]:
+        out = {name: getattr(self, name) for name in self.COUNTS}
+        out["ticks_by_width"] = dict(self.ticks_by_width)
+        out["longest"] = {kind: dict(entry)
+                          for kind, entry in self.longest.items()}
         return out
 
 
@@ -887,10 +1025,13 @@ class GenerationEngine:
         self._adopt_dedup_hits = 0
         self._brownout = 0
         self._quarantined: Dict[str, int] = {}
-        # the loop clock (always on): admit / dispatch / publish / wait /
-        # park, wall and thread CPU, read by stats()["loop"], the
-        # profiler's trace and the sampled tick ring
-        self._clock = _LoopClock(jax.profiler.TraceAnnotation)
+        # the loop clock (always on): the serving thread's phases, wall
+        # and thread CPU, read by stats()["loop"], the profiler's trace
+        # and the sampled tick ring; and the device's timeline beside it,
+        # fed where a fetch lands, read by stats()["timeline"]
+        self._annotation = jax.profiler.TraceAnnotation
+        self._clock = _LoopClock(self._annotation)
+        self._timeline = _Timeline()
         # continuous telemetry plane (ISSUE 16): when a TimeSeriesStore is
         # attached, every Nth decode tick hands it that tick's share of
         # the loop clock. Unsampled ticks pay one attribute load plus a
@@ -955,13 +1096,13 @@ class GenerationEngine:
         # mid-stream and sessions resumed from a peer's snapshot
         self._session_exports = 0
         self._session_adoptions = 0
-        # device-time attribution (ISSUE 10): dispatch→publish wall time
-        # split evenly across a step's participating slots and charged to
-        # {model, slo class}. Attribution, not utilization — pipelined
-        # ticks overlap, so the shares can sum past wall-clock time.
+        # device-time attribution (ISSUE 10): a program's interval on the
+        # timeline (_Timeline: landing to landing) split evenly across
+        # its participating slots and charged to {model, slo class}. The
+        # intervals are disjoint, so the shares sum to at most wall time.
         self._device_seconds: Dict[Tuple[str, str], float] = {}
         # executable-level roofline attribution (ISSUE 17): the same
-        # dispatch→publish window, keyed by compiled-executable family
+        # interval, keyed by compiled-executable family
         # instead of slo class — both views share one charge helper so
         # their totals agree by construction
         self.exec_ledger = ExecutableLedger(metrics=metrics)
@@ -3124,7 +3265,9 @@ class GenerationEngine:
                    for (model, cls), seconds
                    in sorted(self._device_seconds.items())},
                # who holds the serving thread, by phase (_LoopClock)
-               "loop": self._clock.stats()}
+               "loop": self._clock.stats(),
+               # what the device ran, landing to landing (_Timeline)
+               "timeline": self._timeline.stats()}
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
             out["prefix_cache"]["page_ladder"] = list(self._p_ladder)
@@ -3578,29 +3721,23 @@ class GenerationEngine:
         q, clock = self._publishq, self._clock
         clock.passes += 1
         clock.enter("admit")
+        began = clock.stamp()
         # 1. batched admission of everything pending (up to free slots);
         #    each prefill's first-token fetch starts concurrently
-        for first_dev, claimed, step_span, family in \
-                await self._admit_pending(loop):
-            q.append(_Fetch(loop.run_in_executor(None, np.asarray,
-                                                 first_dev),
-                            "prefill", claimed, span=step_span,
-                            family=family))
+        for entry in await self._admit_pending(loop):
+            q.append(entry.start(loop, self._annotation))
 
         # 2. dispatch the next decode tick(s) up to the pipeline depth;
         #    its token fetch starts immediately in its own worker thread
-        admit_lap = clock.enter("dispatch")
+        clock.enter("dispatch")
+        admitted = clock.stamp()
         tick_entry = None
         if (self.active_slots > 0
                 and self._ticks_inflight < self.max_inflight_ticks):
-            tick = await self._dispatch_tick(loop)
-            if tick is not None:
-                kind, fetch, payload, step_span, family = tick
+            tick_entry = await self._dispatch_tick(loop)
+            if tick_entry is not None:
                 self._ticks_inflight += 1
-                tick_entry = _Fetch(loop.run_in_executor(None, fetch),
-                                    kind, payload, span=step_span,
-                                    family=family)
-                q.append(tick_entry)
+                q.append(tick_entry.start(loop, self._annotation))
 
         if not q:
             if (self.active_slots == 0 and self._pending.empty()
@@ -3626,17 +3763,20 @@ class GenerationEngine:
         #    drain whatever else already completed.
         block = (tick_entry is None
                  or self._ticks_inflight >= self.max_inflight_ticks)
-        dispatch_lap = clock.enter("wait" if block else "publish")
+        clock.enter("wait" if block else "publish")
         if tick_entry is not None and self.telemetry is not None:
-            # every Nth tick carries this pass's laps to _publish, which
-            # completes them for the sampled tick ring (/debug/timez)
+            # every Nth tick carries this pass's admit and dispatch
+            # phases, their enqueue and upload laps with them, to
+            # _publish, which completes them for the sampled tick ring
+            # (/debug/timez)
             self._tick_seq += 1
             if self._tick_seq % self._tick_every == 0:
+                dispatched = clock.stamp()
                 tick_entry.anatomy = {
-                    "admission_s": admit_lap[0],
-                    "admission_cpu_s": admit_lap[1],
-                    "host_dispatch_s": dispatch_lap[0],
-                    "host_dispatch_cpu_s": dispatch_lap[1]}
+                    "admission_s": admitted[0] - began[0],
+                    "admission_cpu_s": admitted[1] - began[1],
+                    "host_dispatch_s": dispatched[0] - admitted[0],
+                    "host_dispatch_cpu_s": dispatched[1] - admitted[1]}
         if block:
             entry = q.popleft()
             self._publish(entry, await entry.task)
@@ -3644,18 +3784,16 @@ class GenerationEngine:
             entry = q.popleft()
             self._publish(entry, entry.task.result())
 
-    def _attribute_device_time(self, entry: _Fetch) -> None:
-        """Charge the step's dispatch→publish wall time to the
+    def _attribute_device_time(self, entry: _Fetch, interval: float) -> None:
+        """Charge the entry's interval on the device's timeline
+        (``_Timeline``: landing to landing, so the charges over one
+        device are disjoint and sum to at most wall time) to the
         participating requests' {model, slo class}, split evenly, AND to
         the dispatched executable family (ISSUE 17) — both through the
         shared :func:`charge_device_time` helper, so the per-family
         ledger and ``app_tpu_device_seconds_total`` see the exact same
-        elapsed window (the totals agree by construction, no double
-        count). Feeds the hbmz/clusterz rollups and the xlaz roofline
-        table."""
-        elapsed = time.monotonic() - entry.dispatched_at
-        if elapsed <= 0:
-            return
+        seconds (the totals agree by construction, no double count).
+        Feeds the hbmz/clusterz rollups and the xlaz roofline table."""
         if entry.kind == "spec":
             participants = [s for s, _ in entry.payload[0]]
         elif entry.kind == "prefill":
@@ -3667,17 +3805,33 @@ class GenerationEngine:
         classes = [getattr(self._slots[s], "cls", None) or "standard"
                    for s in participants]
         charge_device_time(
-            elapsed, self.model_name, classes=classes,
+            interval, self.model_name, classes=classes,
             family=entry.family or entry.kind,
             device_seconds=self._device_seconds, metrics=self.metrics,
             ledger=self.exec_ledger)
 
+    def _stall(self, entry: _Fetch, interval: float) -> None:
+        """What a prefill group cost the requests it stopped: every slot
+        that is active, has its first token and is not of the group sat
+        out the group's ``interval`` on the device. Booked on the
+        request (``RequestRecord.stalled``) and, times the slots, on the
+        timeline."""
+        group = {slot_idx for slot_idx, _, _ in entry.payload}
+        stopped = 0
+        for slot_idx, slot in enumerate(self._slots):
+            if slot.active and slot.tokens and slot_idx not in group:
+                stopped += 1
+                if slot.record is not None:
+                    slot.record.stalled(interval)
+        self._timeline.stalled_slot_s += interval * stopped
+
     def _publish(self, entry: _Fetch, host) -> None:
         clock = self._clock
         clock.enter("publish")       # ends the wait, or the last publish
-        landed = clock.at
-        self._attribute_device_time(entry)
+        interval = self._timeline.land(entry)
+        self._attribute_device_time(entry, interval)
         if entry.kind == "prefill":
+            self._stall(entry, interval)
             for slot_idx, gen, row in entry.payload:
                 self._push_tokens(slot_idx, gen, [int(host[row])])
         elif entry.kind == "spec":
@@ -3718,17 +3872,18 @@ class GenerationEngine:
                 # on a worker thread — so this copy is host-side)
                 host = host.copy()
                 host[:, entry.payload[0][0]] = -1
-            for slot_idx, gen in entry.payload:
+            self._timeline.tick_tokens += sum(
                 self._push_tokens(slot_idx, gen,
                                   [int(t) for t in host[:, slot_idx]])
+                for slot_idx, gen in entry.payload)
         if entry.span is not None:   # step span covers dispatch → publish
             entry.span.finish()
         if entry.anatomy is not None and self.telemetry is not None:
             # a sampled tick (see _loop_body): the device wait (dispatch →
-            # fetch taken up) and this publish, off the same loop clock
+            # the fetch's landing) and this publish, off the loop clock
             publish_lap = clock.enter("publish")
             entry.anatomy.update(
-                device_wait_s=landed - entry.dispatched_at,
+                device_wait_s=entry.landed_at - entry.dispatched_at,
                 publish_s=publish_lap[0], publish_cpu_s=publish_lap[1],
                 kind=entry.kind,
                 batch=len(entry.payload[0] if entry.kind == "spec"
@@ -3819,9 +3974,8 @@ class GenerationEngine:
         (prefix-pages, prompt-length-bucket) group — prefix_pages is 0
         (full prefill, publishing its pages back to the prefix store when
         one is configured) or a prefix-ladder rung (suffix-only prefill
-        gathering cached pages). Returns [(first_dev, [(slot, gen, row)],
-        step_span, family)] fetch handles for the first generated
-        tokens."""
+        gathering cached pages). Returns a ``_Fetch`` a group, not yet
+        started, for the first generated tokens."""
         requests: List[Tuple] = []
         # page-deferred requests re-enter FIRST (FIFO fairness: they were
         # admitted-in-order before the pool ran short)
@@ -3832,8 +3986,7 @@ class GenerationEngine:
         if not requests:
             return []
         jnp = self._jnp
-        fetches: List[Tuple[Any, List[Tuple[int, int, int]],
-                            Optional[Span], str]] = []
+        fetches: List[_Fetch] = []
         by_group: Dict[Tuple[int, int, bool], List[Tuple]] = {}
         leases: List[Any] = []
         # pages promised to requests admitted this pass, a cache kind
@@ -4250,8 +4403,12 @@ class GenerationEngine:
                 step_span = self._step_span("tpu.engine.prefill", claimed,
                                             bucket=bucket, padded_batch=nb,
                                             prefix_pages=p_rung)
+                dispatched_at = time.monotonic()
                 if warm:
-                    with self._profile_step("tpu.engine.prefill"):
+                    # the lap opens first: of two annotations that cover
+                    # a device gap, the earlier one names it
+                    with self._clock.lap("enqueue"), \
+                            self._profile_step("tpu.engine.prefill"):
                         first_dev = dispatch()
                         if draft_dispatch is not None:
                             draft_dispatch()
@@ -4269,7 +4426,9 @@ class GenerationEngine:
                 self._prefill_rows += nb
                 family = (f"suffix_prefill[nb={nb},p={p_rung},b={bucket}]"
                           if p_rung else f"prefill[nb={nb},b={bucket}]")
-                fetches.append((first_dev, claimed, step_span, family))
+                fetches.append(_Fetch(
+                    "prefill", claimed, partial(np.asarray, first_dev),
+                    dispatched_at, span=step_span, family=family))
         finally:
             if self._prefix is not None and leases:
                 self._prefix.release(leases)
@@ -4323,14 +4482,16 @@ class GenerationEngine:
         back on device with a bit-exact jitted bitcast — greedy decode is
         token-identical with coalescing on or off. Off, each array is its
         own metered ``jnp.asarray``. Either way the caller indexes the
-        returned dict by name, so the two paths share all dispatch code."""
+        returned dict by name, so the two paths share all dispatch code.
+        On the loop's thread the transfer is the clock's ``upload`` lap."""
         live = {k: v for k, v in arrays.items() if v is not None}
-        if self.coalesce_uploads and len(live) > 1:
-            out = self._coalescer.upload(live)
-        else:
-            jnp = self._jnp
-            out = {k: self._h2d.upload(v, jnp.asarray, path="dispatch")
-                   for k, v in live.items()}
+        with self._clock.lap("upload"):
+            if self.coalesce_uploads and len(live) > 1:
+                out = self._coalescer.upload(live)
+            else:
+                jnp = self._jnp
+                out = {k: self._h2d.upload(v, jnp.asarray, path="dispatch")
+                       for k, v in live.items()}
         for k in arrays:
             out.setdefault(k, None)
         return out
@@ -4348,7 +4509,7 @@ class GenerationEngine:
 
     async def _dispatch_tick(self, loop):
         """Choose K adaptively, dispatch one decode executable, return
-        (device tokens handle, active snapshot) without syncing.
+        its ``_Fetch`` (not yet started) without syncing.
 
         Slots whose budget is already covered by in-flight tokens are
         excluded from this tick (frozen in the mask) rather than stalling
@@ -4451,8 +4612,10 @@ class GenerationEngine:
         step_span = self._step_span("tpu.engine.step", snapshot,
                                     k=k, window=window or self.max_len,
                                     sampled=sampled, step=self._steps)
+        dispatched_at = time.monotonic()
         if (k, sampled, biased, width) in self._tick_fns:
-            with self._profile_step("tpu.engine.step"):
+            with self._clock.lap("enqueue"), \
+                    self._profile_step("tpu.engine.step"):
                 tokens_dev, counts_dev = dispatch()
         else:
             tokens_dev, counts_dev = await self._off_loop(loop, dispatch)
@@ -4466,20 +4629,9 @@ class GenerationEngine:
             self.metrics.record_histogram(
                 "app_tpu_batch_size", float(len(snapshot)),
                 exemplar=exemplar, model=self.model_name)
-            self.metrics.set_gauge(
-                "app_tpu_attention_window",
-                float(window or self.max_len), model=self.model_name)
             self.metrics.increment_counter(
                 "app_tpu_attn_kernel_total", model=self.model_name,
                 path=self.attn_path)
-            if self.paged:
-                held = sum(s.held_pages() for _, s in eligible)
-                filled = sum(s.fill for _, s in eligible)
-                if held:
-                    self.metrics.set_gauge(
-                        "app_tpu_kv_ragged_fill_ratio",
-                        min(1.0, filled / (held * self.kv_page)),
-                        model=self.model_name)
 
         if counts_dev:
             def fetch(dev=(tokens_dev, counts_dev[0])):
@@ -4489,16 +4641,27 @@ class GenerationEngine:
             def fetch(dev=tokens_dev):
                 return np.asarray(dev)
 
+        # what the timeline books when the tick lands: its steps, its
+        # rung, and on the paged gather path the rows under the
+        # participants' lengths (step j of K reads fill + j + 1 a slot)
+        # over the rows its gathers bring in for every slot
+        live = gathered = 0
+        if self.attn_path == "gather":
+            live = k * sum(fills) + len(fills) * k * (k + 1) // 2
+            gathered = k * self.max_slots * width * self.kv_page
+        work = (k, str(width) if width else "full", live, gathered)
         # executable-family name for the roofline ledger (ISSUE 17)
         family = self._tick_names(k, biased, width)[1]
-        return "tick", fetch, snapshot, step_span, family
+        return _Fetch("tick", snapshot, fetch, dispatched_at,
+                      span=step_span, family=family, work=work)
 
     async def _dispatch_spec(self, loop, eligible, g: int):
         """Dispatch one speculative tick at rung ``g``: charge every
         participating slot ``g + 1`` in-flight tokens (the conservative
         worst case — ``_publish`` refunds the rejected remainder), run the
         fused draft+verify executable, and hand back a fetch that lands
-        both the (g+1, B) token matrix and the per-slot accept counts."""
+        both the (g+1, B) token matrix and the per-slot accept counts
+        (a ``_Fetch``, not yet started)."""
         if self.paged:
             covered = self._cover_pages(eligible, g + 1)
             if not covered:
@@ -4530,8 +4693,10 @@ class GenerationEngine:
         step_span = self._step_span("tpu.engine.spec", snapshot,
                                     gamma=g, window=window or self.max_len,
                                     step=self._steps)
+        dispatched_at = time.monotonic()
         if (g, width) in self._spec_fns:
-            pair = dispatch()
+            with self._clock.lap("enqueue"):
+                pair = dispatch()
         else:
             pair = await self._off_loop(loop, dispatch)
         self._steps += 1
@@ -4550,7 +4715,8 @@ class GenerationEngine:
 
         family = (f"spec_paged[g={g},pw={width}]" if self.paged
                   else f"spec[g={g},w={window or self.max_len}]")
-        return "spec", fetch, (snapshot, g), step_span, family
+        return _Fetch("spec", (snapshot, g), fetch, dispatched_at,
+                      span=step_span, family=family)
 
     def _kind_pages(self, tokens: int, pinned: int = 0
                     ) -> Dict[str, Tuple[int, int]]:
@@ -4732,22 +4898,27 @@ class GenerationEngine:
         ``app_tpu_ttft`` uses, so per request queue + first_token = ttft.
         A request cancelled, expired or refused before it got a slot has
         neither, like ``app_tpu_ttft``; one that got a slot and ended
-        before its first token has ``queue`` alone."""
+        before its first token has ``queue`` alone. ``stall`` is observed
+        once a request that had a first token, when it ends: the seconds
+        of its decode phase (first token → end) in which the device ran
+        other requests' prefill groups (``RequestRecord.stall_s``). It is
+        part of the decode phase, not of TTFT."""
         if self.metrics is not None:
             self.metrics.record_histogram(
                 "app_tpu_request_phase_seconds", seconds,
                 model=self.model_name, phase=phase)
 
     def _push_tokens(self, slot_idx: int, gen: int,
-                     tokens: List[int]) -> None:
+                     tokens: List[int]) -> int:
         """Append generated tokens to a slot, handling eos/budget; stale
-        generations (slot reclaimed since dispatch) are dropped."""
+        generations (slot reclaimed since dispatch) are dropped. Returns
+        how many tokens the request took."""
         slot = self._slots[slot_idx]
         if slot.gen != gen:
-            return
+            return 0
         slot.inflight -= len(tokens)
         if not slot.active:
-            return
+            return 0
         if not slot.tokens:
             # first published token for this request: submit → now is the
             # operator-facing TTFT — admission wait + prefill dispatch +
@@ -4795,7 +4966,7 @@ class GenerationEngine:
                         f"slot {slot_idx} produced out-of-range token "
                         f"{token} (vocab {self.cfg.vocab_size}); "
                         "NaN/inf logits upstream — request quarantined"))
-                return
+                return pushed
             slot.tokens.append(token)
             slot.remaining -= 1
             pushed += 1
@@ -4826,7 +4997,7 @@ class GenerationEngine:
                     if chunk and slot.queue is not None:  # failure is
                         slot.queue.put_nowait(chunk)      # this request's
                     self._quarantine_slot(slot_idx, slot, "grammar", exc)
-                    return
+                    return pushed
             if done:
                 slot.active = False    # rest of the chunk is discarded
                 self._release_slot_kv(slot_idx, slot)
@@ -4868,6 +5039,7 @@ class GenerationEngine:
             self.metrics.delta_updown_counter(
                 "app_tpu_sched_tokens_total", float(pushed),
                 model=self.model_name, cls=slot.cls)
+        return pushed
 
     def _quarantine_slot(self, slot_idx: int, slot: _Slot, reason: str,
                          exc: BaseException) -> None:
@@ -4941,6 +5113,7 @@ class GenerationEngine:
         if slot.record is not None:
             if slot.record.tokens:
                 slot.record.first_token()   # idempotent backstop
+                self._observe_phase("stall", slot.record.stall_s)
             self.recorder.finish(slot.record, status)
             slot.record = None
         slot.req_span = None

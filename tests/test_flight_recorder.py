@@ -182,6 +182,26 @@ def test_flight_recorder_ring_and_lifecycle():
                                      "mean": 1.5}
 
 
+@pytest.mark.parametrize("stalls", [(), (0.25,), (0.25, 0.5, 0.06)])
+def test_request_record_books_the_prefills_it_met(stalls):
+    """Other requests' prefill groups that ran while this one decoded are
+    two bounded aggregates, shown beside the decode phase they are part
+    of; a request that has no first token has no decode phase."""
+    record = RequestRecord(prompt_len=3, budget=8)
+    assert record.decode_s is None and record.to_dict()["decode_s"] is None
+    record.admitted()
+    record.first_token()
+    for seconds in stalls:
+        record.stalled(seconds)
+    record.finish("done")
+    shown = record.to_dict()
+    assert shown["prefills_met"] == len(stalls)
+    assert shown["stall_s"] == pytest.approx(sum(stalls))
+    assert shown["decode_s"] == pytest.approx(
+        record.finished_at - record.first_token_at, abs=1e-6)
+    assert not hasattr(record, "__dict__")       # still a slotted record
+
+
 def test_flight_recorder_tracks_in_flight():
     recorder = FlightRecorder(capacity=4)
     record = recorder.start(RequestRecord(prompt_len=1, budget=2))

@@ -653,3 +653,8 @@ def test_step_counters_add_up_through_the_engine(model):
     assert attn["rows_read"] % (4 * PAGE * cfg.n_layers) == 0
     assert metrics.value("app_tpu_step_counter_total", model="generate",
                          counter="attn.rows_live") == attn["rows_live"]
+    # the host's count of the same rows (the engine's timeline, a layer)
+    # is the device's
+    timeline = stats["timeline"]
+    assert timeline["rows_live"] * cfg.n_layers == attn["rows_live"]
+    assert timeline["rows_gathered"] * cfg.n_layers == attn["rows_read"]

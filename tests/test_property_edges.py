@@ -158,9 +158,9 @@ def test_env_overlay_precedence(tmp_path, monkeypatch):
     assert config.get("C") == "process"     # process env wins over all
 
 
-def test_window_gauge_and_stats_exposed():
+def test_window_rung_and_stats_exposed():
     """The attention-window rung is observable: stats() lists the ladder
-    and a tick sets the app_tpu_attention_window gauge."""
+    and counts the ticks each rung took (timeline.ticks_by_width)."""
     import asyncio
 
     from gofr_tpu.container import new_mock_container
@@ -169,8 +169,6 @@ def test_window_gauge_and_stats_exposed():
 
     cfg = llama.config("tiny")
     params = llama.init(cfg, jax.random.PRNGKey(0))
-    # no manual registration: the framework catalog (container.py
-    # register_framework_metrics) must provide the gauge
     container = new_mock_container()
     engine = GenerationEngine(cfg, params, max_slots=2, max_len=256,
                               prompt_buckets=(8,),
@@ -183,8 +181,9 @@ def test_window_gauge_and_stats_exposed():
         try:
             await asyncio.wait_for(
                 engine.generate([1, 2, 3], max_new_tokens=4), 60.0)
-            assert container.metrics.value(
-                "app_tpu_attention_window", model="generate") == 128.0
+            timeline = engine.stats()["timeline"]
+            assert set(timeline["ticks_by_width"]) == {"128"}
+            assert timeline["ticks_by_width"]["128"] == timeline["ticks"] > 0
         finally:
             await engine.stop()
     asyncio.run(main())
