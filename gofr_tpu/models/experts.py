@@ -1,8 +1,9 @@
 """The sparse expert layer, shared by the model families that route
-(``models/mla_moe.py``, ``models/swa_moe.py``): router, this chip's share
-of the routed experts in two forms, the shared experts, and the step
-counters. A family's config object is read for ``scoring`` (``sigmoid``
-or ``softmax``), ``top_k``, ``norm_topk_prob``, ``routed_scale``,
+(``models/mla_moe.py``, ``models/swa_moe.py``, ``models/hc_mla_moe.py``):
+router, this chip's share of the routed experts in a form for a prefill
+and one for a decode step, the shared experts, and the step counters.
+A family's config object is read for ``scoring`` (``sigmoid`` or
+``softmax``), ``top_k``, ``norm_topk_prob``, ``routed_scale``,
 ``n_routed_experts`` (the router's width), ``n_held_experts`` and
 ``expert_rank`` (which share lives on this chip), and ``shared_scale``
 (what the shared experts' sum is multiplied by: 1, or ``1 /
@@ -23,9 +24,13 @@ blocks of one expert's rows, so its work follows the pairs
 (``experts_grouped``: given the whole stack and a layer's index it
 reads each expert where it lies; how a token's pairs are summed
 follows from the share of the experts held); a decode
-step has one token a slot, is bound by the experts' bytes and not by
-rows, and runs every held expert over all rows in one batched product
-(rows an expert was not chosen for weigh zero).
+step has one token a slot and is bound by the experts' bytes and not
+by rows, so its work follows the experts *hit* (``experts_hit``): a
+loop with one trip a held expert that some row chose, which reads that
+expert where it lies and runs it over all rows (rows that did not
+choose it weigh zero); an expert no row chose is not read.
+``experts_batched``, every held expert over every row in one batched
+product, is the plain formulation the tests hold that loop to.
 """
 
 from __future__ import annotations
@@ -41,10 +46,13 @@ STEP_COUNTERS = ("moe.routed_pairs", "moe.held_pairs", "moe.experts_hit",
                  "moe.hot_expert_pairs", "moe.layer_steps")
 
 
-def _swiglu(w, x):
+def _swiglu(w, x, out_dtype=None):
+    """``out_dtype``: what the down product leaves (its accumulator's
+    float32 unrounded, where asked for); x's dtype without it."""
     gate = jax.nn.silu((x @ w["w_gate"]).astype(jnp.float32))
     up = (x @ w["w_up"]).astype(jnp.float32)
-    return (gate * up).astype(x.dtype) @ w["w_down"]
+    return jnp.matmul((gate * up).astype(x.dtype), w["w_down"],
+                      preferred_element_type=out_dtype)
 
 
 def route(cfg, router, h, bias=None):
@@ -78,22 +86,75 @@ def _held(cfg, ids, valid):
     return local, held
 
 
-def experts_batched(cfg, experts, h, ids, weights, valid=None):
-    """Every held expert over every row in one batched product; a row
-    weighs zero for an expert it did not choose. For a decode step: few
-    rows, and the cost is the experts' bytes. Returns (y (T, D) float32,
-    pairs routed to each held expert (E,) int32)."""
+def _combine(cfg, ids, weights, valid):
+    """(each row's weight for each held expert (T, E) float32, zero
+    where the row did not choose it; pairs routed to each held expert
+    (E,) int32)."""
     local, held = _held(cfg, ids, valid)
     onehot = (local[..., None] == jnp.arange(cfg.n_held_experts)) \
         & held[..., None]                                   # (T, K, E)
-    combine = (onehot * weights[..., None]).sum(axis=1)     # (T, E) f32
+    return ((onehot * weights[..., None]).sum(axis=1),
+            onehot.sum(axis=(0, 1)).astype(jnp.int32))
+
+
+def _expert_at(experts, e, at=None):
+    """Held expert ``e``'s matrices, read where they lie: ``experts`` is
+    one layer's, (E, ..) a leaf, or with ``at`` (a layer's index,
+    traced) the whole stack, (layers, E, ..) a leaf, read at ``[at,
+    e]``."""
+    if at is None:
+        return {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
+                for name, leaf in experts.items()}
+    return {name: lax.dynamic_slice(
+        leaf, (at, e) + (0,) * (leaf.ndim - 2),
+        (1, 1) + leaf.shape[2:]).reshape(leaf.shape[2:])
+        for name, leaf in experts.items()}
+
+
+def experts_batched(cfg, experts, h, ids, weights, valid=None):
+    """Every held expert over every row in one batched product; a row
+    weighs zero for an expert it did not choose. The plain formulation
+    of a decode step's routed part, on no served path: the oracle the
+    tests hold ``experts_hit`` to (it reads every held expert whatever
+    the router chose). Returns (y (T, D) float32, pairs routed to each
+    held expert (E,) int32)."""
+    combine, counts = _combine(cfg, ids, weights, valid)
     gate = jax.nn.silu(jnp.einsum(
         "td,edf->etf", h, experts["w_gate"]).astype(jnp.float32))
     up = jnp.einsum("td,edf->etf", h, experts["w_up"]).astype(jnp.float32)
     out = jnp.einsum("etf,efd->etd", (gate * up).astype(h.dtype),
                      experts["w_down"], preferred_element_type=jnp.float32)
     y = jnp.einsum("te,etd->td", combine, out)
-    return y, onehot.sum(axis=(0, 1)).astype(jnp.int32)
+    return y, counts
+
+
+def experts_hit(cfg, experts, h, ids, weights, valid=None, at=None):
+    """A decode step's routed part, with work that follows the experts
+    hit: few rows, and the cost is the experts' bytes, so an expert no
+    row chose is not read. The held experts are put hit-first (a stable
+    sort, so the hit ones stay in ascending order) and a loop makes one
+    trip a hit expert: its three matrices read where they lie
+    (``_expert_at``; a caller whose layers are a scan's steps passes the
+    whole stack and ``at``, as for ``experts_grouped``: a scan's slice
+    handed to a loop is a copy of the layer's experts), all rows
+    through it, and its result added to the (T, D) float32 sum with
+    each row's weight for it, zero for a row that did not choose it. No
+    row gather, no capacity, no dropped pair; no valid row is zero trips
+    and a zero sum. bfloat16 products, float32 accumulators and
+    weights, as ``experts_batched``; the sum over experts goes in the
+    loop's order. Returns (y (T, D) float32, pairs routed to each held
+    expert (E,) int32)."""
+    combine, counts = _combine(cfg, ids, weights, valid)
+    hit_first = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)
+
+    def add_expert(i, y):
+        e = hit_first[i]
+        out = _swiglu(_expert_at(experts, e, at), h, jnp.float32)
+        return y + lax.dynamic_slice_in_dim(combine, e, 1, axis=1) * out
+
+    y = lax.fori_loop(0, (counts > 0).sum().astype(jnp.int32), add_expert,
+                      jnp.zeros(h.shape, jnp.float32))
+    return y, counts
 
 
 def _block_rows(cfg, tokens: int) -> int:
@@ -159,13 +220,7 @@ def experts_grouped(cfg, experts, h, ids, weights, valid=None, at=None):
         return e, first, live, pair
 
     def expert(e):
-        if at is None:
-            return {name: lax.dynamic_index_in_dim(leaf, e, 0, keepdims=False)
-                    for name, leaf in experts.items()}
-        return {name: lax.dynamic_slice(
-            leaf, (at, e) + (0,) * (leaf.ndim - 2),
-            (1, 1) + leaf.shape[2:]).reshape(leaf.shape[2:])
-            for name, leaf in experts.items()}
+        return _expert_at(experts, e, at)
 
     def add_block(i, y):
         e, _, live, pair = rows_of(i)
@@ -205,8 +260,8 @@ def moe_ffn(cfg, layer, h, valid=None, grouped=False):
                                         weights, valid,
                                         layer.get("experts_at"))
     else:
-        y, per_expert = experts_batched(cfg, layer["experts"], h, ids,
-                                        weights, valid)
+        y, per_expert = experts_hit(cfg, layer["experts"], h, ids, weights,
+                                    valid, layer.get("experts_at"))
     if "shared" in layer:
         shared = _swiglu(layer["shared"], h).astype(jnp.float32)
         scale = getattr(cfg, "shared_scale", 1.0)

@@ -38,12 +38,13 @@ sqrt(mean(x^2) + eps) * g``):
   float32; ``T`` = the ``top_k`` largest; ``w_e = routed_scale * s_e /
   sum_{j in T} s_j``; ``y = Shared(h) + sum_{e in T and held} w_e
   Expert_e(h)``. This chip holds experts ``expert_rank * n_held_experts
-  ..`` of them; ``prefill`` runs the grouped form, a decode step the
-  batched one. ``prefill`` keeps the routed experts' stack out of its
-  layer scan and the grouped form reads ``[layer, expert]`` from it: a
-  scan's slice handed to the block loop is a copy of a layer's experts
-  a layer a call (``docs/tpu/model-serving.md``; every member's prefill
-  reads in place, ``models/hc_mla_moe.py``'s too).
+  ..`` of them; ``prefill`` runs the grouped form (work that follows the
+  routed pairs), a decode step the loop over the experts its rows hit
+  (``experts_hit``: an expert no row chose is not read). Both keep the
+  routed experts' stack out of their layer scans and read ``[layer,
+  expert]`` from it: a scan's slice handed to a loop is a copy of a
+  layer's experts a layer a call (``docs/tpu/model-serving.md``; every
+  member reads in place, ``models/hc_mla_moe.py`` too).
 
 Serving contract (``docs/tpu/model-serving.md``): ``init_cache``,
 ``prefill``, ``decode_step``, ``decode_step_paged``, ``cache_leaves``
@@ -69,7 +70,7 @@ from jax import lax
 
 from gofr_tpu.models.experts import (  # noqa: F401  (the module's names)
     STEP_COUNTERS, _block_rows, _held, _swiglu, experts_batched,
-    experts_grouped, moe_ffn, route)
+    experts_grouped, experts_hit, moe_ffn, route)
 from gofr_tpu.ops import apply_rope, rms_norm, rope_table
 from gofr_tpu.ops.rotary import yarn_mscale
 
@@ -519,6 +520,10 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
     ``idx``'s new row for every sequence, ``view_of(leaf, idx)`` gives
     the (B, T, cache_row) rows attention reads. The new row is written
     first and read back with the rest: position ``cache_len`` is valid.
+    The routed experts' stack stays out of the layer scan, as in
+    ``prefill``: the expert layer's loop over the experts hit reads
+    ``[layer, expert]`` from it (``experts_hit``'s ``at``), where the
+    scan's slice would be a copy of a layer's experts every step.
     Returns (logits, leaf, counters), and with ``routes`` the experts
     chosen, (expert layers, B, top_k), as a fourth."""
     cos, sin = cfg.rope()
@@ -529,10 +534,17 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
 
     for kind, stack, first in _stacks(params, cfg):
         n = jax.tree.leaves(stack)[0].shape[0]
+        scanned = stack
+        if kind == "moe":       # all but the routed experts, as prefill
+            scanned = {name: leaf for name, leaf in stack.items()
+                       if name != "experts"}
 
-        def body(carry, layer_and_idx, kind=kind):
+        def body(carry, layer_and_idx, kind=kind, stack=stack, first=first):
             x, leaf, counters = carry
             layer, idx = layer_and_idx
+            if kind == "moe":
+                layer = dict(layer, experts=stack["experts"],
+                             experts_at=idx - first)
 
             def attend(attn, h):
                 q_n, q_r, row = _latent(attn, h, cfg, cos, sin, positions)
@@ -552,7 +564,7 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
 
         (x, leaf, counters), ids = lax.scan(
             body, (x, leaf, counters),
-            (stack, first + jnp.arange(n, dtype=jnp.int32)))
+            (scanned, first + jnp.arange(n, dtype=jnp.int32)))
         if kind == "moe":
             chosen = ids
     out = (_head(params, cfg, residual.gather(x)[:, 0]), leaf, counters)

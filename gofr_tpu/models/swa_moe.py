@@ -72,7 +72,7 @@ from jax import lax
 
 from gofr_tpu.models import experts
 from gofr_tpu.models.experts import (  # noqa: F401  (the module's names)
-    experts_batched, experts_grouped, moe_ffn, route)
+    experts_batched, experts_grouped, experts_hit, moe_ffn, route)
 from gofr_tpu.ops import (apply_rope, banded_attention,
                           decode_attention_cached, gather_kv_pages,
                           layer_norm, rope_table)
@@ -428,12 +428,19 @@ def _decode(params, cfg: SwaMoeConfig, token, cache, cache_len, active,
         read = cache_len if window is None else cache_len - starts[kind]
         rows[kind] = jnp.where(live, read, 0).sum().astype(jnp.int32)
     ordinals = _ordinals(cfg)
+    # the routed experts stay out of the scan: the expert layer's loop
+    # reads [period, expert] from the whole stack (experts_hit's at),
+    # where a scan's slice of it would be a copy of a layer's experts
+    scanned = tuple({name: leaf for name, leaf in layer.items()
+                     if name != "experts"} for layer in params["layers"])
 
     def body(carry, layers_and_period):
         x, cache, counters = carry
         layers, p = layers_and_period
-        for (kind, per_period, place), layer in zip(ordinals, layers):
+        for (kind, per_period, place), layer, whole in zip(
+                ordinals, layers, params["layers"]):
             idx = p * per_period + place
+            layer = dict(layer, experts=whole["experts"], experts_at=p)
 
             def attend(h, kind=kind, layer=layer, idx=idx):
                 q, k, v = _qkv(cfg, kind, layer, h, rope, positions)
@@ -455,7 +462,7 @@ def _decode(params, cfg: SwaMoeConfig, token, cache, cache_len, active,
     periods = cfg.n_layers // len(cfg.period)
     (x, cache, counters), _ = lax.scan(
         body, (x, cache, jnp.zeros((_N_COUNTERS,), jnp.int32)),
-        (params["layers"], jnp.arange(periods, dtype=jnp.int32)))
+        (scanned, jnp.arange(periods, dtype=jnp.int32)))
     return _head(params, cfg, x[:, 0]), cache, counters
 
 

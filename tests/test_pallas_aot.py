@@ -59,6 +59,13 @@ def v5e():
     return jax.sharding.SingleDeviceSharding(topology.devices[0])
 
 
+def _on_chip(tree, sharding):
+    """A tree of shapes, each placed on the described chip."""
+    return jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=sharding), tree)
+
+
 def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in shapes]
@@ -440,23 +447,18 @@ def test_window_and_global_kinds_decode_in_one_tick_for_v5e(v5e):
         max_seq_len=9216)
     pages = {"window": 4224, "full": 9216}
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                              sharding=v5e), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda: swa_moe.init(cfg, jax.random.key(0))))
+    params = _on_chip(jax.eval_shape(
+        lambda: swa_moe.init(cfg, jax.random.key(0))), v5e)
     pool = {kind: {name: jax.ShapeDtypeStruct(
         (spec["layers"], pages[kind], PAGE, *tail), dtype)
         for name, (tail, dtype) in spec["leaves"].items()}
         for kind, spec in swa_moe.cache_leaves(cfg).items()}
-    rest = on_chip((
+    rest = _on_chip((
         jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.int32), pool,
         {kind: jax.ShapeDtypeStruct((CMDA_SLOTS, CMDA_COLUMNS), jnp.int32)
          for kind in pool},
         jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.int32),
-        jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.bool_)))
+        jax.ShapeDtypeStruct((CMDA_SLOTS,), jnp.bool_)), v5e)
 
     def tick(params, token, pool, table, cache_len, active):
         logits, pool, cache_len, counted = swa_moe.decode_step_paged(
@@ -513,3 +515,78 @@ def test_latent_prefill_copies_no_layers_experts_for_v5e(v5e):
     assert not a_layers, a_layers[:3]
     whole = re.findall(rf"^.*= bf16\[2,16,{leaf}\S* (\S+?)\(", hlo, re.M)
     assert whole and set(whole) <= {"parameter", "get-tuple-element"}, whole
+
+
+# -- a decode step reads only the experts hit, where they lie (ISSUE 36) ------
+
+def _expert_step(family):
+    """(module, its config at the cell's widths with two expert layers,
+    pool, page table, the step's keywords), shapes only."""
+    from gofr_tpu.models import hc_mla_moe, mla_moe, swa_moe
+
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    if family == "cmda-two-periods":
+        # two periods: the scan's slice of a stack is no longer the leaf;
+        # 4 of 128 experts held, so that 8 layers fit a chip
+        cfg = swa_moe.config(
+            "command-a-plus", n_layers=8, layer_types=swa_moe.SwaMoeConfig()
+            .layer_types[:8], n_held_experts=4, vocab_size=32768,
+            max_seq_len=9216)
+        pool = {kind: {name: shape(spec["layers"], 256, PAGE, *tail,
+                                   dtype=dtype)
+                       for name, (tail, dtype) in spec["leaves"].items()}
+                for kind, spec in swa_moe.cache_leaves(cfg).items()}
+        return (swa_moe, cfg, pool, {kind: shape(32, 8) for kind in pool},
+                {"ragged": True})
+    if family == "pangu":
+        module, cfg = mla_moe, mla_moe.config(
+            "pangu-ultra-moe", n_layers=3, n_dense_layers=1,
+            n_held_experts=16, vocab_size=19200, max_seq_len=2048)
+    else:
+        module, cfg = hc_mla_moe, hc_mla_moe.config(
+            "xing4", n_layers=3, n_dense_layers=1, max_seq_len=6656)
+    pool = {"ckv": shape(cfg.n_layers, 256, PAGE, cfg.cache_row,
+                         dtype=cfg.dtype)}
+    return module, cfg, pool, shape(32, 8), {}
+
+
+@pytest.mark.parametrize("family", ["pangu", "xing", "cmda-two-periods"])
+def test_decode_steps_read_the_hit_experts_where_they_lie_for_v5e(v5e,
+                                                                  family):
+    """Each expert family's paged decode step at its cell's widths, two
+    expert layers (two periods for the window family): the routed part
+    is a loop over the experts hit, so the optimised HLO holds no
+    operation whose result is a layer's experts (the layer scan's slice,
+    which a loop's operand would make a copy every step), none whose
+    result is a batched ``(experts, rows, width)`` product, and the
+    whole stack only as it is given."""
+    module, cfg, pool, table, kwargs = _expert_step(family)
+    params = _on_chip(jax.eval_shape(
+        lambda: module.init(cfg, jax.random.key(0))), v5e)
+    slots = jax.tree.leaves(table)[0].shape[0]
+    rest = _on_chip((jax.ShapeDtypeStruct((slots,), jnp.int32), pool, table,
+                     jax.ShapeDtypeStruct((slots,), jnp.int32),
+                     jax.ShapeDtypeStruct((slots,), jnp.bool_)), v5e)
+
+    def step(params, token, pool, table, cache_len, active):
+        return module.decode_step_paged(params, cfg, token, pool, table,
+                                        cache_len, active, counters=True,
+                                        **kwargs)
+
+    hlo = jax.jit(step, donate_argnums=(2,)).lower(
+        params, *rest).compile().as_text()
+    held, d, f = cfg.n_held_experts, cfg.dim, cfg.moe_ffn_dim
+    leaf = rf"(?:{d},{f}|{f},{d})\]"
+    a_layers = re.findall(rf"^.*= bf16\[(?:1,)?{held},{leaf}.*$", hlo, re.M)
+    assert not a_layers, a_layers[:3]
+    whole = re.findall(rf"^.*= bf16\[2,{held},{leaf}\S* (\S+?)\(", hlo,
+                       re.M)
+    assert whole and set(whole) <= {"parameter", "get-tuple-element"}, whole
+    batched = re.findall(
+        rf"^.*= \w+\[{held},(?:{slots},(?:{d}|{f})|(?:{d}|{f}),{slots})\].*$",
+        hlo, re.M)
+    assert not batched, batched[:3]
+    one = re.findall(rf"bf16\[1,1,{d},{f}\]\S* dynamic-slice\(", hlo)
+    assert one, "no single expert is sliced from the stack"
