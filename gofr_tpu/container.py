@@ -291,6 +291,10 @@ class Container:
                           "KV page pool: pages currently referenced")
         metrics.new_gauge("app_tpu_kv_pages_capacity",
                           "KV page pool: total pages in the pool")
+        metrics.new_gauge("app_tpu_state_slots_claimed",
+                          "per-slot cache kind: rows whose slot is claimed")
+        metrics.new_gauge("app_tpu_state_slots_capacity",
+                          "per-slot cache kind: rows (the engine's slots)")
         metrics.new_updown_counter(
             "app_tpu_kv_pages_written_total",
             "pool pages written by prefill/publish scatters — a prefix "

@@ -3,8 +3,10 @@
 from gofr_tpu.ops.pallas.flash_attention import flash_attention
 from gofr_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_decode_attention, ragged_paged_verify_attention)
-from gofr_tpu.ops.pallas.select import flash_tileable, ragged_tileable
+from gofr_tpu.ops.pallas.select import (flash_tileable, ragged_tileable,
+                                        scan_tileable)
+from gofr_tpu.ops.pallas.selective_scan import selective_scan
 
 __all__ = ["flash_attention", "ragged_paged_decode_attention",
-           "ragged_paged_verify_attention", "flash_tileable",
-           "ragged_tileable"]
+           "ragged_paged_verify_attention", "selective_scan",
+           "flash_tileable", "ragged_tileable", "scan_tileable"]

@@ -24,7 +24,8 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
-__all__ = ["flash_tileable", "ragged_tileable", "lower_for_target"]
+__all__ = ["flash_tileable", "ragged_tileable", "scan_tileable",
+           "lower_for_target"]
 
 
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
@@ -49,6 +50,17 @@ def ragged_tileable(head_dim: int, q_heads: int, kv_heads: int,
     return (q_heads % kv_heads == 0 and head_dim % 128 == 0
             and q_heads % 8 == 0 and page % 16 == 0
             and kv_heads % (4 // kv_itemsize) == 0)
+
+
+def scan_tileable(seq_len: int, channels: int, n_state: int) -> bool:
+    """Selective-scan (state-space prefill) tiling predicate: whole
+    128-token blocks (one 128 x 128 transpose of the projections a
+    block), whole 512-channel tiles, and states that fill whole
+    sublanes with both projections inside one 128-lane row
+    (AOT-compiled for v5e at 5120 channels x 16 states by
+    tests/test_pallas_aot.py)."""
+    return (seq_len % 128 == 0 and channels % 512 == 0
+            and n_state % 8 == 0 and 2 * n_state <= 128)
 
 
 def lower_for_target(kernel: Callable, interpret: Optional[bool],

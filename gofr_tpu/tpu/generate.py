@@ -66,7 +66,8 @@ from gofr_tpu.tpu.compile_ledger import (ExecutableLedger, ShapeStats,
                                          charge_device_time, suggest_ladder)
 from gofr_tpu.tpu.constrain import GrammarWalker
 from gofr_tpu.tpu.flightrecorder import FlightRecorder, RequestRecord
-from gofr_tpu.tpu.page_pool import ONE_KIND, by_kind, cache_kinds
+from gofr_tpu.tpu.page_pool import (ONE_KIND, by_kind, cache_kinds,
+                                    slot_kinds)
 from gofr_tpu.tpu.sched import (ClassQueues, DEFAULT_CLASS_WEIGHTS,
                                 brownout_shed_classes, deadline_class)
 from gofr_tpu.trace import Span, current_span, extract_traceparent
@@ -638,6 +639,30 @@ class GenerationEngine:
         self._llama = llama if model_module is None else model_module
         self.model_name = str(model_name)
         named = getattr(self._llama, "__name__", repr(self._llama))
+        # what the module's cache holds (cache_leaves; None: the pool's
+        # own k/v form). A per-slot kind (a recurrent state a slot, not
+        # pages) lives in the page pool's manager beside the paged kinds
+        # and is refused, by name, wherever only pages are handled
+        cache_leaves = getattr(self._llama, "cache_leaves", None)
+        self._leaf_specs: Optional[Dict[str, Any]] = (
+            cache_leaves(cfg) if cache_leaves else None)
+        per_slot = sorted(k.name for k in slot_kinds(self._leaf_specs))
+        if per_slot:
+            state = f"model_module {named}: per-slot cache kind {per_slot}"
+            for given, why in (
+                    (not paged_kv, "is kept by the page pool's manager "
+                     "(paged_kv=True); the dense cache has rows of tokens"),
+                    (prefix_cache, "cannot use prefix_cache: prefix pages "
+                     "hold no state, so a hit would skip the tokens that "
+                     "made it"),
+                    (draft_cfg is not None, "cannot use speculative "
+                     "decode: a rejected draft would have to roll the "
+                     "state back"),
+                    (mesh is not None, "has no sharding rule (mesh=None)"),
+                    (page_pool is not None, "is a row a slot of this "
+                     "engine: a shared page_pool's slots are not")):
+                if given:
+                    raise ValueError(f"{state} {why}")
         if model_module is not None and model_module is not llama:
             wanted = ("init_cache", "prefill",
                       "decode_step_paged" if paged_kv else "decode_step")
@@ -666,9 +691,6 @@ class GenerationEngine:
         # the pool's own k/v form). kv_wire ships k/v pages (export,
         # adoption, session migration): a module whose cache leaves are
         # of another form cannot use it
-        cache_leaves = getattr(self._llama, "cache_leaves", None)
-        self._leaf_specs: Optional[Dict[str, Any]] = (
-            cache_leaves(cfg) if cache_leaves else None)
         # a module whose layers keep caches of different kinds answers by
         # kind (page_pool.cache_kinds): the pool keeps pages and a slot a
         # page table a kind, and a window kind's pages go back to the pool
@@ -680,6 +702,10 @@ class GenerationEngine:
                 f"{sorted(self._leaf_specs)} are served from the page "
                 f"pool (paged_kv=True); the dense cache has one kind")
         self._kv_wire_refusal: Optional[str] = (
+            f"model_module {named}: per-slot cache kind {per_slot} has no "
+            f"snapshot yet, and kv_wire (prefill export, adoption, session "
+            f"migration) ships pages alone"
+            if per_slot else
             f"model_module {named}: its cache has a page table a layer "
             f"kind {sorted(self._leaf_specs)}, kv_wire (prefill export, "
             f"adoption, session migration) ships one table's k/v pages"
@@ -866,15 +892,22 @@ class GenerationEngine:
                         f"model_module {named}: kv_pages is a number of "
                         f"pages a cache kind, {sorted(pages)}")
                 elif kv_pool_bytes is not None:
+                    # the per-slot kinds' state is not to scale: the
+                    # pages get the budget less it
+                    state = max_slots * sum(
+                        PagePool.slot_bytes_of(k)
+                        for k in slot_kinds(self._leaf_specs))
                     full = sum(pages[k.name] * PagePool._kind_page_bytes(
                         k, self.kv_page) for k in kinds)
-                    share = min(1.0, int(kv_pool_bytes) / full)
+                    share = min(1.0, max(0, int(kv_pool_bytes) - state)
+                                / full)
                     pages = {name: max(1, int(n * share))
                              for name, n in pages.items()}
                 self._pool = PagePool(cfg, page=self.kv_page,
                                       num_pages=pages, mesh=mesh,
                                       metrics=metrics,
-                                      leaf_specs=self._leaf_specs)
+                                      leaf_specs=self._leaf_specs,
+                                      slots=max_slots)
             elif kv_pages is not None:
                 self._pool = PagePool(cfg, page=self.kv_page,
                                       num_pages=int(kv_pages), mesh=mesh,
@@ -1659,8 +1692,19 @@ class GenerationEngine:
                             *small[name].shape[3:]),
                         mode="drop") for name in leaves}
 
+                held = pool
                 pool = self._pool.map_kinds(scatter, pool, small,
                                             flat_ids)
+                # a per-slot kind: the group's states whole into the
+                # claimed slots' rows (a padding row's slot is
+                # max_slots: dropped), so a slot claimed again holds
+                # nothing of its last tenant
+                for name in self._pool.slot_kinds:
+                    pool[name] = {
+                        leaf: rows.at[:, slots].set(
+                            small[name][leaf].astype(rows.dtype),
+                            mode="drop")
+                        for leaf, rows in held[name].items()}
                 cache_len = cache_len.at[slots].set(plen + lengths,
                                                     mode="drop")
                 last_token = last_token.at[slots].set(first, mode="drop")
@@ -2992,8 +3036,9 @@ class GenerationEngine:
             """Fresh slot state and a zeroed cache of the engine's shapes:
             what the donating executables consume in the engine's stead."""
             return SimpleNamespace(
-                _kv={name: jnp.zeros(leaf.shape, leaf.dtype)
-                     for name, leaf in self._kv.items()},
+                _kv=self._jax.tree.map(
+                    lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+                    self._kv),
                 cache_len=jnp.zeros((self.max_slots,), jnp.int32),
                 last_token=jnp.zeros((self.max_slots,), jnp.int32),
                 temps=jnp.zeros((self.max_slots,), jnp.float32),
@@ -4144,6 +4189,8 @@ class GenerationEngine:
                       submitted_at, flight, page_ids,
                       nodes, cls, grammar) in enumerate(group):
                 slot_idx = self._free.pop()
+                if self.paged:
+                    self._pool.claim_slot(slot_idx)
                 slot = self._slots[slot_idx]
                 slot.future = future
                 slot.submitted_at = submitted_at
@@ -5087,6 +5134,7 @@ class GenerationEngine:
         never gather a stale page."""
         if not self.paged:
             return
+        self._pool.release_slot(slot_idx)
         if slot.nodes:
             if self._prefix is not None:
                 self._prefix.release(slot.nodes)

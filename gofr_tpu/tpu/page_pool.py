@@ -25,6 +25,18 @@ table a kind, because a window kind lets go of the pages that fall
 behind its window (``release_behind``) while a global kind keeps the
 whole context. One kind is the case above: same leaves, same calls.
 
+A kind may be **per slot** instead (``"per_slot": True`` in its answer: a
+state-space layer's recurrent state): what a *slot* holds in a layer
+whatever its context, ``leaves`` name -> (shape, dtype). The pool keeps
+it as ``(layers, slots, *shape)`` arrays in ``leaves[kind]`` beside the
+paged kinds', counted in ``pool_bytes``, with no pages, no free list, no
+refcounts and no table (``slot_kinds``, apart from ``kinds``: every loop
+over ``kinds`` is a loop over pages). Row ``i`` is slot ``i``'s; its
+owner's insert writes the whole row when the slot is claimed and its
+decode tick rewrites it, so nothing of a last tenant outlives a claim.
+The pool only counts which rows are claimed (``claim_slot`` /
+``release_slot``).
+
 Host-side state is a free list plus a per-page refcount, a kind:
 
 - ``alloc`` hands out pages at refcount 1 (the allocating owner — an
@@ -54,7 +66,8 @@ import dataclasses
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
-__all__ = ["PagePool", "HBMBudget", "CacheKind", "cache_kinds"]
+__all__ = ["PagePool", "HBMBudget", "CacheKind", "SlotKind", "cache_kinds",
+           "slot_kinds"]
 
 # the one kind of a pool whose module answers ``cache_leaves`` flat
 ONE_KIND = "kv"
@@ -96,18 +109,43 @@ class CacheKind:
         return self.num_pages - len(self.free)
 
 
+@dataclasses.dataclass
+class SlotKind:
+    """One per-slot kind of layer cache: how many layers keep it and
+    what a slot holds in one of them (name -> (shape, dtype)). ``claimed``
+    is the set of rows whose slot is claimed (``PagePool`` fills it)."""
+    name: str
+    layers: int
+    leaves: Dict[str, tuple]
+    slots: int = 0
+    slot_bytes: int = 0
+    claimed: set = dataclasses.field(default_factory=set)
+    claimed_peak: int = 0
+    resets: int = 0            # times the arrays were built anew
+
+
 def cache_kinds(cfg, leaf_specs: Optional[Dict[str, Any]] = None
                 ) -> List[CacheKind]:
-    """A module's ``cache_leaves(cfg)`` answer as a list of kinds. A
-    flat answer (name -> spec tuple; None: the pool's own k/v form) is
-    one kind over all ``cfg.n_layers``; an answer by kind is taken as it
-    is, in its own order."""
+    """A module's ``cache_leaves(cfg)`` answer as a list of its *paged*
+    kinds. A flat answer (name -> spec tuple; None: the pool's own k/v
+    form) is one kind over all ``cfg.n_layers``; an answer by kind is
+    taken as it is, in its own order, the per-slot kinds left to
+    ``slot_kinds``."""
     specs = dict(leaf_specs or kv_leaf_specs(cfg))
     if not by_kind(specs):
         return [CacheKind(ONE_KIND, cfg.n_layers, specs)]
     return [CacheKind(name, int(kind["layers"]), dict(kind["leaves"]),
                       kind.get("window"))
-            for name, kind in specs.items()]
+            for name, kind in specs.items() if not kind.get("per_slot")]
+
+
+def slot_kinds(leaf_specs: Optional[Dict[str, Any]]) -> List[SlotKind]:
+    """The per-slot kinds of a ``cache_leaves`` answer (none in a flat
+    one)."""
+    if not by_kind(leaf_specs):
+        return []
+    return [SlotKind(name, int(kind["layers"]), dict(kind["leaves"]))
+            for name, kind in leaf_specs.items() if kind.get("per_slot")]
 
 
 def by_kind(leaf_specs: Optional[Dict[str, Any]]) -> bool:
@@ -122,13 +160,16 @@ class PagePool:
     kind) or derived from ``budget_bytes`` (HBM cap across every leaf;
     one kind only: a pool of several kinds is told how its owner splits
     the bytes). Every ownership call takes ``kind``; left out it means
-    the pool's first kind, which is the only one of a flat pool."""
+    the pool's first kind, which is the only one of a flat pool.
+    ``slots`` is how many rows a per-slot kind's arrays have (the
+    owner's ``max_slots``)."""
 
     def __init__(self, cfg, page: int = 32,
                  num_pages: Union[None, int, Dict[str, int]] = None,
                  budget_bytes: Optional[int] = None,
                  mesh=None, metrics=None,
-                 leaf_specs: Optional[Dict[str, Any]] = None):
+                 leaf_specs: Optional[Dict[str, Any]] = None,
+                 slots: int = 0):
         import threading
 
         import jax
@@ -163,6 +204,16 @@ class PagePool:
                     f"kv-head; kind {kind.name!r} with leaves "
                     f"{sorted(kind.leaves)} has no sharding rule")
             kind.page_bytes = self._kind_page_bytes(kind, self.page)
+        self.slot_kinds: Dict[str, SlotKind] = {
+            kind.name: kind for kind in slot_kinds(self.leaf_specs)}
+        for kind in self.slot_kinds.values():
+            if mesh is not None or slots <= 0:
+                raise ValueError(
+                    f"PagePool: per-slot kind {kind.name!r} needs its "
+                    f"owner's slot count (slots=) and has no sharding "
+                    f"rule (mesh=None)")
+            kind.slots = int(slots)
+            kind.slot_bytes = self.slot_bytes_of(kind)
         # one page of every kind: what a token position costs the pool
         self.page_bytes = sum(k.page_bytes for k in self.kinds.values())
         if isinstance(num_pages, dict):
@@ -183,6 +234,8 @@ class PagePool:
         self.leaves: Dict[str, Any] = {}
         self._reset_subscribers: List[Callable[[], None]] = []
         self.reset()
+        for kind in self.slot_kinds.values():
+            kind.resets = 0        # the first build is no reset
 
     @property
     def _first(self) -> CacheKind:
@@ -245,6 +298,15 @@ class PagePool:
         return kind.layers * page * per_token
 
     @staticmethod
+    def slot_bytes_of(kind: SlotKind) -> int:
+        """HBM bytes one slot's row occupies across the kind's layers."""
+        import jax.numpy as jnp
+
+        return kind.layers * sum(
+            math.prod(spec[0]) * jnp.dtype(spec[1]).itemsize
+            for spec in kind.leaves.values())
+
+    @staticmethod
     def _page_bytes(cfg, page: int,
                     leaf_specs: Optional[Dict[str, Any]] = None) -> int:
         """HBM bytes one page occupies across every cache leaf (of every
@@ -261,11 +323,18 @@ class PagePool:
                                    spec[2] if len(spec) > 2 else 0, spec[1])
                     for name, spec in kind.leaves.items()}
 
+        def fresh_slots(kind: SlotKind):
+            lead = (kind.layers, kind.slots)
+            return {name: jnp.zeros(lead + tuple(spec[0]), spec[1])
+                    for name, spec in kind.leaves.items()}
+
         def fresh():
             if not self.by_kind:
                 return fresh_kind(self._first)
-            return {name: fresh_kind(kind)
-                    for name, kind in self.kinds.items()}
+            return {name: fresh_slots(self.slot_kinds[name])
+                    if name in self.slot_kinds
+                    else fresh_kind(self.kinds[name])
+                    for name in self.leaf_specs}
 
         if self.mesh is None:
             self.leaves = fresh()
@@ -295,6 +364,9 @@ class PagePool:
                 kind.free = list(range(kind.num_pages))
                 kind.refs = self._np.zeros((kind.num_pages,),
                                            self._np.int32)
+            for kind in self.slot_kinds.values():
+                kind.claimed = set()
+                kind.resets += 1
             self._init_leaves()
             self._set_gauges()
             callbacks = list(self._reset_subscribers)
@@ -370,6 +442,27 @@ class PagePool:
             self.release(page_ids, kind)
             self.kinds[kind].freed_behind += len(page_ids)
 
+    def claim_slot(self, slot: int) -> None:
+        """Count row ``slot`` of every per-slot kind as claimed. The
+        row's contents are its owner's to write (the insert)."""
+        if not self.slot_kinds:
+            return
+        with self.lock:
+            for kind in self.slot_kinds.values():
+                kind.claimed.add(int(slot))
+                kind.claimed_peak = max(kind.claimed_peak,
+                                        len(kind.claimed))
+            self._set_gauges()
+
+    def release_slot(self, slot: int) -> None:
+        """Row ``slot`` is claimed no longer (a no-op if it was not)."""
+        if not self.slot_kinds:
+            return
+        with self.lock:
+            for kind in self.slot_kinds.values():
+                kind.claimed.discard(int(slot))
+            self._set_gauges()
+
     @staticmethod
     def pad_table(table, block: int, sentinel: int):
         """Pad a host page table's width to a multiple of ``block`` with
@@ -412,8 +505,14 @@ class PagePool:
         return self.num_pages - self.free_pages
 
     @property
+    def state_bytes(self) -> int:
+        """Bytes of the per-slot kinds: every slot's row, claimed or not."""
+        return sum(k.slots * k.slot_bytes for k in self.slot_kinds.values())
+
+    @property
     def pool_bytes(self) -> int:
-        return sum(k.num_pages * k.page_bytes for k in self.kinds.values())
+        return self.state_bytes + sum(
+            k.num_pages * k.page_bytes for k in self.kinds.values())
 
     def refs(self, pid: int, kind: Optional[str] = None) -> int:
         return int(self._kind(kind).refs[pid])
@@ -428,10 +527,29 @@ class PagePool:
                                    float(kind.used_pages), **labels)
             self.metrics.set_gauge("app_tpu_kv_pages_capacity",
                                    float(kind.num_pages), **labels)
+        for kind in self.slot_kinds.values():
+            self.metrics.set_gauge("app_tpu_state_slots_claimed",
+                                   float(len(kind.claimed)), kind=kind.name)
+            self.metrics.set_gauge("app_tpu_state_slots_capacity",
+                                   float(kind.slots), kind=kind.name)
 
     def stats(self) -> Dict[str, Any]:
-        """The totals over every kind under the names they always had,
-        and each kind's own under ``kinds.<kind>``."""
+        """The totals over every paged kind under the names they always
+        had (``pool_bytes`` with the per-slot kinds' bytes in it), and
+        each kind's own under ``kinds.<kind>``."""
+        kinds: Dict[str, Any] = {
+            name: {"layers": k.layers, "window": k.window,
+                   "num_pages": k.num_pages, "used_pages": k.used_pages,
+                   "page_bytes": k.page_bytes, "allocs": k.allocs,
+                   "stalls": k.stalls, "freed_behind": k.freed_behind}
+            for name, k in self.kinds.items()}
+        for name, k in self.slot_kinds.items():
+            kinds[name] = {"layers": k.layers, "per_slot": True,
+                           "slots": k.slots, "slot_bytes": k.slot_bytes,
+                           "bytes": k.slots * k.slot_bytes,
+                           "claimed": len(k.claimed),
+                           "claimed_peak": k.claimed_peak,
+                           "resets": k.resets}
         return {
             "page_tokens": self.page,
             "num_pages": self.num_pages,
@@ -445,13 +563,7 @@ class PagePool:
             "allocs": self.allocs,
             "writes": self.writes,
             "stalls": self.stalls,
-            "kinds": {name: {"layers": k.layers, "window": k.window,
-                             "num_pages": k.num_pages,
-                             "used_pages": k.used_pages,
-                             "page_bytes": k.page_bytes,
-                             "allocs": k.allocs, "stalls": k.stalls,
-                             "freed_behind": k.freed_behind}
-                      for name, k in self.kinds.items()},
+            "kinds": kinds,
         }
 
 

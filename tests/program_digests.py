@@ -106,12 +106,19 @@ def config_digests(root: str, name: str) -> Dict[str, str]:
         kwargs = {}
     else:
         # a pool and a table a layer kind, read through the ragged kernel
-        pool = {kind: {leaf: shape(spec["layers"], POOL_PAGES, page, *tail,
-                                   dtype=dtype)
+        # where the configuration expects it; a per-slot kind is rows
+        # of slots and has no table
+        pool = {kind: {leaf: shape(spec["layers"],
+                                   *((slots,) if spec.get("per_slot")
+                                     else (POOL_PAGES, page)),
+                                   *tail, dtype=dtype)
                        for leaf, (tail, dtype) in spec["leaves"].items()}
                 for kind, spec in leaves.items()}
-        table = {kind: shape(slots, columns) for kind in leaves}
-        kwargs = {"ragged": True}
+        table = {kind: shape(slots, columns)
+                 for kind, spec in leaves.items()
+                 if not spec.get("per_slot")}
+        kwargs = {"ragged": config.get("expect_attn_path",
+                                       "ragged") == "ragged"}
     step = jax.jit(lambda p, t, pl, tb, cl, a: module.decode_step_paged(
         p, cfg, t, pl, tb, cl, a, counters=True, **kwargs))
     out[f"{name}.decode_paged"] = _digest(

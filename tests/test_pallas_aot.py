@@ -590,3 +590,88 @@ def test_decode_steps_read_the_hit_experts_where_they_lie_for_v5e(v5e,
     assert not batched, batched[:3]
     one = re.findall(rf"bf16\[1,1,{d},{f}\]\S* dynamic-slice\(", hlo)
     assert one, "no single expert is sliced from the stack"
+
+
+# -- the selective scan and the per-slot state (models/jamba.py) ---------------------
+
+@pytest.mark.parametrize("rows,bucket", [(4, 512), (1, 2048), (16, 128)])
+def test_selective_scan_compiles_for_v5e(v5e, rows, bucket):
+    """The scan kernel at Jamba2-3B's widths (5120 channels x 16 states)
+    at three of ``jamba2-3b.chat``'s prefill shapes: Mosaic takes the
+    128 x 128 transpose of the projections, the static lane slices of a
+    token's columns and the unrolled walk; its name is the custom call's
+    (a trace finds it by that)."""
+    from gofr_tpu.ops.pallas import scan_tileable, selective_scan
+
+    channels, states = 5120, 16
+    assert scan_tileable(bucket, channels, states)
+    f32 = jnp.float32
+    compiled = _compile(
+        functools.partial(selective_scan, interpret=False), v5e,
+        ((rows, bucket, channels), jnp.bfloat16),
+        ((rows, bucket, channels), f32), ((rows, bucket, states), f32),
+        ((rows, bucket, states), f32), ((states, channels), f32),
+        ((channels,), f32), ((rows, states, channels), f32),
+        ((rows,), jnp.int32))
+    kernel, = re.findall(r"^.*custom_call_target=\"tpu_custom_call\".*$",
+                         compiled.as_text(), re.M)
+    assert re.match(r"\s*(ROOT )?%selective_scan[.\d]* = ", kernel), kernel
+    assert f"f32[{rows},{bucket},{channels}]" in kernel
+
+
+def test_state_space_tick_updates_the_state_in_place_for_v5e(v5e):
+    """``jamba2-3b.chat``'s fused decode tick on shapes alone: all 28
+    layers, 128 slots, 12288 pages. The per-slot state is the tick's
+    donated carry: the optimised HLO copies no array of a state leaf's
+    shape and none of one layer's, the outputs alias the state and the
+    pages, the temporaries are a few hundred MB (the gathered K/V views
+    in float32), and a layer's weights reach their product out of the
+    stack (no result has a layer's ``w_in`` shape but a slice fused
+    into its product)."""
+    from gofr_tpu.models import jamba
+
+    cfg = jamba.config("jamba2_3b", max_seq_len=3072)
+    slots, columns, pages, k_steps = 128, 96, 12288, 4
+    params = _on_chip(jax.eval_shape(
+        lambda key: jamba.init(cfg, key), jax.random.key(0)), v5e)
+    leaves = jamba.cache_leaves(cfg)
+    pool = {kind: {name: jax.ShapeDtypeStruct(
+        (spec["layers"],) + ((slots,) if spec.get("per_slot")
+                             else (pages, PAGE)) + tuple(shape), dtype)
+        for name, (shape, dtype) in spec["leaves"].items()}
+        for kind, spec in leaves.items()}
+    rest = _on_chip((
+        jax.ShapeDtypeStruct((slots,), jnp.int32), pool,
+        {"attn": jax.ShapeDtypeStruct((slots, columns), jnp.int32)},
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)), v5e)
+
+    def tick(params, token, pool, table, cache_len, active):
+        def one(carry, _):
+            token, pool, cache_len = carry
+            logits, pool, new_len, counts = jamba.decode_step_paged(
+                params, cfg, token, pool, table, cache_len, active,
+                counters=True)
+            token = jnp.where(active, logits.argmax(-1).astype(token.dtype),
+                              token)
+            return (token, pool, jnp.where(active, new_len, cache_len)), \
+                (token, counts)
+
+        (_, pool, cache_len), (tokens, counts) = jax.lax.scan(
+            one, (token, pool, cache_len), None, length=k_steps)
+        return tokens, pool, cache_len, counts.sum(0)
+
+    compiled = jax.jit(tick, donate_argnums=(2, 4)).lower(
+        params, *rest).compile()
+    hlo = compiled.as_text()
+    state = r"(?:26,)?128,(?:16,5120|15360)\]"
+    copies = re.findall(rf"^.*= \w+\[{state}\S* copy\(.*$", hlo, re.M)
+    assert not copies, copies[:3]
+    memory = compiled.memory_analysis()
+    state_bytes = 26 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < 512 << 20
+    # a layer's w_in is read by its product, not copied out of the stack
+    sliced = re.findall(r"^\s*(?:ROOT )?%\S+ = bf16\[2560,10240\]\S* "
+                        r"(\S+?)\(", hlo, re.M)
+    assert set(sliced) <= {"fusion", "bitcast", "parameter"}, sliced
