@@ -60,8 +60,13 @@ four and unrolls that).
 Prefill's scan is the Pallas kernel where Mosaic tiles the bucket
 (``select.scan_tileable``), else ``ops.ssm.selective_scan_chunked``;
 prefill's attention the flash kernel where it tiles, else
-``ops.banded_attention``. Entry points: ``init``, ``init_cache``,
-``prefill``, ``decode_step_paged``, ``cache_leaves``, ``STEP_COUNTERS``.
+``ops.banded_attention``. A decode step's state-space layer runs
+``dt_proj`` and its softplus, the recurrence on the layer's rows of the
+whole ``h`` stack (in place) and the gate in the Pallas step kernel
+where Mosaic tiles the slots (``select.step_tileable``), else
+``ops.ssm.selective_step`` and the gate in XLA. Entry points: ``init``,
+``init_cache``, ``prefill``, ``decode_step_paged``, ``cache_leaves``,
+``STEP_COUNTERS``.
 """
 
 from __future__ import annotations
@@ -82,10 +87,12 @@ from gofr_tpu.ops.ssm import selective_scan_chunked, selective_step
 # rows the step read (every slot's: the state is one array a layer), a
 # state-space layer; the cached K/V rows of the active slots and the
 # rows the step read (the gathered view's, or the live rows through the
-# ragged kernel), an attention layer; and the layers. Summed by the
-# engine over a tick's steps
+# ragged kernel), an attention layer; the layers; and the state-space
+# layer steps that ran in the Pallas step kernel. Summed by the engine
+# over a tick's steps
 STEP_COUNTERS = ("ssm.rows_live", "ssm.rows_read", "ssm.layer_steps",
-                 "attn.rows_live", "attn.rows_read", "attn.calls")
+                 "attn.rows_live", "attn.rows_read", "attn.calls",
+                 "ssm.kernel_steps")
 _N_COUNTERS = len(STEP_COUNTERS)
 
 
@@ -147,6 +154,13 @@ class JambaConfig:
         (where Mosaic tiles it), or in the chunked XLA form?"""
         from gofr_tpu.ops.pallas import scan_tileable
         return scan_tileable(seq_len, self.d_inner, self.d_state)
+
+    def steps_in_kernel(self, rows: int) -> bool:
+        """Does a decode step of ``rows`` slots run its state-space
+        layers in the step kernel (where Mosaic tiles it), or in the
+        XLA form?"""
+        from gofr_tpu.ops.pallas import step_tileable
+        return step_tileable(self.d_inner, self.d_state, rows)
 
 
 PRESETS: Dict[str, JambaConfig] = {
@@ -279,9 +293,10 @@ def _qkv(cfg: JambaConfig, layer, u):
     return q, k, v
 
 
-def _steps(cfg: JambaConfig, layer, xc):
-    """The convolved input ``xc`` (..., C) to what the recurrence takes:
-    (D_t (..., C), B_t (..., N), C_t (..., N)), all float32."""
+def _low_steps(cfg: JambaConfig, layer, xc):
+    """The convolved input ``xc`` (..., C) through ``x_proj`` and its
+    inner norms: (the low-rank step (..., R) in xc's type, B_t (..., N),
+    C_t (..., N) float32)."""
     f32 = jnp.float32
     n, r = cfg.d_state, cfg.dt_rank
     low = jnp.matmul(xc, layer["w_x"], preferred_element_type=f32)
@@ -290,8 +305,14 @@ def _steps(cfg: JambaConfig, layer, xc):
                   cfg.norm_eps)
     cm = rms_norm(low[..., r + n:], layer["c_norm"].astype(f32),
                   cfg.norm_eps)
-    dt = jnp.matmul(dt.astype(xc.dtype), layer["w_dt"],
-                    preferred_element_type=f32)
+    return dt.astype(xc.dtype), bm, cm
+
+
+def _steps(cfg: JambaConfig, layer, xc):
+    """The convolved input ``xc`` (..., C) to what the recurrence takes:
+    (D_t (..., C), B_t (..., N), C_t (..., N)), all float32."""
+    dt, bm, cm = _low_steps(cfg, layer, xc)
+    dt = jnp.matmul(dt, layer["w_dt"], preferred_element_type=jnp.float32)
     return jax.nn.softplus(dt + layer["b_dt"]), bm, cm
 
 
@@ -341,9 +362,14 @@ def prefill_scan(cfg: JambaConfig, xc, dt, bm, cm, a, d, lengths=None):
         return selective_scan_chunked(xc, dt, bm, cm, a, d, None, lengths)
 
 
-def _ssm_step(cfg: JambaConfig, layer, u, h, conv):
-    """One token a row: u (B, D), h (B, N, C), conv (B, 3 C). Returns
-    (out (B, D), the new h, the new conv)."""
+def _ssm_step(cfg: JambaConfig, layer, u, h, conv, idx, active):
+    """One token a row: u (B, D); h (layers, B, N, C), the whole stack,
+    and ``idx`` the layer's index in it; conv (B, 3 C); active (B,).
+    Returns (out (B, D), the stack with the layer's active rows stepped
+    and every other row bit for bit, the new conv). The step kernel
+    where Mosaic tiles the slots (``steps_in_kernel``), else the XLA
+    form (``ops.ssm.selective_step`` and the gate); decided by shape
+    alone, so a CPU run and a chip run take one path."""
     f32 = jnp.float32
     c, taps = cfg.d_inner, cfg.d_conv
     xz = u @ layer["w_in"]
@@ -354,11 +380,22 @@ def _ssm_step(cfg: JambaConfig, layer, u, h, conv):
             layer["conv_w"][j].astype(f32) * held[j].astype(f32)
             for j in range(taps))).astype(x.dtype)
         conv = jnp.concatenate(held[1:], axis=-1)
+    a = -jnp.exp(layer["a_log"])
+    if cfg.steps_in_kernel(u.shape[0]):
+        from gofr_tpu.ops.pallas import selective_step as step_kernel
+        low, bm, cm = _low_steps(cfg, layer, xc)
+        with jax.named_scope("ssm.step"):
+            gated, h = step_kernel(xc, low, bm, cm, z, layer["w_dt"],
+                                   layer["b_dt"], a, layer["d"], h, idx,
+                                   active)
+        return gated @ layer["w_out"], h, conv
     dt, bm, cm = _steps(cfg, layer, xc)
+    held_h = lax.dynamic_index_in_dim(h, idx, 0, keepdims=False)
     with jax.named_scope("ssm.step"):
-        y, h = selective_step(xc, dt, bm, cm, -jnp.exp(layer["a_log"]),
-                              layer["d"], h)
-    return _gate_out(layer, y, z), h, conv
+        y, new = selective_step(xc, dt, bm, cm, a, layer["d"], held_h)
+    new = jnp.where(active[:, None, None], new, held_h)
+    return (_gate_out(layer, y, z),
+            lax.dynamic_update_index_in_dim(h, new, idx, 0), conv)
 
 
 def _head(params, cfg: JambaConfig, x):
@@ -488,9 +525,12 @@ def decode_step_paged(params: Dict[str, Any], cfg: JambaConfig,
     live_rows = jnp.where(active, cache_len, 0).sum().astype(jnp.int32)
     read = live_rows if ragged else jnp.int32(batch * table.shape[1] * page)
     zero, one = jnp.int32(0), jnp.int32(1)
+    in_kernel = jnp.int32(cfg.steps_in_kernel(batch))
     counted = {"ssm": jnp.stack([active.sum().astype(jnp.int32),
-                                 jnp.int32(batch), one, zero, zero, zero]),
-               "attn": jnp.stack([zero, zero, zero, live_rows, read, one])}
+                                 jnp.int32(batch), one, zero, zero, zero,
+                                 in_kernel]),
+               "attn": jnp.stack([zero, zero, zero, live_rows, read, one,
+                                  zero])}
 
     def attn_layer(carry, layer, idx):
         x, pool, counts = carry
@@ -518,16 +558,15 @@ def decode_step_paged(params: Dict[str, Any], cfg: JambaConfig,
 
     def ssm_layer(carry, layer, idx):
         x, pool, counts = carry
-        held = {name: lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False)
-                for name, leaf in pool["ssm"].items()}
+        stacks = pool["ssm"]
+        held = lax.dynamic_index_in_dim(stacks["conv"], idx, 0,
+                                        keepdims=False)
         out, h, conv = _ssm_step(cfg, layer,
                                  _norm(cfg, x, layer["norm1"])[:, 0],
-                                 held["h"], held["conv"])
-        new = {"h": jnp.where(active[:, None, None], h, held["h"]),
-               "conv": jnp.where(active[:, None], conv, held["conv"])}
-        leaves = {name: lax.dynamic_update_index_in_dim(
-            leaf, new[name].astype(leaf.dtype), idx, 0)
-            for name, leaf in pool["ssm"].items()}
+                                 stacks["h"], held, idx, active)
+        conv = jnp.where(active[:, None], conv, held)
+        leaves = {"h": h, "conv": lax.dynamic_update_index_in_dim(
+            stacks["conv"], conv.astype(stacks["conv"].dtype), idx, 0)}
         x = _residual(cfg, layer, x, out[:, None, :])
         return (x, dict(pool, ssm=leaves), counts + counted["ssm"]), None
 

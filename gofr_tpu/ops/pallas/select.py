@@ -25,7 +25,7 @@ import functools
 from typing import Callable, Optional
 
 __all__ = ["flash_tileable", "ragged_tileable", "scan_tileable",
-           "lower_for_target"]
+           "step_tileable", "lower_for_target"]
 
 
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
@@ -61,6 +61,15 @@ def scan_tileable(seq_len: int, channels: int, n_state: int) -> bool:
     tests/test_pallas_aot.py)."""
     return (seq_len % 128 == 0 and channels % 512 == 0
             and n_state % 8 == 0 and 2 * n_state <= 128)
+
+
+def step_tileable(channels: int, n_state: int, rows: int) -> bool:
+    """Selective-step (state-space decode) tiling predicate: whole
+    1024-channel tiles, whole 16-row blocks of slots (a bfloat16 block
+    of rows fills its sublanes) and states that fill whole sublanes
+    (AOT-compiled for v5e at 128 slots x 5120 channels x 16 states by
+    tests/test_pallas_aot.py)."""
+    return channels % 1024 == 0 and rows % 16 == 0 and n_state % 8 == 0
 
 
 def lower_for_target(kernel: Callable, interpret: Optional[bool],

@@ -19,7 +19,12 @@ Three forms of the same walk, all with the signature of
   (chunk, N, C) float32 a row: what the kernel exists to avoid). For
   tests, and the served prefill's path where Mosaic cannot tile the
   shape (``select.scan_tileable``).
-- ``selective_step``: one token, the decode form, on a state a row.
+- ``selective_step``: one token, the decode form, on a state a row. The
+  oracle of ``ops.pallas.selective_step``, the served decode step's
+  kernel (``dt_proj`` and its softplus, this step on the layer's rows of
+  the whole state stack in place, and the gate, in one Pallas call), and
+  its XLA fallback where Mosaic cannot tile the shape
+  (``select.step_tileable``).
 
 **States lie states-major**, ``(B, N, C)``: on the chip an array's last
 two axes are tiled (8, 128), and 16 states last would be padded to 128,

@@ -252,7 +252,7 @@ def test_prefill_insert_and_fused_paged_steps_equal_the_reference(model):
             live = int(cache_len[0] + cache_len[2])
             # 6 state-space layers of 2 live rows of 4; 2 attention
             # layers, the gathered view 4 rows x 16 pages x 8
-            assert counts.tolist() == [12, 24, 6, 2 * live, 2 * 512, 2]
+            assert counts.tolist() == [12, 24, 6, 2 * live, 2 * 512, 2, 0]
             cache_len = jnp.where(active, new_len, cache_len)
 
 
@@ -275,6 +275,79 @@ def test_an_inactive_rows_state_is_bit_identical_after_a_tick(model):
             assert (got[:, row] == before[name][:, row]).all(), (name, row)
         assert (got[:, 1] != before[name][:, 1]).any(), name
     assert engine.cache_len.tolist() == [5, 11, 0, 3]
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The toy preset at 1024 state channels, which the decode step's
+    kernel tiles at 16 slots (the toy's 128 do not)."""
+    cfg = jamba.config("tiny", dim=512, dtype=jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        params = jamba.init(cfg, jax.random.PRNGKey(3))
+    return cfg, params
+
+
+def paged_steps(model, slots, steps=3):
+    """``steps`` paged decode steps over a pool of random contents, every
+    fifth slot from the third not active. Returns (the pool before, the
+    pool after, each step's logits and counters)."""
+    cfg, params = model
+    columns = 4
+    pool = PagePool(cfg, page=PAGE, num_pages={"attn": slots * columns},
+                    leaf_specs=jamba.cache_leaves(cfg), slots=slots)
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+    leaves = jax.tree.map(lambda leaf: jax.random.normal(
+        next(keys), leaf.shape).astype(leaf.dtype), pool.leaves)
+    before = jax.tree.map(np.asarray, leaves)
+    table = jnp.arange(slots * columns, dtype=jnp.int32).reshape(slots, -1)
+    cache_len = jax.random.randint(next(keys), (slots,), 1,
+                                   columns * PAGE - steps)
+    active = jnp.arange(slots) % 5 != 2
+    step = jax.jit(lambda token, leaves, cache_len: jamba.decode_step_paged(
+        params, cfg, token, leaves, {"attn": table}, cache_len, active,
+        counters=True))
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for t in range(steps):
+            token = jnp.asarray(tokens_of(20 + t, slots))
+            logits, leaves, new_len, counts = step(token, leaves, cache_len)
+            out.append((np.asarray(logits), counts.tolist()))
+            cache_len = jnp.where(active, new_len, cache_len)
+    return before, jax.tree.map(np.asarray, leaves), out, np.asarray(active)
+
+
+def test_the_decode_steps_kernel_equals_its_xla_form(wide, monkeypatch):
+    """Three paged decode steps of 16 slots through the step kernel
+    (interpreted) and through the XLA form give the same logits, state
+    and conv window; an inactive slot's state comes back bit for bit on
+    both; the counters say which ran."""
+    cfg = wide[0]
+    assert cfg.steps_in_kernel(16) and not cfg.steps_in_kernel(8)
+    assert not jamba.config("tiny").steps_in_kernel(16)
+    assert jamba.config("jamba2_3b").steps_in_kernel(128)
+    before, kernel, kernel_steps, active = paged_steps(wide, 16)
+    from gofr_tpu.ops import pallas
+    monkeypatch.setattr(pallas, "step_tileable", lambda *shape: False)
+    assert not cfg.steps_in_kernel(16)
+    _, xla, xla_steps, _ = paged_steps(wide, 16)
+    monkeypatch.undo()
+    layer_steps = cfg.layer_types.count("ssm")
+    for (got, counts), (want, xla_counts) in zip(kernel_steps, xla_steps):
+        assert reference.rel_l2(got, want) < TOL
+        assert counts[:6] == xla_counts[:6]
+        assert counts[2] == counts[6] == layer_steps
+        assert xla_counts[6] == 0
+    # the inputs of later steps and layers carry float32 sums in
+    # another order
+    for name in ("h", "conv"):
+        np.testing.assert_allclose(kernel["ssm"][name], xla["ssm"][name],
+                                   atol=1e-5, rtol=1e-5)
+    for after in (kernel, xla):
+        for name in ("h", "conv"):
+            assert (after["ssm"][name][:, ~active]
+                    == before["ssm"][name][:, ~active]).all(), name
+            assert (after["ssm"][name][:, active]
+                    != before["ssm"][name][:, active]).any(), name
 
 
 # -- (d) the pool's per-slot kind ---------------------------------------------------------

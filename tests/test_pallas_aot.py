@@ -619,6 +619,34 @@ def test_selective_scan_compiles_for_v5e(v5e, rows, bucket):
     assert f"f32[{rows},{bucket},{channels}]" in kernel
 
 
+def test_selective_step_compiles_for_v5e(v5e):
+    """The decode step's kernel at ``jamba2-3b.chat``'s call shape (128
+    slots, 5120 channels, 16 states, ``dt_rank`` 160 as it is, a stack of
+    26 layers): Mosaic takes the (16, 160) x (160, 1024) product, the
+    rows' walk and the lane slices of ``b`` and ``c``; the custom call is
+    named ``selective_step`` and returns ``y`` and the whole stack, which
+    it aliases (a trace finds it by that name)."""
+    from gofr_tpu.ops.pallas import selective_step, step_tileable
+
+    rows, channels, states, rank, layers = 128, 5120, 16, 160, 26
+    assert step_tileable(channels, states, rows)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = _compile(
+        functools.partial(selective_step, interpret=False), v5e,
+        ((rows, channels), bf16), ((rows, rank), bf16),
+        ((rows, states), f32), ((rows, states), f32),
+        ((rows, channels), bf16), ((rank, channels), bf16),
+        ((channels,), f32), ((states, channels), f32), ((channels,), f32),
+        ((layers, rows, states, channels), f32), ((), jnp.int32),
+        ((rows,), jnp.bool_))
+    kernel, = re.findall(r"^.*custom_call_target=\"tpu_custom_call\".*$",
+                         compiled.as_text(), re.M)
+    assert re.match(r"\s*(ROOT )?%selective_step[.\d]* = \(bf16\[128,5120\]"
+                    r"\S*, f32\[26,128,16,5120\]\S*\) custom-call\(",
+                    kernel), kernel
+    assert "output_to_operand_aliasing" in kernel
+
+
 def test_state_space_tick_updates_the_state_in_place_for_v5e(v5e):
     """``jamba2-3b.chat``'s fused decode tick on shapes alone: all 28
     layers, 128 slots, 12288 pages. The per-slot state is the tick's
@@ -627,7 +655,10 @@ def test_state_space_tick_updates_the_state_in_place_for_v5e(v5e):
     pages, the temporaries are a few hundred MB (the gathered K/V views
     in float32), and a layer's weights reach their product out of the
     stack (no result has a layer's ``w_in`` shape but a slice fused
-    into its product)."""
+    into its product). The state-space layers step ``h`` in the
+    selective-step kernel, once a span of them, on the whole stack: no
+    XLA update of the stack's shape is left (PR 37's
+    ``select_dynamic-update-slice_fusion``)."""
     from gofr_tpu.models import jamba
 
     cfg = jamba.config("jamba2_3b", max_seq_len=3072)
@@ -675,3 +706,10 @@ def test_state_space_tick_updates_the_state_in_place_for_v5e(v5e):
     sliced = re.findall(r"^\s*(?:ROOT )?%\S+ = bf16\[2560,10240\]\S* "
                         r"(\S+?)\(", hlo, re.M)
     assert set(sliced) <= {"fusion", "bitcast", "parameter"}, sliced
+    kernels = re.findall(r"^\s*(?:ROOT )?%selective_step[.\d]* = \(bf16"
+                         r"\[128,5120\]\S*, f32\[26,128,16,5120\]\S*\) "
+                         r"custom-call\(", hlo, re.M)
+    assert len(kernels) == 2, kernels         # one a span's layer body
+    updates = re.findall(r"^.*= f32\[26,128,16,5120\]\S* fusion\(.*$",
+                         hlo, re.M)
+    assert not updates, updates[:2]
