@@ -217,6 +217,23 @@ def gather_kv_pages(pages: jnp.ndarray,
     return gathered.reshape(b, p * pages.shape[1], *pages.shape[2:])
 
 
+def index_scores(q, w, k_plane, page_table, lengths) -> jnp.ndarray:
+    """The sparse-attention indexer's scores over a slot's paged keys,
+    gathered: ``I[b, s] = sum_j w[b, j] ReLU(q[b, j] . k_s)`` for ``s <
+    lengths[b]``, ``-inf`` elsewhere. q (B, heads, D); w (B, heads)
+    float32; k_plane (num_pages, page, D), one layer of the pool's
+    ``ik`` leaf; page_table (B, P). Returns (B, P * page) float32. The
+    oracle of ``ops.pallas.index_scores``, which reads the pages in
+    place; products in the keys' dtype with float32 accumulation, the
+    ReLU and the weighted sum in float32, as the kernel."""
+    keys = gather_kv_pages(k_plane, page_table)             # (B, T, D)
+    s = jnp.einsum("bhd,btd->bht", q, keys,
+                   preferred_element_type=jnp.float32)
+    score = (jnp.maximum(s, 0.0) * w[..., None]).sum(axis=1)
+    live = jnp.arange(keys.shape[1])[None, :] < lengths[:, None]
+    return jnp.where(live, score, -jnp.inf)
+
+
 def check_sentinel_masked(page_table, cache_len, page: int, sentinel: int,
                           new_tokens: int = 1) -> None:
     """Enforce the sentinel-safety contract :func:`gather_kv_pages` can

@@ -25,7 +25,7 @@ import functools
 from typing import Callable, Optional
 
 __all__ = ["flash_tileable", "ragged_tileable", "scan_tileable",
-           "step_tileable", "lower_for_target"]
+           "step_tileable", "index_tileable", "lower_for_target"]
 
 
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
@@ -70,6 +70,20 @@ def step_tileable(channels: int, n_state: int, rows: int) -> bool:
     (AOT-compiled for v5e at 128 slots x 5120 channels x 16 states by
     tests/test_pallas_aot.py)."""
     return channels % 1024 == 0 and rows % 16 == 0 and n_state % 8 == 0
+
+
+def index_tileable(head_dim: int, heads: int, page: int) -> bool:
+    """Index-scores (sparse attention's indexer, decode) tiling
+    predicate: a 128-lane key, heads that fill whole sublanes, a page
+    deep enough to tile a bfloat16 block of keys and no longer than a
+    block (the ring holds blocks of pages in VMEM), and blocks that are
+    whole lane tiles of the scores' row (AOT-compiled for v5e at 64
+    heads of 128, page 32, by tests/test_pallas_aot.py)."""
+    from gofr_tpu.ops.pallas.index_scores import _BLOCK_TOKENS, index_walk
+
+    return (head_dim % 128 == 0 and heads % 8 == 0 and page % 16 == 0
+            and page <= _BLOCK_TOKENS
+            and index_walk(page) * page % 128 == 0)
 
 
 def lower_for_target(kernel: Callable, interpret: Optional[bool],

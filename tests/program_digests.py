@@ -117,8 +117,9 @@ def config_digests(root: str, name: str) -> Dict[str, str]:
         table = {kind: shape(slots, columns)
                  for kind, spec in leaves.items()
                  if not spec.get("per_slot")}
-        kwargs = {"ragged": config.get("expect_attn_path",
-                                       "ragged") == "ragged"}
+        kwargs = ({"ragged": True}
+                  if config.get("expect_attn_path", "ragged") == "ragged"
+                  else {})
     step = jax.jit(lambda p, t, pl, tb, cl, a: module.decode_step_paged(
         p, cfg, t, pl, tb, cl, a, counters=True, **kwargs))
     out[f"{name}.decode_paged"] = _digest(

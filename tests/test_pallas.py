@@ -83,10 +83,40 @@ def test_flash_in_bfloat16_runs_its_products_in_bfloat16():
         jnp.abs(ref).max())
 
 
+def _selected(seq, keep, batch=2, seed=1):
+    """A per-row selection within the causal band: each row keeps about
+    ``keep`` of its keys at random, and always one (its first)."""
+    pick = jax.random.uniform(jax.random.PRNGKey(seed),
+                              (batch, seq, seq)) < keep
+    causal = jnp.tril(jnp.ones((seq, seq), bool))
+    return (pick | (jnp.arange(seq) == 0)) & causal
+
+
+@pytest.mark.parametrize("keep", [0.02, 0.3, 1.0],
+                         ids=["sparse", "some", "all"])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 256)])
+def test_flash_with_a_selection_equals_the_masked_oracle(keep, block_q,
+                                                         block_k):
+    """Query t attends only the keys its row of ``mask`` selects: K
+    blocks after the diagonal are still skipped, every other block is
+    masked, and a block in which a row selects nothing leaves that row's
+    statistics as they were. GQA 4:1; a selection of every key is the
+    causal kernel's."""
+    q, k, v = _qkv(512, q_heads=8, kv_heads=2)
+    mask = _selected(512, keep)
+    ref = attention(q, k, v, mask=mask[:, None, None])
+    out = flash_attention(q, k, v, interpret=True, block_q=block_q,
+                          block_k=block_k, mask=mask)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
 def test_flash_window_needs_causal():
     q, k, v = _qkv(128)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, causal=False, window=64)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, mask=_selected(128, 0.5))
 
 
 def test_flash_small_shapes_run_the_kernel():
