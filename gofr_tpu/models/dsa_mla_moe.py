@@ -41,11 +41,19 @@ sqrt(mean(x^2) + eps) * g``, ``LN`` a LayerNorm with gain and bias):
 row and key, scores the slot's live keys where they lie through the
 Pallas ``index_scores`` kernel (its gathered oracle,
 ``ops.attention.index_scores``, where ``select.index_tileable`` refuses
-the shape), takes the exact ``lax.top_k`` of ``min(index_topk, table
-positions)``, maps the positions through the slot's table to (page,
-offset), gathers the selected latent rows and runs the absorbed
-attention over them (``mla_moe.absorbed_attention``). A sliding layer
-gathers its window's pages alone (the columns from the window's lower
+the shape), and selects the exact top ``min(index_topk, table
+positions)`` as a mask (``_top_mask``, the prefill's threshold below).
+The Pallas ``sparse_latent_attention`` kernel then walks the slot's live
+latent pages where they lie and attends the masked rows absorbed (the
+query with ``W_UK`` in it, an online softmax a head, ``W_UV`` applied
+to the weighted latent after): no row is gathered and no position is
+looked up in the table. Where ``select.sparse_latent_tileable`` refuses
+the shape (the tiny preset's four heads, a dense cache's one page of its
+whole length) the step takes ``lax.top_k``'s positions, maps them
+through the slot's table to (page, offset), gathers the selected latent
+rows and runs ``mla_moe.absorbed_attention`` over them: the kernel's
+oracle. Both attend the same set. A sliding layer gathers its window's
+pages alone (the columns from the window's lower
 bound; the engine has given the pages behind it back to the pool). A
 prefill selects per query row, in blocks of ``_QUERY_BLOCK`` rows, with
 an exact threshold: the ``index_topk``-th largest score of each row
@@ -96,11 +104,13 @@ KIND_OF = {"full_attention": "full", "sliding_attention": "window",
 
 # what a decode step counts: the expert layer's five, then a full layer's
 # live rows (the keys its indexer scores) and the rows it selects, its
-# calls (one a full layer a step), and a sliding layer's rows in its
-# window; rows are summed over the active slots and the kind's layers.
-# Summed by the engine over a tick's steps
+# calls (one a full layer a step), a sliding layer's rows in its window,
+# and the full layers' calls that attended in the sparse latent kernel;
+# rows are summed over the active slots and the kind's layers. Summed by
+# the engine over a tick's steps
 STEP_COUNTERS = experts.STEP_COUNTERS + (
-    "dsa.rows_live", "dsa.rows_selected", "dsa.calls", "attn.window_rows")
+    "dsa.rows_live", "dsa.rows_selected", "dsa.calls", "attn.window_rows",
+    "dsa.kernel_calls")
 _N_MOE = len(experts.STEP_COUNTERS)
 _N_COUNTERS = len(STEP_COUNTERS)
 
@@ -390,6 +400,13 @@ def _gate(attn, h, out, view: mla_moe.MlaMoeConfig):
         out.dtype).reshape(b, s, -1)
 
 
+def _positions(chosen, k: int):
+    """A selection mask (B, T) -> its positions (B, k) in order, -1
+    where it holds fewer."""
+    top, spots = lax.top_k(chosen.astype(jnp.int32), k)
+    return jnp.where(top > 0, spots, -1)
+
+
 def _ordered(scores):
     """float32 -> uint32 whose order is the floats' order."""
     bits = lax.bitcast_convert_type(scores, jnp.uint32)
@@ -647,14 +664,20 @@ def decode_step_paged(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
     key). Each layer appends the token's row (and a full layer its index
     key) first and reads it back with the rest: position ``cache_len``
     is live. The keys are scored through the Pallas kernel where
-    ``select.index_tileable`` says Mosaic tiles the shape, else gathered,
-    so a CPU run and a chip run of one configuration take one path.
+    ``select.index_tileable`` says Mosaic tiles the shape, else gathered;
+    the selection is attended where its rows lie, in the sparse latent
+    kernel given the exact top-k as a mask, where
+    ``select.sparse_latent_tileable`` says Mosaic tiles the shape, else
+    its rows are gathered through ``lax.top_k``'s positions: a CPU run
+    and a chip run of one configuration take one path.
     Returns (logits, pool, cache_len + 1),
     and with ``counters`` the step's ``STEP_COUNTERS`` as a fourth, with
     ``routes`` the experts chosen (expert layers, B, top_k), with
     ``selections`` the positions each full layer attended (full layers,
     B, k) int32, -1 where fewer were live."""
-    from gofr_tpu.ops.pallas import index_scores, index_tileable
+    from gofr_tpu.ops.pallas import (index_scores, index_tileable,
+                                     sparse_latent_attention,
+                                     sparse_latent_tileable)
 
     ropes = _ropes(cfg)
     batch = token.shape[0]
@@ -670,6 +693,9 @@ def decode_step_paged(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
                       cache_len % page)
     index_kernel = index_tileable(cfg.index_head_dim, cfg.index_n_heads,
                                   pool["full"]["ik"].shape[2])
+    full = cfg.view("full")
+    latent_kernel = sparse_latent_tileable(full.cache_row, full.n_heads,
+                                           pool["full"]["ckv"].shape[2])
     mine = jnp.where(active, live, 0)
     x = params["tok_emb"][token][:, None, :]                 # (B, 1, D)
     moe = jnp.zeros((_N_MOE,), jnp.int32)
@@ -700,15 +726,31 @@ def decode_step_paged(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
                                              keepdims=False),
                     table, mine)
             k = min(cfg.index_topk, scores.shape[1])
-            top, spots = lax.top_k(scores, k)
-            ok = top > -jnp.inf
-            pages = jnp.take_along_axis(table, spots // page, axis=1)
-            view_rows = leaves["ckv"][at, pages, spots % page]  # (B, k, C)
+            if latent_kernel:
+                # the selection as a mask, attended where the rows lie
+                mask = _top_mask(scores, scores > -jnp.inf, k)
+                u = sparse_latent_attention(
+                    mla_moe.absorbed_query(attn, q_n[:, 0], q_r[:, 0], view),
+                    mask, leaves["ckv"], table, mine, at,
+                    rank=view.kv_lora_rank, scale=view.softmax_scale)
+                out = jnp.einsum("bhc,hcd->bhd",
+                                 u.astype(leaves["ckv"].dtype),
+                                 attn["w_uv"]).reshape(batch, 1, -1)
+                if selections:
+                    picked.append(_positions(mask, k))
+            else:
+                top, spots = lax.top_k(scores, k)
+                ok = top > -jnp.inf
+                pages = jnp.take_along_axis(table, spots // page, axis=1)
+                view_rows = leaves["ckv"][at, pages, spots % page]
+                out = mla_moe.absorbed_attention(
+                    attn, q_n[:, 0], q_r[:, 0], view_rows, ok, view)[:, None]
+                if selections:
+                    picked.append(jnp.where(ok, spots, -1))
             selected = jnp.minimum(mine, k)
             dsa = dsa + jnp.stack([mine.sum(), selected.sum(), jnp.int32(1),
-                                   jnp.int32(0)]).astype(jnp.int32)
-            if selections:
-                picked.append(jnp.where(ok, spots, -1))
+                                   jnp.int32(0), jnp.int32(latent_kernel)]
+                                  ).astype(jnp.int32)
         else:
             window = cfg.sliding_window
             start = jnp.maximum(live - window, 0)
@@ -725,10 +767,10 @@ def decode_step_paged(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
             ok = (spots >= start[:, None]) & (spots < live[:, None])
             rows = jnp.where(active, jnp.minimum(live, window), 0)
             dsa = dsa + jnp.stack([jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                                   rows.sum()]).astype(jnp.int32)
+                                   rows.sum(), jnp.int32(0)]).astype(jnp.int32)
+            out = mla_moe.absorbed_attention(attn, q_n[:, 0], q_r[:, 0],
+                                             view_rows, ok, view)[:, None]
         pool = dict(pool, **{kind: leaves})
-        out = mla_moe.absorbed_attention(attn, q_n[:, 0], q_r[:, 0],
-                                         view_rows, ok, view)[:, None]
         x = x + _gate(attn, h, out, view) @ attn["w_o"]
         h = rms_norm(x, layer["pre_mlp_norm"], cfg.norm_eps)
         y, counted, ids = _ffn(cfg, i, layer, h, active[:, None], False)

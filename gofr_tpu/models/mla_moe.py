@@ -371,6 +371,15 @@ def prefill_attention(attn, q_n, q_r, rows, cfg: MlaMoeConfig):
     return flash_expanded_attention(attn, q_n, q_r, rows, cfg, block)
 
 
+def absorbed_query(attn, q_n, q_r, cfg: MlaMoeConfig):
+    """A query q_n (B,H,dn), q_r (B,H,dr) as a cached row reads it:
+    ``[q_n W_UK; q_r; zeros]`` (B, H, cache_row)."""
+    q_abs = jnp.einsum("bhd,hcd->bhc", q_n, attn["w_uk"])
+    pad = jnp.zeros((*q_r.shape[:2], cfg.cache_row - cfg.cache_dim),
+                    q_r.dtype)
+    return jnp.concatenate([q_abs, q_r, pad], axis=-1)
+
+
 def absorbed_attention(attn, q_n, q_r, view, valid, cfg: MlaMoeConfig):
     """One query a row against cached rows read as they are. q_n
     (B,H,dn), q_r (B,H,dr), view (B,T,cache_row), valid (B,T) bool ->
@@ -378,10 +387,7 @@ def absorbed_attention(attn, q_n, q_r, view, valid, cfg: MlaMoeConfig):
     to the weighted latent, so no per-head key or value of a cached token
     is ever formed."""
     b = view.shape[0]
-    q_abs = jnp.einsum("bhd,hcd->bhc", q_n, attn["w_uk"])
-    pad = jnp.zeros((*q_r.shape[:2], cfg.cache_row - cfg.cache_dim),
-                    q_r.dtype)
-    q_full = jnp.concatenate([q_abs, q_r, pad], axis=-1)   # (B,H,cache_row)
+    q_full = absorbed_query(attn, q_n, q_r, cfg)           # (B,H,cache_row)
     scores = jnp.einsum("bhc,btc->bht", q_full, view,
                         preferred_element_type=jnp.float32)
     scores = scores * cfg.softmax_scale

@@ -25,7 +25,8 @@ import functools
 from typing import Callable, Optional
 
 __all__ = ["flash_tileable", "ragged_tileable", "scan_tileable",
-           "step_tileable", "index_tileable", "lower_for_target"]
+           "step_tileable", "index_tileable", "sparse_latent_tileable",
+           "lower_for_target"]
 
 
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
@@ -84,6 +85,22 @@ def index_tileable(head_dim: int, heads: int, page: int) -> bool:
     return (head_dim % 128 == 0 and heads % 8 == 0 and page % 16 == 0
             and page <= _BLOCK_TOKENS
             and index_walk(page) * page % 128 == 0)
+
+
+def sparse_latent_tileable(cache_row: int, heads: int, page: int) -> bool:
+    """Sparse-latent-attention (a full layer's decode attention over its
+    selection) tiling predicate: a latent row of whole lanes, heads that
+    fill whole sublanes, a page deep enough to tile a bfloat16 block of
+    rows and no longer than a block (the ring holds blocks of pages in
+    VMEM), and blocks that are whole lane tiles of the mask's row
+    (AOT-compiled for v5e at 128 heads over rows of 640, page 32, by
+    tests/test_pallas_aot.py)."""
+    from gofr_tpu.ops.pallas.sparse_latent_attention import (_BLOCK_TOKENS,
+                                                             latent_walk)
+
+    return (cache_row % 128 == 0 and heads % 8 == 0 and page % 16 == 0
+            and page <= _BLOCK_TOKENS
+            and latent_walk(page) * page % 128 == 0)
 
 
 def lower_for_target(kernel: Callable, interpret: Optional[bool],

@@ -19,7 +19,10 @@ attends a selection:
     result;
 (e) the expert layer's shares, the shared expert counted once, add up
     to the uncut layer;
-(f) the index kernel equals its gathered oracle (interpreted);
+(f) the index kernel equals its gathered oracle (interpreted); the
+    sparse latent kernel equals the gathered absorbed attention over the
+    same selection, and a decode step through it equals the step through
+    the gathered rows;
 (g) the engine serves it: the greedy stream is the reference's choice,
     the window kind's pages go back to the pool behind the window, the
     counters reach ``stats()`` and ``app_tpu_step_counter_total``.
@@ -38,10 +41,10 @@ import pytest
 from jax import lax
 
 from gofr_tpu.container import new_mock_container
-from gofr_tpu.models import dsa_mla_moe
+from gofr_tpu.models import dsa_mla_moe, mla_moe
 from gofr_tpu.models.experts import _swiglu, moe_ffn
 from gofr_tpu.ops.attention import index_scores as gathered_index_scores
-from gofr_tpu.ops.pallas import index_scores
+from gofr_tpu.ops.pallas import index_scores, sparse_latent_attention
 from gofr_tpu.tpu.generate import GenerationEngine
 from gofr_tpu.tpu.page_pool import PagePool
 
@@ -169,24 +172,27 @@ def test_a_bucket_that_tiles_attends_its_selection_in_the_flash_kernel():
     np.testing.assert_array_equal(picked[:, 0], want_picked)
 
 
-def _pool_and_table(cfg, small, bucket, columns):
+def _pool_and_table(cfg, small, bucket, columns, page=PAGE, slots=1):
     """Both kinds' prompt rows put into pages of a pool, in another order
-    than the sequence's, through the pool's own tables; slot 1 holds no
-    page."""
-    pool = PagePool(cfg, page=PAGE, num_pages={"full": columns + 2,
-                                               "window": columns + 2},
+    than the sequence's, through the pool's own tables; the first
+    ``slots`` slots hold pages, the last slot none."""
+    pool = PagePool(cfg, page=page,
+                    num_pages={"full": slots * columns + 2,
+                               "window": slots * columns + 2},
                     leaf_specs=dsa_mla_moe.cache_leaves(cfg))
     tables, leaves = {}, dict(pool.leaves)
     for kind in ("full", "window"):
-        ids = pool.alloc(columns, kind=kind)[::-1]
-        table = np.full((2, columns), pool.sentinel_of(kind), np.int32)
-        table[0] = ids
+        table = np.full((slots + 1, columns), pool.sentinel_of(kind),
+                        np.int32)
+        for b in range(slots):
+            ids = pool.alloc(columns, kind=kind)[::-1]
+            table[b] = ids
+            leaves[kind] = {
+                name: leaf.at[:, jnp.asarray(ids[:bucket // page])]
+                .set(small[kind][name][:, b].reshape(
+                    leaf.shape[0], bucket // page, page, *leaf.shape[3:]))
+                for name, leaf in leaves[kind].items()}
         tables[kind] = jnp.asarray(table)
-        leaves[kind] = {name: leaf.at[:, jnp.asarray(ids[:bucket // PAGE])]
-                        .set(small[kind][name][:, 0].reshape(
-                            leaf.shape[0], bucket // PAGE, PAGE,
-                            *leaf.shape[3:]))
-                        for name, leaf in leaves[kind].items()}
     return leaves, tables
 
 
@@ -233,6 +239,7 @@ def test_prefill_then_paged_decode_equals_the_reference(model, length):
             assert got["dsa.calls"] == 3
             assert got["attn.window_rows"] == 3 * min(live,
                                                       cfg.sliding_window)
+            assert got["dsa.kernel_calls"] == 0          # 4 heads: gathered
             assert got["moe.layer_steps"] == 5
             cache_len = jnp.where(active, grown, cache_len)
     assert int(cache_len[0]) == length + steps and int(cache_len[1]) == 1
@@ -378,6 +385,148 @@ def test_index_kernel_equals_its_oracle(dtype):
                                atol=1e-4)
 
 
+# a full layer's latent geometry that the sparse latent kernel tiles:
+# heads in whole sublanes, a row of one lane tile (64 + 16, padded)
+_VIEW = mla_moe.MlaMoeConfig(n_layers=1, n_dense_layers=0, n_heads=8,
+                             kv_lora_rank=64, qk_nope_dim=16, qk_rope_dim=16,
+                             v_head_dim=32, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["below-page", "below-k", "at-k", "past-k",
+                                  "ties", "shuffled", "inactive"])
+def test_sparse_latent_kernel_equals_the_gathered_attention(case):
+    """The kernel interpreted against ``lax.top_k``'s positions, the rows
+    gathered through the table and ``mla_moe.absorbed_attention``, a
+    selection of 40 in pages of 16 (blocks of 1024 positions): slot 0 at
+    a length below a page, below the selection, at it, or past it over
+    three blocks; its scores normal, or whole numbers that tie at the
+    40th (as keys every indexer head scores below zero tie at 0); its
+    pages in table order or shuffled over the pool; or inactive (length
+    0), whose weighted latent must be finite zeros. Slot 1 selects 40
+    of 1500 over two blocks after it in the same ring, on layer 1 of 2."""
+    view, page, columns, pages, k = _VIEW, 16, 160, 256, 40
+    length = {"below-page": 9, "below-k": 30, "at-k": 40,
+              "inactive": 0}.get(case, 2300)
+    lengths = jnp.array([length, 1500], jnp.int32)
+    rng = np.random.default_rng(len(case))
+    rows = jnp.asarray(rng.standard_normal((2, pages, page, view.cache_row)),
+                       jnp.float32)
+    ids = rng.permutation(pages) if case == "shuffled" else np.arange(pages)
+    table = np.full((2, columns), pages, np.int32)
+    taken = 0
+    for b, n in enumerate(np.asarray(lengths)):
+        used = -(-int(n) // page)
+        table[b, :used] = ids[taken:taken + used]
+        taken += used
+    table = jnp.asarray(table)
+    if case == "ties":
+        scores = np.maximum(rng.integers(-3, 3, (2, columns * page)), 0)
+    else:
+        scores = rng.standard_normal((2, columns * page))
+    scores = jnp.where(jnp.arange(columns * page) < lengths[:, None],
+                       jnp.asarray(scores, jnp.float32), -jnp.inf)
+    heads = view.n_heads
+    attn = {name: jnp.asarray(rng.standard_normal(
+                (heads, view.kv_lora_rank, width)) / 8, jnp.float32)
+            for name, width in (("w_uk", view.qk_nope_dim),
+                                ("w_uv", view.v_head_dim))}
+    q_n = jnp.asarray(rng.standard_normal((2, heads, view.qk_nope_dim)),
+                      jnp.float32)
+    q_r = jnp.asarray(rng.standard_normal((2, heads, view.qk_rope_dim)),
+                      jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        top, spots = lax.top_k(scores, k)
+        gathered = rows[1, jnp.take_along_axis(table, spots // page, axis=1),
+                        spots % page]
+        want = mla_moe.absorbed_attention(attn, q_n, q_r, gathered,
+                                          top > -jnp.inf, view)
+        u = sparse_latent_attention(
+            mla_moe.absorbed_query(attn, q_n, q_r, view),
+            dsa_mla_moe._top_mask(scores, scores > -jnp.inf, k), rows,
+            table, lengths, 1, rank=view.kv_lora_rank,
+            scale=view.softmax_scale, interpret=True)
+        got = jnp.einsum("bhc,hcd->bhd", u, attn["w_uv"]).reshape(2, -1)
+    assert u.shape == (2, heads, view.kv_lora_rank)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=1e-5, atol=1e-5)
+    if case == "inactive":
+        assert np.isfinite(np.asarray(u[0])).all()
+        assert not np.asarray(u[0]).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_decode_step_through_the_sparse_kernel_equals_the_gathered_step(
+        monkeypatch, dtype):
+    """Eight heads and pages of 16: the full layers attend through the
+    sparse latent kernel (interpreted), and the same steps with the
+    predicate refused gather ``lax.top_k``'s rows. Slot 0 selects 16 of
+    41-43 rows, slot 1 holds 10-12, slot 2 is inactive. The selections
+    are the same set (every full layer in float32; the first, whose
+    scores are the same numbers, in bfloat16), the logits equal within
+    the dtype's rounding, and the kernel's calls are counted."""
+    from gofr_tpu.ops import pallas
+
+    cfg = dsa_mla_moe.config("tiny", dtype=dtype, n_heads=8)
+    params = jax.jit(lambda key: dsa_mla_moe.init(cfg, key))(
+        jax.random.key(2))
+    page, bucket, columns, steps = 16, 48, 4, 3
+    lengths = np.array([40, 9])
+    tokens = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(9), (2, bucket + steps), 0, cfg.vocab_size))
+    active = jnp.array([True, True, False])
+
+    def run(kernel):
+        if not kernel:
+            monkeypatch.setattr(pallas, "sparse_latent_tileable",
+                                lambda *_: False)
+        step = jax.jit(lambda p, t, leaves, table, n: (
+            dsa_mla_moe.decode_step_paged(p, cfg, t, leaves, table, n,
+                                          active, counters=True,
+                                          selections=True)))
+        with jax.default_matmul_precision("highest"):
+            prompt = jnp.asarray(np.where(np.arange(bucket) < lengths[:, None],
+                                          tokens[:, :bucket], 0))
+            _, small, cache_len = dsa_mla_moe.prefill(
+                params, cfg, prompt, dsa_mla_moe.init_cache(cfg, 2, bucket),
+                lengths=jnp.asarray(lengths))
+            leaves, tables = _pool_and_table(cfg, small, bucket, columns,
+                                             page=page, slots=2)
+            cache_len = jnp.concatenate([cache_len, jnp.zeros((1,),
+                                                              jnp.int32)])
+            out = []
+            for i in range(steps):
+                fed = jnp.asarray(np.append(tokens[np.arange(2), lengths + i],
+                                            0))
+                logits, leaves, grown, counted, picked = step(
+                    params, fed, leaves, tables, cache_len)
+                cache_len = jnp.where(active, grown, cache_len)
+                got = dict(zip(dsa_mla_moe.STEP_COUNTERS,
+                               np.asarray(counted).tolist()))
+                out.append((np.asarray(logits[:2], np.float32),
+                            np.asarray(picked), got))
+        monkeypatch.undo()
+        return out
+
+    for (logits, picked, got), (want, want_picked, want_got) in zip(
+            run(True), run(False)):
+        assert got["dsa.kernel_calls"] == 3
+        assert want_got["dsa.kernel_calls"] == 0
+        assert {name: n for name, n in got.items()
+                if name != "dsa.kernel_calls"} == {
+            name: n for name, n in want_got.items()
+            if name != "dsa.kernel_calls"}
+        layers = 3 if dtype == jnp.float32 else 1
+        for layer in range(layers):
+            for b in range(2):
+                assert (set(picked[layer, b][picked[layer, b] >= 0])
+                        == set(want_picked[layer, b][want_picked[layer, b]
+                                                     >= 0]))
+        tolerance = 1e-5 if dtype == jnp.float32 else 2e-2
+        for b in range(2):
+            assert reference.rel_l2(logits[b], want[b]) < tolerance
+
+
 # -- (g) through the engine ---------------------------------------------------------
 
 def test_the_engine_serves_it_and_releases_the_window(model):
@@ -465,3 +614,18 @@ def test_which_shapes_take_the_index_kernel(head_dim, heads, page, tiles):
     from gofr_tpu.ops.pallas import index_tileable
 
     assert index_tileable(head_dim, heads, page) is tiles
+
+
+@pytest.mark.parametrize("cache_row,heads,page,tiles", [
+    (640, 128, 32, True), (640, 128, 16, True), (128, 4, 8, False),
+    (128, 8, 16, True), (640, 60, 32, False), (576, 128, 32, False),
+    (640, 128, 10240, False)],
+    ids=["cell", "page16", "tiny", "wide", "heads", "row", "dense-cache"])
+def test_which_shapes_take_the_sparse_latent_kernel(cache_row, heads, page,
+                                                   tiles):
+    """The cell's full layers take the kernel; the tiny preset's four
+    heads and the dense cache's page of the whole length take the
+    gathered rows."""
+    from gofr_tpu.ops.pallas import sparse_latent_tileable
+
+    assert sparse_latent_tileable(cache_row, heads, page) is tiles

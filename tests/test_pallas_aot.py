@@ -768,15 +768,46 @@ def test_index_kernel_compiles_at_the_cells_call_shape(v5e):
                     kernel), kernel
 
 
+def test_sparse_latent_kernel_compiles_at_the_cells_call_shape(v5e):
+    """A full layer's decode attention at ``dots3-note-ep32.longctx``'s
+    call shape (64 slots, 128 heads over latent rows of 640, the full
+    kind's stacked ``ckv`` leaf of 3 layers x 16384 pages, a table of 256
+    columns, the selection a mask of 8192 positions a slot): Mosaic takes
+    the ring of page copies, the (128, 640) x (640, 1024) scores, the
+    online softmax and the (128, 1024) x (1024, 512) weighted latent; the
+    custom call is named ``sparse_latent_attention``, no fusion wraps it
+    (a trace finds it by that name) and its result is the float32
+    weighted latent."""
+    from gofr_tpu.ops.pallas import (sparse_latent_attention,
+                                     sparse_latent_tileable)
+
+    assert sparse_latent_tileable(640, 128, PAGE)
+    compiled = _compile(
+        functools.partial(sparse_latent_attention, rank=512, scale=0.07,
+                          interpret=False), v5e,
+        ((64, 128, 640), jnp.bfloat16), ((64, 8192), jnp.bool_),
+        ((3, 16384, PAGE, 640), jnp.bfloat16), ((64, 256), jnp.int32),
+        ((64,), jnp.int32), ((), jnp.int32))
+    kernel, = re.findall(r"^.*custom_call_target=\"tpu_custom_call\".*$",
+                         compiled.as_text(), re.M)
+    assert re.match(r"\s*(ROOT )?%sparse_latent_attention[.\d]* = "
+                    r"f32\[64,128,512\]", kernel), kernel
+
+
 def test_sparse_attention_tick_reads_the_pool_in_place_for_v5e(v5e):
     """``dots3-note-ep32.longctx``'s fused decode tick on shapes alone:
     64 slots, K = 4, both kinds' pools at every slot's max_len (16384
     full pages of ``ckv`` 640 and ``ik`` 128, 1152 window pages of
-    1152). The index kernel runs once a full layer (three in the
-    unrolled step), the pool is the donated carry and no copy of a leaf
-    is made, and the temporaries (the gathered selected rows, the scores
-    and their top-k) stay under 1 GB beside 7.0 GB of weights and
-    pool."""
+    1152). The index kernel and the sparse latent kernel run once a full
+    layer each (three of each in the unrolled step): the selected rows
+    are read where they lie, so no gather of 64 x 2048 rows
+    (``bf16[131072,640]``), no index arithmetic of its positions
+    (``s32[131072]``) and no sort of a slot's scores are left (the
+    gathered form's tick holds all three). The pool is the donated
+    carry and no copy of a leaf is made, and the temporaries (the
+    scores, the selection's mask, the window's gathered rows) stay under
+    384 MiB beside 7.0 GB of weights and pool (the gathered form: 571
+    MB)."""
     module, cfg = _dots3_note()
     slots, columns, k_steps = 64, 256, 4
     pages = {"full": slots * columns, "window": slots * 18}
@@ -814,6 +845,15 @@ def test_sparse_attention_tick_reads_the_pool_in_place_for_v5e(v5e):
     kernels = re.findall(r"^\s*(?:ROOT )?%index_scores[.\d]* = "
                          r"f32\[64,1,8192\]\S* custom-call\(", hlo, re.M)
     assert len(kernels) == 3, kernels
+    kernels = re.findall(r"^\s*(?:ROOT )?%sparse_latent_attention[.\d]* = "
+                         r"f32\[64,128,512\]\S* custom-call\(", hlo, re.M)
+    assert len(kernels) == 3, kernels
+    gathered = re.findall(r"^.*= (?:bf16\[131072,640\]|s32\[131072\]).*$",
+                          hlo, re.M)
+    assert not gathered, gathered[:3]
+    sorts = re.findall(r"^.*= \(f32\[64,8192\]\S*, .*\) sort\(.*$", hlo,
+                       re.M)
+    assert not sorts, sorts[:3]
     copies = re.findall(r"^.*= bf16\[3,(?:16384|1152),32,(?:640|128|1152)\]"
                         r"\S* copy\(.*$", hlo, re.M)
     assert not copies, copies[:3]
@@ -829,4 +869,4 @@ def test_sparse_attention_tick_reads_the_pool_in_place_for_v5e(v5e):
     pool_bytes = 3 * PAGE * (pages["full"] * (640 + 128)
                              + pages["window"] * 1152) * 2
     assert memory.alias_size_in_bytes >= pool_bytes
-    assert memory.temp_size_in_bytes < 1 << 30
+    assert memory.temp_size_in_bytes < 384 << 20
