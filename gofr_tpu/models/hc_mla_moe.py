@@ -6,7 +6,7 @@ whose token carries ``hc_mult`` residual streams (Xing4.0-29B-A4B,
 This file holds what is new: the hyper-connected layer, the state the
 two stacks carry around it, and the read-out. Latent attention (the
 latent cache ``[c; k_r]``, the expanded prefill through the Pallas
-flash kernel, the absorbed decode step over gathered latent pages),
+flash kernel, the absorbed decode step over the latent pages),
 the two stacks, YaRN (``MlaMoeConfig.rope_scaling``) and the entry
 points are ``models/mla_moe.py``'s, imported and called with this
 member's ``Residual``; the expert layer with its correction bias is
@@ -40,11 +40,13 @@ weights (a test holds it to that).
 
 Serving contract (``docs/tpu/model-serving.md``): ``init``,
 ``init_cache``, ``prefill``, ``decode_step``, ``decode_step_paged``,
-``cache_leaves`` and ``STEP_COUNTERS``: the expert layer's five, then
+``cache_leaves`` and ``STEP_COUNTERS``: ``mla_moe.py``'s (the expert
+layer's five, ``attn.calls``, ``attn.kernel_calls``), then
 ``attn.rows_live`` (latent rows under ``cache_len``, the new one with
-them, over the active slots) and ``attn.rows_read`` (rows the page
-gather brought in for all slots), each summed over layers and steps:
-their ratio is what the gather ladder wastes.
+them, over the active slots) and ``attn.rows_read`` (rows a gather of
+the table's pages brings in for all slots), each summed over layers and
+steps: their ratio is what the gathered form wastes, and what the
+sparse latent kernel's walk of live pages does not read.
 """
 
 from __future__ import annotations
@@ -55,13 +57,13 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from gofr_tpu.models import experts, hyper_connection, mla_moe
+from gofr_tpu.models import hyper_connection, mla_moe
 from gofr_tpu.models.experts import route  # noqa: F401  (the module's name)
 from gofr_tpu.models.mla_moe import (  # noqa: F401  (the module's names)
     MlaMoeConfig, cache_leaves, init_cache)
 from gofr_tpu.ops import rms_norm
 
-STEP_COUNTERS = experts.STEP_COUNTERS + ("attn.rows_live", "attn.rows_read")
+STEP_COUNTERS = mla_moe.STEP_COUNTERS + ("attn.rows_live", "attn.rows_read")
 
 _TOPK_METHODS = ("greedy", "noaux_tc")
 
@@ -202,8 +204,8 @@ def decode_step_paged(params: Dict[str, Any], cfg: HcMlaMoeConfig,
                       active: jnp.ndarray, counters: bool = False,
                       routes: bool = False):
     """``mla_moe.decode_step_paged`` around the hyper-connected layer;
-    with ``counters`` the step's ``STEP_COUNTERS``: the expert layer's
-    and the latent rows live and read."""
+    with ``counters`` the step's ``STEP_COUNTERS``: ``mla_moe``'s and
+    the latent rows live and read."""
     out = mla_moe.decode_step_paged(params, cfg, token, pool, page_table,
                                     cache_len, active, counters,
                                     residual=Residual, routes=routes)

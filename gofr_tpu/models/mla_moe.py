@@ -33,6 +33,11 @@ sqrt(mean(x^2) + eps) * g``):
   The decode steps are *absorbed*: ``q~_h = W_UK,h^T q_n,h``, score ``= (q~_h . c_t
   + q_r,h . k_r,t) / sqrt(..)``, ``u_h = sum_t p_t c_t``, ``o_h = W_UV,h
   u_h`` — the cached rows are read as they are, never expanded to heads.
+  The paged step reads them where they lie in the page pool, a slot's
+  live pages walked by the sparse latent kernel with every position
+  under its length selected, wherever ``select.sparse_latent_tileable``
+  takes the shape; elsewhere, and in the dense step, the rows are
+  gathered and attended in plain XLA (the kernel's oracle).
 - Expert layer (``models/experts.py``, shared with ``models/swa_moe.py``):
   ``s = sigmoid(W_g h)`` (or softmax) over all ``n_routed_experts``, in
   float32; ``T`` = the ``top_k`` largest; ``w_e = routed_scale * s_e /
@@ -48,7 +53,9 @@ sqrt(mean(x^2) + eps) * g``):
 
 Serving contract (``docs/tpu/model-serving.md``): ``init_cache``,
 ``prefill``, ``decode_step``, ``decode_step_paged``, ``cache_leaves``
-and ``STEP_COUNTERS``.
+and ``STEP_COUNTERS``: the expert layer's five, then ``attn.calls`` and
+``attn.kernel_calls`` (a layer a step each; the second where the
+sparse latent kernel ran).
 
 ``models/hc_mla_moe.py`` (a member whose residual is hyper-connected)
 imports from here and calls with its own ``Residual``: ``init``,
@@ -61,6 +68,7 @@ everything under them (``_latent``, ``prefill_attention``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -77,8 +85,11 @@ from gofr_tpu.ops.rotary import yarn_mscale
 _NEG_INF = -1e30
 _LANES = 128
 
-# what a decode step counts: the expert layer's (models/experts.py)
-_N_COUNTERS = len(STEP_COUNTERS)
+# what a decode step counts: the expert layer's (models/experts.py), then
+# the layers' decode attention calls and those the sparse latent kernel
+# ran (a layer a step each; app_tpu_step_counter_total)
+_N_MOE = len(STEP_COUNTERS)
+STEP_COUNTERS = STEP_COUNTERS + ("attn.calls", "attn.kernel_calls")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -418,7 +429,7 @@ def _ffn(cfg: MlaMoeConfig, kind: str, layer, h, valid, grouped):
     experts chosen (B, S, top_k); zeros if dense)."""
     b, s, d = h.shape
     if kind == "dense":
-        return (_swiglu(layer, h), jnp.zeros((_N_COUNTERS,), jnp.int32),
+        return (_swiglu(layer, h), jnp.zeros((_N_MOE,), jnp.int32),
                 jnp.zeros((b, s, cfg.top_k), jnp.int32))
     y, counters, ids = moe_ffn(
         cfg, layer, h.reshape(b * s, d),
@@ -521,11 +532,13 @@ def prefill(params: Dict[str, Any], cfg: MlaMoeConfig, tokens: jnp.ndarray,
 
 
 def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
-            append, view_of, residual=Residual, routes=False):
+            append, read, residual=Residual, routes=False):
     """One absorbed decode step. ``append(leaf, idx, row)`` writes layer
-    ``idx``'s new row for every sequence, ``view_of(leaf, idx)`` gives
-    the (B, T, cache_row) rows attention reads. The new row is written
-    first and read back with the rest: position ``cache_len`` is valid.
+    ``idx``'s new row for every sequence, ``read(attn, q_n, q_r, leaf,
+    idx)`` attends layer ``idx``'s cached rows (``_gathered`` or, over
+    the page pool, the sparse latent kernel) and gives (B, H *
+    v_head_dim). The new row is written first and read back with the
+    rest: position ``cache_len`` is valid.
     The routed experts' stack stays out of the layer scan, as in
     ``prefill``: the expert layer's loop over the experts hit reads
     ``[layer, expert]`` from it (``experts_hit``'s ``at``), where the
@@ -535,7 +548,7 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
     cos, sin = cfg.rope()
     positions = cache_len[:, None]
     x = residual.spread(cfg, params["tok_emb"][token][:, None, :])
-    counters = jnp.zeros((_N_COUNTERS,), jnp.int32)
+    counters = jnp.zeros((_N_MOE,), jnp.int32)
     chosen = None
 
     for kind, stack, first in _stacks(params, cfg):
@@ -555,11 +568,7 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
             def attend(attn, h):
                 q_n, q_r, row = _latent(attn, h, cfg, cos, sin, positions)
                 grown = append(leaf, idx, row[:, 0])
-                view = view_of(grown, idx)
-                valid = (jnp.arange(view.shape[1])[None, :]
-                         <= cache_len[:, None])
-                out = absorbed_attention(attn, q_n[:, 0], q_r[:, 0], view,
-                                         valid, cfg)
+                out = read(attn, q_n[:, 0], q_r[:, 0], grown, idx)
                 return out[:, None, :], grown
 
             x, leaf, counted, ids = residual.layer(
@@ -575,6 +584,33 @@ def _decode(params, cfg: MlaMoeConfig, token, leaf, cache_len, active,
             chosen = ids
     out = (_head(params, cfg, residual.gather(x)[:, 0]), leaf, counters)
     return out + (chosen,) if routes else out
+
+
+def _gathered(cfg: MlaMoeConfig, cache_len, view_of):
+    """``_decode``'s ``read`` over the (B, T, cache_row) rows
+    ``view_of(leaf, idx)`` gives: absorbed attention masked by
+    ``cache_len``, plain XLA."""
+    def read(attn, q_n, q_r, leaf, idx):
+        view = view_of(leaf, idx)
+        valid = jnp.arange(view.shape[1])[None, :] <= cache_len[:, None]
+        return absorbed_attention(attn, q_n, q_r, view, valid, cfg)
+
+    return read
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale"))
+def _walk_live_pages(q, rows, page_table, lengths, layer, *, rank: int,
+                     scale: float):
+    """The sparse latent kernel over every live position of each slot,
+    as one jitted call so that its trace is cached: a tick program's
+    two call sites (the dense layer, the expert layers' scan body) and
+    every program of one table width (the K ladder) share one. Traced at
+    every call site, the kernel cost ~0.7 s of set-up a tick program on
+    the host, where pangu's warm-up builds 15 of them."""
+    from gofr_tpu.ops.pallas import sparse_latent_attention
+
+    return sparse_latent_attention(q, None, rows, page_table, lengths, layer,
+                                   rank=rank, scale=scale)
 
 
 def decode_step(params: Dict[str, Any], cfg: MlaMoeConfig,
@@ -594,7 +630,8 @@ def decode_step(params: Dict[str, Any], cfg: MlaMoeConfig,
         return view if window is None else view[:, :window]
 
     logits, leaf, _ = _decode(params, cfg, token, cache["ckv"], cache_len,
-                              None, append, view_of, residual)
+                              None, append, _gathered(cfg, cache_len, view_of),
+                              residual)
     return logits, {"ckv": leaf}, cache_len + 1
 
 
@@ -607,22 +644,39 @@ def decode_step_paged(params: Dict[str, Any], cfg: MlaMoeConfig,
     num_pages, page, cache_row), ``page_table`` (B, P) with ``num_pages``
     as the unallocated sentinel, ``active`` (B,) bool gating the append
     (an inactive slot's page may belong to another stream by now: its
-    row goes to the sentinel page and is dropped). Per layer the table's
-    pages are gathered as they are, (B, P * page, cache_row), and
-    attended in the absorbed form: plain XLA, a gather and two products.
+    row goes to the sentinel page and is dropped). Per layer, where
+    ``select.sparse_latent_tileable`` takes the shape, the sparse latent
+    kernel walks each active slot's live pages where they lie, every
+    position under its length selected (the layer's plane and the table
+    as they are: a narrowed table bounds the walk), and ``W_UV`` is
+    applied to its weighted latent after; elsewhere the table's pages
+    are gathered as they are, (B, P * page, cache_row), and attended in
+    the absorbed form: plain XLA, a gather and two products.
     Returns (logits, pool, cache_len + 1), and with ``counters`` the
     step's ``STEP_COUNTERS`` over the active rows as a fourth, with
     ``routes`` the experts chosen, (expert layers, B, top_k), as the
     last (for a comparison of the routing with a reference's)."""
+    from gofr_tpu.ops.pallas import sparse_latent_tileable
+
     num_pages, page = pool["ckv"].shape[1:3]
     page_col = cache_len // page
     page_row = jnp.take_along_axis(page_table, page_col[:, None], axis=1,
                                    mode="clip")[:, 0]
     dest_row = jnp.where(active, page_row, num_pages)       # sentinel: drop
     offset = cache_len % page
+    kernel = sparse_latent_tileable(cfg.cache_row, cfg.n_heads, page)
+    # the new row included; an inactive slot walks nothing, reads zeros
+    live = jnp.where(active, cache_len + 1, 0)
 
     def append(leaf, idx, row):
         return leaf.at[idx, dest_row, offset].set(row, mode="drop")
+
+    def in_place(attn, q_n, q_r, leaf, idx):
+        u = _walk_live_pages(absorbed_query(attn, q_n, q_r, cfg), leaf,
+                             page_table, live, idx, rank=cfg.kv_lora_rank,
+                             scale=cfg.softmax_scale)
+        out = jnp.einsum("bhc,hcd->bhd", u.astype(leaf.dtype), attn["w_uv"])
+        return out.reshape(out.shape[0], cfg.n_heads * cfg.v_head_dim)
 
     def view_of(leaf, idx):
         # one gather straight out of the stacked leaf: a layer's plane
@@ -631,8 +685,12 @@ def decode_step_paged(params: Dict[str, Any], cfg: MlaMoeConfig,
         pages = leaf[idx, page_table]                   # (B, P, page, C)
         return pages.reshape(pages.shape[0], -1, pages.shape[-1])
 
+    read = in_place if kernel else _gathered(cfg, cache_len, view_of)
     logits, leaf, counted, *chosen = _decode(
-        params, cfg, token, pool["ckv"], cache_len, active, append, view_of,
+        params, cfg, token, pool["ckv"], cache_len, active, append, read,
         residual, routes)
+    # attn.calls, attn.kernel_calls: a layer each, every step
+    counted = jnp.concatenate([counted, jnp.array(
+        [cfg.n_layers, cfg.n_layers * kernel], jnp.int32)])
     out = (logits, {"ckv": leaf}, cache_len + 1)
     return out + ((counted,) if counters else ()) + tuple(chosen)

@@ -1,7 +1,9 @@
 """A full layer's sparse decode attention over the latent rows the
 indexer selected, read where they lie in the page pool: one Pallas TPU
 kernel (DeepSeek-V3.2's sparse attention over DeepSeek-V3's latent cache,
-as ``models/dsa_mla_moe.py`` serves it).
+as ``models/dsa_mla_moe.py`` serves it). With every live position
+selected it is also the latent family's absorbed decode attention
+(``models/mla_moe.py``'s paged step, pangu's and xing's).
 
 A decode step's query of a full layer attends the ``index_topk`` cached
 positions of its slot that the indexer scored highest (2048 of 2k-8k at
@@ -22,12 +24,14 @@ another.
   core). A slot of length 0 (an inactive one) copies nothing and
   returns zeros.
 - The selection arrives as a mask, one int32 a position of the slot's
-  table, nonzero where the position is selected. A block's scores are
-  one ``(heads, width) x (width, tokens)`` product on the MXU in the
-  pool's dtype with float32 accumulation; positions the mask leaves out
-  (and any at or past the slot's length) get no weight. The softmax is
-  online, a head a row, in float32: the block's running maximum and sum,
-  and ``acc += p . rows[:, :lanes]`` with ``p`` in the pool's dtype.
+  table, nonzero where the position is selected; or, with ``chosen``
+  None, every live position is selected and no mask is read. A block's
+  scores are one ``(heads, width) x (width, tokens)`` product on the MXU
+  in the pool's dtype with float32 accumulation; positions the mask
+  leaves out (and any at or past the slot's length) get no weight. The
+  softmax is online, a head a row, in float32: the block's running
+  maximum and sum, and ``acc += p . rows[:, :lanes]`` with ``p`` in the
+  pool's dtype.
 - The output is ``u`` in float32, ``(slots, heads, rank)``: the caller
   applies ``W_UV``.
 
@@ -35,9 +39,9 @@ A dead page inside a partly live block is not copied; its ring place
 holds an earlier block's rows (finite, and zeroed before the first
 program), which meet weights of exactly 0.
 
-The gathered form (``lax.top_k``, the rows gathered through the table,
-``mla_moe.absorbed_attention``) is the oracle the tests hold this kernel
-to, and the path a caller takes where ``select.sparse_latent_tileable``
+The gathered form (``lax.top_k``, or every position, the rows gathered
+through the table, ``mla_moe.absorbed_attention``) is the oracle the
+tests hold this kernel to, and the path a caller takes where ``select.sparse_latent_tileable``
 says Mosaic cannot tile the shape. The ``pallas_call`` is named
 ``sparse_latent_attention``: a trace finds the kernel by that name.
 """
@@ -71,12 +75,16 @@ def latent_walk(page: int) -> int:
     return max(1, _BLOCK_TOKENS // page)
 
 
-def _sparse_kernel(table_ref, len_ref, layer_ref, q_ref, mask_ref, rows_hbm,
-                   o_ref, ring, sems, *, block_pages: int, ring_blocks: int,
-                   lanes: int, scale: float):
+def _sparse_kernel(table_ref, len_ref, layer_ref, q_ref, *refs,
+                   block_pages: int, ring_blocks: int, lanes: int,
+                   scale: float, masked: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if masked:
+        mask_ref, rows_hbm, o_ref, ring, sems = refs
+    else:                   # every live position: no mask operand
+        rows_hbm, o_ref, ring, sems = refs
     b = pl.program_id(0)
     num_slots = pl.num_programs(0)
     table_width = table_ref.shape[1]
@@ -135,10 +143,13 @@ def _sparse_kernel(table_ref, len_ref, layer_ref, q_ref, mask_ref, rows_hbm,
             block_tokens, width)[...]
         s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-        start = pl.multiple_of(j * block_tokens, block_tokens)
-        chosen = jnp.logical_and(
-            mask_ref[0, :, pl.ds(start, block_tokens)] != 0,
-            offset < length - j * block_tokens)            # (1, tokens)
+        if masked:
+            start = pl.multiple_of(j * block_tokens, block_tokens)
+            chosen = jnp.logical_and(
+                mask_ref[0, :, pl.ds(start, block_tokens)] != 0,
+                offset < length - j * block_tokens)        # (1, tokens)
+        else:
+            chosen = offset < length - j * block_tokens
         s = jnp.where(chosen, s * scale, _NEG_INF)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.where(chosen, jnp.exp(s - m_new), 0.0)
@@ -172,22 +183,25 @@ def _pallas_sparse(q, chosen, rows_pages, page_table, lengths, layer, *,
     page = rows_pages.shape[2]
     block_pages = latent_walk(page)
     block_tokens = block_pages * page
-    columns = -(-page_table.shape[1] // block_pages) * block_tokens
     lanes = -(-rank // _LANES) * _LANES
-    mask = jnp.pad(chosen.astype(jnp.int32),
-                   ((0, 0), (0, columns - chosen.shape[1])))[:, None, :]
+    masked = chosen is not None
 
     def slot(b, *_):
         return (b, 0, 0)
 
+    mask, mask_specs = (), []
+    if masked:
+        columns = -(-page_table.shape[1] // block_pages) * block_tokens
+        mask = (jnp.pad(chosen.astype(jnp.int32),
+                        ((0, 0), (0, columns - chosen.shape[1])))[:, None, :],)
+        mask_specs = [pl.BlockSpec((1, 1, columns), slot)]
     kernel = functools.partial(_sparse_kernel, block_pages=block_pages,
                                ring_blocks=_RING_BLOCKS, lanes=lanes,
-                               scale=scale)
+                               scale=scale, masked=masked)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(batch,),
-        in_specs=[pl.BlockSpec((1, heads, width), slot),
-                  pl.BlockSpec((1, 1, columns), slot),
+        in_specs=[pl.BlockSpec((1, heads, width), slot), *mask_specs,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, heads, lanes), slot),
         scratch_shapes=[
@@ -210,7 +224,7 @@ def _pallas_sparse(q, chosen, rows_pages, page_table, lengths, layer, *,
         interpret=interpret,
         name="sparse_latent_attention",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, mask, rows_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), q, *mask, rows_pages)
     return out[..., :rank]
 
 
@@ -220,7 +234,8 @@ def sparse_latent_attention(q, chosen, rows_pages, page_table, lengths,
     """``u[b, h] = sum_s softmax_s(scale * q[b, h] . row_s) c_s`` over
     slot ``b``'s positions ``s < lengths[b]`` that ``chosen[b, s]``
     selects; zeros where it selects none. q (B, heads, width) the
-    absorbed query in the pool's dtype; chosen (B, P * page) bool;
+    absorbed query in the pool's dtype; chosen (B, P * page) bool, or
+    None for every position under the length (no mask is read);
     rows_pages the stacked ``ckv`` leaf (L, num_pages, page, width) and
     ``layer`` the int32 scalar naming the plane; page_table (B, P) int32,
     ``num_pages`` the unallocated sentinel; lengths (B,) int32, the live

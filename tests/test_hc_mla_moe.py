@@ -172,6 +172,35 @@ def test_dense_decode_step_equals_the_paged_one(model):
     assert reference.rel_l2(logits[0], want[0]) < 1e-5
 
 
+@pytest.mark.parametrize("rung", ["whole", "narrowed"])
+@pytest.mark.parametrize("heads", [32, 128])
+def test_paged_step_through_the_latent_kernel_equals_the_gathered_step(
+        monkeypatch, heads, rung):
+    """xing's 32 heads and pangu's 128 at page 32, under the four
+    streams: the paged step's absorbed attention walks every live
+    position in the sparse latent kernel (``mla_moe.decode_step_paged``)
+    and equals the gathered form's for the active slots, over a whole
+    table and a ladder rung's narrowed one; the counters differ in
+    ``attn.kernel_calls`` alone (the rows live and read are what a
+    gather would bring in, on both forms)."""
+    from test_mla_moe import kernel_and_gathered_steps
+
+    cfg = hc_mla_moe.config("tiny", dtype=jnp.float32, n_heads=heads)
+    params = hc_mla_moe.init(cfg, jax.random.PRNGKey(4))
+    out, active = kernel_and_gathered_steps(monkeypatch, hc_mla_moe, cfg,
+                                            params, rung)
+    kernel_calls = hc_mla_moe.STEP_COUNTERS.index("attn.kernel_calls")
+    for (got, got_counts), (want, want_counts) in zip(out["kernel"],
+                                                      out["gathered"]):
+        assert reference.rel_l2(got[active], want[active]) < 1e-5
+        assert np.isfinite(got).all()
+        assert got_counts[kernel_calls] == cfg.n_layers
+        assert want_counts[kernel_calls] == 0
+        np.testing.assert_array_equal(
+            np.delete(got_counts, kernel_calls),
+            np.delete(want_counts, kernel_calls))
+
+
 # -- (c) the mixer ----------------------------------------------------------------
 
 def _state(cfg, seed, tokens=64):
@@ -643,7 +672,7 @@ def test_step_counters_add_up_through_the_engine(model):
 
     stats, metrics = asyncio.run(serve())
     moe, attn = stats["moe"], stats["attn"]
-    assert set(attn) == {"rows_live", "rows_read"}
+    assert set(attn) == {"calls", "kernel_calls", "rows_live", "rows_read"}
     # all 16 experts are held: every routed pair is a held pair
     assert moe["held_pairs"] == moe["routed_pairs"] \
         == 3 * 6 * cfg.n_moe_layers * cfg.top_k
@@ -651,6 +680,10 @@ def test_step_counters_add_up_through_the_engine(model):
     assert attn["rows_live"] == 3 * sum(range(10, 16)) * cfg.n_layers
     assert attn["rows_read"] >= attn["rows_live"]
     assert attn["rows_read"] % (4 * PAGE * cfg.n_layers) == 0
+    # every layer's attention a step, none in the kernel at 4 heads
+    assert attn["calls"] == moe["layer_steps"] // cfg.n_moe_layers \
+        * cfg.n_layers
+    assert attn["kernel_calls"] == 0
     assert metrics.value("app_tpu_step_counter_total", model="generate",
                          counter="attn.rows_live") == attn["rows_live"]
     # the host's count of the same rows (the engine's timeline, a layer)

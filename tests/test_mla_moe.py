@@ -226,6 +226,76 @@ def test_absorbed_attention_equals_expanded(model):
                                atol=2e-5)
 
 
+def kernel_and_gathered_steps(monkeypatch, module, cfg, params, rung,
+                              steps=2):
+    """``steps`` paged decode steps of ``module`` at page 32, float32,
+    through the sparse latent kernel (interpreted) and with
+    ``select.sparse_latent_tileable`` refused (the gathered form), from
+    one pool of seeded rows: slot 0 past two pages, slot 1 filling two
+    pages exactly with its first new row, slot 2 starting a page with
+    it, slot 3 inactive over slot 0's first page. ``rung`` "narrowed"
+    gives the table 3 columns (the window ladder's narrowest rung that
+    covers every slot), "whole" 8 with the unallocated sentinel after
+    the live ones. Returns ({"kernel" | "gathered": [(logits, counters)
+    a step]}, active)."""
+    from gofr_tpu.ops import pallas
+
+    page, num_pages = 32, 16
+    columns = {"narrowed": 3, "whole": 8}[rung]
+    cache_len = jnp.array([70, 63, 64, 5], jnp.int32)
+    active = jnp.array([True, True, True, False])
+    rng = np.random.default_rng(cfg.n_heads)
+    ids = rng.permutation(num_pages)
+    table = np.full((4, columns), num_pages, np.int32)
+    table[:3, :3] = ids[:9].reshape(3, 3)
+    table[3, 0] = table[0, 0]
+    rows = jnp.asarray(rng.standard_normal(
+        (cfg.n_layers, num_pages, page, cfg.cache_row)), jnp.float32)
+    tokens = rng.integers(0, cfg.vocab_size, (steps, 4))
+    assert pallas.sparse_latent_tileable(cfg.cache_row, cfg.n_heads, page)
+    out = {}
+    for form in ("kernel", "gathered"):
+        if form == "gathered":
+            monkeypatch.setattr(pallas, "sparse_latent_tileable",
+                                lambda *_: False)
+        step = jax.jit(lambda p, t, leaves, n: module.decode_step_paged(
+            p, cfg, t, leaves, jnp.asarray(table), n, active,
+            counters=True))
+        leaves, n, out[form] = {"ckv": rows}, cache_len, []
+        with jax.default_matmul_precision("highest"):
+            for i in range(steps):
+                logits, leaves, grown, counted = step(
+                    params, jnp.asarray(tokens[i]), leaves, n)
+                n = jnp.where(active, grown, n)
+                out[form].append((np.asarray(logits), np.asarray(counted)))
+    return out, np.asarray(active)
+
+
+@pytest.mark.parametrize("rung", ["whole", "narrowed"])
+@pytest.mark.parametrize("heads", [128, 32])
+def test_paged_step_through_the_latent_kernel_equals_the_gathered_step(
+        monkeypatch, heads, rung):
+    """pangu's 128 heads and xing's 32 at page 32: the paged step's
+    absorbed attention walks every live position in the sparse latent
+    kernel and equals the gathered form's, active slot by active slot,
+    over a whole table and a ladder rung's narrowed one; the inactive
+    slot's logits are finite, and the step counts its layers' attention
+    calls and the kernel's."""
+    cfg = mla_moe.config("tiny", dtype=jnp.float32, n_heads=heads)
+    params = mla_moe.init(cfg, jax.random.PRNGKey(4))
+    out, active = kernel_and_gathered_steps(monkeypatch, mla_moe, cfg,
+                                            params, rung)
+    calls = mla_moe.STEP_COUNTERS.index("attn.calls")
+    for (got, got_counts), (want, want_counts) in zip(out["kernel"],
+                                                      out["gathered"]):
+        assert reference.rel_l2(got[active], want[active]) < 1e-5
+        assert np.isfinite(got).all()
+        assert list(got_counts[calls:]) == [cfg.n_layers, cfg.n_layers]
+        assert list(want_counts[calls:]) == [cfg.n_layers, 0]
+        np.testing.assert_array_equal(got_counts[:calls],
+                                      want_counts[:calls])
+
+
 # -- (d) the shares add up -------------------------------------------------------
 
 @pytest.mark.parametrize("grouped", [False, True],
@@ -509,6 +579,13 @@ def test_step_counters_add_up_through_the_engine(model):
     assert moe["layer_steps"] % cfg.n_moe_layers == 0
     assert metrics.value("app_tpu_step_counter_total", model="generate",
                          counter="moe.routed_pairs") == moe["routed_pairs"]
+    # every layer's attention a step; the tiny preset's 4 heads do not
+    # tile the sparse latent kernel, so none ran in it
+    steps = moe["layer_steps"] // cfg.n_moe_layers
+    assert stats["attn"] == {"calls": steps * cfg.n_layers,
+                             "kernel_calls": 0}
+    assert metrics.value("app_tpu_step_counter_total", model="generate",
+                         counter="attn.calls") == steps * cfg.n_layers
 
 
 def test_llama_tick_has_no_counters_and_three_outputs():

@@ -160,3 +160,46 @@ def test_llama_use_flash_config():
     with pytest.warns(UserWarning, match="DENSE attention"):
         out = llama.forward(params, cfg_flash, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("lengths", [(0, 32, 1500), (9, 1024, 1600),
+                                     (2300, 1, 16)],
+                         ids=["inactive-boundary-two-blocks",
+                              "short-block-edge-table-edge",
+                              "past-the-table-one-row-one-page"])
+def test_sparse_latent_every_live_position_equals_an_all_true_mask(lengths):
+    """The sparse latent kernel's ``chosen=None`` form (no mask read,
+    every position under the length attended) against its masked form
+    given a mask that selects every position, interpreted on bfloat16
+    rows of 128 lanes, 8 heads, pages of 16 (blocks of 1024 positions)
+    shuffled over the pool, a table of 100 columns on layer 1 of 2:
+    three slots in one ring at lengths of 0 (finite zeros), a page's
+    and a block's edge, past one block, at the table's end and past it
+    (the walk stops at the table), a row and a page. Both forms compute
+    the same numbers, so they are equal bit for bit."""
+    from gofr_tpu.ops.pallas import sparse_latent_attention
+
+    heads, width, rank, page, columns, pages = 8, 128, 64, 16, 100, 320
+    rng = np.random.default_rng(sum(lengths))
+    lengths = jnp.asarray(lengths, jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((2, pages, page, width)),
+                       jnp.bfloat16)
+    ids = rng.permutation(pages)
+    table = np.full((3, columns), pages, np.int32)
+    taken = 0
+    for b, n in enumerate(np.minimum(np.asarray(lengths), columns * page)):
+        used = -(-int(n) // page)
+        table[b, :used] = ids[taken:taken + used]
+        taken += used
+    q = jnp.asarray(rng.standard_normal((3, heads, width)), jnp.bfloat16)
+    kwargs = dict(rank=rank, scale=0.1, interpret=True)
+    every = sparse_latent_attention(q, None, rows, jnp.asarray(table),
+                                    lengths, 1, **kwargs)
+    masked = sparse_latent_attention(q, jnp.ones((3, columns * page), bool),
+                                     rows, jnp.asarray(table), lengths, 1,
+                                     **kwargs)
+    assert every.shape == (3, heads, rank) and every.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(every), np.asarray(masked))
+    assert np.isfinite(np.asarray(every)).all()
+    for b in np.flatnonzero(np.asarray(lengths) == 0):
+        assert not np.asarray(every[b]).any()

@@ -870,3 +870,106 @@ def test_sparse_attention_tick_reads_the_pool_in_place_for_v5e(v5e):
                              + pages["window"] * 1152) * 2
     assert memory.alias_size_in_bytes >= pool_bytes
     assert memory.temp_size_in_bytes < 384 << 20
+
+
+# -- the latent family's decode attention walks the pool in place ------------
+
+def _latent_cell(family):
+    """(module, config, slots, table columns, pool pages) of
+    ``pangu-ultra-moe-ep16.reason`` (5 layers, 16 of 256 experts held,
+    128 heads, the widest rung's 64 columns) or ``xing4-29b.docqa`` (6
+    layers, 32 heads, 208 columns: the ladder is off)."""
+    from gofr_tpu.models import hc_mla_moe, mla_moe
+
+    if family == "pangu":
+        return mla_moe, mla_moe.config(
+            "pangu-ultra-moe", n_layers=5, n_dense_layers=1,
+            n_held_experts=16, vocab_size=19200,
+            max_seq_len=2048), 64, 64, 4096
+    return hc_mla_moe, hc_mla_moe.config(
+        "xing4", n_layers=6, n_dense_layers=1,
+        max_seq_len=6656), 32, 208, 6144
+
+
+def _kernel_calls(jaxpr) -> int:
+    """Compiled (not interpreted) ``sparse_latent_attention`` calls a
+    run of ``jaxpr`` makes: a scan's times its length, a branch of a
+    ``cond`` (the lowering target's choice) at most once."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            total += (eqn.params.get("name") == "sparse_latent_attention"
+                      and not eqn.params.get("interpret"))
+        elif eqn.primitive.name == "scan":
+            total += eqn.params["length"] * _kernel_calls(
+                eqn.params["jaxpr"].jaxpr)
+        elif eqn.primitive.name == "cond":
+            total += max(_kernel_calls(branch.jaxpr)
+                         for branch in eqn.params["branches"])
+        else:
+            total += sum(_kernel_calls(getattr(value, "jaxpr", value))
+                         for value in eqn.params.values()
+                         if hasattr(getattr(value, "jaxpr", value), "eqns"))
+    return total
+
+
+@pytest.mark.parametrize("family", ["pangu", "xing"])
+def test_latent_ticks_walk_the_pool_in_place_for_v5e(v5e, family):
+    """pangu's and xing's fused decode tick (K = 4) at their cells'
+    widths and call shapes, on shapes alone: every layer's absorbed
+    attention is one ``sparse_latent_attention`` call on the stacked
+    ``ckv`` leaf as the layer scan carries it (5 and 6 a step; its
+    result the float32 weighted latent of every slot and head), so no
+    gather of a table's pages (``bf16[slots x columns,32,640]``, the
+    fusion ``decode_step_ms.pangu`` counted steps by) is left, nor any
+    other result of that form but xing's absorbed query (32 slots of 32
+    heads), and the pool is the donated carry, never copied."""
+    module, cfg, slots, columns, pages = _latent_cell(family)
+    from gofr_tpu.ops.pallas import sparse_latent_tileable
+
+    assert sparse_latent_tileable(cfg.cache_row, cfg.n_heads, PAGE)
+    params = _on_chip(jax.eval_shape(
+        lambda key: module.init(cfg, key), jax.random.key(0)), v5e)
+    pool = {"ckv": jax.ShapeDtypeStruct(
+        (cfg.n_layers, pages, PAGE, cfg.cache_row), cfg.dtype)}
+    rest = _on_chip((
+        jax.ShapeDtypeStruct((slots,), jnp.int32), pool,
+        jax.ShapeDtypeStruct((slots, columns), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)), v5e)
+
+    def step(params, token, pool, table, cache_len, active):
+        return module.decode_step_paged(params, cfg, token, pool, table,
+                                        cache_len, active, counters=True)
+
+    def tick(params, token, pool, table, cache_len, active):
+        def one(carry, _):
+            token, pool, cache_len = carry
+            logits, pool, new_len, counts = step(params, token, pool, table,
+                                                 cache_len, active)
+            token = jnp.where(active, logits.argmax(-1).astype(token.dtype),
+                              token)
+            return (token, pool, jnp.where(active, new_len, cache_len)), \
+                (token, counts)
+
+        (_, pool, cache_len), (tokens, counts) = jax.lax.scan(
+            one, (token, pool, cache_len), None, length=4)
+        return tokens, pool, cache_len, counts.sum(0)
+
+    assert _kernel_calls(jax.make_jaxpr(step)(params, *rest).jaxpr) \
+        == cfg.n_layers
+    compiled = jax.jit(tick, donate_argnums=(2, 4)).lower(
+        params, *rest).compile()
+    hlo = compiled.as_text()
+    kernels = re.findall(
+        rf"^\s*(?:ROOT )?%sparse_latent_attention[.\d]* = "
+        rf"f32\[{slots},{cfg.n_heads},{cfg.kv_lora_rank}\]\S* custom-call\(",
+        hlo, re.M)
+    assert len(kernels) == 2, kernels       # the dense layer, the scan body
+    rows = set(re.findall(rf"= bf16\[(\d+),{PAGE},{cfg.cache_row}\]", hlo))
+    assert rows <= ({str(slots)} if cfg.n_heads == PAGE else set()), rows
+    copies = re.findall(rf"^.*= bf16\[{cfg.n_layers},{pages},{PAGE},"
+                        rf"{cfg.cache_row}\]\S* copy\(.*$", hlo, re.M)
+    assert not copies, copies[:3]
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        cfg.n_layers * pages * PAGE * cfg.cache_row * 2)
