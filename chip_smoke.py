@@ -615,16 +615,8 @@ async def _generate(run: Run, size: str, phase: str, mesh,
           f"attention path {engine.attn_path!r} ({engine.attn_reason}), "
           f"expected {expected!r}")
     # warm only what the run uses: every admission-count rung (groups of
-    # any size up to max_slots form under concurrent arrivals), every
-    # k rung, and the window rungs warmup() takes as reachable at start —
-    # which must cover the longest prompt plus its whole generation, or a
-    # deeper rung would compile on the serving path
-    deepest = engine._pick_window(
-        [max(spec["prompt_buckets"]) + spec["max_new_tokens"]],
-        spec["steps_per_tick"])
-    check(deepest in engine._startup_window_rungs(engine._k_ladder),
-          f"generation reaches window rung {deepest}, which warmup() "
-          f"does not compile")
+    # any size up to max_slots form under concurrent arrivals) and every
+    # k rung, one tick each over the whole table
     await engine.warmup(prompt_counts=tuple(engine._n_ladder))
     setup_s = time.perf_counter() - started
 

@@ -32,9 +32,9 @@ Checks:
   call whose argument is sliced to a ``len(...)``/``.shape``-derived
   bound (``table[:, :len(pages)]``). The slice width becomes an array
   dimension, so a *live count* — pages held, slots active — mints one
-  executable per distinct value. Slice to a declared ladder rung
-  instead (the page-gather-width idiom in
-  ``GenerationEngine._table_dev``).
+  executable per distinct value. Upload the whole table instead, one
+  static width cached against the table's version (the idiom of
+  ``GenerationEngine._table_dev``), or slice to a declared ladder rung.
 
 Known-jitted callables are resolved module-locally: names bound to
 ``jax.jit(...)`` and functions decorated with ``@jax.jit`` /

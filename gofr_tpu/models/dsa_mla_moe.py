@@ -790,13 +790,12 @@ def decode_step_paged(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
 
 def decode_step(params: Dict[str, Any], cfg: DsaMlaMoeConfig,
                 token: jnp.ndarray, cache: Dict[str, Dict[str, jnp.ndarray]],
-                cache_len: jnp.ndarray, window: Optional[int] = None):
+                cache_len: jnp.ndarray):
     """One decode step over the dense cache of ``init_cache``: a slot's
     rows are one page of the cache's length, its table the one column
     holding its own index, so the step is ``decode_step_paged``'s
     (a page that long tiles no kernel block: the index scores are
-    gathered). ``window`` (the engine's ladder rung) bounds nothing here:
-    the selection reads what it selects."""
+    gathered)."""
     batch = token.shape[0]
     table = jnp.arange(batch, dtype=jnp.int32)[:, None]
     return decode_step_paged(params, cfg, token, cache,

@@ -192,9 +192,9 @@ def prefill(params: Dict[str, Any], cfg: HcMlaMoeConfig, tokens: jnp.ndarray,
 
 def decode_step(params: Dict[str, Any], cfg: HcMlaMoeConfig,
                 token: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                cache_len: jnp.ndarray, window: Optional[int] = None):
+                cache_len: jnp.ndarray):
     """``mla_moe.decode_step`` around the hyper-connected layer."""
-    return mla_moe.decode_step(params, cfg, token, cache, cache_len, window,
+    return mla_moe.decode_step(params, cfg, token, cache, cache_len,
                                residual=Residual)
 
 

@@ -60,13 +60,12 @@ class LlamaConfig:
     use_flash: bool = False
     # int8 KV cache (ops/quant.quantize_kv): per-(token, head) scales,
     # halving the cache's HBM *footprint* — the capacity lever for longer
-    # contexts / more slots per chip. MEASURED (v5e, 7B geometry,
-    # 2026-07-30): decode is ~12% SLOWER than bf16 through plain XLA —
-    # the int8→bf16 convert does not stay fused into the attention dots,
-    # so the "saved" bytes come back as a materialized converted copy
-    # (bf16 full-window 300 tok/s vs int8 265; window-bounded 366 vs 260
-    # standalone-tick numbers). Default off: use it when the cache must
-    # fit, not to go faster; on the paged path the ragged kernel
+    # contexts / more slots per chip. On the dense path the int8→bf16
+    # convert does not stay fused into the attention dots, so the
+    # "saved" bytes come back as a materialized converted copy (its
+    # speed on the current chip is not measured). Default off: use it
+    # when the cache must fit, not to go faster; on the paged path the
+    # ragged kernel
     # (ops/pallas/ragged_paged_attention) dequantizes in-kernel.
     kv_int8: bool = False
 
@@ -400,10 +399,11 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jnp.ndarray,
 
 def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
                 token: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                cache_len: jnp.ndarray, window: Optional[int] = None
+                cache_len: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], jnp.ndarray]:
     """One decode step. token (B,) int32; returns (logits (B,V), cache,
-    cache_len+1). Static shapes: scatters into the cache at cache_len.
+    cache_len+1). Static shapes: scatters into the cache at cache_len;
+    attention reads each row's whole cache, masked at cache_len.
 
     The full stacked cache is a scan CARRY, not an xs→ys pair: scanning
     the cache as xs makes XLA materialize a fresh stacked ys every step —
@@ -413,15 +413,8 @@ def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     end-to-end at 7B geometry, within 6% of a no-scatter ceiling). The
     attention still runs over (old cache + current K/V) via
     decode_attention_cached with the scatter off its critical path.
-
-    ``window`` (static) bounds the attention read to the cache's first
-    ``window`` positions — fill-bounded decode: the caller guarantees
-    every *active* row's cache_len < window, picks the executable from a
-    small window ladder, and the dead tail of the static cache is never
-    streamed from HBM (it dominates early-fill decode traffic). The
-    scatter still targets the full cache, so growing past a window rung
-    just switches executables, never moves data. With ``cfg.kv_int8`` the
-    cache is int8 + scale planes; the new row quantizes before scatter.
+    With ``cfg.kv_int8`` the cache is int8 + scale planes; the new row
+    quantizes before scatter.
     """
     b = token.shape[0]
     cos, sin = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
@@ -437,8 +430,6 @@ def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
         layer, idx = layer_and_idx
         views = [lax.dynamic_index_in_dim(c, idx, 0, keepdims=False)
                  for c in caches]
-        if window is not None:
-            views = [v[:, :window] for v in views]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
         k_scale = views[2] if int8 else None
@@ -491,10 +482,9 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     token (B,) int32; ``pool`` holds the shared page-pool leaves
     (L, num_pages, page, Hkv, Dh) (+ int8 scale planes); ``page_table``
     (B, P) int32 maps each slot's sequence pages to pool rows, with
-    ``num_pages`` as the unallocated sentinel — P is a *static* ladder
-    rung, so one executable serves every fill level just like the dense
-    cache, and P plays the attention-window role (only the table's pages
-    are gathered/streamed, not a max_len tail). ``active`` (B,) bool
+    ``num_pages`` as the unallocated sentinel — P is static (the engine
+    passes the whole table), so one executable serves every fill level
+    just like the dense cache. ``active`` (B,) bool
     gates the append: the pool is shared, so an inactive slot must not
     scatter — its row could have been freed and reallocated to another
     stream while a pipelined tick was in flight — hence its destination
@@ -514,8 +504,8 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
     for the fused Pallas ragged kernel
     (ops.pallas.ragged_paged_decode_attention): no (B, P*page) view is
     materialized — the kernel walks the slot's actual pages via scalar
-    prefetch — so ``page_table`` may carry the slot's *full* table (no
-    ladder rung slicing) and int8 dequant happens in-kernel from the
+    prefetch — so a dead table column costs nothing, and int8 dequant
+    happens in-kernel from the
     scale planes. The kernel takes the carried leaves WHOLE plus the
     scan's layer index and picks the layer in its page copies: a
     ``dynamic_index_in_dim`` here cannot fuse into a pallas_call the way
@@ -586,7 +576,7 @@ def decode_step_paged(params: Dict[str, Any], cfg: LlamaConfig,
 
 def verify_step(params: Dict[str, Any], cfg: LlamaConfig,
                 tokens: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                cache_len: jnp.ndarray, window: Optional[int] = None
+                cache_len: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Speculative verify forward: score G tokens per row in ONE step.
 
@@ -622,8 +612,6 @@ def verify_step(params: Dict[str, Any], cfg: LlamaConfig,
         layer, idx = layer_and_idx
         views = [lax.dynamic_index_in_dim(c, idx, 0, keepdims=False)
                  for c in caches]
-        if window is not None:
-            views = [v[:, :window] for v in views]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(layer, h, cfg, cos, sin, positions)
         k_scale = views[2] if int8 else None

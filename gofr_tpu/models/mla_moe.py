@@ -615,19 +615,17 @@ def _walk_live_pages(q, rows, page_table, lengths, layer, *, rank: int,
 
 def decode_step(params: Dict[str, Any], cfg: MlaMoeConfig,
                 token: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                cache_len: jnp.ndarray, window: Optional[int] = None,
-                residual=Residual
+                cache_len: jnp.ndarray, residual=Residual
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], jnp.ndarray]:
-    """One decode step over the dense latent cache (L, B, T, cache_row).
-    ``window`` statically bounds the rows attention reads."""
+    """One decode step over the dense latent cache (L, B, T, cache_row):
+    attention reads a row's whole cache, masked at ``cache_len``."""
     batch = jnp.arange(token.shape[0])
 
     def append(leaf, idx, row):
         return leaf.at[idx, batch, cache_len].set(row, mode="drop")
 
     def view_of(leaf, idx):
-        view = lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False)
-        return view if window is None else view[:, :window]
+        return lax.dynamic_index_in_dim(leaf, idx, 0, keepdims=False)
 
     logits, leaf, _ = _decode(params, cfg, token, cache["ckv"], cache_len,
                               None, append, _gathered(cfg, cache_len, view_of),

@@ -256,7 +256,7 @@ def prefill(params: Dict[str, Any], cfg: MoEConfig, tokens: jnp.ndarray,
 
 def decode_step(params: Dict[str, Any], cfg: MoEConfig,
                 token: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                cache_len: jnp.ndarray, window: Optional[int] = None
+                cache_len: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], jnp.ndarray]:
     """One decode step: token (B,) → (logits (B, V), cache, cache_len+1).
     Cache rides the scan carry and the scatter writes only the B new
@@ -274,9 +274,6 @@ def decode_step(params: Dict[str, Any], cfg: MoEConfig,
         layer, idx = layer_and_idx
         k_view = lax.dynamic_index_in_dim(ck, idx, 0, keepdims=False)
         v_view = lax.dynamic_index_in_dim(cv, idx, 0, keepdims=False)
-        if window is not None:
-            k_view = k_view[:, :window]
-            v_view = v_view[:, :window]
         h = rms_norm(x, layer["attn_norm"], base.norm_eps)
         q, k, v = llama_mod._qkv(layer, h, base, cos, sin, positions)
         attn = decode_attention_cached(q, k_view, v_view, k[:, 0],

@@ -468,18 +468,14 @@ def _decode(params, cfg: SwaMoeConfig, token, cache, cache_len, active,
 
 def decode_step(params: Dict[str, Any], cfg: SwaMoeConfig,
                 token: jnp.ndarray, cache: Dict[str, Dict[str, jnp.ndarray]],
-                cache_len: jnp.ndarray, window: Optional[int] = None):
-    """One decode step over the dense cache of ``init_cache``. ``window``
-    statically bounds the rows attention reads (the engine's ladder
-    rung, every kind's; a window kind's own bound is a mask beside
-    it)."""
+                cache_len: jnp.ndarray):
+    """One decode step over the dense cache of ``init_cache``: a kind's
+    whole cache row, a window kind's own bound a mask beside it."""
     batch = jnp.arange(token.shape[0])
 
     def attend_kind(kind, leaves, idx, q, k_new, v_new, start):
         views = [lax.dynamic_index_in_dim(leaves[name], idx, 0,
                                           keepdims=False) for name in "kv"]
-        if window is not None:
-            views = [view[:, :window] for view in views]
         return decode_attention_cached(q, *views, k_new, v_new, cache_len,
                                        start=start)
 

@@ -4,10 +4,10 @@ Attention", arxiv 2604.15464).
 
 The gather formulation (ops/attention.paged_decode_attention) simulates
 raggedness: it materializes a dense ``(B, P*page)`` KV view per layer
-with ``P`` a static ladder rung, so every width rung is a separately
-compiled executable (graftcheck's GT003 page-width hazard class exists
-because of it) and HBM bandwidth is spent rebuilding views the kernel
-could walk in place. This kernel walks them in place:
+over the whole static table of ``P`` columns, live or not (graftcheck's
+GT003 page-width hazard is the class a live-count ``P`` would open), so
+HBM bandwidth is spent rebuilding views the kernel could walk in place.
+This kernel walks them in place:
 
 - Grid ``(slot,)``: **one program a slot, which walks that slot's live
   pages and nothing else.** The page table, the per-slot fill and the

@@ -159,7 +159,7 @@ class ExecutableLedger:
     """Device-seconds per compiled executable *family* — the answer to
     "which executable is burning the device time, and how far from
     roofline is it?". A family is the stable human-readable key of one
-    compiled program shape (``decode_paged[k=8,pw=16]``,
+    compiled program shape (``decode_paged[k=8]``,
     ``prefill[nb=4,b=64]``, executor ``b32`` buckets); rows accumulate
     device-seconds, dispatch counts, and (when the caller knows them)
     executed FLOPs, from which the snapshot derives achieved FLOP/s and

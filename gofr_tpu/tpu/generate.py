@@ -31,7 +31,7 @@ across v5e-8, KV-cache in HBM ... continuous batching on the generate loop"
   are unchanged; per-slot ``inflight`` accounting keeps speculative
   depth from overshooting any budget.
 - Inactive slots are frozen in the decode executable (cache_len does not
-  advance), so an idle slot's window never grows between requests.
+  advance), so an idle slot's fill never grows between requests.
 - Per-slot host state (remaining budget, eos, emitted tokens, generation
   counter) stays in numpy; device state is (cache, cache_len, last_token)
   plus per-slot sampling state (temperature, top_k, top_p, PRNG key —
@@ -55,7 +55,7 @@ from collections import deque
 from contextlib import contextmanager, nullcontext
 from functools import partial
 from types import SimpleNamespace
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -326,7 +326,7 @@ class _Slot:
         self.temperature = 0.0   # host copy: picks greedy vs sampled tick
         self.fill = 0         # host mirror of device cache_len (exact: set
                               # at admission, +k per participated tick) —
-                              # picks the attention-window rung
+                              # what the page covers and row counts read
         self.record: Optional[RequestRecord] = None  # flight recorder entry
         self.req_span: Optional[Span] = None   # request span (link target)
         self.phase_span: Optional[Span] = None  # open prefill/decode span
@@ -535,25 +535,23 @@ class _Timeline:
     K of each tick summed: ``decode_steps`` counts ticks) and
     ``tick_tokens`` (tokens the ticks published to live requests) the
     decode ticks; ``prefill_s``, ``prefill_groups``; ``spec_s``;
-    ``ticks_by_width`` a tick's table or window width (``"full"``: the
-    dense cache's whole length) to ticks: which rung the ladder took;
     ``rows_live`` / ``rows_gathered`` (paged gather path only, a layer):
     cache rows under the participants' lengths, the new row with them,
-    over the rows ``max_slots x width x kv_page`` a step's page gather
-    brings in; ``stalled_slot_s``: prefill intervals times the decoding
-    slots each stopped (``GenerationEngine._stall``)."""
+    over the rows ``max_slots x pages_per_slot x kv_page`` a step's page
+    gather brings in (the whole table); ``stalled_slot_s``: prefill
+    intervals times the decoding slots each stopped
+    (``GenerationEngine._stall``)."""
 
     COUNTS = ("busy_s", "tick_s", "ticks", "tick_steps", "tick_tokens",
               "prefill_s", "prefill_groups", "spec_s", "rows_live",
               "rows_gathered", "stalled_slot_s")
-    __slots__ = COUNTS + ("ticks_by_width", "longest", "_landed_at")
+    __slots__ = COUNTS + ("longest", "_landed_at")
 
     def __init__(self):
         self.busy_s = self.tick_s = self.prefill_s = self.spec_s = 0.0
         self.stalled_slot_s = 0.0
         self.ticks = self.tick_steps = self.tick_tokens = 0
         self.prefill_groups = self.rows_live = self.rows_gathered = 0
-        self.ticks_by_width: Dict[str, int] = {}
         self.longest: Dict[str, Dict[str, Any]] = {}
         self._landed_at = 0.0
 
@@ -570,12 +568,10 @@ class _Timeline:
         elif entry.kind == "spec":
             self.spec_s += interval
         else:
-            steps, width, live, gathered = entry.work
+            steps, live, gathered = entry.work
             self.tick_s += interval
             self.ticks += 1
             self.tick_steps += steps
-            self.ticks_by_width[width] = \
-                self.ticks_by_width.get(width, 0) + 1
             self.rows_live += live
             self.rows_gathered += gathered
         longest = self.longest.get(entry.kind)
@@ -586,7 +582,6 @@ class _Timeline:
 
     def stats(self) -> Dict[str, Any]:
         out = {name: getattr(self, name) for name in self.COUNTS}
-        out["ticks_by_width"] = dict(self.ticks_by_width)
         out["longest"] = {kind: dict(entry)
                           for kind, entry in self.longest.items()}
         return out
@@ -767,30 +762,15 @@ class GenerationEngine:
                     "paged_kv: the shared page pool cannot shard pages "
                     "over dp (any slot may gather any page); use a "
                     "tp-only mesh")
-        # attention-window ladder (fill-bounded decode): rungs double from
-        # 128 up to max_len; a tick attends only the smallest rung covering
-        # every participating slot's fill + k, so early-fill decode never
-        # streams the dead tail of the static cache from HBM. The top rung
-        # is encoded as window=None (identical executable to the
-        # pre-ladder design). On the paged path the rung is demoted to a
-        # page-gather width bound (table columns = rung // kv_page): paging
-        # already keeps dead HBM out of the tick, superseding windowing as
-        # the HBM relief mechanism.
-        if self.paged and window_ladder is True and logger is not None:
-            logger.warn(
-                "attention_window ladder requested together with paged_kv: "
-                "paging supersedes windowing as the HBM relief mechanism; "
-                "the window rung now only bounds the per-tick page-gather "
-                "width")
-        window_ladder = True if window_ladder is None else bool(window_ladder)
-        self._window_ladder: List[Optional[int]] = [None]
-        if window_ladder and self.max_len > 128:
-            rungs = []
-            w = 128
-            while w < self.max_len:
-                rungs.append(w)
-                w *= 2
-            self._window_ladder = rungs + [None]
+        # every decode tick reads the whole page table (paged) or cache
+        # row (dense). window_ladder= is taken only in its no-ladder form,
+        # for benchmark/configs/xing4-29b.json and dots3-note-ep32.json,
+        # which still pass it
+        if window_ladder not in (None, False):
+            raise ValueError(
+                f"window_ladder={window_ladder!r}: the attention-window "
+                f"ladder was removed; every decode tick reads the whole "
+                f"page table or cache row (pass None or False)")
         # admission-count ladder: 1,2,4,... up to max_slots. max_slots is
         # always the top rung even when it is not a power of two (e.g.
         # GENERATE_SLOTS=12 or dp-rounding 9→12): _admit_pending can group
@@ -935,11 +915,10 @@ class GenerationEngine:
                     kind.num_pages
                     for kind in self._pool.kinds.values()) // 8))
             # per-slot page tables, one a cache kind (host master copies;
-            # device uploads are cached per gather-width and invalidated
-            # by version bumps)
+            # the device upload is cached against _table_version)
             self._fresh_tables()
             self._table_version = 0
-            self._table_cache: Dict[int, Tuple[int, Any]] = {}
+            self._table_cache: Optional[Tuple[int, Any]] = None
             self._page_stalls = 0
             # shared-pool reset fan-out: when a co-resident engine rebuilds
             # the pool, this engine's page ids dangle — _on_pool_reset
@@ -963,8 +942,6 @@ class GenerationEngine:
         # it); "on" forces it everywhere — interpret mode off-TPU, which
         # is how CPU tier-1 tests and benches exercise the kernel path,
         # and the compiler's own error on a TPU geometry it rejects.
-        # Active ragged retires the gather-width ladder: page tables ship
-        # whole, so decode executables key on (k, sampled) alone.
         self.ragged_attn = str(ragged_attn).lower()
         if self.ragged_attn not in ("auto", "on", "off"):
             raise ValueError(
@@ -1101,12 +1078,9 @@ class GenerationEngine:
         self._insert_fns: Dict[Tuple[int, int], Any] = {}
         # paged-path insert keyed (nb, bucket, plen)
         self._insert_paged_fns: Dict[Tuple[int, int, int], Any] = {}
-        # every decode tick, keyed (k, sampled, biased, width): width is
-        # the dense window rung (None: the whole cache) or the paged
-        # page-gather width (_tick_width). Unconstrained serving never
-        # builds a biased key, so its warm keys are its own
-        self._tick_fns: Dict[Tuple[int, bool, bool, Optional[int]],
-                             Any] = {}
+        # every decode tick, keyed (k, sampled, biased). Unconstrained
+        # serving never builds a biased key, so its warm keys are its own
+        self._tick_fns: Dict[Tuple[int, bool, bool], Any] = {}
         # prefix KV reuse (ISSUE 4): page-granular prefix store + the
         # suffix-only prefill/insert executable families keyed
         # (nb, prefix_pages, suffix_bucket). The prefix-pages ladder
@@ -1115,9 +1089,9 @@ class GenerationEngine:
         self._suffix_prefill_fns: Dict[Tuple[int, int, int], Any] = {}
         self._suffix_insert_fns: Dict[Tuple[int, int, int], Any] = {}
         # speculative-decode families: one fused draft-propose/target-verify
-        # executable per (γ rung, width) — the "(nb, γ) verify rung" of
-        # ISSUE 7 — plus KV-only draft prefill/insert per (nb, bucket)
-        self._spec_fns: Dict[Tuple[int, Optional[int]], Any] = {}
+        # executable per γ rung — the "(nb, γ) verify rung" of ISSUE 7 —
+        # plus KV-only draft prefill/insert per (nb, bucket)
+        self._spec_fns: Dict[int, Any] = {}
         self._draft_prefill_fns: Dict[Tuple[int, int], Any] = {}
         self._draft_insert_fns: Dict[Tuple[int, int], Any] = {}
         # disaggregated serving (ISSUE 8): page-adoption scatter keyed by
@@ -1393,12 +1367,6 @@ class GenerationEngine:
             self._note_compile("suffix_insert", (nb, p, lb))
         return fn
 
-    def _tick_width(self, window: Optional[int]) -> Optional[int]:
-        """The width a tick's executable is keyed by, from a window rung:
-        the rung itself on the dense cache, the page-gather width on the
-        pool."""
-        return self._pick_page_width(window) if self.paged else window
-
     def _tick_operands(self, sampled: bool, biased: bool) -> Tuple[str, ...]:
         """A decode tick's operands in call order. The order is written
         here and nowhere else: ``_tick_fn`` unpacks and donates by it,
@@ -1409,34 +1377,26 @@ class GenerationEngine:
                 + (("bias",) if biased else ())
                 + (("temps", "top_ks", "top_ps", "keys") if sampled else ()))
 
-    def _tick_names(self, k: int, biased: bool,
-                    width: Optional[int]) -> Tuple[str, str]:
+    def _tick_names(self, k: int, biased: bool) -> Tuple[str, str]:
         """(compile-ledger kind, roofline-ledger family) of a tick:
-        ``decode_paged_bias``, ``decode_paged_bias[k=1,pw=8]``. Device
-        time lands on the granularity the jit cache is keyed by."""
+        ``decode_paged_bias``, ``decode_paged_bias[k=1]``. Device time
+        lands on the granularity the jit cache is keyed by."""
         kind = ("decode" + ("_paged" if self.paged else "")
                 + ("_bias" if biased else ""))
-        at = (f"pw={width}" if self.paged
-              else f"w={width or self.max_len}")
-        return kind, f"{kind}[k={k},{at}]"
+        return kind, f"{kind}[k={k}]"
 
-    def _tick_fn(self, k_steps: int, sampled: bool, biased: bool,
-                 width: Optional[int]):
+    def _tick_fn(self, k_steps: int, sampled: bool, biased: bool):
         """Decode-tick executable: ``k_steps`` fused steps in one
         ``lax.scan``, built from three parts (``_tick_program``).
 
-        The step: on the dense cache ``decode_step`` with ``width`` (a
-        rung of the attention-window ladder, None = full) statically
-        bounding the cache positions attention streams; on the pool
-        (ISSUE 6) ``decode_step_paged`` through a ``(max_slots, width)``
-        page-table slice, ``width`` being the page-gather width — the
-        window rung demoted to ``ceil(rung / kv_page)`` table columns, a
-        static ladder value. With the ragged kernel active ``width`` is
-        always ``pages_per_slot`` (the ladder is retired) and the step
-        attends pool pages in place. Inactive rows scatter to the
-        sentinel page id and drop. A module that declares
-        ``STEP_COUNTERS`` returns their sums over the K steps as one
-        more output; the others' ticks are the program they always were.
+        The step: on the dense cache ``decode_step`` over each slot's
+        whole cache row; on the pool (ISSUE 6) ``decode_step_paged``
+        through the whole ``(max_slots, pages_per_slot)`` page table
+        (with the ragged kernel active the step attends pool pages in
+        place). Inactive rows scatter to the sentinel page id and drop.
+        A module that declares ``STEP_COUNTERS`` returns their sums over
+        the K steps as one more output; the others' ticks are the
+        program they always were.
 
         The choice: argmax (the greedy serving hot path), or
         ``sample_batch``, which additionally carries per-slot (temps,
@@ -1455,7 +1415,7 @@ class GenerationEngine:
 
         Operands: ``_tick_operands``. Outputs: (tokens (K, B), cache,
         cache_len[, keys][, counters])."""
-        key = (k_steps, sampled, biased, width)
+        key = (k_steps, sampled, biased)
         if key not in self._tick_fns:
             if key in self._decided:
                 # the steady tick was compiled when it decided the
@@ -1465,15 +1425,15 @@ class GenerationEngine:
                 tick, donated = self._tick_program(*key)
                 self._tick_fns[key] = self._jit(tick,
                                                 donate_argnums=donated)
-            self._note_compile(self._tick_names(k_steps, biased, width)[0],
-                               (k_steps, sampled, width))
+            self._note_compile(self._tick_names(k_steps, biased)[0],
+                               (k_steps, sampled))
         return self._tick_fns[key]
 
     def _lay_out_weights(self) -> None:
         """Put each leaf of ``self.params`` into the layout the steady
         decode tick reads it in, once, before anything is compiled.
 
-        The steady tick (greedy, the widest K, the full width) is
+        The steady tick (greedy, the widest K) is
         compiled on shapes alone with the weights' layouts left to the
         compiler (``weight_formats``); each leaf whose layout differs is
         put into the one the tick stated, and every executable built
@@ -1502,8 +1462,7 @@ class GenerationEngine:
                 leaf.shape, leaf.dtype,
                 sharding=self._placement or leaf.sharding)
 
-        key = (self._k_ladder[-1], False, False, self._tick_width(None))
-        width = key[-1]
+        key = (self._k_ladder[-1], False, False)
         tick, donated = self._tick_program(*key)
         row = (self.max_slots,)
         operands = dict(
@@ -1512,7 +1471,7 @@ class GenerationEngine:
             cache=jax.tree.map(abstract, self._kv),
             table=(jax.tree.map(
                 lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype),
-                self._empty_table(width)) if self.paged else None),
+                self._empty_table()) if self.paged else None),
             cache_len=jax.ShapeDtypeStruct(row, jnp.int32),
             active=jax.ShapeDtypeStruct(row, jnp.bool_))
         formats, temp_bytes, compiled = weight_formats(
@@ -1548,8 +1507,7 @@ class GenerationEngine:
                         wanted.layout, put.format.layout)
         self.params = treedef.unflatten(leaves)
 
-    def _tick_program(self, k_steps: int, sampled: bool, biased: bool,
-                      width: Optional[int]):
+    def _tick_program(self, k_steps: int, sampled: bool, biased: bool):
         """``_tick_fn``'s tick before it is jitted, and the positions of
         the operands it donates: what ``_lay_out_weights`` compiles with
         the weights' layouts left open."""
@@ -1575,8 +1533,7 @@ class GenerationEngine:
         else:
             def step(params, token, cache, table, cache_len, active):
                 return llama.decode_step(
-                    params, cfg, token, cache, cache_len,
-                    window=width) + ((),)
+                    params, cfg, token, cache, cache_len) + ((),)
 
         def tick(*operands):
             o = dict(zip(names, operands))
@@ -1623,9 +1580,9 @@ class GenerationEngine:
         donated = ("cache", "cache_len") + (("keys",) if sampled else ())
         return tick, tuple(names.index(n) for n in donated)
 
-    def _run_tick(self, k: int, sampled: bool, width: Optional[int], active,
-                  bias=None, state=None):
-        """Call the tick ``(k, sampled, bias given, width)`` and write its
+    def _run_tick(self, k: int, sampled: bool, active, bias=None,
+                  state=None):
+        """Call the tick ``(k, sampled, bias given)`` and write its
         outputs back: the one place a decode executable is called.
         ``active`` is the device mask (int32 beside a ``bias``, see
         ``_tick_fn``). Without ``state`` the tick runs on the engine's own
@@ -1637,14 +1594,13 @@ class GenerationEngine:
         nothing of the engine's. Returns (tokens (K, B) on the device,
         the step counters' sums as a 1-tuple, or ())."""
         biased = bias is not None
-        fn = self._tick_fn(k, sampled, biased, width)
+        fn = self._tick_fn(k, sampled, biased)
         own = state is None
         s = self if own else state
         with self._pool.lock if own and self.paged else nullcontext():
             table = None
             if self.paged:
-                table = (self._table_dev(width) if own
-                         else self._empty_table(width))
+                table = self._table_dev() if own else self._empty_table()
             operands = dict(
                 params=self.params, token=s.last_token, cache=s._kv,
                 table=table, cache_len=s.cache_len, active=active, bias=bias,
@@ -1789,7 +1745,7 @@ class GenerationEngine:
             self._note_compile("draft_insert", (nb, lb))
         return fn
 
-    def _spec_fn(self, g: int, width: Optional[int]):
+    def _spec_fn(self, g: int):
         """Fused draft-propose/target-verify tick (ISSUE 7): the draft
         scans ``g + 1`` decode steps proposing ``g`` tokens (the extra
         step writes the last proposal's KV so a full acceptance leaves the
@@ -1805,23 +1761,19 @@ class GenerationEngine:
         ``_tick_fn``: their garbage KV writes land at frozen positions
         that are always overwritten before they can be attended.
 
-        The verify step is the part that differs by cache kind (``width``
-        as in ``_tick_fn``). Dense: ``verify_step`` over the window rung,
-        which bounds the draft's steps too. Paged: the draft stays dense
-        (the draft model is small, a dense row per slot keeps it
+        The verify step is the part that differs by cache kind. Dense:
+        ``verify_step`` over the whole cache row. Paged: the draft stays
+        dense (the draft model is small, a dense row per slot keeps it
         independent of the target's paging) and the target verifies
-        through the page table via ``verify_step_paged`` — inactive rows
-        scatter to the sentinel page and drop; the table's ``width`` must
-        cover fill + g + 1 (``_pick_window`` → ``_pick_page_width``
-        guarantees it; a too-narrow table would silently clamp the
-        per-position gather).
+        through the whole page table via ``verify_step_paged`` — inactive
+        rows scatter to the sentinel page and drop.
 
         Contract: (params, dparams, last_token, cache, dcache, [table,]
         cache_len, active, temps, top_ks, top_ps, keys) → (tokens
         (g+1, B), accepts (B,), cache, dcache, new_len, new_last,
         new_keys); row b commits ``accepts[b] + 1`` tokens and cache_len
         advances by the same."""
-        if (g, width) not in self._spec_fns:
+        if g not in self._spec_fns:
             jax, jnp, llama, cfg = (self._jax, self._jnp, self._llama,
                                     self.cfg)
             dcfg = self.draft_cfg
@@ -1832,18 +1784,15 @@ class GenerationEngine:
                                                speculative_accept)
             if paged:
                 step_kw = {"ragged": True} if self._ragged else {}
-                draft_kw = {}
 
                 def verify(params, tokens, pool, cache_len, active, table):
                     return llama.verify_step_paged(
                         params, cfg, tokens, pool, table, cache_len, active,
                         **step_kw)
             else:
-                draft_kw = {"window": width}
-
                 def verify(params, tokens, cache, cache_len, active):
                     return llama.verify_step(
-                        params, cfg, tokens, cache, cache_len, window=width)
+                        params, cfg, tokens, cache, cache_len)
 
             def spec_tick(params, dparams, last_token, cache, dcache,
                           *operands):
@@ -1857,7 +1806,7 @@ class GenerationEngine:
                 def draft_step(carry, step_keys):
                     token, dcache, dlen = carry
                     logits, dcache, new_len = llama.decode_step(
-                        dparams, dcfg, token, dcache, dlen, **draft_kw)
+                        dparams, dcfg, token, dcache, dlen)
                     q_logp = filtered_log_probs_batch(logits, temps,
                                                       top_ks, top_ps)
                     choice = jax.vmap(jax.random.categorical)(
@@ -1890,20 +1839,19 @@ class GenerationEngine:
                         new_keys)
 
             # cache, dcache, cache_len, keys: the table shifts the last two
-            self._spec_fns[(g, width)] = self._jit(
+            self._spec_fns[g] = self._jit(
                 spec_tick, donate_argnums=(3, 4, 5 + paged, 10 + paged))
-            self._note_compile("spec_paged" if paged else "spec",
-                               (g, width))
-        return self._spec_fns[(g, width)]
+            self._note_compile("spec_paged" if paged else "spec", (g,))
+        return self._spec_fns[g]
 
-    def _run_spec(self, g: int, width: Optional[int], active):
-        """Call the speculative tick ``(g, width)`` on the engine's slot
+    def _run_spec(self, g: int, active):
+        """Call the speculative tick of rung ``g`` on the engine's slot
         state and write its outputs back (``_run_tick``'s twin: the pool
         lock and the slots' table on the paged path). Returns (tokens
         (g+1, B), accepts (B,)) on the device."""
-        fn = self._spec_fn(g, width)
+        fn = self._spec_fn(g)
         with self._pool.lock if self.paged else nullcontext():
-            table = [self._table_dev(width)] if self.paged else []
+            table = [self._table_dev()] if self.paged else []
             (toks_dev, accepts_dev, self._kv, self._draft_cache,
              self.cache_len, self.last_token, self.sample_keys) = fn(
                 self.params, self.draft_params, self.last_token, self._kv,
@@ -1926,43 +1874,28 @@ class GenerationEngine:
         self._tables = {name: np.full(shape, kind.num_pages, np.int32)
                         for name, kind in self._pool.kinds.items()}
 
-    def _empty_table(self, width: int):
-        """A device table of ``width`` columns that holds no page: what
-        the warm-ups and the deciding compile run a tick with."""
+    def _empty_table(self):
+        """A device table that holds no page: what the warm-ups and the
+        deciding compile run a tick with."""
         jnp = self._jnp
-        shape = (self.max_slots, width)
+        shape = (self.max_slots, self.pages_per_slot)
         return self._pool.as_leaves(
             {name: jnp.full(shape, kind.num_pages, jnp.int32)
              for name, kind in self._pool.kinds.items()})
 
-    def _table_dev(self, pw: int):
-        """Device copy of the first ``pw`` page-table columns (of each
-        kind's table), cached per gather width and invalidated by
-        host-table version bumps. ``pw`` is always ladder-derived (window
-        rung // kv_page, or the full pages_per_slot) — never a live page
-        count — so the executable set stays bounded (graftcheck GT003
-        page-width rule)."""
-        cached = self._table_cache.get(pw)
+    def _table_dev(self):
+        """Device copy of the whole page table (of each kind's table),
+        cached against ``_table_version``: every tick reads all
+        ``pages_per_slot`` columns, never a live page count, so one
+        program serves every fill (graftcheck GT003 page-width rule)."""
+        cached = self._table_cache
         if cached is not None and cached[0] == self._table_version:
             return cached[1]
         dev = self._pool.as_leaves(
-            {name: self._jnp.asarray(table[:, :pw])
+            {name: self._jnp.asarray(table)
              for name, table in self._tables.items()})
-        self._table_cache[pw] = (self._table_version, dev)
+        self._table_cache = (self._table_version, dev)
         return dev
-
-    def _pick_page_width(self, rung: Optional[int]) -> int:
-        """Window rung -> page-gather width (table columns). None (full
-        window) gathers every column.
-
-        With the ragged kernel active the ladder is retired: the kernel
-        walks only each slot's live pages via scalar prefetch, so a
-        narrower table buys nothing — every tick ships the full-width
-        table and the executable set collapses to one per (k, γ) family
-        (the GT003 recompile class the rungs existed to bound)."""
-        if self._ragged or rung is None:
-            return self.pages_per_slot
-        return min(self.pages_per_slot, -(-rung // self.kv_page))
 
     def _resolve_ragged(self) -> Tuple[bool, str]:
         """(run the ragged kernel?, why) — the one place the decode
@@ -2046,61 +1979,21 @@ class GenerationEngine:
             return "dense"
         return "ragged" if self._ragged else "gather"
 
-    def _startup_window_rungs(self, ks: List[int]) -> List[Optional[int]]:
-        """Window rungs reachable right after startup: every rung up to and
-        including the one covering the largest prompt bucket + the largest
-        fused-step count (a fresh prompt can land its first tick on any of
-        these). Deeper rungs compile lazily off-loop as generations grow
-        past them."""
-        if len(self._window_ladder) == 1:
-            return list(self._window_ladder)
-        max_k = max(ks) if ks else 1
-        deepest = max(self.prompt_buckets) if self.prompt_buckets else 1
-        reach = self._pick_window([deepest], max_k)
-        rungs: List[Optional[int]] = []
-        for w in self._window_ladder:
-            rungs.append(w)
-            if w == reach:
-                break
-        return rungs
-
-    def _pick_window(self, fills: List[int], k: int) -> Optional[int]:
-        """Smallest window rung covering every participating slot's fill
-        plus the k fused steps (None = full cache)."""
-        needed = max(fills) + k if fills else k
-        for rung in self._window_ladder:
-            if rung is None or rung >= needed:
-                return rung
-        return None
-
     async def warmup(self, prompt_counts: Tuple[int, ...] = (1,),
                      ks: Optional[Tuple[int, ...]] = None,
                      sampling: bool = False,
-                     windows: Union[Tuple[Optional[int], ...], str,
-                                    None] = None
-                     ) -> None:
+                     windows: Optional[str] = None) -> None:
         """Pre-compile the decode ladder and prefill/insert executables so
         the serving path never traces (executor.warmup analog). ``ks``
         restricts which decode rungs to precompile (default: the whole
         ladder); an unwarmed rung still compiles lazily off-loop if the
         scheduler ever picks it. ``sampling=True`` additionally warms the
-        sampled decode variants (temperature/top-k/top-p requests).
+        sampled decode variants (temperature/top-k/top-p requests): one
+        tick a k rung, two with ``sampling``, whatever ``max_len`` is.
 
-        ``windows`` selects which attention-window rungs to warm:
-
-        - ``None`` (default): only the rungs reachable at startup — every
-          rung up to and including the one covering the largest prompt
-          bucket (a fresh prompt's first tick can land on any of those).
-          A long generation ascends past these and compiles the next rung
-          lazily off-loop; the alternative (warming the full k x window
-          cross-product) multiplies startup compiles by the full ladder
-          depth (7x at max_len=8192), which is the wrong default at 7B
-          scale.
-        - ``"all"``: the full ladder (opt-in full-matrix warmup).
-        - an explicit tuple: exactly those rungs. Every entry must be a
-          ladder rung (``stats()["window_ladder"]`` lists them,
-          with ``None`` spelled as max_len) — a silent mismatch would warm
-          nothing and push compilation onto the first serving tick.
+        ``windows`` is taken only as ``None`` or ``"all"``, which mean
+        the same: the adapters under ``benchmark/adapters/`` still pass
+        ``"all"``, from when ticks had attention-window rungs to warm.
 
         Must run before ``start()``: warmup mutates cache/cache_len/
         last_token through donated-buffer executables, and racing the
@@ -2120,52 +2013,28 @@ class GenerationEngine:
                     f"warmup ks={unknown or ks} are not k-ladder rungs "
                     f"{self._k_ladder}; nothing would be warmed for them")
             rungs = [k for k in self._k_ladder if k in ks]
-        if windows is None:
-            window_rungs = self._startup_window_rungs(rungs)
-        elif isinstance(windows, str):
-            if windows != "all":
-                raise ValueError(
-                    f"warmup windows={windows!r}: the only string sentinel "
-                    f"is 'all' (full-matrix warmup)")
-            window_rungs = list(self._window_ladder)
-        else:
-            # stats()["window_ladder"] spells the top rung as max_len, so
-            # accept max_len as an alias for the internal None sentinel —
-            # callers can pass the ladder exactly as stats() printed it
-            requested = [None if w == self.max_len else w for w in windows]
-            unknown = [w for w in requested if w not in self._window_ladder]
-            if unknown or not requested:
-                raise ValueError(
-                    f"warmup windows={unknown or list(windows)} are not "
-                    f"window-ladder rungs {self._window_ladder} (max_len="
-                    f"{self.max_len} aliases the None top rung); nothing "
-                    f"would be warmed for them and the first serving tick "
-                    f"would compile on the hot path")
-            window_rungs = [w for w in self._window_ladder if w in requested]
+        if windows not in (None, "all"):
+            raise ValueError(
+                f"warmup windows={windows!r}: the attention-window ladder "
+                f"was removed, every tick reads the whole table; pass "
+                f"None or 'all'")
         if self.logger is not None:
-            n = len(rungs) * len(window_rungs) * (2 if sampling else 1)
             self.logger.info(
                 "engine warmup: compiling %d decode executables "
-                "(ks=%s windows=%s sampling=%s)",
-                n, rungs, window_rungs, sampling)
+                "(ks=%s sampling=%s)",
+                len(rungs) * (2 if sampling else 1), rungs, sampling)
 
         def compile_all():
             active = jnp.zeros((self.max_slots,), bool)
-            # on the pool the window rungs demote to page-gather widths;
-            # dedup keeps the executable count <= the dense ladder's
-            widths = list(dict.fromkeys(
-                self._tick_width(w) for w in window_rungs))
             for k in rungs:
-                for width in widths:
-                    for sampled in (False, True) if sampling else (False,):
-                        self._run_tick(k, sampled, width, active)
+                for sampled in (False, True) if sampling else (False,):
+                    self._run_tick(k, sampled, active)
             if self.spec:
                 # the speculative ladder: one fused draft+verify executable
-                # per (γ rung, window/width). Inactive-row garbage writes
-                # land at frozen positions that every later insert covers.
+                # per γ rung. Inactive-row garbage writes land at frozen
+                # positions that every later insert covers.
                 for g in self._g_ladder:
-                    for width in widths:
-                        self._run_spec(g, width, active)
+                    self._run_spec(g, active)
             for lb in self.prompt_buckets:
                 # rungs over the admission bound are unreachable, and
                 # counts that share a rung warm it once
@@ -3009,11 +2878,11 @@ class GenerationEngine:
         cache (dense) or one dummy page-pool leaf set (paged) at a
         time; on a memory-tight replica, prewarm during a quiet
         window. New prompt buckets are warmed across the whole
-        admission-count ladder and new decode rungs across the whole
-        window/width ladder, greedy and sampled, and in the constrained
-        form where constrained traffic can reach them (every prefill,
-        the k=1 ticks), so an applied move never compiles on the serving
-        path (the zero-serve-time-compiles bar)."""
+        admission-count ladder and new decode rungs greedy and sampled,
+        and in the constrained form where constrained traffic can reach
+        them (every prefill, the k=1 ticks), so an applied move never
+        compiles on the serving path (the zero-serve-time-compiles
+        bar)."""
         buckets, k = self._op_shape_sig(point)
         bad = [b for b in buckets if b > self.max_len]
         if bad or not buckets:
@@ -3077,25 +2946,20 @@ class GenerationEngine:
                      True: (active.astype(jnp.int32),
                             jnp.zeros((self.max_slots, self.cfg.vocab_size),
                                       jnp.float32))}
-            widths = list(dict.fromkeys(
-                self._tick_width(w) for w in self._window_ladder))
             state = None
             for rung in rungs:
-                for width in widths:
-                    for sampled in (False, True):
-                        # a constrained slot rides k=1 ticks only
-                        for biased in (False, True) if rung == 1 else (
-                                False,):
-                            if (rung, sampled, biased, width) \
-                                    in self._tick_fns:
-                                continue
-                            # one dummy serves every tick: each call
-                            # writes its donated leaves back into it
-                            state = state or dummy_state()
-                            mask, bias = masks[biased]
-                            self._run_tick(rung, sampled, width, mask,
-                                           bias=bias, state=state)
-                            compiled += 1
+                for sampled in (False, True):
+                    # a constrained slot rides k=1 ticks only
+                    for biased in (False, True) if rung == 1 else (False,):
+                        if (rung, sampled, biased) in self._tick_fns:
+                            continue
+                        # one dummy serves every tick: each call writes
+                        # its donated leaves back into it
+                        state = state or dummy_state()
+                        mask, bias = masks[biased]
+                        self._run_tick(rung, sampled, mask, bias=bias,
+                                       state=state)
+                        compiled += 1
             return compiled
 
         def compile_warming() -> int:
@@ -3241,7 +3105,6 @@ class GenerationEngine:
             max_slots=self.max_slots, max_len=self.max_len,
             prompt_buckets=buckets, steps_per_tick=k,
             mesh=self.mesh,
-            window_ladder=len(self._window_ladder) > 1,
             model_module=(None if self._llama.__name__.endswith("llama")
                           else self._llama),
             model_name=f"{self.model_name}@shadow",
@@ -3302,8 +3165,9 @@ class GenerationEngine:
                "session_exports": self._session_exports,
                "session_adoptions": self._session_adoptions,
                "max_len": self.max_len,
-               "window_ladder": [w or self.max_len
-                                 for w in self._window_ladder],
+               # what benchmark/adapters/mla_moe.py still reads: one
+               # window a tick, the whole of max_len
+               "window_ladder": [self.max_len],
                "mesh": dict(self.mesh.shape) if self.mesh else None,
                "device_seconds": {
                    f"{model}/{cls}": round(seconds, 6)
@@ -3602,24 +3466,19 @@ class GenerationEngine:
                 "prefill_real_tokens": self._prefill_real_tokens,
             }
         if self.paged:
-            # the page-gather width ladder is the paged path's analogue of
-            # the attention-window ladder: one decode executable per
-            # (k, sampled, biased, width), width always ladder-derived. With the
-            # ragged kernel active the set collapses to the single
-            # full-table width — the width-rung recompile class is gone.
+            # one decode executable per (k, sampled, biased), each over
+            # the whole table
             out["paged_kv"] = {
                 "page_tokens": self.kv_page,
                 "attn_path": self.attn_path,
                 "ragged_attn": self.ragged_attn,
-                "gather_widths": sorted({self._pick_page_width(w)
-                                         for w in self._window_ladder}),
                 "decode_executables": sorted(
                     str(key) for key in self._tick_fns),
                 "pool": self._pool.stats(),
             }
         if self.spec:
             # the speculative executable family is the only NEW compile
-            # surface this subsystem adds: (γ rung × window/width), plus
+            # surface this subsystem adds: one a γ rung, plus
             # draft prefill/insert riding the existing (nb, bucket) grid
             out["speculative"] = {
                 "gamma_ladder": list(self._g_ladder),
@@ -3707,7 +3566,7 @@ class GenerationEngine:
                 self._in_pool_reset = False
             self._fresh_tables()
             self._table_version += 1
-            self._table_cache.clear()
+            self._table_cache = None
             for slot in self._slots:
                 slot.chains = {}
                 slot.nodes = []
@@ -4630,7 +4489,6 @@ class GenerationEngine:
                 sampled = True
             if slot.record is not None:
                 slot.record.rode_batch(len(eligible))
-        window = self._pick_window(fills, k)
         if biased:
             # per-tick grammar masks: every constrained participant's
             # current-state bias row lands in a fresh (max_slots, vocab)
@@ -4651,16 +4509,14 @@ class GenerationEngine:
             self._constrained_ticks += 1
         else:
             mask, bias = self._active_mask(active), None
-        width = self._tick_width(window)
 
         def dispatch():
-            return self._run_tick(k, sampled, width, mask, bias=bias)
+            return self._run_tick(k, sampled, mask, bias=bias)
 
         step_span = self._step_span("tpu.engine.step", snapshot,
-                                    k=k, window=window or self.max_len,
-                                    sampled=sampled, step=self._steps)
+                                    k=k, sampled=sampled, step=self._steps)
         dispatched_at = time.monotonic()
-        if (k, sampled, biased, width) in self._tick_fns:
+        if (k, sampled, biased) in self._tick_fns:
             with self._clock.lap("enqueue"), \
                     self._profile_step("tpu.engine.step"):
                 tokens_dev, counts_dev = dispatch()
@@ -4688,17 +4544,18 @@ class GenerationEngine:
             def fetch(dev=tokens_dev):
                 return np.asarray(dev)
 
-        # what the timeline books when the tick lands: its steps, its
-        # rung, and on the paged gather path the rows under the
-        # participants' lengths (step j of K reads fill + j + 1 a slot)
-        # over the rows its gathers bring in for every slot
+        # what the timeline books when the tick lands: its steps, and on
+        # the paged gather path the rows under the participants' lengths
+        # (step j of K reads fill + j + 1 a slot) over the rows its
+        # gathers bring in for every slot: the whole table
         live = gathered = 0
         if self.attn_path == "gather":
             live = k * sum(fills) + len(fills) * k * (k + 1) // 2
-            gathered = k * self.max_slots * width * self.kv_page
-        work = (k, str(width) if width else "full", live, gathered)
+            gathered = k * self.max_slots * self.pages_per_slot \
+                * self.kv_page
+        work = (k, live, gathered)
         # executable-family name for the roofline ledger (ISSUE 17)
-        family = self._tick_names(k, biased, width)[1]
+        family = self._tick_names(k, biased)[1]
         return _Fetch("tick", snapshot, fetch, dispatched_at,
                       span=step_span, family=family, work=work)
 
@@ -4718,30 +4575,25 @@ class GenerationEngine:
             eligible = covered
         active = np.zeros((self.max_slots,), bool)
         snapshot = []
-        fills = []
         for slot_idx, slot in eligible:
             active[slot_idx] = True
             slot.inflight += g + 1
-            fills.append(slot.fill)
             # conservative fill mirror: assume full acceptance until the
-            # accepts land; the refund keeps window/page covers safe under
+            # accepts land; the refund keeps page covers safe under
             # pipelining (an overestimate can only widen the cover)
             slot.fill += g + 1
             snapshot.append((slot_idx, slot.gen))
             if slot.record is not None:
                 slot.record.rode_batch(len(eligible))
-        window = self._pick_window(fills, g + 1)
         mask = self._active_mask(active)
-        width = self._tick_width(window)
 
         def dispatch():
-            return self._run_spec(g, width, mask)
+            return self._run_spec(g, mask)
 
         step_span = self._step_span("tpu.engine.spec", snapshot,
-                                    gamma=g, window=window or self.max_len,
-                                    step=self._steps)
+                                    gamma=g, step=self._steps)
         dispatched_at = time.monotonic()
-        if (g, width) in self._spec_fns:
+        if g in self._spec_fns:
             with self._clock.lap("enqueue"):
                 pair = dispatch()
         else:
@@ -4760,8 +4612,7 @@ class GenerationEngine:
         def fetch(pair=pair):
             return np.asarray(pair[0]), np.asarray(pair[1])
 
-        family = (f"spec_paged[g={g},pw={width}]" if self.paged
-                  else f"spec[g={g},w={window or self.max_len}]")
+        family = f"spec_paged[g={g}]" if self.paged else f"spec[g={g}]"
         return _Fetch("spec", (snapshot, g), fetch, dispatched_at,
                       span=step_span, family=family)
 
@@ -4933,7 +4784,7 @@ class GenerationEngine:
             "shared kv page pool was reset by a co-resident engine"))
         self._fresh_tables()
         self._table_version += 1
-        self._table_cache.clear()
+        self._table_cache = None
         if self._prefix is not None:
             self._prefix.reset()
 

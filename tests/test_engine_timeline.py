@@ -6,6 +6,7 @@ ticks are in flight; a prefill group's interval is also booked on every
 decoding request it stopped."""
 
 import asyncio
+import re
 from types import SimpleNamespace
 
 import pytest
@@ -78,7 +79,7 @@ def test_intervals_are_disjoint_where_dispatch_to_publish_overlapped():
     from gofr_tpu.tpu.generate import _Timeline
 
     timeline = _Timeline()
-    work = (4, "8", 10, 64)
+    work = (4, 10, 64)
     windows = []
     for i in range(4):
         entry = _entry("tick", 5.0 + i, 15.0 + 10.0 * i, work=work)
@@ -101,7 +102,6 @@ def test_intervals_are_disjoint_where_dispatch_to_publish_overlapped():
     assert stats["spec_s"] == pytest.approx(1.0)
     assert (stats["ticks"], stats["tick_steps"]) == (5, 20)
     assert stats["prefill_groups"] == 1
-    assert stats["ticks_by_width"] == {"8": 5}
     assert (stats["rows_live"], stats["rows_gathered"]) == (50, 320)
     assert stats["longest"]["prefill"]["family"] == "prefill[nb=1,b=8]"
     assert stats["longest"]["tick"]["s"] == pytest.approx(10.0)
@@ -143,9 +143,11 @@ def test_device_time_is_charged_once(served):
 
 
 def test_ticks_are_counted_by_rung_and_by_step(served):
+    """A tick is counted once and its k rung's steps with it; its family
+    names the rung and nothing else (every tick reads the whole table
+    or cache row)."""
     timeline = served.stats["timeline"]
     assert timeline["ticks"] == served.stats["decode_steps"] > 0
-    assert sum(timeline["ticks_by_width"].values()) == timeline["ticks"]
     assert timeline["ticks"] <= timeline["tick_steps"] \
         <= 4 * timeline["ticks"]
     assert timeline["prefill_groups"] == served.stats["prefill_batches"]
@@ -153,15 +155,12 @@ def test_ticks_are_counted_by_rung_and_by_step(served):
     tokens = sum(r["tokens"] for r in served.records)
     assert len(served.records) == 8
     assert timeline["tick_tokens"] == tokens - 8
-    engine = served.engine
-    if served.path == "dense":
-        widths = {str(w) if w else "full" for w in engine._window_ladder}
-    elif served.path == "ragged":
-        widths = {str(engine.pages_per_slot)}
-    else:
-        widths = {str(engine._pick_page_width(w))
-                  for w in engine._window_ladder}
-    assert set(timeline["ticks_by_width"]) <= widths
+    ticks = [row["family"] for row in
+             served.engine.exec_ledger.snapshot(limit=64)["top"]
+             if row["family"].startswith("decode")]
+    assert timeline["longest"]["tick"]["family"] in ticks
+    for family in ticks:
+        assert re.fullmatch(r"decode(_paged)?\[k=[124]\]", family), family
     for kind in ("tick", "prefill"):
         longest = timeline["longest"][kind]
         assert 0.0 < longest["s"] <= timeline[kind + "_s"]
@@ -175,10 +174,11 @@ def test_rows_are_counted_on_the_gather_path_only(served):
         assert timeline["rows_live"] == timeline["rows_gathered"] == 0
         return
     assert 0 < timeline["rows_live"] <= timeline["rows_gathered"]
-    # a step gathers max_slots x width x page rows whoever is live
+    # a step gathers the whole table, max_slots x pages_per_slot x page
+    # rows, whoever is live
     engine = served.engine
-    assert timeline["rows_gathered"] % (engine.max_slots
-                                        * engine.kv_page) == 0
+    assert timeline["rows_gathered"] == timeline["tick_steps"] \
+        * engine.max_slots * engine.pages_per_slot * engine.kv_page
     # a request of p prompt tokens and t tokens read p+1 .. p+t-1 rows in
     # its steps; a tick's steps past a request's budget read rows too
     least = sum(sum(range(r["prompt_len"] + 1,

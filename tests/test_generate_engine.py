@@ -217,9 +217,9 @@ def test_engine_warmup_precompiles(setup):
     async def main():
         engine = _make_engine(cfg, params, steps_per_tick=4)
         await engine.warmup(prompt_counts=(1, 2))
-        assert sorted(engine._tick_fns) == [(1, False, False, None),
-                                            (2, False, False, None),
-                                            (4, False, False, None)]
+        assert sorted(engine._tick_fns) == [(1, False, False),
+                                            (2, False, False),
+                                            (4, False, False)]
         assert set(engine._prefill_fns) == {
             (1, 8, False), (1, 16, False), (2, 8, False), (2, 16, False)}
         await engine.start()
@@ -232,45 +232,106 @@ def test_engine_warmup_precompiles(setup):
     asyncio.run(main())
 
 
-def test_warmup_defaults_to_startup_window_subset(setup):
-    """ADVICE r4 medium: default warmup must not compile the full
-    k x window cross-product — only the startup-reachable rungs; the
-    full matrix is opt-in via windows="all"."""
+# -- one decode tick a k rung: the window ladder is gone ---------------------------
+
+PATHS = {"dense": {},
+         "gather": dict(paged_kv=True, kv_page=32, ragged_attn="off"),
+         "ragged": dict(paged_kv=True, kv_page=32, ragged_attn="on")}
+
+
+@pytest.mark.parametrize("max_len", [128, 1024])
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_warmup_compiles_one_tick_a_k_rung_whatever_max_len(path, max_len):
+    """Every tick reads its whole table or cache row, so warm-up compiles
+    one decode tick a k rung (two with sampling) at any ``max_len``, and
+    a generation from a short prompt past half of ``max_len`` compiles
+    nothing on the serving path and is the reference's stream."""
+    import jax.numpy as jnp
+    cfg = llama.config("tiny", max_seq_len=max_len, dtype=jnp.float32)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    prompt, budget = [1, 2, 3, 4, 5], max_len // 2 + 8
+
+    async def main():
+        engine = _make_engine(cfg, params, max_len=max_len,
+                              prompt_buckets=(32,), steps_per_tick=8,
+                              **PATHS[path])
+        assert engine.attn_path == path
+        await engine.warmup(prompt_counts=(1,), sampling=True)
+        warm = sorted(engine._tick_fns)
+        assert warm == [(k, sampled, False) for k in (1, 2, 4, 8)
+                        for sampled in (False, True)]
+        await engine.start()
+        try:
+            out = await asyncio.wait_for(
+                engine.generate(prompt, max_new_tokens=budget), 300.0)
+        finally:
+            await engine.stop()
+        assert engine.stats()["compiles"]["serving"] == 0, \
+            engine._compile_events
+        assert sorted(engine._tick_fns) == warm
+        return out
+
+    out = asyncio.run(main())
+    ref = llama.generate(params, cfg, np.asarray([prompt], np.int32), budget)
+    assert out == [int(t) for t in np.asarray(ref)[0]]
+
+
+REMOVED = {"window_ladder=True": ({"window_ladder": True}, {}),
+           "windows=(128,)": ({}, {"windows": (128,)}),
+           "windows=ALL": ({}, {"windows": "ALL"})}
+
+
+@pytest.mark.parametrize("built,warmed", REMOVED.values(), ids=REMOVED)
+def test_the_window_ladder_is_refused_by_name(setup, built, warmed):
+    """What selected a window rung is refused, saying the ladder went:
+    a silent acceptance would let a caller think it still narrows."""
     cfg, params = setup
 
     async def main():
-        engine = _make_engine(cfg, params, max_len=512,
-                              prompt_buckets=(8, 16), steps_per_tick=4)
-        assert engine._window_ladder == [128, 256, None]
-        await engine.warmup(prompt_counts=(1,))
-        warmed = {w for (_, _, _, w) in engine._tick_fns}
-        assert warmed == {128}, warmed   # bucket 16 + k 4 fits rung 128
+        engine = _make_engine(cfg, params, max_len=512, **built)
+        await engine.warmup(**warmed)
 
-        full = _make_engine(cfg, params, max_len=512,
-                            prompt_buckets=(8, 16), steps_per_tick=4)
-        await full.warmup(prompt_counts=(1,), windows="all")
-        assert {w for (_, _, _, w) in full._tick_fns} == {128, 256, None}
-    asyncio.run(main())
+    with pytest.raises(ValueError, match="ladder was removed"):
+        asyncio.run(main())
 
 
-def test_warmup_rejects_unknown_rungs(setup):
-    """ADVICE r4 low: a windows/ks filter that matches nothing must raise,
-    not silently warm zero executables."""
+@pytest.mark.parametrize("ks", [(3,), ()], ids=["off-ladder", "empty"])
+def test_warmup_refuses_ks_that_warm_nothing(setup, ks):
+    """A ks filter that matches no k rung must raise, not silently warm
+    zero executables."""
     cfg, params = setup
 
     async def main():
-        engine = _make_engine(cfg, params, max_len=512, steps_per_tick=4)
-        with pytest.raises(ValueError, match="window-ladder"):
-            await engine.warmup(windows=(999,))
-        with pytest.raises(ValueError, match="k-ladder"):
-            await engine.warmup(ks=(3,))
-        with pytest.raises(ValueError, match="window-ladder"):
-            await engine.warmup(windows=())     # empty = warms nothing
-        with pytest.raises(ValueError, match="k-ladder"):
-            await engine.warmup(ks=())
-        with pytest.raises(ValueError, match="sentinel"):
-            await engine.warmup(windows="ALL")
-    asyncio.run(main())
+        await _make_engine(cfg, params, steps_per_tick=4).warmup(ks=ks)
+
+    with pytest.raises(ValueError, match="k-ladder"):
+        asyncio.run(main())
+
+
+def test_the_no_ladder_values_warm_what_the_defaults_warm(setup):
+    """``window_ladder=False`` and ``warmup(windows="all")``, which the
+    benchmark's configs and adapters still pass, warm the programs the
+    defaults warm, and stats() reports one window, the whole of
+    ``max_len``."""
+    cfg, params = setup
+    paged = dict(max_len=256, steps_per_tick=4, paged_kv=True, kv_page=8,
+                 ragged_attn="off")
+
+    async def warmed(engine, **kwargs):
+        await engine.warmup(prompt_counts=(1, 2), sampling=True, **kwargs)
+        stats = engine.stats()
+        return (sorted(engine._tick_fns), sorted(engine._prefill_fns),
+                sorted(engine._insert_paged_fns), stats["compiles"],
+                stats["window_ladder"])
+
+    default = asyncio.run(warmed(_make_engine(cfg, params, **paged)))
+    vestiges = asyncio.run(warmed(
+        _make_engine(cfg, params, window_ladder=False, **paged),
+        windows="all"))
+    assert vestiges == default
+    assert default[0] == [(k, sampled, False) for k in (1, 2, 4)
+                          for sampled in (False, True)]
+    assert default[-1] == [256]
 
 
 def test_warmup_after_start_rejected(setup):
@@ -376,8 +437,8 @@ def test_tick_failure_resets_device_state_and_recovers(setup):
         real = engine._tick_fn
         boom = {"armed": True}
 
-        def exploding(k, sampled, biased, width):
-            fn = real(k, sampled, biased, width)
+        def exploding(k, sampled, biased):
+            fn = real(k, sampled, biased)
 
             def wrapped(*args):
                 out = fn(*args)   # consumes the donated cache for real
@@ -457,36 +518,6 @@ def test_inactive_slots_frozen(setup):
             assert lens == [0, 0, 3, 20]
         finally:
             await engine.stop()
-    asyncio.run(main())
-
-
-def test_window_ladder_token_identical(setup):
-    """Fill-bounded attention (window ladder) must not change tokens: an
-    engine whose max_len spans several window rungs produces exactly the
-    reference sequence, and actually exercises a sub-full rung."""
-    cfg, params = setup
-
-    async def main():
-        # max_len 256 > 128 → ladder [128, None]; fills stay < 128 so
-        # every tick should run the 128-window executable
-        engine = _make_engine(cfg, params, max_len=128, window_ladder=True)
-        engine.max_len = 128
-        assert engine._window_ladder == [None]  # 128 is not > 128
-        engine2 = GenerationEngine(cfg, params, max_slots=4, max_len=256,
-                                   prompt_buckets=(8, 16))
-        assert engine2._window_ladder == [128, None]
-        await engine2.start()
-        try:
-            prompt = [1, 2, 3, 4, 5]
-            out = await asyncio.wait_for(
-                engine2.generate(prompt, max_new_tokens=6), 60.0)
-            ref = llama.generate(params, cfg,
-                                 np.asarray([prompt], np.int32), 6)
-            assert out == [int(t) for t in np.asarray(ref)[0]]
-            # the sub-full rung was used (fills stayed far below 128)
-            assert any(key[3] == 128 for key in engine2._tick_fns)
-        finally:
-            await engine2.stop()
     asyncio.run(main())
 
 

@@ -239,7 +239,7 @@ def test_prefill_insert_and_fused_paged_steps_equal_the_reference(model):
         step = jax.jit(lambda *a: jamba.decode_step_paged(
             params, cfg, *a, counters=True))
         pool, cache_len = engine._pool.leaves, engine.cache_len
-        table = engine._table_dev(engine.pages_per_slot)
+        table = engine._table_dev()
         for t in range(steps):
             token = np.zeros((4,), np.int32)
             for i, slot in enumerate(slots):
@@ -267,7 +267,7 @@ def test_an_inactive_rows_state_is_bit_identical_after_a_tick(model):
     engine._pool.leaves = dict(engine._pool.leaves, ssm=leaves)
     engine.cache_len = jnp.asarray([5, 9, 0, 3], jnp.int32)
     active = jnp.asarray([False, True, False, False])
-    engine._run_tick(2, False, engine._tick_width(None), active)
+    engine._run_tick(2, False, active)
     after = engine._pool.leaves["ssm"]
     for name in ("h", "conv"):
         got = np.asarray(after[name])

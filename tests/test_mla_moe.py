@@ -169,7 +169,7 @@ def test_dense_decode_step_equals_the_paged_one(model):
         _, cache, cache_len = mla_moe.prefill(
             params, cfg, tokens[None, :10], mla_moe.init_cache(cfg, 1, 16))
         logits, _, _ = mla_moe.decode_step(params, cfg, tokens[10:],
-                                           cache, cache_len, window=12)
+                                           cache, cache_len)
     assert reference.rel_l2(logits[0], want[0]) < 1e-5
 
 
@@ -594,7 +594,7 @@ def test_llama_tick_has_no_counters_and_three_outputs():
     engine, _ = engine_for(llama, cfg, params)
     assert engine._step_counters == ()
     out = jax.eval_shape(
-        engine._tick_fn(2, False, False, 8), engine.params,
+        engine._tick_fn(2, False, False), engine.params,
         engine.last_token,
         engine._pool.leaves, jnp.zeros((4, 8), jnp.int32), engine.cache_len,
         jnp.zeros((4,), bool))

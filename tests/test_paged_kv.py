@@ -322,33 +322,3 @@ def test_pool_hbm_does_not_scale_with_max_len_times_slots(setup):
     # bytes accounting agrees
     stats = engine._pool.stats()
     assert stats["pool_bytes"] == 6 * stats["page_bytes"]
-
-
-def test_window_ladder_demotes_to_page_gather_width(setup):
-    """Satellite: attention_window on the paged path only bounds the
-    page-gather width; requesting it explicitly warns."""
-    cfg, params = setup
-
-    class _Warns:
-        def __init__(self):
-            self.messages = []
-
-        def warn(self, msg, *args):
-            self.messages.append(msg % args if args else msg)
-
-        def info(self, msg, *args):
-            pass
-
-        def error(self, msg, *args):
-            pass
-
-    logger = _Warns()
-    container = new_mock_container()
-    engine = GenerationEngine(cfg, params, max_slots=4, max_len=256,
-                              prompt_buckets=(8, 16), paged_kv=True,
-                              kv_page=4, window_ladder=True,
-                              logger=logger, metrics=container.metrics)
-    # 256 max_len -> window rungs [128, None] -> widths [32, 64]
-    assert engine._pick_page_width(128) == 32
-    assert engine._pick_page_width(None) == engine.pages_per_slot
-    assert any("paging supersedes windowing" in m for m in logger.messages)

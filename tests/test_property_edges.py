@@ -1,14 +1,11 @@
 """Property tests for pure components: BPE tokenizer round-trips,
-window-rung selection, cron field edges, and env-file parsing
+cron field edges, and env-file parsing
 (test-depth push, VERDICT r3 #5; sampling semantics live in
 test_sampling.py). Seeded RNG — failures reproduce."""
 
 import random
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 # -- tokenizer ---------------------------------------------------------------
@@ -49,47 +46,6 @@ def test_tokenizer_native_matches_python_path():
         text = "".join(rng.choice("abnd ") for _ in range(rng.randint(0, 60)))
         assert tok._encode_native(text.encode()) == \
             tok._encode_python(text.encode())
-
-
-# -- engine window-rung selection -------------------------------------------
-
-def _ladder_engine(max_len):
-    from gofr_tpu.models import llama
-    from gofr_tpu.tpu.generate import GenerationEngine
-    cfg = llama.config("tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
-    return GenerationEngine(cfg, params, max_slots=2, max_len=max_len,
-                            prompt_buckets=(8,))
-
-
-def test_window_ladder_shape():
-    assert _ladder_engine(64)._window_ladder == [None]
-    assert _ladder_engine(512)._window_ladder == [128, 256, None]
-    assert _ladder_engine(1024)._window_ladder == [128, 256, 512, None]
-
-
-def test_window_rung_selection_boundaries():
-    engine = _ladder_engine(512)
-    assert engine._pick_window([100], 8) == 128      # 108 fits 128
-    assert engine._pick_window([120], 8) == 128      # 128 exactly fits
-    assert engine._pick_window([121], 8) == 256      # 129 spills to 256
-    assert engine._pick_window([240], 8) == 256      # 248 fits 256
-    assert engine._pick_window([250], 8) is None     # 258 → full cache
-    assert engine._pick_window([300], 8) is None
-    assert engine._pick_window([], 4) == 128         # no active fills
-    # the max across slots drives the rung
-    assert engine._pick_window([10, 200], 4) == 256
-
-
-def test_window_ladder_off_by_flag():
-    from gofr_tpu.models import llama
-    from gofr_tpu.tpu.generate import GenerationEngine
-    cfg = llama.config("tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
-    engine = GenerationEngine(cfg, params, max_slots=2, max_len=512,
-                              prompt_buckets=(8,), window_ladder=False)
-    assert engine._window_ladder == [None]
-    assert engine._pick_window([10], 1) is None
 
 
 # -- cron field edges --------------------------------------------------------
@@ -156,34 +112,3 @@ def test_env_overlay_precedence(tmp_path, monkeypatch):
     assert config.get("A") == "base"        # base survives
     assert config.get("B") == "prod"        # overlay wins over base
     assert config.get("C") == "process"     # process env wins over all
-
-
-def test_window_rung_and_stats_exposed():
-    """The attention-window rung is observable: stats() lists the ladder
-    and counts the ticks each rung took (timeline.ticks_by_width)."""
-    import asyncio
-
-    from gofr_tpu.container import new_mock_container
-    from gofr_tpu.models import llama
-    from gofr_tpu.tpu.generate import GenerationEngine
-
-    cfg = llama.config("tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
-    container = new_mock_container()
-    engine = GenerationEngine(cfg, params, max_slots=2, max_len=256,
-                              prompt_buckets=(8,),
-                              logger=container.logger,
-                              metrics=container.metrics)
-    assert engine.stats()["window_ladder"] == [128, 256]
-
-    async def main():
-        await engine.start()
-        try:
-            await asyncio.wait_for(
-                engine.generate([1, 2, 3], max_new_tokens=4), 60.0)
-            timeline = engine.stats()["timeline"]
-            assert set(timeline["ticks_by_width"]) == {"128"}
-            assert timeline["ticks_by_width"]["128"] == timeline["ticks"] > 0
-        finally:
-            await engine.stop()
-    asyncio.run(main())

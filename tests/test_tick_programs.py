@@ -96,9 +96,7 @@ def test_zero_bias_emits_the_unbiased_ticks_tokens(tiny, kind, sampled):
             mask, bias = jnp.asarray(active), None
         ticks = []
         for k in (2, 1, 2):
-            width = engine._tick_width(engine._pick_window(fills, k))
-            tokens, counts = engine._run_tick(k, sampled, width, mask,
-                                              bias=bias)
+            tokens, counts = engine._run_tick(k, sampled, mask, bias=bias)
             assert counts == ()
             ticks.append(np.asarray(tokens))
             fills = [fill + k for fill in fills]
@@ -126,7 +124,7 @@ def test_warmed_engine_serves_every_tick_form_without_compiling(
         engine = engine_for(cfg, params, kind, max_len=256,
                             steps_per_tick=2)
         await engine.warmup(prompt_counts=tuple(engine._n_ladder),
-                            sampling=sampled, windows="all")
+                            sampling=sampled)
         warm = set(engine._tick_fns)
         assert {key[1] for key in warm} == ({False, True} if sampled
                                             else {False})
@@ -140,10 +138,9 @@ def test_warmed_engine_serves_every_tick_form_without_compiling(
         served = []
         run_tick = engine._run_tick
 
-        def spy(k, sampled, width, active, bias=None, state=None):
-            served.append((k, sampled, bias is not None, width))
-            return run_tick(k, sampled, width, active, bias=bias,
-                            state=state)
+        def spy(k, sampled, active, bias=None, state=None):
+            served.append((k, sampled, bias is not None))
+            return run_tick(k, sampled, active, bias=bias, state=state)
 
         engine._run_tick = spy
         await engine.start()
@@ -196,7 +193,7 @@ def test_constrained_tick_of_a_counting_module_returns_its_counters():
     out, stats, keys = asyncio.run(serve())
     assert len(out) == 6
     # every tick was a constrained one
-    assert keys and all(biased for _, _, biased, _ in keys)
+    assert keys and all(biased for _, _, biased in keys)
     # 5 decode steps (the first token is the prefill's) x the expert
     # layers x top-k: a tick that dropped its counters leaves no "moe"
     moe = stats["moe"]

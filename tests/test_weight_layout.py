@@ -193,24 +193,23 @@ def test_a_tick_compiled_after_start_is_the_one_warmup_finds(
     _, engine = build(f"llama-{kind}")
     engine = engine()
     assert engine.stats()["compiles"] == {"warmup": 0, "serving": 0}
-    width = engine._tick_width(None)
     mask = engine._jnp.zeros((engine.max_slots,), bool)
     caplog.clear()                   # the deciding compile is a decode_k too
     with caplog.at_level(logging.WARNING,
                          logger="jax._src.interpreters.pxla"):
-        engine._run_tick(2, False, width, mask)
+        engine._run_tick(2, False, mask)
         assert engine.stats()["compiles"]["serving"] == 1
         assert compiled_programs(caplog) == []
         early = {}
         for k in (2, 1, 1):
             # the state a tick returned is committed: no second compile
-            engine._run_tick(k, False, width, mask)
-            early[k] = engine._tick_fns[(k, False, False, width)]
+            engine._run_tick(k, False, mask)
+            early[k] = engine._tick_fns[(k, False, False)]
         assert engine.stats()["compiles"]["serving"] == 2
         assert compiled_programs(caplog) == ["decode_k"]
 
         asyncio.run(engine.warmup(prompt_counts=(1, 2, 4)))
-        assert {k: engine._tick_fns[(k, False, False, width)]
+        assert {k: engine._tick_fns[(k, False, False)]
                 for k in early} == early
         charged = [what for _, _, what in engine._compile_events]
         assert len(charged) == len(set(charged))

@@ -218,7 +218,7 @@ def test_charge_device_time_totals_agree():
     ledger = ExecutableLedger(metrics=metrics)
     device_seconds = {}
     charge_device_time(0.12, "llama", classes=["interactive", "standard"],
-                       family="decode_paged[k=8,pw=16]",
+                       family="decode_paged[k=8]",
                        device_seconds=device_seconds, metrics=metrics,
                        ledger=ledger)
     charge_device_time(0.03, "llama", classes=["standard"],
