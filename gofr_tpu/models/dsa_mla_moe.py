@@ -170,6 +170,7 @@ class DsaMlaMoeConfig:
 
     # what models/experts.py reads and this family has not varied
     scoring = "sigmoid"
+    topk_norm_eps = 0.0
 
     def __post_init__(self):
         if len(self.layer_types) != self.n_layers:

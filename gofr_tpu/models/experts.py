@@ -1,17 +1,22 @@
 """The sparse expert layer, shared by the model families that route
-(``models/mla_moe.py``, ``models/swa_moe.py``, ``models/hc_mla_moe.py``):
+(``models/mla_moe.py``, ``models/swa_moe.py``, ``models/hc_mla_moe.py``,
+``models/dsa_mla_moe.py``, ``models/conv_moe.py``):
 router, this chip's share of the routed experts in a form for a prefill
 and one for a decode step, the shared experts, and the step counters.
 A family's config object is read for ``scoring`` (``sigmoid`` or
 ``softmax``), ``top_k``, ``norm_topk_prob``, ``routed_scale``,
 ``n_routed_experts`` (the router's width), ``n_held_experts`` and
-``expert_rank`` (which share lives on this chip), and ``shared_scale``
+``expert_rank`` (which share lives on this chip), ``shared_scale``
 (what the shared experts' sum is multiplied by: 1, or ``1 /
-n_shared_experts`` where a family averages them).
+n_shared_experts`` where a family averages them), and
+``topk_norm_eps`` (what the top-k normalisation adds to its divisor:
+0.0 where a family has nothing there, LFM2-MoE's 1e-6; every family
+states it).
 
 ``s = score(W_g h)`` over all ``n_routed_experts``, in float32; ``T`` =
 the ``top_k`` largest (of ``s + b`` where a layer carries a correction
-bias ``router_bias``, DeepSeek-V3's ``noaux_tc``); ``w_e = routed_scale * s_e / sum_{j in T} s_j``;
+bias ``router_bias``, DeepSeek-V3's ``noaux_tc``); ``w_e = routed_scale * s_e /
+(sum_{j in T} s_j + topk_norm_eps)``;
 ``y = shared_scale * Shared(h) + sum_{e in T and held} w_e Expert_e(h)``.
 The shared experts are held as one SwiGLU of ``n_shared_experts`` times
 the expert width: a sum of SwiGLUs over disjoint columns is one SwiGLU
@@ -71,7 +76,10 @@ def route(cfg, router, h, bias=None):
         _, ids = lax.top_k(scores + bias.astype(jnp.float32), cfg.top_k)
         top = jnp.take_along_axis(scores, ids, axis=-1)
     if cfg.norm_topk_prob:
-        top = top / top.sum(axis=-1, keepdims=True)
+        # no add where a family has no eps: its programs stay as they were
+        total = top.sum(axis=-1, keepdims=True)
+        top = top / (total + cfg.topk_norm_eps if cfg.topk_norm_eps
+                     else total)
     return ids.astype(jnp.int32), top * cfg.routed_scale
 
 

@@ -114,6 +114,7 @@ class MlaMoeConfig:
     norm_topk_prob: bool = True
     routed_scale: float = 2.5
     scoring: str = "sigmoid"          # or "softmax"
+    topk_norm_eps = 0.0               # not a field: the family has none
     sandwich_norm: bool = True
     max_seq_len: int = 131072
     rope_theta: float = 25.6e6

@@ -123,6 +123,7 @@ class SwaMoeConfig:
     # has not varied: not fields
     scoring = "sigmoid"
     routed_scale = 1.0
+    topk_norm_eps = 0.0
     def flash_block(self, seq_len: int) -> Optional[int]:
         """The block size the prefill's flash kernel runs a
         ``seq_len``-token bucket in, or None where Mosaic cannot tile

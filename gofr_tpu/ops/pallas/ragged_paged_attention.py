@@ -94,6 +94,19 @@ This kernel walks them in place:
   may not be. The tests poison every page outside a live prefix with
   NaN, table entries past the prefix inside a partly live block
   included.
+- HEADS NARROWER THAN A LANE ROW (head 64: LFM2-8B-A1B's GQA 32:8)
+  are kept **packed**: the pool is ``(L, num_pages, page, Hkv * D)``, a
+  token's KV heads side by side in one row of lanes, so the leaf takes
+  its logical bytes on the chip (a trailing ``(Hkv, 64)`` pair of axes
+  is tiled to whole 128-lane rows, twice its bytes or more). The kernel
+  then walks *units* of 128 lanes, ``128 / D`` heads each, as it walks
+  heads: a unit's ``(tokens, 128)`` rows are a lane slice of the block,
+  no strided load. A query row is widened to its unit's 128 lanes with
+  zeros outside its own head's (``_packed_ragged``), so a unit's
+  product is block-diagonal as ``product_heads``' is, with the heads
+  stored side by side instead of concatenated; P.V comes out a unit
+  wide and a row keeps its own head's lanes. The scale is the true
+  head's, ``D ** -0.5``.
 - int8 pools dequantize **in-kernel** from the scale planes that live
   beside the pages: a page's scales ride the ring head-major in whole
   lane rows, ``page`` scales a head, in the order of the head's score
@@ -107,7 +120,8 @@ This kernel walks them in place:
 
 Measured on the chip through the engine: bf16 pools at GQA 32:8 (the
 benchmark's ``mistral7b.batch``) and at GQA 128:8 with and without a
-window's bound (``command-a-plus-ep8.mixed``), decode. int8 pools, the
+window's bound (``command-a-plus-ep8.mixed``), decode; packed bf16
+pools at GQA 32:8 and head 64 (``lfm2-8b-a1b-pp2.chat``). int8 pools, the
 verify variant and MHA 32:32 are compared with the oracle there by
 ``chip_smoke.py``, not served (PERF.md, PR 28 and PR 32).
 
@@ -284,7 +298,15 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
     b = pl.program_id(0)
     num_slots = pl.num_programs(0)
     table_width = table_ref.shape[1]
-    num_pages, page, kv_heads, head_dim = k_hbm.shape[1:]
+    packed = k_hbm.ndim == 4
+    if packed:
+        # a token's heads side by side in one row: the walk's heads are
+        # the row's units of 128 lanes (module docstring)
+        num_pages, page, width = k_hbm.shape[1:]
+        head_dim = _LANES
+        kv_heads = width // head_dim
+    else:
+        num_pages, page, kv_heads, head_dim = k_hbm.shape[1:]
     rows_all = q_ref.shape[1]
     rows = rows_all // kv_heads                # query rows of a KV head
     product_rows = product_heads * rows
@@ -391,7 +413,12 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
         of one token: a load of every ``kv_heads / pack``-th word is
         ``pack`` heads' rows, and a shift or a mask is one of them (a
         bfloat16 is the top half of a float32; an int8 its top byte,
-        shifted down with its sign)."""
+        shifted down with its sign). A packed ring's are lane slices of
+        its rows."""
+        if packed:
+            tokens = ring.at[at].reshape(block_tokens, kv_heads * head_dim)
+            return [tokens[:, pl.ds(h * head_dim, head_dim)].astype(dtype)
+                    for h in range(kv_heads)]
         tokens = ring.at[at].reshape(block_tokens * kv_heads, head_dim)
         pack = 4 // ring.dtype.itemsize
         if pack == 1 or kv_heads % pack:
@@ -570,7 +597,7 @@ def _ragged_kernel(table_ref, len_ref, layer_ref, *rest,
 
 def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
                    cache_len, layer, *scale_pages, interpret: bool,
-                   start=None):
+                   start=None, sm_scale=None):
     """``k_pages`` / ``v_pages`` are the STACKED pool leaves
     ``(L, num_pages, page, Hkv, D)`` and ``layer`` (int32, shape (1,),
     traced) says which layer to read; ``scale_pages`` is
@@ -584,12 +611,20 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     Mistral-7B sizes: two 452 MB copies a layer). One layer's scale
     plane is 1/128 of its K plane, so slicing it costs what it always
     did; the slice comes out head-major in whole lane rows, ``page``
-    scales a head a page, in the order of the scores they scale."""
+    scales a head a page, in the order of the scores they scale.
+
+    A packed pool ``(L, num_pages, page, width)`` comes with q and the
+    new K/V already in its units of 128 lanes (``_packed_ragged``), and
+    the true head's ``sm_scale``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, g_len, q_heads, head_dim = q.shape
-    _, num_pages, page, kv_heads, _ = k_pages.shape
+    if k_pages.ndim == 4:
+        _, num_pages, page, width = k_pages.shape
+        kv_heads = width // head_dim
+    else:
+        _, num_pages, page, kv_heads, _ = k_pages.shape
     group = q_heads // kv_heads
     rows = g_len * group
     rows_all = kv_heads * rows
@@ -628,7 +663,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         _ragged_kernel, block_pages=sizes.block_pages,
         ring_blocks=sizes.ring_blocks, keep_scores=sizes.keep_scores,
         product_heads=sizes.product_heads, int8=int8,
-        sm_scale=head_dim ** -0.5, bounded=bounded)
+        sm_scale=head_dim ** -0.5 if sm_scale is None else sm_scale,
+        bounded=bounded)
     prefetch = (table, lens, layer) + (
         (start.astype(jnp.int32),) if bounded else ())
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -642,8 +678,8 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
     operands = [q_hm, k_pages, v_pages, kn_hm, vn_hm,
                 jnp.asarray(new_ok, jnp.int32)]
     scratch = [
-        pltpu.VMEM((sizes.ring_blocks, sizes.block_pages, page, kv_heads,
-                    head_dim), k_pages.dtype),
+        pltpu.VMEM((sizes.ring_blocks, sizes.block_pages)
+                   + k_pages.shape[2:], k_pages.dtype),
         pltpu.SemaphoreType.DMA((sizes.ring_blocks,)),
     ]
     if sizes.keep_scores:
@@ -686,6 +722,52 @@ def _pallas_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
         .transpose(0, 2, 1, 3, 4).reshape(q.shape)
 
 
+def _packed_ragged(q, k_pages, v_pages, page_table, k_new, v_new,
+                   cache_len, layer, *scale_pages, interpret: bool,
+                   start=None):
+    """``_pallas_ragged`` over a packed pool ``(L, num_pages, page, Hkv *
+    D)`` (module docstring): q (B, G, Hq, D) is widened to its unit's
+    128 lanes, zero outside its own head's; the new K/V (B, G, Hkv, D)
+    become rows of units as the pool's are; the output, a unit wide, is
+    narrowed back to each head's own lanes. Returns (B, G, Hq, D)."""
+    batch, g_len, q_heads, head_dim = q.shape
+    width = k_pages.shape[-1]
+    kv_heads, pair = width // head_dim, _LANES // head_dim
+    if scale_pages or _LANES % head_dim or width % _LANES:
+        raise ValueError(
+            f"a packed pool holds bfloat16 or float32 heads that fill "
+            f"whole 128-lane rows: head_dim {head_dim}, width {width}"
+            + (", int8 scales" if scale_pages else ""))
+    group = q_heads // kv_heads
+    # query head h meets KV head h // group, whose lanes in its unit
+    # are the ((h // group) % pair)-th D of them
+    own = (np.arange(q_heads) // group) % pair
+    lanes = own[:, None] == (np.arange(_LANES) // head_dim)[None, :]
+    wide = jnp.where(lanes, jnp.tile(q, (1, 1, 1, pair)),
+                     jnp.zeros((), q.dtype))
+
+    def units(x):
+        return x.reshape(batch, g_len, width // _LANES, _LANES)
+
+    out = _pallas_ragged(wide, k_pages, v_pages, page_table, units(k_new),
+                         units(v_new), cache_len, layer,
+                         interpret=interpret, start=start,
+                         sm_scale=head_dim ** -0.5)
+    out = out.reshape(batch, g_len, q_heads, pair, head_dim)
+    return jnp.take_along_axis(
+        out, jnp.asarray(own, jnp.int32)[None, None, :, None, None],
+        axis=3)[:, :, :, 0]
+
+
+def kernel_heads(leaf_shape) -> tuple:
+    """(KV heads, head width) as the kernel walks a pool leaf whose
+    per-token shape is ``leaf_shape``: ``(Hkv, D)`` as it is, a packed
+    ``(Hkv * D,)`` as its units of 128 lanes."""
+    if len(leaf_shape) == 1:
+        return leaf_shape[0] // _LANES, _LANES
+    return tuple(leaf_shape)
+
+
 def _scales(k_scale_pages, v_scale_pages):
     if (k_scale_pages is None) != (v_scale_pages is None):
         raise ValueError("int8 pools pass both scale planes, bf16 neither")
@@ -712,17 +794,19 @@ def ragged_paged_decode_attention(q, k_pages, v_pages, page_table, k_new,
     planes. ``start`` (B,) int32 or None: a sliding-window layer's first
     attended position (``max(cache_len - window + 1, 0)``); the walk
     begins at its page and masks what lies before it in that page.
+    A packed pool ``(L,num_pages,page,Hkv*D)`` (heads narrower than a
+    lane row, side by side: module docstring) takes the same operands.
     ``interpret=None`` follows the lowering target
     (ops/pallas/select). Returns (B,1,Hq,D)."""
-    kernel = _pallas_ragged
+    kernel = call = (_packed_ragged if k_pages.ndim == 4
+                     else _pallas_ragged)
     operands = (q, k_pages, v_pages, page_table, k_new[:, None],
                 v_new[:, None], cache_len, _layer(layer),
                 *_scales(k_scale_pages, v_scale_pages))
     if start is not None:
         # the bound rides as the last operand through platform_dependent
         def kernel(*ops, interpret):
-            return _pallas_ragged(*ops[:-1], interpret=interpret,
-                                  start=ops[-1])
+            return call(*ops[:-1], interpret=interpret, start=ops[-1])
         operands += (start,)
     return lower_for_target(kernel, interpret, *operands)
 

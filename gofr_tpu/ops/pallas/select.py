@@ -32,25 +32,40 @@ __all__ = ["flash_tileable", "ragged_tileable", "scan_tileable",
 def flash_tileable(seq_len: int, head_dim: int, block_q: int = 512,
                    block_k: int = 512) -> bool:
     """Flash-attention (prefill) tiling predicate: whole blocks, a
-    128-lane head_dim and at least one 128-row tile of sequence."""
+    128-lane head_dim and at least one 128-row tile of sequence. A
+    narrower head goes in zero-padded to 128 lanes by its caller, with
+    its own scale (models/conv_moe.py at head 64), and is asked about
+    as the 128 it then is (AOT-compiled for v5e at heads of 128 in
+    blocks of 512, of 64 padded to 128 in blocks of 128 ... 1024, and
+    of 192 padded to 256, by tests/test_pallas_aot.py)."""
     block_q, block_k = min(block_q, seq_len), min(block_k, seq_len)
     return (seq_len % block_q == 0 and seq_len % block_k == 0
             and head_dim % 128 == 0 and seq_len >= 128)
 
 
 def ragged_tileable(head_dim: int, q_heads: int, kv_heads: int,
-                    page: int, kv_itemsize: int = 2) -> bool:
+                    page: int, kv_itemsize: int = 2,
+                    packed: bool = False) -> bool:
     """Ragged-paged-attention tiling predicate: a 128-lane head_dim, a
     sublane-filling q-head count, a page deep enough to tile the KV
     block, and KV heads that fill whole 32-bit words down a page's
     sublanes (two bfloat16 heads, four int8: the kernel takes a head's
     rows out of a block by words; Mosaic has no strided load of
-    narrower rows, and refused a single KV head before that) (AOT-
-    compiled for v5e at MHA 32:32, GQA 32:8 and GQA 128:8 by
-    tests/test_pallas_aot.py)."""
-    return (q_heads % kv_heads == 0 and head_dim % 128 == 0
-            and q_heads % 8 == 0 and page % 16 == 0
-            and kv_heads % (4 // kv_itemsize) == 0)
+    narrower rows, and refused a single KV head before that). A
+    ``packed`` pool, whose leaf keeps a token's heads side by side in
+    one row of ``Hkv * head_dim`` lanes (``kernel_heads`` of a 1-D
+    per-token shape), may instead hold bfloat16 heads of 64, an even
+    number of them: the kernel walks two heads a 128-lane unit
+    (ragged_paged_attention's packed form). Heads of 64 in a pool of
+    ``(Hkv, 64)`` rows are refused: that is the strided load of rows
+    narrower than 128 lanes. AOT-compiled for v5e at MHA 32:32, GQA
+    32:8 and GQA 128:8 at head 128, and packed GQA 32:8 at head 64, by
+    tests/test_pallas_aot.py."""
+    pairs = (packed and head_dim == 64 and kv_heads % 2 == 0
+             and kv_itemsize == 2)
+    return (q_heads % kv_heads == 0 and q_heads % 8 == 0 and page % 16 == 0
+            and (pairs or (head_dim % 128 == 0
+                           and kv_heads % (4 // kv_itemsize) == 0)))
 
 
 def scan_tileable(seq_len: int, channels: int, n_state: int) -> bool:

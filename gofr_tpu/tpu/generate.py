@@ -1925,15 +1925,19 @@ class GenerationEngine:
         if self.mesh is not None:
             return False, ("auto: pallas_call has no partitioning rule, "
                            "a mesh would replicate the pool into it")
+        kinds = self._pool.kinds.values()
         kv_itemsize = min(self._jnp.dtype(kind.leaves["k"][1]).itemsize
-                          for kind in self._pool.kinds.values())
+                          for kind in kinds)
+        # a leaf of one row a token keeps its heads side by side
+        packed = all(len(kind.leaves["k"][0]) == 1 for kind in kinds)
         if not ragged_tileable(cfg.head_dim, cfg.n_heads, cfg.n_kv_heads,
-                               self.kv_page, kv_itemsize):
+                               self.kv_page, kv_itemsize, packed=packed):
             return False, (
                 f"auto: head_dim {cfg.head_dim}, heads {cfg.n_heads}:"
                 f"{cfg.n_kv_heads}, page {self.kv_page} do not tile "
                 f"(head_dim % 128, heads % 8, page % 16, kv heads % "
-                f"{4 // kv_itemsize})")
+                f"{4 // kv_itemsize}; or bfloat16 heads of 64 in pairs "
+                f"in a packed pool)")
         return True, "auto: tpu devices and the geometry tiles"
 
     def attention_paths(self) -> Dict[str, Any]:
@@ -1958,10 +1962,12 @@ class GenerationEngine:
         (``walk_sizes``: pages a block, blocks in flight, whether a
         slot's scores are kept so that K leaves HBM once, KV heads a
         product), so this is where it shows."""
-        from gofr_tpu.ops.pallas.ragged_paged_attention import walk_sizes
+        from gofr_tpu.ops.pallas.ragged_paged_attention import (
+            kernel_heads, walk_sizes)
         walks = {}
         for name, kind in self._pool.kinds.items():
-            (kv_heads, head_dim), dtype = kind.leaves["k"][:2]
+            shape, dtype = kind.leaves["k"][:2]
+            kv_heads, head_dim = kernel_heads(shape)
             walks[name] = walk_sizes(
                 self.kv_page, kv_heads, head_dim, self.cfg.n_heads,
                 self._jnp.dtype(dtype).itemsize,

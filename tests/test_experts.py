@@ -34,7 +34,7 @@ def _cfg(held=ROUTED, rank=0):
     return types.SimpleNamespace(
         scoring="sigmoid", top_k=TOP_K, norm_topk_prob=True,
         routed_scale=2.0, n_routed_experts=ROUTED, n_held_experts=held,
-        expert_rank=rank)
+        expert_rank=rank, topk_norm_eps=0.0)
 
 
 def _case(cfg, tokens, seed=0, layers=None):

@@ -352,6 +352,7 @@ def test_plain_members_keep_their_scale_and_table():
 
 class _Routing:
     scoring, top_k, norm_topk_prob, routed_scale = "sigmoid", 4, True, 2.0
+    topk_norm_eps = 0.0
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],

@@ -74,13 +74,14 @@ def _operands(seed, B, g_len, int8, num_pages, page, hkv, hq, head_dim):
     return q, k_pages, v_pages, k_new, v_new, scales
 
 
-def _scenario(cache_lens, g_len=1, int8=False, head_dim=D, seed=0, hq=HQ):
+def _scenario(cache_lens, g_len=1, int8=False, head_dim=D, seed=0, hq=HQ,
+              hkv=HKV):
     """Pool leaves + a page table covering each slot's cache_len (pages
     allocated bottom-up, page NUM_PAGES-1 deliberately never used — it
     is the kernel's clamp target for sentinel entries)."""
     B = len(cache_lens)
     q, k_pages, v_pages, k_new, v_new, scales = _operands(
-        seed, B, g_len, int8, NUM_PAGES, PAGE, HKV, hq, head_dim)
+        seed, B, g_len, int8, NUM_PAGES, PAGE, hkv, hq, head_dim)
     table = np.full((B, P), SENTINEL, np.int32)
     nxt = 0
     for b, n in enumerate(cache_lens):
@@ -121,13 +122,24 @@ ROWS_A_HEAD = pytest.mark.parametrize("hq", [HQ, 16 * HKV],
                                       ids=["2-rows", "16-rows"])
 
 
-@ROWS_A_HEAD
-def test_decode_identity_vs_gather_fill_patterns(hq):
-    assert walk_sizes(PAGE, HKV, D, hq, 2, P).product_heads == (
-        HKV if hq == HQ else 1)
-    args, _, _ = _scenario(FILLS, hq=hq)
+@pytest.mark.parametrize("hq,hkv,head_dim", [
+    (HQ, HKV, D), (16 * HKV, HKV, D), (32, 8, 64), (8, 2, 64)],
+    ids=["2-rows", "16-rows", "packed-64-gqa-32:8", "packed-64-gqa-8:2"])
+def test_decode_identity_vs_gather_fill_patterns(hq, hkv, head_dim):
+    """bf16 pools; heads of 64 packed, a token's heads side by side in
+    one row (``(L, num_pages, page, Hkv * 64)``), which the kernel walks
+    two heads a 128-lane unit."""
+    args, _, _ = _scenario(FILLS, hq=hq, hkv=hkv, head_dim=head_dim)
     oracle = paged_decode_attention(*args)
-    out = ragged_paged_decode_attention(*_stacked(args)[0])
+    kernel_args = _stacked(args)[0]
+    if head_dim == D:
+        assert walk_sizes(PAGE, HKV, D, hq, 2, P).product_heads == (
+            HKV if hq == HQ else 1)
+    else:
+        q, k_pages, v_pages, *rest = kernel_args
+        kernel_args = (q, *(pages.reshape(*pages.shape[:3], -1)
+                            for pages in (k_pages, v_pages)), *rest)
+    out = ragged_paged_decode_attention(*kernel_args)
     assert out.dtype == oracle.dtype
     assert bool((out == oracle).all())
 
@@ -502,6 +514,12 @@ def test_ragged_tileable_predicate():
     assert ragged_tileable(128, 32, 8, 32)                   # GQA 32:8
     assert ragged_tileable(128, 8, 2, 16)
     assert not ragged_tileable(64, 8, 2, 16)                 # hd % 128
+    # heads of 64 in bf16 pairs, where the pool keeps them packed
+    assert ragged_tileable(64, 8, 2, 16, packed=True)
+    assert ragged_tileable(64, 32, 8, 32, packed=True)
+    assert not ragged_tileable(64, 8, 1, 16, packed=True)    # no pair
+    assert not ragged_tileable(64, 32, 8, 32, kv_itemsize=1, packed=True)
+    assert not ragged_tileable(96, 8, 2, 16, packed=True)    # hd % 128
     assert not ragged_tileable(128, 4, 2, 16)                # hq % 8
     assert not ragged_tileable(128, 8, 2, 8)                 # page % 16
     assert not ragged_tileable(128, 8, 3, 16)                # hq % hkv
@@ -623,6 +641,27 @@ def test_engine_int8_identity(setup):
         _make_engine(cfg8, params, paged_kv=True, kv_page=4,
                      ragged_attn="on")[0], prompts, budget=4))
     assert ragged == gather
+
+
+def test_auto_keeps_heads_of_64_in_an_unpacked_pool_off_the_kernel(
+        monkeypatch):
+    """llama's ``small`` geometry (heads of 64, 16:16) keeps a head's 64
+    lanes as a row of its pool: on a TPU, auto takes the gather path,
+    since only a packed pool's heads of 64 are walked by the kernel."""
+    import dataclasses
+    import types
+
+    cfg = dataclasses.replace(llama.config("small"), n_layers=1,
+                              vocab_size=256, ffn_dim=256)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    engine, _ = _make_engine(cfg, params, paged_kv=True, kv_page=16,
+                             ragged_attn="auto", prompt_buckets=(16,))
+    assert engine._pool.kinds["kv"].leaves["k"][0] == (16, 64)
+    monkeypatch.setattr(engine._jax, "devices", lambda: [
+        types.SimpleNamespace(platform="tpu")])
+    ragged, why = engine._resolve_ragged()
+    assert not ragged and "packed pool" in why
+    assert ragged_tileable(cfg.head_dim, 16, 16, 16, packed=True)
 
 
 def test_ragged_attn_knob_validation(setup):
